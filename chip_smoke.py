@@ -1,0 +1,563 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the three main paths once, in ONE process, through the entry
+points a user calls, at published widths, on seeded random weights:
+
+  executor_resnet50   models/resnet.build_train_program -> fluid.Executor
+  bert_base_step      models/bert.build_pretrain_step (flash kernels)
+  generation_engine   serving.AutoregressiveEngine over a LayeredDecoder
+
+    python chip_smoke.py            # one chip (what the driver runs)
+    python chip_smoke.py --chips 4  # the four-chip host: data-parallel
+                                    # ResNet-50, dp 2 x mp 2 BERT-base
+
+It has no CPU mode: without a TPU whose device_kind is in the peak
+table (obs/cost.py) it exits non-zero before any phase and prints no
+result.  Every phase prints what it checked, its compile seconds and
+its wall seconds; a failed check or an exception ends the run
+non-zero.  It starts no child process (a chip belongs to one process).
+The phase functions take sizes, so tests/test_chip_smoke.py calls them
+tiny on the CPU with `platform="cpu"`.
+
+It measures nothing: no step time, rate or utilization is printed.
+The last line of stdout is one JSON object,
+{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import re
+import sys
+import time
+
+import numpy as np
+
+SEED = 20260926
+
+
+class SmokeFailure(Exception):
+    """A phase's check did not hold."""
+
+
+class _Phase:
+    """Collects what a phase checked; a check that fails raises."""
+
+    def __init__(self, name: str):
+        self.name = name
+        self.checked = []
+        self.compile_s = 0.0
+        self.info = {}
+        self._t0 = time.perf_counter()
+        print(f"chip_smoke: [{name}] start", flush=True)
+
+    def check(self, ok: bool, what: str) -> None:
+        if not ok:
+            raise SmokeFailure(f"[{self.name}] FAILED: {what}")
+        self.checked.append(what)
+
+    def done(self) -> dict:
+        wall_s = time.perf_counter() - self._t0
+        print(f"chip_smoke: [{self.name}] passed — compile "
+              f"{self.compile_s:.1f}s, wall {wall_s:.1f}s")
+        for what in self.checked:
+            print(f"chip_smoke:   ok: {what}")
+        for k, v in self.info.items():
+            print(f"chip_smoke:   {k}: {v}")
+        sys.stdout.flush()
+        return {"phase": self.name, "compile_s": round(self.compile_s, 1),
+                "wall_s": round(wall_s, 1), **self.info}
+
+
+def _stats():
+    from paddle_tpu import profiler
+
+    return profiler.get_int_stats()
+
+
+def _fallback_counts() -> dict:
+    s = _stats()
+    return {k: s.get(k, 0) for k in ("flash_fallback_total",
+                                     "serving_ragged_fallback_total")}
+
+
+def _device_platforms(arr) -> set:
+    return {d.platform for d in arr.devices()}
+
+
+def require_chip(chips: int):
+    """The device JAX found, or exit: JAX itself falls back to the CPU
+    without a chip and exits 0."""
+    import jax
+
+    try:
+        from paddle_tpu.obs import cost
+    except ModuleNotFoundError as e:
+        if e.name != "paddle_tpu":
+            raise
+        raise SystemExit("chip_smoke: no paddle_tpu package beside this "
+                         "script — it drives the repo's own entry points "
+                         "and proves nothing alone")
+
+    devs = jax.devices()
+    d = devs[0]
+    print(f"chip_smoke: platform={d.platform} "
+          f"device_kind={d.device_kind!r} count={len(devs)}", flush=True)
+    try:
+        cost.require_chip()
+    except RuntimeError as e:
+        raise SystemExit(f"chip_smoke: {e}; this script has no CPU mode")
+    if len(devs) < chips:
+        raise SystemExit(
+            f"chip_smoke: --chips {chips} needs {chips} devices, JAX "
+            f"reports {len(devs)}")
+    return d, len(devs)
+
+
+# ---------------------------------------------------------------------------
+# phase 1: Program -> transforms -> verifier -> Executor
+# ---------------------------------------------------------------------------
+
+def executor_resnet50(batch, steps=5, *, depth=50, class_num=1000,
+                      image_shape=(3, 224, 224), width=64,
+                      platform="tpu", data_parallel=0):
+    """ResNet train steps through `fluid.Executor` with default flags.
+    `data_parallel=N` runs the same program through
+    `CompiledProgram.with_data_parallel` on a {data: N} mesh and checks
+    its first loss against a one-device forward pass of the same global
+    batch."""
+    import paddle_tpu
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu import profiler
+    from paddle_tpu.fluid import unique_name
+    from paddle_tpu.fluid.executor import Scope, scope_guard
+    from paddle_tpu.models import resnet
+    from paddle_tpu.parallel import mesh as mesh_lib
+
+    ph = _Phase("executor_resnet50" + (f"_dp{data_parallel}"
+                                       if data_parallel else ""))
+    paddle_tpu.seed(SEED)
+    net = dict(depth=depth, class_num=class_num, width=width)
+    with unique_name.guard():
+        main, startup, _, (avg_loss, acc) = resnet.build_train_program(
+            image_shape=image_shape, batch_size=batch, **net)
+    rng = np.random.RandomState(SEED)
+    feed = {"image": rng.randn(batch, *image_shape).astype(np.float32),
+            "label": rng.randint(0, class_num, (batch, 1)).astype(np.int64)}
+    place = fluid.TPUPlace(0) if platform == "tpu" else fluid.CPUPlace()
+    scope = Scope()
+    t_compile0 = profiler.get_time_stats().get("compile_ms", 0.0)
+    try:
+        with scope_guard(scope):
+            exe = fluid.Executor(place)
+            c0 = _stats().get("executor_compile_count", 0)
+            exe.run(startup)
+            if data_parallel:
+                run_target, ref_loss = _data_parallel_target(
+                    ph, exe, main, avg_loss, feed, data_parallel, net)
+            else:
+                run_target = main
+            losses = []
+            for i in range(steps):
+                loss_v, acc_v = exe.run(run_target, feed=feed,
+                                        fetch_list=[avg_loss, acc])
+                losses.append(float(np.asarray(loss_v).reshape(-1)[0]))
+                if i == 0:
+                    c1 = _stats().get("executor_compile_count", 0)
+            c2 = _stats().get("executor_compile_count", 0)
+            ph.info["batch"] = batch
+            ph.info["losses"] = [round(v, 4) for v in losses]
+            ph.check(all(math.isfinite(v) for v in losses),
+                     f"{steps} losses finite")
+            ph.check(0.0 <= float(np.asarray(acc_v).reshape(-1)[0]) <= 1.0,
+                     "accuracy in [0, 1]")
+            ph.check(len({round(v, 5) for v in losses}) > 1,
+                     "loss moves between steps")
+            if data_parallel:
+                ph.check(abs(losses[0] - ref_loss)
+                         <= 2e-2 * max(1.0, abs(ref_loss)),
+                         f"first loss {losses[0]:.4f} matches the "
+                         f"one-device forward of the same global batch "
+                         f"{ref_loss:.4f} within bf16 tolerance")
+            else:
+                ph.check(c1 - c0 == 2 and c2 == c1,
+                         "startup and main compiled once each, steps "
+                         "2..N compiled nothing (executor_compile_count)")
+
+            # dispatch-ahead loop: lazy fetches, no device->host sync
+            s0 = _stats().get("executor_sync_count", 0)
+            for _ in range(2):
+                lazy = exe.run(run_target, feed=feed,
+                               fetch_list=[avg_loss, acc],
+                               return_numpy=False)
+            ph.check(_stats().get("executor_sync_count", 0) == s0,
+                     "a return_numpy=False loop adds no sync")
+            fetched = lazy[0].jax()
+            fetched.block_until_ready()
+            param = next(v for v in main.list_vars()
+                         if v.persistable and scope.has(v.name)
+                         and hasattr(scope.get(v.name), "devices"))
+            pval = scope.get(param.name)
+            ph.check(_device_platforms(fetched) == {platform}
+                     and _device_platforms(pval) == {platform},
+                     f"fetched loss and parameter {param.name!r} sit on "
+                     f"platform {platform!r}")
+            if data_parallel:
+                _check_all_devices_hold(ph, pval, data_parallel,
+                                        f"parameter {param.name!r}")
+            exe.close()
+    finally:
+        mesh_lib.set_current_mesh(None)
+    ph.compile_s = (profiler.get_time_stats().get("compile_ms", 0.0)
+                    - t_compile0) / 1e3
+    s = _stats()
+    ph.info["aot_cache"] = {k: s.get(f"aot_cache_{k}", 0)
+                            for k in ("hits", "misses", "stores",
+                                      "errors")}
+    return ph.done()
+
+
+def _data_parallel_target(ph, exe, main, avg_loss, feed, n, net):
+    """The {data: n} CompiledProgram for `main`, plus the reference
+    first loss: a forward-only twin of the program (same parameter
+    names, same scope, train-mode batch norm) run on ONE device over
+    the same global batch — the full train step at n x the one-chip
+    batch does not fit one chip, its forward pass does."""
+    import jax
+
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import unique_name
+    from paddle_tpu.models import resnet
+    from paddle_tpu.parallel.compiler import BuildStrategy
+
+    with unique_name.guard():
+        fwd_main, fwd_startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(fwd_main, fwd_startup):
+            img = fluid.data("image", list(feed["image"].shape),
+                             "float32")
+            label = fluid.data("label", list(feed["label"].shape),
+                               "int64")
+            pred = resnet.resnet(img, **net)
+            fwd_loss = fluid.layers.mean(
+                fluid.layers.loss.cross_entropy(pred, label))
+    (ref,) = exe.run(fwd_main, feed=feed, fetch_list=[fwd_loss])
+    ref_loss = float(np.asarray(ref).reshape(-1)[0])
+
+    bs = BuildStrategy()
+    bs.mesh_axes = {"data": n}
+    compiled = fluid.CompiledProgram(main).with_data_parallel(
+        loss_name=avg_loss.name, build_strategy=bs,
+        places=fluid.tpu_places(list(range(n))))
+    mesh = compiled._mesh
+    ph.check(mesh.devices.size == n
+             and all(isinstance(d, jax.Device)
+                     for d in mesh.devices.flat),
+             f"with_data_parallel(places=tpu_places()) built a mesh of "
+             f"{n} jax devices")
+    return compiled, ref_loss
+
+
+def _check_all_devices_hold(ph, arr, n, what):
+    devs = {s.device for s in arr.addressable_shards}
+    ph.check(len(devs) == n, f"{what} has shards on all {n} devices")
+    stats = [d.memory_stats() for d in sorted(devs, key=lambda d: d.id)]
+    if all(s is not None for s in stats):
+        ph.check(all(s.get("bytes_in_use", 0) > 0 for s in stats),
+                 f"memory_stats() shows bytes in use on all {n} devices")
+
+
+# ---------------------------------------------------------------------------
+# phase 2: the functional BERT pretrain step with the flash kernels
+# ---------------------------------------------------------------------------
+
+def bert_base_step(cfg, batch=32, seq=512, n_masked=76, steps=3, *,
+                   platform="tpu", mesh_shape=None):
+    """`bert.build_pretrain_step(bf16=True)`: dropout 0.1, key-padding
+    mask.  `mesh_shape=(dp, mp)` runs the same step sharded and checks
+    its first loss against the one-device step."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu
+    from paddle_tpu.models import bert
+    from paddle_tpu.ops.pallas import attention as att
+
+    ph = _Phase("bert_base_step" + (
+        "_dp%dxmp%d" % tuple(mesh_shape) if mesh_shape else ""))
+    b = bert.fake_batch(cfg, batch, seq, num_masked=n_masked, seed=SEED)
+    lr = jnp.float32(1e-4)
+
+    def run(mesh, n_steps):
+        paddle_tpu.seed(SEED)
+        model = bert.BertForPretraining(cfg)
+        kw = dict(mesh=mesh, dp_axis="dp", mp_axis="mp") if mesh else {}
+        step, state = bert.build_pretrain_step(model, bf16=True, **kw)
+        t0 = time.perf_counter()
+        compiled = step.lower(state, b, lr).compile()
+        ph.compile_s += time.perf_counter() - t0
+        losses = []
+        for _ in range(n_steps):
+            state, loss = compiled(state, b, lr)
+            losses.append(float(loss))
+        return compiled, state, losses
+
+    # under a mesh the one-device step only supplies the reference loss
+    compiled, state, losses = run(None, 1 if mesh_shape else steps)
+    if mesh_shape:
+        from jax.sharding import Mesh
+
+        n = mesh_shape[0] * mesh_shape[1]
+        mesh = Mesh(np.array(jax.devices()[:n]).reshape(mesh_shape),
+                    ("dp", "mp"))
+        ref_loss = losses[0]
+        del compiled, state  # free the one-device step's HBM first
+        compiled, state, losses = run(mesh, steps)
+        ph.check(abs(losses[0] - ref_loss) <= 2e-2 * abs(ref_loss),
+                 f"first loss {losses[0]:.4f} matches the one-device "
+                 f"step {ref_loss:.4f} within bf16 tolerance")
+        w = state["params"]["bert.encoder.layers.0.linear1.weight"]
+        _check_all_devices_hold(ph, w, n, "the column-parallel FFN weight")
+        ph.check(w.addressable_shards[0].data.shape[1]
+                 == w.shape[1] // mesh_shape[1],
+                 "the FFN weight is split over the mp axis")
+    ph.info["losses"] = [round(v, 4) for v in losses]
+    expect = math.log(cfg.vocab_size) + math.log(2.0)
+    ph.check(all(math.isfinite(v) for v in losses),
+             f"{len(losses)} losses finite")
+    ph.check(abs(losses[0] - expect) < 1.0,
+             f"first loss {losses[0]:.3f} near ln(vocab) + ln 2 = "
+             f"{expect:.3f}")
+    ph.check(losses[-1] < losses[0], "loss falls on the repeated batch")
+    ph.check(_device_platforms(state["params"][
+        "bert.embeddings.word_embeddings.weight"]) == {platform},
+        f"parameters sit on platform {platform!r}")
+    if platform == "tpu":
+        layers = cfg.num_hidden_layers
+        # the kernel is named by the call's op_name metadata (operand
+        # names repeat the forward kernel's name in backward calls)
+        ops = [m.group(1) for m in re.finditer(
+            r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"',
+            compiled.as_text())]
+        fwd = sum("_flash_forward" in op for op in ops)
+        bwd = sum("_flash_backward" in op for op in ops)
+        ph.check(fwd >= layers and bwd >= 2 * layers,
+                 f"flash kernels in the executable: {fwd} forward and "
+                 f"{bwd} backward Mosaic calls for {layers} layers")
+        ph.info["flash_rungs"] = sorted(
+            {(k[6], k[7], k[8]) for k, ok in
+             att._EXACT_PROBE_CACHE.items() if ok})
+        ph.check(_fallback_counts()["flash_fallback_total"] == 0,
+                 "flash_fallback_total == 0 (no give-way to "
+                 "_xla_attention)")
+    return ph.done()
+
+
+# ---------------------------------------------------------------------------
+# phase 3: continuous-batching generation over the paged KV cache
+# ---------------------------------------------------------------------------
+
+def build_decoder(vocab, d_model, n_head, d_ff, n_layer, max_len,
+                  dtype=None):
+    """A pre-LN decoder-only stack as a `serving.LayeredDecoder`, from
+    seeded random weights (tied embedding, sinusoid positions)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu import serving
+    from paddle_tpu.models.transformer_wmt import \
+        sinusoid_position_encoding
+
+    dtype = dtype or jnp.bfloat16
+    hd = d_model // n_head
+    rng = np.random.RandomState(SEED)
+
+    def w(*shape):
+        return jnp.asarray(rng.randn(*shape) / math.sqrt(shape[0]), dtype)
+
+    emb = jnp.asarray(rng.randn(vocab, d_model), dtype)
+    pos_enc = jnp.asarray(sinusoid_position_encoding(max_len, d_model),
+                          dtype)
+
+    def norm(x):
+        x32 = x.astype(jnp.float32)
+        mu = x32.mean(-1, keepdims=True)
+        var = jnp.square(x32 - mu).mean(-1, keepdims=True)
+        return ((x32 - mu) * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype)
+
+    def make_layer():
+        wq, wk, wv, wo = (w(d_model, d_model) for _ in range(4))
+        w1, w2 = w(d_model, d_ff), w(d_ff, d_model)
+
+        def qkv(x, positions):
+            h = norm(x)
+            split = lambda y: y.reshape(y.shape[:2] + (n_head, hd))
+            return split(h @ wq), split(h @ wk), split(h @ wv)
+
+        def merge(x, attn):
+            x = x + attn.reshape(attn.shape[:2] + (d_model,)) @ wo
+            return x + jax.nn.relu(norm(x) @ w1) @ w2
+
+        return qkv, merge
+
+    def embed(tokens, positions):
+        return emb[tokens] * math.sqrt(d_model) + pos_enc[positions]
+
+    def unembed(x):
+        return jnp.dot(norm(x), emb.T,
+                       preferred_element_type=jnp.float32)
+
+    return serving.LayeredDecoder(
+        embed, [make_layer() for _ in range(n_layer)], unembed)
+
+
+def _paged_parity(ph, b, t, heads, head_dim, page_size, pages_per_seq,
+                  dtype, interpret):
+    """The ragged kernel against the dense gather, on the engine's own
+    shapes (on the CPU: the kernel in interpret mode)."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import attention as att
+
+    rng = np.random.RandomState(SEED + t)
+    n_pages = b * pages_per_seq + 1
+    q = jnp.asarray(rng.randn(b, t, heads, head_dim), dtype)
+    kp = jnp.asarray(rng.randn(n_pages, page_size, heads, head_dim), dtype)
+    vp = jnp.asarray(rng.randn(n_pages, page_size, heads, head_dim), dtype)
+    rows = jnp.asarray(1 + np.arange(b * pages_per_seq).reshape(
+        b, pages_per_seq), jnp.int32)
+    lens = jnp.asarray(rng.randint(t, page_size * pages_per_seq + 1,
+                                   (b,)), jnp.int32)
+    qpos = lens[:, None] - t + jnp.arange(t, dtype=jnp.int32)[None, :]
+    out = att.paged_attention(q, kp, vp, rows, lens,
+                              interpret=interpret)
+    ref = att._dense_paged_attention(q, kp, vp, rows, lens, qpos,
+                                     1.0 / math.sqrt(head_dim))
+    err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                - ref.astype(jnp.float32))))
+    ph.check(err < 5e-2, f"ragged kernel matches the dense gather at "
+                         f"B={b} T={t} (max err {err:.1e})")
+
+
+def generation_engine(vocab=30000, d_model=512, n_head=8, d_ff=2048,
+                      n_layer=6, *, slots=8, prompt_lens=(
+                          9, 300, 40, 17, 100, 64, 9, 25),
+                      new_tokens=32, prompt_buckets=(32, 64, 128),
+                      platform="tpu"):
+    """`serving.AutoregressiveEngine(model=LayeredDecoder(...))`, bf16
+    pool, default page size: start(), submit() mixed prompts (one
+    longer than prefill_chunk, so it prefills in chunks between decode
+    steps), result() on all."""
+    import jax.numpy as jnp
+
+    from paddle_tpu import profiler, serving
+
+    ph = _Phase("generation_engine")
+    hd = d_model // n_head
+    page = 16  # the engine's default page_size
+    longest = max(prompt_lens) + new_tokens
+    pages_per_seq = -(-longest // page) + 1
+    model = build_decoder(vocab, d_model, n_head, d_ff, n_layer,
+                          max_len=longest + 1)
+    eng = serving.AutoregressiveEngine(
+        model=model, num_heads=n_head, head_dim=hd,
+        num_pages=slots * pages_per_seq + 1, max_slots=slots,
+        max_pages_per_seq=pages_per_seq, max_queue=len(prompt_lens),
+        prompt_buckets=prompt_buckets, dtype=jnp.bfloat16)
+    ph.check(eng.kv.page_size == page and eng.kv.k.dtype == jnp.bfloat16,
+             "bf16 pool at the engine's default page size")
+    ph.check(max(prompt_lens) > eng.prefill_chunk,
+             f"one prompt ({max(prompt_lens)}) is longer than "
+             f"prefill_chunk ({eng.prefill_chunk})")
+    rng = np.random.RandomState(SEED)
+    prompts = [rng.randint(0, vocab, (n,)).astype(np.int32)
+               for n in prompt_lens]
+    # two requests share one prompt: each slot's result must not depend
+    # on what is batched beside it
+    twins = [i for i, n in enumerate(prompt_lens)
+             if n == prompt_lens[0]][:2]
+    prompts[twins[-1]] = prompts[twins[0]]
+    s0 = _stats()
+    t_compile0 = profiler.get_time_stats().get("serving_compile_ms", 0.0)
+    eng.start()
+    try:
+        reqs = [eng.submit(p, max_new_tokens=new_tokens) for p in prompts]
+        outs = [r.result(timeout=900) for r in reqs]
+    finally:
+        eng.shutdown(drain=False)
+    s1 = _stats()
+    ph.compile_s = (profiler.get_time_stats().get(
+        "serving_compile_ms", 0.0) - t_compile0) / 1e3
+
+    def moved(name):
+        return s1.get(name, 0) - s0.get(name, 0)
+
+    ph.check(all(o.shape == (new_tokens,) and o.dtype == np.int32
+                 and 0 <= o.min() and o.max() < vocab for o in outs),
+             f"all {len(outs)} requests returned {new_tokens} tokens "
+             "inside the vocabulary")
+    if len(twins) == 2:
+        ph.check(np.array_equal(outs[twins[0]], outs[twins[1]]),
+                 "two requests with one prompt generated the same tokens")
+    ph.check(moved("serving_completed_total") == len(outs),
+             f"serving_completed_total moved by {len(outs)}")
+    ph.check(moved("serving_prefill_chunks") >= 2,
+             f"serving_prefill_chunks >= 2 "
+             f"({moved('serving_prefill_chunks')})")
+    ph.check(moved("executor_sync_count") == len(outs),
+             "one device->host sync per retired request")
+    ph.info["decode_steps"] = moved("serving_decode_steps")
+    interpret = platform != "tpu"
+    _paged_parity(ph, slots, 1, n_head, hd, page, pages_per_seq,
+                  jnp.bfloat16, interpret)
+    _paged_parity(ph, 1, eng.prefill_chunk, n_head, hd, page,
+                  pages_per_seq, jnp.bfloat16, interpret)
+    if platform == "tpu":
+        for k, v in _fallback_counts().items():
+            ph.check(v == 0, f"{k} == 0")
+    return ph.done()
+
+
+# ---------------------------------------------------------------------------
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: data-parallel ResNet-50 and dp 2 x mp 2 "
+                    "BERT-base on the four-chip host")
+    args = ap.parse_args(argv)
+
+    t0 = time.perf_counter()
+    device, count = require_chip(args.chips)
+
+    from paddle_tpu.fluid.compile_cache import enable_persistent_cache
+    from paddle_tpu.models import bert
+
+    print(f"chip_smoke: compile cache at {enable_persistent_cache()}")
+    phases = []
+    if args.chips == 1:
+        # fp32 Program at the batch of the one on-chip record: the v5e
+        # compiler places it in 9.3 of 16 GB (0.3 arguments + 9.0 temp)
+        phases.append(executor_resnet50(128))
+        phases.append(bert_base_step(bert.BertConfig.base()))
+        phases.append(generation_engine())
+    else:
+        phases.append(executor_resnet50(4 * 128, data_parallel=4))
+        phases.append(bert_base_step(bert.BertConfig.base(),
+                                     mesh_shape=(2, 2)))
+    fallbacks = _fallback_counts()
+    print(f"chip_smoke: all {len(phases)} phases passed in "
+          f"{time.perf_counter() - t0:.0f}s; {fallbacks}; "
+          f"compile seconds {[p['compile_s'] for p in phases]}")
+    if any(fallbacks.values()):
+        raise SmokeFailure(f"a kernel gave way: {fallbacks}")
+    print(json.dumps({"ok": True, "device": {
+        "platform": device.platform, "kind": device.device_kind,
+        "count": count}}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -11,6 +11,10 @@ env when present, else --ips/localhost), exports the PADDLE_* +
 coordinator env to each local worker, spawns them, and propagates the
 first failure.  There is no PS mode: parameter-server strategies are out
 of TPU scope (SURVEY.md §2.9 #13-15); collective is the only mode.
+
+One process per host owns all of that host's chips (a chip belongs to
+one process at a time), so on a TPU host `--nproc_per_node` above 1 is
+refused; shard over the local chips with a mesh inside the one worker.
 """
 
 from __future__ import annotations
@@ -20,8 +24,8 @@ import os
 import sys
 
 from .launch_utils import (find_free_ports, get_cluster,
-                           get_cluster_from_tpu_env, start_local_trainers,
-                           watch_local_trainers)
+                           get_cluster_from_tpu_env, on_tpu_host,
+                           start_local_trainers, watch_local_trainers)
 
 
 def _parse_args(argv=None):
@@ -32,8 +36,10 @@ def _parse_args(argv=None):
     p.add_argument("--node_ip", type=str, default=None,
                    help="this node's ip (default: first of --ips)")
     p.add_argument("--nproc_per_node", type=int, default=None,
-                   help="worker processes per node (default: 1 — a JAX "
-                        "process owns all local chips)")
+                   help="worker processes per node (default: 1 — one "
+                        "process per host owns all its chips; above 1 "
+                        "is refused on a TPU host and meant for "
+                        "CPU-mesh testing)")
     p.add_argument("--started_port", type=int, default=None)
     p.add_argument("--log_dir", type=str, default=None)
     p.add_argument("training_script", type=str)
@@ -43,6 +49,15 @@ def _parse_args(argv=None):
 
 def launch_collective(args):
     nproc = args.nproc_per_node or 1
+    if nproc > 1 and on_tpu_host():
+        sys.exit(
+            f"paddle_tpu.distributed.launch: --nproc_per_node {nproc} "
+            "refused on a TPU host: one process per host owns all its "
+            "chips, and a chip belongs to one process at a time, so "
+            f"{nproc} workers would each claim every chip and fail or "
+            "hang.  Start one worker per host (the default) and shard "
+            "over its chips with a mesh; set JAX_PLATFORMS=cpu to run "
+            "several workers on the CPU mesh.")
     topo = get_cluster_from_tpu_env(nproc)
     if topo is not None:
         cluster, pod = topo

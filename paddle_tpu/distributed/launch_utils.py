@@ -7,8 +7,10 @@ trainers).  Differences by design:
 
 * One worker PROCESS per host is the JAX model (a process owns all local
   chips through one runtime), not one process per device like the
-  reference's one-proc-per-GPU — `nproc_per_node` stays configurable for
-  CPU-mesh testing and host-parallel ingestion.
+  reference's one-proc-per-GPU.  A chip belongs to one process at a
+  time, so `nproc_per_node > 1` on a TPU host — where every worker
+  would claim every chip — is refused (`on_tpu_host`); it stays
+  available for CPU-mesh testing and host-parallel ingestion.
 * Rendezvous is `jax.distributed.initialize` against a coordinator
   address (the rank-0 endpoint) instead of gloo HTTP stores +
   `c_gen_nccl_id` broadcast: the JAX coordination service replaces both.
@@ -19,6 +21,7 @@ trainers).  Differences by design:
 
 from __future__ import annotations
 
+import glob
 import os
 import signal
 import socket
@@ -57,6 +60,37 @@ class Cluster:
 
     def coordinator(self) -> str:
         return self.endpoints()[0]
+
+
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+
+
+def _vfio_group_vendors(group: str) -> List[str]:
+    """PCI vendor ids of the devices behind /dev/vfio/<group>."""
+    vendors = []
+    for path in glob.glob(
+            f"/sys/kernel/iommu_groups/{group}/devices/*/vendor"):
+        with open(path) as f:
+            vendors.append(f.read().strip())
+    return vendors
+
+
+def on_tpu_host() -> bool:
+    """Whether workers started here would find TPU chips: JAX is not
+    pinned to another platform and the host exposes TPU device nodes —
+    `/dev/accel<N>` (the TPU driver's own nodes; the kernel's generic
+    accelerators sit under `/dev/accel/`), or a VFIO group holding a
+    Google PCI device (any passthrough host has `/dev/vfio/<N>`, so the
+    node alone says nothing).  Decided without touching JAX — a
+    launcher that initialised the runtime would itself hold the chips
+    its worker needs."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    if glob.glob("/dev/accel[0-9]*"):
+        return True
+    return any(_GOOGLE_PCI_VENDOR in _vfio_group_vendors(
+        os.path.basename(node)) for node in glob.glob("/dev/vfio/[0-9]*"))
 
 
 def find_free_ports(n: int) -> List[int]:

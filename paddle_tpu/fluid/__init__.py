@@ -30,13 +30,20 @@ class CPUPlace:
 
 class TPUPlace:
     """TPU device identity — the new first-class Place the north star asks
-    for (BASELINE.json).  device_id indexes jax.devices()."""
+    for (BASELINE.json).  device_id indexes jax.devices() (tpu_places(),
+    mesh building); `Executor(TPUPlace(i))` reads the platform only — it
+    runs on JAX's default device and raises when that is not a TPU."""
 
     def __init__(self, device_id: int = 0):
         self.device_id = device_id
 
     def __repr__(self):
         return f"TPUPlace({self.device_id})"
+
+    def jax_device(self):
+        import jax
+
+        return jax.devices()[self.device_id]
 
 
 # CUDAPlace name kept as an alias so reference scripts run unchanged: on

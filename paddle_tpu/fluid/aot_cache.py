@@ -51,7 +51,7 @@ from typing import Any, Dict, Optional, Tuple
 
 # bump when the on-disk layout or the executor entry metadata changes:
 # old entries become drift misses, never misloads
-SCHEMA = 1
+SCHEMA = 2
 
 _TMP_IDS = itertools.count()
 
@@ -217,10 +217,18 @@ def try_load(stable: str, label: str = "",
                 return None, None
             with open(os.path.join(path, "exec.bin"), "rb") as f:
                 payload, in_tree, out_tree = pickle.load(f)
+            import jax
             from jax.experimental.serialize_executable import \
                 deserialize_and_load
 
-            compiled = deserialize_and_load(payload, in_tree, out_tree)
+            # the entry's own devices, in assignment order: without
+            # them the loaded executable expects one shard per local
+            # device and dies on its first dispatch on any host with
+            # more than one
+            by_id = {d.id: d for d in jax.devices()}
+            compiled = deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in meta["device_ids"]])
     except Exception:  # noqa: BLE001 - corrupt/truncated entry: counted miss
         stat_add("aot_cache_errors")
         stat_add("aot_cache_misses")
@@ -253,6 +261,8 @@ def try_store(stable: str, compiled, label: str = "",
             payload, in_tree, out_tree = serialize(compiled)
             blob = pickle.dumps((payload, in_tree, out_tree),
                                 protocol=pickle.HIGHEST_PROTOCOL)
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
     except Exception:  # noqa: BLE001 - backend refused: recorded miss
         stat_add("aot_cache_store_unsupported")
         return False
@@ -261,6 +271,7 @@ def try_store(stable: str, compiled, label: str = "",
         "label": str(label),
         "stable": stable,
         "volatile": vol,
+        "device_ids": device_ids,
         "payload_bytes": len(blob),
         "extra": _canon(extra_meta or {}),
     }

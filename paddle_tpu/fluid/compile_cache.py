@@ -18,8 +18,49 @@ uncontended there and costs one atomic acquire per step.
 from __future__ import annotations
 
 import collections
+import os
 import threading
-from typing import Any, Callable, Iterator, Optional
+from typing import Any, Callable, Iterator, Optional, Tuple
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def persistent_cache_dirs() -> Tuple[str, str]:
+    """Where compiled code persists across processes: `(jax_dir,
+    aot_dir)` — JAX's persistent compilation cache and the default of
+    FLAGS_aot_cache_dir (fluid/aot_cache.py).
+
+    The rule: if `JAX_COMPILATION_CACHE_DIR` is set, JAX's cache lives
+    there (JAX reads the variable itself; code sets no other
+    directory) and the AOT cache is its `paddle_aot/` subdirectory.
+    If it is not set, they are `<checkout>/.jax_cache` and
+    `<checkout>/artifacts/aot_cache`, resolved from this package's own
+    location — never from the working directory, a temporary name, a
+    pid or the time: the path is part of the cache key, so a directory
+    that moves never hits.  `PADDLE_AOT_CACHE_DIR` overrides the AOT
+    half either way (fluid/flags.py)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env, os.path.join(env, "paddle_aot")
+    return (os.path.join(_CHECKOUT, ".jax_cache"),
+            os.path.join(_CHECKOUT, "artifacts", "aot_cache"))
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache under the rule of
+    `persistent_cache_dirs` (chip_smoke.py, bench.py; tools/ci.sh
+    exports the same directory to the processes it starts).  Returns
+    the directory in use."""
+    import jax
+
+    jax_dir, _ = persistent_cache_dirs()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", jax_dir)
+    # the serving engine stages many sub-second compiles; a restart
+    # should find those too
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return jax_dir
 
 
 class CompileCache:

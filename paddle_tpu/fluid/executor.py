@@ -827,6 +827,20 @@ class Executor:
     FEED_CACHE_MAX_BYTES = 8 << 20
 
     def __init__(self, place=None):
+        # A place names a PLATFORM, not a device: the Executor always
+        # runs on JAX's default device (or on the mesh of a
+        # CompiledProgram) and `device_id` places nothing.  place=None
+        # and CPUPlace accept whatever that device is (the CPU test
+        # mesh included); TPUPlace — and its alias CUDAPlace — requires
+        # the default device to be a TPU, because JAX itself falls back
+        # to the CPU without one and says nothing.
+        from . import TPUPlace
+
+        if isinstance(place, TPUPlace) and jax.default_backend() != "tpu":
+            raise RuntimeError(
+                f"Executor({place!r}): no TPU found — JAX's default "
+                f"device is {jax.devices()[0]}; pass no place to run "
+                "on it")
         self.place = place
         # shared bounded-LRU machinery (fluid/compile_cache.py), the
         # same class backing CompiledProgram and the serving engine's

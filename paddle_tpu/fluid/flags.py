@@ -14,6 +14,8 @@ from __future__ import annotations
 import os
 from typing import Any, Callable, Dict
 
+from .compile_cache import persistent_cache_dirs
+
 _REGISTRY: Dict[str, dict] = {}
 
 
@@ -183,9 +185,10 @@ _define("aot_cache", "on",
         "numerics, quant mode, jax/backend fingerprint — keys the "
         "entry, so drift is a hard miss, never a stale load)",
         env_var="PADDLE_AOT_CACHE")
-_define("aot_cache_dir", "artifacts/aot_cache",
+_define("aot_cache_dir", persistent_cache_dirs()[1],
         "root directory of the persistent AOT executable cache "
         "(entries commit via tmp-dir + os.replace, the ckpt idiom); "
+        "the default follows compile_cache.persistent_cache_dirs(); "
         "empty disables the cache like FLAGS_aot_cache='off'",
         env_var="PADDLE_AOT_CACHE_DIR")
 # -- self-tuning compile pipeline (paddle_tpu.tune, docs/autotune.md):

@@ -8,15 +8,6 @@ contract is (address, shape) in, (bytes, shape) out."""
 from __future__ import annotations
 
 import ctypes
-import os
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    # C hosts select the backend via env only; the env var alone doesn't
-    # beat the TPU plugin (see tests/fixtures/infer_loader.py) — both
-    # are needed, and this module is the ABI's Python entry point
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 

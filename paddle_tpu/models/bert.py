@@ -305,6 +305,7 @@ def build_pretrain_step(model: BertForPretraining,
             import contextlib
 
             from ..ops.pallas.attention import (ring_attention_scope,
+                                                sharded_attention_scope,
                                                 ulysses_attention_scope)
 
             ring_active = (use_ring_attention and mesh is not None
@@ -315,6 +316,10 @@ def build_pretrain_step(model: BertForPretraining,
                 sp_scope = ring_attention_scope(mesh, sp_axis)
             elif uly_active:
                 sp_scope = ulysses_attention_scope(mesh, sp_axis)
+            elif mesh is not None:
+                # GSPMD cannot partition the flash kernels: run them
+                # per (dp, mp) shard
+                sp_scope = sharded_attention_scope(mesh, dp_axis, mp_axis)
             else:
                 sp_scope = contextlib.nullcontext()
             am = b.get("attention_mask")

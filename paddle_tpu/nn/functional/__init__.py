@@ -718,8 +718,8 @@ def fused_feedforward(x, w1, b1, w2, b2, activation="gelu",
 
     Routes through paddle_tpu/ops/pallas/ffn.py (the reference's
     fused_feedforward_op.cu counterpart): by default an XLA path —
-    measured FASTER than the Pallas kernel on v5e (2026-07-31 A/B;
-    "noffn" arm in the git history of artifacts/dimsem_ab.json) — with
+    measured FASTER than the Pallas kernel on v5e (2026-07-31 A/B,
+    artifacts/FFN_AB_r19.md; not measured since) — with
     the VMEM-resident kernel opt-in
     via PADDLE_TPU_FUSED_FFN=1 for memory-limited configs.  Both use
     the SAME stateless hash dropout, so trajectories agree across

@@ -29,10 +29,13 @@ supplies both seams:
   quantized all-reduce lowering will shrink exactly this number — the
   assertion seam for the ROADMAP item.
 
-Peak numbers are per-chip (v5e bf16 197 TFLOP/s, ~819 GB/s HBM); the
-CPU fallbacks make the gauges meaningful (nonzero, test-assertable)
-off-chip without pretending to be chip numbers — `device_class` labels
-which regime produced them.
+Peaks live in ONE table keyed by jax `device_kind` (`DEVICE_PEAKS`),
+each with its source; a TPU kind that is not in the table raises
+instead of borrowing another chip's numbers.  Off-chip (the CPU test
+mesh) the gauges run against `HOST_NOMINAL`, a labelled placeholder
+that keeps them nonzero and assertable in tier-1; `device_class` and
+`peak_source` in the snapshot say which regime produced a number, and
+nothing computed against the placeholder is a device metric.
 """
 
 from __future__ import annotations
@@ -44,11 +47,18 @@ import time
 import warnings
 from typing import Any, Dict, List, Optional, Tuple
 
-# per-chip peaks (bench.py imports these — one definition, not two)
-TPU_V5E_PEAK_FLOPS = 197e12
-TPU_V5E_PEAK_HBM_BPS = 819e9
-CPU_PEAK_FLOPS = 2e11     # rough; only labels the cpu-fallback regime
-CPU_PEAK_HBM_BPS = 5e10
+# per-chip peaks by `jax.devices()[0].device_kind` (bench.py and
+# chip_smoke.py read this table — one definition)
+DEVICE_PEAKS: Dict[str, Dict[str, Any]] = {
+    "TPU v5 lite": {
+        "flops": 197e12, "hbm_bps": 819e9,
+        "source": "Google Cloud documentation, \"TPU v5e\": 197 "
+                  "TFLOP/s bf16, 819 GB/s HBM per chip"},
+}
+# NOT a device peak: keeps the live gauges nonzero on the CPU test mesh
+HOST_NOMINAL: Dict[str, Any] = {
+    "flops": 2e11, "hbm_bps": 5e10,
+    "source": "nominal host placeholder (tier-1 only, not measured)"}
 
 _COST_ENV = "PADDLE_OBS_COST"
 
@@ -59,38 +69,58 @@ def cost_capture_enabled() -> bool:
 
 
 def device_class() -> str:
-    """"tpu" on a real chip, else "cpu-fallback" — the label bench.py
-    stamps on BENCH JSON so persisted on-chip numbers are never
-    silently mixed with fallback numbers."""
-    try:
-        import jax
+    """"tpu" on a real chip, else "cpu-fallback" — the label stamped
+    beside every gauge so on-chip numbers are never mixed with
+    off-chip ones."""
+    import jax
 
-        return "tpu" if jax.default_backend() == "tpu" else "cpu-fallback"
-    except Exception:  # noqa: BLE001 - no jax: still a fallback regime
-        return "cpu-fallback"
+    return "tpu" if jax.default_backend() == "tpu" else "cpu-fallback"
 
 
-def peak_flops(cls: Optional[str] = None) -> float:
-    cls = cls or device_class()
-    return TPU_V5E_PEAK_FLOPS if cls == "tpu" else CPU_PEAK_FLOPS
+def require_chip():
+    """`(device, peak row)` of the first device when it is a TPU the
+    peak table knows; RuntimeError otherwise.  The entry points that
+    measure or prove something on the chip (bench.py, chip_smoke.py)
+    call this first: JAX itself falls back to the CPU without a chip
+    and exits 0."""
+    import jax
+
+    d = jax.devices()[0]
+    if d.platform != "tpu":
+        raise RuntimeError(
+            f"no chip found — JAX reports platform {d.platform!r}")
+    if d.device_kind not in DEVICE_PEAKS:
+        raise RuntimeError(
+            f"no peak numbers for TPU device_kind {d.device_kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)}): add a row with its "
+            "source to obs.cost.DEVICE_PEAKS")
+    return d, DEVICE_PEAKS[d.device_kind]
 
 
-def peak_hbm_bps(cls: Optional[str] = None) -> float:
-    cls = cls or device_class()
-    return TPU_V5E_PEAK_HBM_BPS if cls == "tpu" else CPU_PEAK_HBM_BPS
+def device_peaks() -> Dict[str, Any]:
+    """The peak row of the device this process runs on."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return HOST_NOMINAL
+    return require_chip()[1]
+
+
+def peak_flops() -> float:
+    return device_peaks()["flops"]
+
+
+def peak_hbm_bps() -> float:
+    return device_peaks()["hbm_bps"]
 
 
 def cost_of_compiled(compiled) -> Optional[Dict[str, float]]:
     """{"flops", "bytes_accessed"} from an AOT executable's XLA
-    cost_analysis, or None when the backend does not report one
-    (jax 0.4.x returns a per-device list; device 0 is the per-chip
-    number MFU wants)."""
+    cost_analysis, or None when the backend does not report one."""
     try:
         cost = compiled.cost_analysis()
     except Exception:  # noqa: BLE001 - optional on some PJRT plugins
         return None
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
     if not cost:
         return None
     flops = float(cost.get("flops", 0.0) or 0.0)
@@ -139,8 +169,8 @@ class ProgramCost:
                 return
             step_s = elapsed / n
             self.step_ms = step_s * 1e3
-            pf = peak_flops()
-            pb = peak_hbm_bps()
+            peaks = device_peaks()
+            pf, pb = peaks["flops"], peaks["hbm_bps"]
             if self.flops > 0.0 and pf > 0.0:
                 self.mfu_pct = self.flops / step_s / pf * 100.0
             if self.bytes_accessed > 0.0 and pb > 0.0:
@@ -257,11 +287,12 @@ def snapshot() -> Dict[str, Any]:
         if pc.dispatches > 1 and (live is None
                                   or (pc._t_last or 0) > (live._t_last or 0)):
             live = pc
-    cls = device_class()
+    peaks = device_peaks()
     return {
-        "device_class": cls,
-        "peak_flops": peak_flops(cls),
-        "peak_hbm_bps": peak_hbm_bps(cls),
+        "device_class": device_class(),
+        "peak_flops": peaks["flops"],
+        "peak_hbm_bps": peaks["hbm_bps"],
+        "peak_source": peaks["source"],
         "mfu_pct": round(live.mfu_pct, 8) if live else 0.0,
         "hbm_bw_pct": round(live.hbm_bw_pct, 8) if live else 0.0,
         "programs": [pc.as_dict() for pc in progs],

@@ -787,7 +787,7 @@ class DevprofWindow:
             from . import cost
 
             cls = cost.device_class()
-            pf, pb = cost.peak_flops(cls), cost.peak_hbm_bps(cls)
+            pf, pb = cost.peak_flops(), cost.peak_hbm_bps()
         except Exception:  # noqa: BLE001 - no jax: label the regime
             cls, pf, pb = "cpu-fallback", 0.0, 0.0
         res = {

@@ -495,9 +495,13 @@ def set_device_stats_fn(fn: Optional[Callable[[], Optional[dict]]]
 
 
 def device_memory_stats() -> Optional[dict]:
-    """`device.memory_stats()` of the first addressable device, or
-    None when the backend doesn't report them (CPU) or jax is absent
-    (tracetool path-loaded usage)."""
+    """`device.memory_stats()` summed over every local device (the
+    ledger it is reconciled against counts shards on all of them), with
+    the fullest device beside the sums — `fullest_frac` and
+    `min_headroom_bytes`, because a sum hides one full device among
+    empty ones and a program's temp must fit on EACH device.  None when
+    the backend doesn't report stats (CPU) or jax is absent (tracetool
+    path-loaded usage)."""
     fn = _DEVICE_STATS_FN[0]
     if fn is not None:
         try:
@@ -507,9 +511,19 @@ def device_memory_stats() -> Optional[dict]:
     try:
         import jax  # noqa: PLC0415 - lazy by design (stdlib module scope)
 
-        return jax.devices()[0].memory_stats()
+        per_dev = [d.memory_stats() for d in jax.local_devices()]
     except Exception:  # noqa: BLE001 - no jax / no backend stats
         return None
+    if not per_dev or any(s is None for s in per_dev):
+        return None
+    doc = {k: sum(s.get(k, 0) for s in per_dev)
+           for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")}
+    limited = [(s.get("bytes_in_use", 0), s["bytes_limit"])
+               for s in per_dev if s.get("bytes_limit", 0) > 0]
+    if limited:
+        doc["fullest_frac"] = max(u / lim for u, lim in limited)
+        doc["min_headroom_bytes"] = min(lim - u for u, lim in limited)
+    return doc
 
 
 def _collect_entries() -> Dict[str, int]:
@@ -556,6 +570,10 @@ def ledger_gauges(record: bool = True) -> Dict[str, float]:
         limit = stats.get("bytes_limit")
         if isinstance(limit, (int, float)) and limit > 0:
             g["hbm_limit_bytes"] = float(limit)
+        if "fullest_frac" in stats:
+            g["hbm_fullest_device_frac"] = float(stats["fullest_frac"])
+            g["hbm_min_headroom_bytes"] = float(
+                stats["min_headroom_bytes"])
         peak = stats.get("peak_bytes_in_use")
         with _LEDGER_LOCK:
             cand = float(peak) if isinstance(peak, (int, float)) \

@@ -405,13 +405,19 @@ def rule_hbm_pressure(v, cfg) -> Optional[str]:
     limit = v.last("hbm_limit_bytes")
     if in_use is None or limit is None or limit <= 0:
         return None
-    frac = in_use / limit
+    # in_use and limit are sums over the local devices; the fullest
+    # device decides, where memprof reports it (several devices)
+    frac = v.last("hbm_fullest_device_frac")
+    if frac is None:
+        frac = in_use / limit
     if frac > cfg["hbm_pressure_frac"]:
-        return (f"hbm_bytes_in_use {in_use:.0f} is {frac:.0%} of the "
-                f"{limit:.0f}-byte device limit (threshold "
-                f"{cfg['hbm_pressure_frac']:.0%})")
+        return (f"hbm_bytes_in_use {in_use:.0f} of {limit:.0f} bytes: "
+                f"the fullest device is at {frac:.0%} of its limit "
+                f"(threshold {cfg['hbm_pressure_frac']:.0%})")
     temp = v.last("hbm_static_temp_bytes")
-    headroom = limit - in_use
+    headroom = v.last("hbm_min_headroom_bytes")
+    if headroom is None:
+        headroom = limit - in_use
     if temp and temp > 0 \
             and headroom < cfg["hbm_headroom_temp_frac"] * temp:
         return (f"hbm headroom {headroom:.0f} bytes is below the "

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -12,8 +13,37 @@ def default_backend() -> str:
     return jax.default_backend()
 
 
+# A Sharding over devices of a TPU *topology* (no chip attached), or
+# None; set only inside `compile_target`.
+_COMPILE_TARGET = None
+
+
+@contextlib.contextmanager
+def compile_target(sharding):
+    """Inside the block, kernel dispatch behaves as on a TPU and the
+    compile probes lower for `sharding`'s devices — those of a TPU
+    topology — so Mosaic accepts or refuses every kernel on a host
+    without a chip (tools/aot_analysis.py,
+    tests/test_pallas_attention.py).  Tracing and lowering must happen
+    inside the block; nothing compiled there can run here."""
+    global _COMPILE_TARGET
+    prev, _COMPILE_TARGET = _COMPILE_TARGET, sharding
+    try:
+        yield
+    finally:
+        _COMPILE_TARGET = prev
+
+
 def on_tpu() -> bool:
+    if _COMPILE_TARGET is not None:
+        return True
     return default_backend() == "tpu"
+
+
+def probe_struct(shape, dtype) -> jax.ShapeDtypeStruct:
+    """Abstract operand for a compile probe, placed on the compile
+    target when one is set (else the default device)."""
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=_COMPILE_TARGET)
 
 
 def cdiv(a: int, b: int) -> int:

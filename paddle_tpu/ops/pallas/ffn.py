@@ -1,6 +1,7 @@
 """Fused transformer FFN — Pallas TPU kernel.
 
-Motivation (artifacts/MFU_ANALYSIS.md): the BERT bench step is
+Motivation (the builders' 2026-07-31 chip trace, ROADMAP Queue 3;
+to be reproduced): the BERT bench step is
 HBM-bound, and after attention the largest traffic group is the FFN —
 the (tokens, d_ff) intermediates (gelu input/output, dropout mask and
 select) each round-trip HBM as separate fusion results.  This kernel
@@ -40,10 +41,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._common import round_up
-
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams", None)
 
 
 def _erf(x):
@@ -181,7 +178,7 @@ def _ffn_forward(x, w1, b1, w2, b2, seed, activation="gelu",
         out_specs=pl.BlockSpec((block_t, H), lambda t, f: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((T, H), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_t, H), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(seed, x, w1, b1.reshape(1, F), w2, b2.reshape(1, H))
@@ -295,7 +292,7 @@ def _ffn_backward(x, w1, b1, w2, b2, seed, g, activation="gelu",
             pltpu.VMEM((1, block_f), jnp.float32),
             pltpu.VMEM((block_f, H), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(seed, x, g, w1, b1r, w2)
@@ -317,7 +314,7 @@ def _ffn_backward(x, w1, b1, w2, b2, seed, g, activation="gelu",
         out_specs=pl.BlockSpec((block_t, H), lambda t, f: (t, 0)),
         out_shape=jax.ShapeDtypeStruct((T, H), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_t, H), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(seed, x, g, w1, b1r, w2)
@@ -363,8 +360,8 @@ _fused_ffn.defvjp(_fused_ffn_fwd, _fused_ffn_bwd)
 # -- public API + dispatcher --------------------------------------------------
 
 _PROBE_CACHE = {}
-# OPT-IN since the 2026-07-31 on-chip A/B (the "noffn" arm in the git
-# history of artifacts/dimsem_ab.json — the live file holds newer arms):
+# OPT-IN since the 2026-07-31 on-chip A/B (artifacts/FFN_AB_r19.md;
+# before PR 1, not measured since):
 # the AOT byte model said the kernel saves 15.5 GB/step, but measured
 # v5e steps are 120.9 ms on the XLA FFN path vs 136.6 ms with the
 # kernel — the in-kernel backward recompute costs more wall time than
@@ -375,10 +372,6 @@ _PROBE_CACHE = {}
 _FFN_DISABLED = (
     None if os.environ.get("PADDLE_TPU_FUSED_FFN") == "1"
     else "opt-in (on-chip A/B 2026-07-31: XLA FFN path faster)")
-# AOT-analysis/test hook: True skips the backend + Mosaic-probe gating
-# (tools/aot_analysis.py compiles for a TPU topology from a CPU-default
-# process, where the probe would target the wrong backend)
-_FORCE_KERNEL = False
 
 
 def disable_fused_ffn(reason):
@@ -419,11 +412,9 @@ def _ffn_ok(T, H, F, dtype, activation, dropout_p, block_t, block_f):
             .lower(x, w1, b1, w2, b2, seed, g).compile()
         return True
 
-    # own probe (NOT attention._try_compile: its recovery path flips
-    # the process-wide dimension-semantics flag, which must never be
-    # collateral of an FFN probe).  Silent per rung — the CALLER warns
-    # once if the whole ladder exhausts, so a successful smaller rung
-    # never logs a misleading "falling back" message.
+    # silent per rung — the CALLER warns once if the whole ladder
+    # exhausts, so a successful smaller rung never logs a misleading
+    # "falling back" message
     try:
         _PROBE_CACHE[key] = bool(compile_probe())
         _PROBE_CACHE[(key, "err")] = None
@@ -475,7 +466,7 @@ def fused_ffn(x, w1, b1, w2, b2, activation="gelu", dropout_p=0.0,
         _choice = None
     block_t = block_f = None
     if _choice != "xla" and H % 128 == 0 and ladder:
-        if interpret or _FORCE_KERNEL:
+        if interpret:
             block_t, block_f = ladder[0]
         elif (_FFN_DISABLED is None or _choice == "pallas") \
                 and jax.default_backend() == "tpu":

@@ -49,7 +49,7 @@ def build_localsgd_step(loss_fn, params, mesh, axis: str = DATA_AXIS,
     stacked = jax.device_put(stacked, shard)
     vel = tmap(jnp.zeros_like, stacked)
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def local(pstack, vstack, t, batch):
         p = tmap(lambda a: a[0], pstack)     # this shard's copy
@@ -76,7 +76,7 @@ def build_localsgd_step(loss_fn, params, mesh, axis: str = DATA_AXIS,
             mesh=mesh,
             in_specs=(pspec, pspec, P(), bspec),
             out_specs=(pspec, pspec, P()),
-            check_rep=False)(state["params"], state["vel"], state["t"],
+            check_vma=False)(state["params"], state["vel"], state["t"],
                              batch)
         return {"params": new_p, "vel": new_v,
                 "t": state["t"] + 1}, loss

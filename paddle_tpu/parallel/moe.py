@@ -146,7 +146,7 @@ def build_switch_moe(mesh, n_experts, d_model, d_ff, ep_axis="ep",
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     assert n_experts % mesh.shape[ep_axis] == 0, \
@@ -171,7 +171,7 @@ def build_switch_moe(mesh, n_experts, d_model, d_ff, ep_axis="ep",
     shard_apply = shard_map(local, mesh=mesh,
                             in_specs=(p_spec, P(token_axes)),
                             out_specs=(P(token_axes), P()),
-                            check_rep=False)
+                            check_vma=False)
 
     def apply(params, x):
         assert x.shape[0] % n_shards == 0, (

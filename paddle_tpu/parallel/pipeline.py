@@ -50,7 +50,7 @@ def gpipe(mesh, stage_fn, num_microbatches, axis="pp",
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     m_count = num_microbatches
@@ -98,7 +98,7 @@ def gpipe(mesh, stage_fn, num_microbatches, axis="pp",
         out = shard_map(
             local, mesh=mesh,
             in_specs=(in_params_spec, P()),
-            out_specs=P(axis), check_rep=False)(stacked_params, xs)
+            out_specs=P(axis), check_vma=False)(stacked_params, xs)
         out = out[-1]  # the last stage's row holds the real outputs
         return out.reshape((batch,) + out.shape[2:])
 
@@ -148,7 +148,7 @@ def gpipe_model(mesh, first_fn, block_fn, last_fn, num_microbatches,
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     m_count = num_microbatches
@@ -215,7 +215,7 @@ def gpipe_model(mesh, first_fn, block_fn, last_fn, num_microbatches,
         outs = shard_map(
             local, mesh=mesh,
             in_specs=(P(), block_spec, P(), aux_spec),
-            out_specs=out_spec, check_rep=False)(
+            out_specs=out_spec, check_vma=False)(
                 first_p, block_p, last_p, aux_mbs)
         return tmap(
             lambda o: o[-1].reshape((lead,) + o.shape[3:]), outs)

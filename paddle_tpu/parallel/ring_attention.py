@@ -112,7 +112,7 @@ def ring_attention(mesh, axis="sp"):
     (any resident sharding); runs shard_map over `axis` with batch
     replicated and sequence sharded."""
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def call(q, k, v, is_causal=False, scale=None):
@@ -120,6 +120,6 @@ def ring_attention(mesh, axis="sp"):
                                is_causal=is_causal, scale=scale)
         spec = P(None, axis, None, None)
         return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_rep=False)(q, k, v)
+                         out_specs=spec, check_vma=False)(q, k, v)
 
     return call

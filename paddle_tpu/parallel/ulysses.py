@@ -58,7 +58,7 @@ def ulysses_attention(mesh, axis="sp"):
     outer shard_map, use `ulysses_attention_local` directly.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def attn(q, k, v, mask=None, is_causal=False, scale=None):
@@ -77,7 +77,7 @@ def ulysses_attention(mesh, axis="sp"):
         return shard_map(
             local, mesh=mesh,
             in_specs=(spec, spec, spec, mask_spec),
-            out_specs=spec, check_rep=False)(q, k, v, mask)
+            out_specs=spec, check_vma=False)(q, k, v, mask)
 
     return attn
 

@@ -40,6 +40,13 @@ Int stats (get_int_stats):
 | serving_prefill_chunks        | chunked-prefill chunk dispatches        |
 | serving_ragged_fallback_total | ragged paged-attention Mosaic rejections|
 |                               | that fell back to the dense XLA path    |
+| flash_fallback_total          | flash-attention shapes Mosaic refused   |
+|                               | (ladder exhausted or probe refused) that|
+|                               | took XLA attention (prefill, training); |
+|                               | like the ragged one, bumped once per    |
+|                               | refused shape at trace time, never per  |
+|                               | step; chip_smoke.py and the TPU lane    |
+|                               | fail on a non-zero count of either      |
 | serving_decode_steps          | decode-step dispatches (autoregressive) |
 
 Per-tenant series (multi-tenant fleet, serving/registry.py): every

@@ -5,7 +5,7 @@ multi-process TestDistBase harness for mesh/collective tests)."""
 
 import os
 
-# The TPU lane (PADDLE_TPU_TEST_LANE=1, used by `bench.py --preflight` and
+# The TPU lane (PADDLE_TPU_TEST_LANE=1 with
 # `pytest -m tpu`) keeps the real backend so kernel tests exercise Mosaic
 # lowering on hardware — round 2 shipped a kernel that only ever ran in
 # interpret mode on CPU and crashed on the chip (VERDICT r2 weak #1).

@@ -4,7 +4,6 @@ after the checkpoint save.  Writes per-epoch losses to OUT."""
 import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, "/root/repo")
-import jax; jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import paddle_tpu.fluid as fluid
 import paddle_tpu.fluid.incubate.checkpoint.auto_checkpoint as acp

@@ -4,15 +4,9 @@ model-building code (VERDICT r3 Missing #5 round-trip contract).
 Usage: python infer_loader.py <model_dir> <input.npy> <output.npy>
 """
 
-import os
 import sys
 
 import numpy as np
-
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    # the env var alone doesn't beat the TPU plugin; both are needed
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 import paddle_tpu.fluid as fluid
 

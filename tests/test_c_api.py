@@ -30,10 +30,6 @@ _CTYPES_HOST = r"""
 import ctypes, json, os, sys
 import numpy as np
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 so_path, prefix, inp_path, out_path = sys.argv[1:5]
 lib = ctypes.CDLL(so_path)
 lib.PT_GetLastError.restype = ctypes.c_char_p

@@ -13,13 +13,11 @@
   flow-linked from the `executor.dispatch` span — asserted against the
   real jax.profiler capture under JAX_PLATFORMS=cpu.
 * The PR-7 orphaned-flow suppression still holds with device events
-  merged in, and the BENCH TPU-probe record is diagnosable.
+  merged in.
 """
 
-import json
 import os
 import sys
-import time
 
 import numpy as np
 import pytest
@@ -34,8 +32,6 @@ from paddle_tpu.obs.tracing import Tracer
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
-sys.path.insert(0, REPO_ROOT)
-import bench  # noqa: E402
 import tracetool  # noqa: E402
 
 
@@ -256,7 +252,7 @@ class TestDevprofEndToEnd:
         # the capture published its gauges for telemetry/bench_diff
         from paddle_tpu import profiler
         assert profiler.get_int_stats().get(
-            "devprof_attributed_pct") == int(res["attributed_pct"])
+            "devprof_attributed_pct") == int(round(res["attributed_pct"]))
         assert obs.snapshot()["devprof"]["windows"]
 
     def test_export_trace_device_tracks_and_flow_links(self, tmp_path):
@@ -345,48 +341,3 @@ class TestOrphansWithDeviceEvents:
         assert doc["otherData"]["orphaned_flows"] == 1
         assert doc["otherData"]["devprof"]["flows_linked"] == 1
 
-
-# ---------------------------------------------------------------------------
-# BENCH probe diagnosability (satellite)
-# ---------------------------------------------------------------------------
-
-class TestProbeRecord:
-    def test_cache_hit_record(self, monkeypatch, tmp_path):
-        cache = str(tmp_path / "probe.json")
-        monkeypatch.setattr(bench, "PROBE_CACHE", cache)
-        monkeypatch.setattr(bench, "_PROBE_RECORD", None)
-        with open(cache, "w") as f:
-            json.dump({"ok": True, "reason": "probe ok",
-                       "at": time.time() - 10}, f)
-        rec = bench._tpu_probe_cached()
-        assert rec["ok"] is True and rec["cache"] == "hit"
-        assert rec["reason"] == "probe ok"
-        assert 5 <= rec["verdict_age_s"] <= 60
-        # the detail stamp re-serves the same record
-        assert bench._tpu_probe_detail() == rec
-
-    def test_cache_miss_stamps_probe_reason(self, monkeypatch,
-                                            tmp_path):
-        monkeypatch.setattr(bench, "PROBE_CACHE",
-                            str(tmp_path / "probe.json"))
-        monkeypatch.setattr(bench, "_PROBE_RECORD", None)
-        monkeypatch.setattr(
-            bench, "_tpu_probe_subprocess",
-            lambda **kw: (False, "no TPU backend (probe exited 1)"))
-        rec = bench._tpu_probe_cached()
-        assert rec == {"ok": False,
-                       "reason": "no TPU backend (probe exited 1)",
-                       "cache": "miss", "verdict_age_s": 0.0}
-        # the negative verdict AND its reason were persisted for the
-        # next run in the TTL window
-        with open(bench.PROBE_CACHE) as f:
-            saved = json.load(f)
-        assert saved["ok"] is False and saved["reason"] == rec["reason"]
-
-    def test_env_pinned_reason(self, monkeypatch):
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        monkeypatch.setattr(bench, "_PROBE_RECORD", None)
-        rec = bench._tpu_probe_detail()
-        assert rec["ok"] is False
-        assert rec["reason"] == "JAX_PLATFORMS=cpu (pinned)"
-        assert rec["cache"] == "none"

@@ -286,6 +286,44 @@ class TestHbmPressureRule:
             _gauge_store(hbm_bytes_in_use=[5e9],
                          hbm_limit_bytes=[1e10]), self.CFG) is None
 
+    def test_one_full_device_among_empty_ones_fires(self):
+        # sums say 25% used; the fullest device is at 97%
+        pos = telemetry.rule_hbm_pressure(
+            _gauge_store(hbm_bytes_in_use=[1e10], hbm_limit_bytes=[4e10],
+                         hbm_fullest_device_frac=[0.97],
+                         hbm_min_headroom_bytes=[3e8]), self.CFG)
+        assert pos and "97%" in pos
+        # per-device headroom, not the summed one, must hold the temp
+        pos = telemetry.rule_hbm_pressure(
+            _gauge_store(hbm_bytes_in_use=[1e10], hbm_limit_bytes=[4e10],
+                         hbm_fullest_device_frac=[0.8],
+                         hbm_min_headroom_bytes=[2e9],
+                         hbm_static_temp_bytes=[3e9]), self.CFG)
+        assert pos and "static temp" in pos
+
+    def test_device_stats_report_the_fullest_device(self, monkeypatch):
+        import jax
+
+        class Dev:
+            def __init__(self, used):
+                self.used = used
+
+            def memory_stats(self):
+                return {"bytes_in_use": self.used,
+                        "peak_bytes_in_use": self.used,
+                        "bytes_limit": 1000}
+
+        monkeypatch.setattr(jax, "local_devices",
+                            lambda: [Dev(900), Dev(100)])
+        stats = memprof.device_memory_stats()
+        assert stats["bytes_in_use"] == 1000
+        assert stats["bytes_limit"] == 2000
+        assert stats["fullest_frac"] == 0.9
+        assert stats["min_headroom_bytes"] == 100
+        g = memprof.ledger_gauges(record=False)
+        assert g["hbm_fullest_device_frac"] == 0.9
+        assert g["hbm_min_headroom_bytes"] == 100.0
+
     def test_headroom_below_static_temp_fires(self):
         pos = telemetry.rule_hbm_pressure(
             _gauge_store(hbm_bytes_in_use=[8e9],

@@ -7,14 +7,13 @@
   `obs.op_profile(program)` table whose FLOPs sum to the executable's
   own cost_analysis total (normalized exactly; raw estimate within
   tolerance), with >=95% of FLOPs attributed to named Program ops.
-* The orphaned-flow export fix, the all-hosts snapshot, the probe
-  cache's short negative TTL, and the bench_diff regression gate.
+* The orphaned-flow export fix, the all-hosts snapshot, and the
+  bench_diff regression gate.
 """
 
 import json
 import os
 import sys
-import time
 
 import numpy as np
 import pytest
@@ -294,70 +293,6 @@ class TestAllHostsSnapshot:
         mine = snap["hosts"]["0"]
         assert mine["counters"] == snap["counters"]
         assert mine["timers_ms"] == snap["timers_ms"]
-
-
-# ---------------------------------------------------------------------------
-# probe-cache negative TTL (bench.py satellite)
-# ---------------------------------------------------------------------------
-
-class TestProbeCacheNegativeTTL:
-    def _bench(self):
-        sys.path.insert(0, REPO_ROOT)
-        import bench
-
-        return bench
-
-    def test_fresh_negative_verdict_is_honored(self, tmp_path,
-                                               monkeypatch):
-        bench = self._bench()
-        cache = tmp_path / "probe.json"
-        cache.write_text(json.dumps({"ok": False, "at": time.time()}))
-        monkeypatch.setattr(bench, "PROBE_CACHE", str(cache))
-        monkeypatch.setattr(bench, "_PROBE_RECORD", None)
-        monkeypatch.setattr(bench, "_tpu_probe_subprocess",
-                            lambda *a, **k: pytest.fail(
-                                "fresh negative verdict must not "
-                                "re-probe"))
-        rec = bench._tpu_probe_cached()
-        assert rec["ok"] is False and rec["cache"] == "hit"
-
-    def test_expired_negative_verdict_reprobes(self, tmp_path,
-                                               monkeypatch):
-        bench = self._bench()
-        cache = tmp_path / "probe.json"
-        # 10 min old: inside the positive TTL (1800s) but far past the
-        # negative TTL (120s) — the poisoned-verdict regression shape
-        cache.write_text(json.dumps({"ok": False,
-                                     "at": time.time() - 600}))
-        monkeypatch.setattr(bench, "PROBE_CACHE", str(cache))
-        monkeypatch.setattr(bench, "_PROBE_RECORD", None)
-        calls = []
-        monkeypatch.setattr(
-            bench, "_tpu_probe_subprocess",
-            lambda *a, **k: calls.append(1) or (True, "probe ok"))
-        rec = bench._tpu_probe_cached()
-        assert rec["ok"] is True and rec["cache"] == "miss"
-        assert calls, "expired ok=false must re-probe"
-        # and the recovered verdict is re-cached as positive, with
-        # its reason alongside for the next run's detail stamp
-        saved = json.loads(cache.read_text())
-        assert saved["ok"] is True and saved["reason"] == "probe ok"
-
-    def test_positive_verdict_keeps_long_ttl(self, tmp_path,
-                                             monkeypatch):
-        bench = self._bench()
-        cache = tmp_path / "probe.json"
-        cache.write_text(json.dumps({"ok": True,
-                                     "at": time.time() - 600}))
-        monkeypatch.setattr(bench, "PROBE_CACHE", str(cache))
-        monkeypatch.setattr(bench, "_PROBE_RECORD", None)
-        monkeypatch.setattr(bench, "_tpu_probe_subprocess",
-                            lambda *a, **k: pytest.fail(
-                                "positive verdict inside TTL must not "
-                                "re-probe"))
-        rec = bench._tpu_probe_cached()
-        assert rec["ok"] is True and rec["cache"] == "hit"
-        assert 500 <= rec["verdict_age_s"] <= 700
 
 
 # ---------------------------------------------------------------------------

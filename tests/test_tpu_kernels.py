@@ -1,17 +1,19 @@
 """Real-hardware (non-interpret) Pallas kernel tests — the TPU lane.
 
-Round 2 shipped a flash-attention kernel whose every test ran
-`interpret=True` on CPU; the kernel then failed Mosaic lowering for every
-input shape on the bench chip (VERDICT r2 weak #1, BENCH_r02).  This lane
-exercises the kernels through the actual Mosaic compiler:
+Every other kernel test runs `interpret=True` on CPU, which proves the
+arithmetic and nothing about Mosaic: a kernel can pass all of them and
+fail lowering for every input shape on the chip (BENCH_r02).  This lane
+runs the kernels through the actual Mosaic compiler, on the chip, in
+one process (after `python chip_smoke.py`, the second command there):
 
-    PADDLE_TPU_TEST_LANE=1 python -m pytest tests/test_tpu_kernels.py -q
+    PADDLE_TPU_TEST_LANE=1 python -m pytest tests -m tpu
 
-`bench.py` runs the same checks as a preflight before timing, so a
-kernel regression can never reach the bench silently again.
-
-Oracle: `_xla_attention` (tests/test_pallas_attention.py validates that
-against NumPy in interpret mode; here it runs on the same chip).
+Oracles: `_xla_attention` for the flash kernels
+(tests/test_pallas_attention.py validates it against NumPy in interpret
+mode; here it runs on the same chip) and `_dense_paged_attention` for
+the ragged paged kernel.  The last test fails the lane if any kernel
+gave way to its XLA path on the way (`flash_fallback_total`,
+`serving_ragged_fallback_total`).
 """
 
 import jax
@@ -19,6 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu import profiler
+from paddle_tpu.ops.pallas import attention as A
 from paddle_tpu.ops.pallas.attention import (
     _xla_attention,
     flash_attention,
@@ -100,3 +104,160 @@ def test_bert_seq512_shape_regression():
                           dropout_seed=1)
     assert out.shape == (2, 512, 4, 64)
     assert bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+
+
+def test_flash_pair_at_bench_shape():
+    """The BERT-base step's own instance — (B*H, S, D) = (384, 512, 64)
+    bf16, key-padding bias, dropout 0.1 — forward and both backward
+    kernels against an XLA oracle that applies the SAME keep mask
+    (the in-kernel RNG is a pure hash of absolute coordinates)."""
+    b, s, h, d, p_drop, seed = 32, 512, 12, 64, 0.1, 5
+    q, k, v, g = (_rand((b, s, h, d), i, jnp.bfloat16)
+                  for i in (20, 21, 22, 23))
+    lens = np.random.RandomState(24).randint(s // 2, s + 1, (b,))
+    kb = jnp.where(jnp.arange(s)[None, :] < lens[:, None], 0.0,
+                   -1e9).astype(jnp.float32)
+    keep = A._keep_mask3(jnp.int32(seed), 0, 0, 0, b * h, s, s,
+                         p_drop).reshape(b, h, s, s)
+
+    def oracle(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (d ** 0.5)
+        probs = jax.nn.softmax(logits + kb[:, None, None, :], axis=-1)
+        probs = jnp.where(keep, probs / (1.0 - p_drop), 0.0)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, key_bias=kb, dropout_p=p_drop,
+                               dropout_seed=seed).astype(jnp.float32)
+
+    gf = g.astype(jnp.float32)
+    out, grads = jax.value_and_grad(
+        lambda *a: jnp.sum(kernel(*a) * gf), argnums=(0, 1, 2))(q, k, v)
+    ref, rgrads = jax.value_and_grad(
+        lambda *a: jnp.sum(oracle(*a) * gf), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(out), float(ref), rtol=2e-2)
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
+                               np.asarray(oracle(q, k, v)),
+                               atol=3e-2, rtol=3e-2)
+    for name, a, r in zip("qkv", grads, rgrads):
+        np.testing.assert_allclose(
+            np.asarray(a.astype(jnp.float32)),
+            np.asarray(r.astype(jnp.float32)), atol=5e-2, rtol=5e-2,
+            err_msg=f"d{name} mismatch at the bench shape")
+    rungs = {key[6] for key, ok in A._EXACT_PROBE_CACHE.items()
+             if ok and key[0] == (b * h, s, d)}
+    print(f"flash head-block rung Mosaic accepted: {sorted(rungs)}")
+    assert rungs
+
+
+# -- ragged paged attention (serving decode / chunked prefill) --------------
+
+def _paged_case(lengths, t, dtype, seed=0):
+    """tests/test_fast_decode.py's ragged layout (random pool, scratch
+    page 0 included, length-0 rows on the scratch page) at serving
+    sizes: page 16, 8 heads x 64."""
+    from test_fast_decode import _paged_case as case
+
+    q, kp, vp, rows, lens = case(lengths, t=t, page_size=16, heads=8,
+                                 dim=64, seed=seed)
+    return q.astype(dtype), kp.astype(dtype), vp.astype(dtype), rows, lens
+
+
+def _assert_paged_parity(q, kp, vp, rows, lens, qpos=None):
+    t, d = q.shape[1], q.shape[-1]
+    if qpos is None:
+        qpos = lens[:, None] - t + jnp.arange(t, dtype=jnp.int32)[None, :]
+    out = A.paged_attention(q, kp, vp, rows, lens, q_positions=qpos)
+    ref = A._dense_paged_attention(q, kp, vp, rows, lens, qpos,
+                                   1.0 / (d ** 0.5))
+    out, ref = (np.asarray(x.astype(jnp.float32)) for x in (out, ref))
+    assert np.all(np.isfinite(out))
+    np.testing.assert_allclose(out, ref, atol=3e-2, rtol=3e-2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_paged_decode_parity_on_tpu(dtype):
+    """T == 1 over ragged lengths: one token, an exact page multiple,
+    several pages, and a length-0 lane that only sees the scratch
+    page."""
+    _assert_paged_parity(*_paged_case([1, 16, 75, 0, 33, 128, 5, 64],
+                                      1, dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_paged_chunk_parity_on_tpu(dtype):
+    """T == a prefill chunk at explicit absolute positions (the chunk
+    step's call): the third 64-token chunk of a prompt, causal inside
+    the chunk."""
+    q, kp, vp, rows, lens = _paged_case([192], 64, dtype, seed=1)
+    qpos = (128 + jnp.arange(64, dtype=jnp.int32))[None, :]
+    _assert_paged_parity(q, kp, vp, rows, lens, qpos)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_paged_causal_tail_parity_on_tpu(dtype):
+    """T > 1 at the default positions: the T newest tokens, causally
+    masked among themselves."""
+    _assert_paged_parity(*_paged_case([40, 9, 100], 8, dtype, seed=2))
+
+
+def test_dataloader_workers_feed_executor_on_tpu():
+    """One `DataLoader(num_workers=2)` epoch into the Executor from the
+    process that holds the chip: the worker processes are forked from
+    a parent with the TPU runtime loaded, stay host-side, and must
+    neither hang nor crash."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import framework, unique_name
+    from paddle_tpu.fluid.executor import Scope, scope_guard
+    from paddle_tpu.io import DataLoader, Dataset
+
+    class Rows(Dataset):
+        def __len__(self):
+            return 64
+
+        def __getitem__(self, i):
+            r = np.random.RandomState(i)
+            return r.randn(16).astype(np.float32), \
+                r.randn(1).astype(np.float32)
+
+    main, startup = framework.Program(), framework.Program()
+    with framework.program_guard(main, startup), unique_name.guard(), \
+            scope_guard(Scope()):
+        x = fluid.data("x", [-1, 16], "float32")
+        y = fluid.data("y", [-1, 1], "float32")
+        loss = fluid.layers.reduce_mean(
+            fluid.layers.loss.square_error_cost(
+                fluid.layers.fc(x, 1), y))
+        fluid.optimizer.SGD(0.01).minimize(loss)
+        exe = fluid.Executor(fluid.TPUPlace(0))
+        exe.run(startup)
+        loader = DataLoader(Rows(), batch_size=8, num_workers=2,
+                            timeout=120)
+        assert loader.use_process_workers
+        losses = [exe.run(main, feed={"x": xb, "y": yb},
+                          fetch_list=[loss])[0] for xb, yb in loader]
+    assert len(losses) == 8
+    assert all(np.isfinite(np.asarray(v)).all() for v in losses)
+
+
+def test_launcher_recognises_the_tpu_host(monkeypatch):
+    """The launcher refuses several workers per TPU host; it has to
+    tell, without touching JAX, that this machine is one."""
+    from paddle_tpu.distributed import launch_utils
+
+    import glob
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    seen = {n: launch_utils._vfio_group_vendors(n.rsplit("/", 1)[1])
+            for n in glob.glob("/dev/vfio/[0-9]*")}
+    assert launch_utils.on_tpu_host(), \
+        (glob.glob("/dev/accel*"), seen)
+
+
+def test_no_kernel_gave_way():
+    """Runs last: nothing above (and no other tpu-marked test before
+    it) may have pushed a kernel onto its XLA path."""
+    stats = profiler.get_int_stats()
+    assert stats.get("flash_fallback_total", 0) == 0
+    assert stats.get("serving_ragged_fallback_total", 0) == 0
