@@ -35,6 +35,7 @@ RULE = "hot-path-sync"
 WATCHLIST: List[Tuple[str, str]] = [
     ("paddle_tpu/fluid/executor.py", "Executor.run"),
     ("paddle_tpu/fluid/executor.py", "Executor._dispatch"),
+    ("paddle_tpu/fluid/executor.py", "Executor._dispatch_staged"),
     # SPMD state seat (ISSUE 13): runs at the top of EVERY dispatch —
     # re-seating host arrays under their NamedSharding must stay an
     # async device_put, never a transfer
@@ -150,14 +151,13 @@ WATCHLIST: List[Tuple[str, str]] = [
     ("paddle_tpu/obs/telemetry.py", "_Handler.do_GET"),
     # measured device-time profiling (ISSUE 12): note_dispatch and the
     # autostop check run INSIDE the dispatch/step loop; window
-    # start/finish and the xplane parse run at window boundaries but on
-    # the training thread — capture must never smuggle a sync into the
+    # start/finish run at window boundaries but on the training
+    # thread — capture must never smuggle a sync into the
     # hot path it is measuring
     ("paddle_tpu/obs/devprof.py", "note_dispatch"),
     ("paddle_tpu/obs/devprof.py", "maybe_autostop"),
     ("paddle_tpu/obs/devprof.py", "DevprofWindow.start"),
     ("paddle_tpu/obs/devprof.py", "DevprofWindow.finish"),
-    ("paddle_tpu/obs/devprof.py", "parse_xplane_bytes"),
     # HBM memory observability (ISSUE 14): set/add run on the dispatch /
     # ring / ckpt hot paths; ledger_gauges runs on the telemetry
     # sampler thread and oom_report on the dispatch except-path — all

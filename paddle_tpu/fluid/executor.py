@@ -104,9 +104,9 @@ class LazyFetch:
     # -- materialization (sanctioned sync points) -------------------------
     def numpy(self):
         if self._np is None:
-            from ..profiler import count_sync, timed
+            from ..profiler import count_sync, stage
 
-            with timed("sync_ms"):
+            with stage("executor.sync", "sync_ms"):
                 count_sync()
                 self._np = np.asarray(self._val)  # sync-ok: materialization
         return self._np
@@ -980,22 +980,11 @@ class Executor:
             checkpoint_every_secs, checkpoint_keep, resume)
         # PADDLE_OBS_HTTP_PORT auto-attach: live /metrics + /healthz +
         # watchdog for this training pass (refcounted; None when unset)
+        from .. import obs
+
         telemetry = None
         try:
-            from .. import obs
-
             telemetry = obs.maybe_start_telemetry()
-        except Exception:  # noqa: BLE001 - observability, not control
-            pass
-        # PADDLE_OBS_DEVPROF auto-attach: arm a bounded measured
-        # device-time window over the first N steps of this pass
-        # (None when the env knob is unset)
-        devprof_window = None
-        try:
-            from ..obs import devprof as _devprof
-
-            devprof_window = _devprof.maybe_start_env_window(
-                label="train_from_dataset")
         except Exception:  # noqa: BLE001 - observability, not control
             pass
         if ckpt is not None and ckpt.skip_pass:
@@ -1036,13 +1025,10 @@ class Executor:
                         h.block_until_ready()  # sync-ok: dispatch-ahead throttle
                 if ckpt is not None:
                     ckpt.on_step()
-                if devprof_window is not None:
-                    # step boundary, off the dispatch call itself:
-                    # finish the window once its budget is spent
-                    from ..obs import devprof as _devprof
-
-                    if _devprof.maybe_autostop() is not None:
-                        devprof_window = None
+                # step boundary, off the dispatch call itself: finish
+                # an `obs.profile_window(steps=N)` whose budget is spent
+                # (a single attribute check when none is armed)
+                obs.devprof.maybe_autostop()
                 if step_callback is not None:
                     step_callback(self._step,
                                   step if ckpt is None
@@ -1057,10 +1043,9 @@ class Executor:
             stat_set("in_flight_steps", 0)
             if monitor is not None:
                 monitor.stop()
-            if devprof_window is not None:
-                # short pass: the window outlived the loop; finish it
-                # so the capture is never left armed
-                devprof_window.finish()
+            # short pass: a `steps=N` window outlived the loop; finish
+            # it so the capture is never left armed
+            obs.devprof.maybe_autostop(end_of_pass=True)
             if telemetry is not None:
                 telemetry.close()
         if ckpt is not None:
@@ -1116,9 +1101,9 @@ class Executor:
         return dev
 
     def _normalize_feed(self, program, feed, stage=True) -> Dict[str, Any]:
-        from ..profiler import timed
+        from ..profiler import stage as stage_of
 
-        with timed("host_feed_ms"):
+        with stage_of("executor.feed", "host_feed_ms"):
             return self._normalize_feed_inner(program, feed, stage)
 
     def _normalize_feed_inner(self, program, feed, stage) -> Dict[str, Any]:
@@ -1404,9 +1389,9 @@ class Executor:
             v = scope.get(n)
             if src.get(n) is not v:
                 src[n] = v
-                from ..profiler import timed
+                from ..profiler import stage
 
-                with timed("host_feed_ms"):
+                with stage("executor.feed", "host_feed_ms"):
                     sh = shardings.get(n)
                     if sh is not None:
                         dev[n] = jax.device_put(v, sh)
@@ -1445,8 +1430,17 @@ class Executor:
         XLA cost_analysis lands in `entry.cost`; steady-state calls go
         straight to the cached executable and feed the live MFU gauge
         with their inter-dispatch interval — no sync, no transfer."""
+        from ..profiler import stage
+
+        # the first call traces+compiles inside fn(); book that under
+        # compile_ms so dispatch_ms reflects steady-state host overhead
+        with stage("executor.dispatch",
+                   "dispatch_ms" if entry.dispatched else "compile_ms"):
+            return self._dispatch_staged(entry, scope, feed_arrays)
+
+    def _dispatch_staged(self, entry: _CompiledEntry, scope: Scope,
+                         feed_arrays):
         from .. import obs
-        from ..profiler import time_add
 
         t0 = time.perf_counter()
         mutable_state = self._seat_state(entry, scope)
@@ -1483,37 +1477,36 @@ class Executor:
 
             entry.fn_compiled, entry.cost = compile_entry_with_cache(
                 entry, (mutable_state, const_state, feed_arrays, seed))
-        with obs.span("executor.dispatch") as sp:
-            # devprof window bookkeeping: a single attribute check when
-            # no capture window is armed; never syncs, never transfers
-            obs.devprof.note_dispatch(sp, entry.label)
-            try:
-                if entry.fn_compiled is not None:
-                    try:
-                        result = entry.fn_compiled(mutable_state,
-                                                   const_state,
-                                                   feed_arrays, seed)
-                    except TypeError:
-                        # argument signature drifted from the compiled
-                        # avals (a scope var replaced with a new
-                        # shape/dtype): fall back to the jit wrapper
-                        # permanently, which retraces — the exact
-                        # behavior this entry had pre-obs
-                        entry.fn_compiled = None
-                        result = entry.fn(mutable_state, const_state,
-                                          feed_arrays, seed)
-                else:
+        # devprof window bookkeeping: a single attribute check when
+        # no capture window is armed; never syncs, never transfers
+        obs.devprof.note_dispatch(entry.label)
+        try:
+            if entry.fn_compiled is not None:
+                try:
+                    result = entry.fn_compiled(mutable_state,
+                                               const_state,
+                                               feed_arrays, seed)
+                except TypeError:
+                    # argument signature drifted from the compiled
+                    # avals (a scope var replaced with a new
+                    # shape/dtype): fall back to the jit wrapper
+                    # permanently, which retraces — the exact
+                    # behavior this entry had pre-obs
+                    entry.fn_compiled = None
                     result = entry.fn(mutable_state, const_state,
                                       feed_arrays, seed)
-            except Exception as e:
-                # RESOURCE_EXHAUSTED forensics (obs/memprof.py): the
-                # allocator said no — publish the mem_oom flight bundle
-                # (ledger + the failing program's top static temp
-                # buffers) before re-raising.  Host-registry reads
-                # only; non-OOM errors re-raise untouched.
-                if obs.memprof.is_oom_error(e):
-                    obs.publish_mem_oom(entry.label, e)
-                raise
+            else:
+                result = entry.fn(mutable_state, const_state,
+                                  feed_arrays, seed)
+        except Exception as e:
+            # RESOURCE_EXHAUSTED forensics (obs/memprof.py): the
+            # allocator said no — publish the mem_oom flight bundle
+            # (ledger + the failing program's top static temp
+            # buffers) before re-raising.  Host-registry reads
+            # only; non-OOM errors re-raise untouched.
+            if obs.memprof.is_oom_error(e):
+                obs.publish_mem_oom(entry.label, e)
+            raise
         if entry.cost is not None:
             entry.cost.observe_dispatch(t0)
         entry.dispatched = True
@@ -1553,17 +1546,13 @@ class Executor:
             fetches = [jnp.copy(f) if n in mut and _is_device_array(f)
                        else f
                        for n, f in zip(entry.fetch_names, fetches)]
-        # the first call traces+compiles inside fn(); book that under
-        # compile_ms so dispatch_ms reflects steady-state host overhead
-        time_add("compile_ms" if first_call else "dispatch_ms",
-                 (time.perf_counter() - t0) * 1e3)
         return fetches
 
     def _finish(self, fetches, entry: _CompiledEntry, return_numpy):
         if return_numpy:
-            from ..profiler import count_sync, timed
+            from ..profiler import count_sync, stage
 
-            with timed("sync_ms"):
+            with stage("executor.sync", "sync_ms"):
                 count_sync(len(fetches))
                 return [np.asarray(f) for f in fetches]  # sync-ok: return_numpy=True
         return [LazyFetch(f, n)

@@ -295,9 +295,10 @@ def build_pretrain_step(model: BertForPretraining,
         from ..fluid.dygraph.tracer import rng_key_scope
 
         if bf16:
-            cast = {k: (v.astype(jnp.bfloat16)
-                        if v.dtype == jnp.float32 else v)
-                    for k, v in params.items()}
+            with jax.named_scope("cast"):
+                cast = {k: (v.astype(jnp.bfloat16)
+                            if v.dtype == jnp.float32 else v)
+                        for k, v in params.items()}
         else:
             cast = params
 
@@ -355,10 +356,11 @@ def build_pretrain_step(model: BertForPretraining,
         if remat:
             fwd = jax.checkpoint(fwd)
         mlm, nsp, aux = fwd(cast, batch)
-        loss = criterion(
-            nn.layer.layers.Tensor(mlm), nn.layer.layers.Tensor(nsp),
-            nn.layer.layers.Tensor(batch["masked_labels"]),
-            nn.layer.layers.Tensor(batch["nsp_labels"]))
+        with jax.named_scope("loss"):
+            loss = criterion(
+                nn.layer.layers.Tensor(mlm), nn.layer.layers.Tensor(nsp),
+                nn.layer.layers.Tensor(batch["masked_labels"]),
+                nn.layer.layers.Tensor(batch["nsp_labels"]))
         aux_w = getattr(model.bert.config, "moe_aux_weight", 0.01)
         return loss._value + aux_w * aux
 
@@ -374,23 +376,24 @@ def build_pretrain_step(model: BertForPretraining,
         # its f32 optimizer math and the fused conv runs far off MXU
         # peak (profiled round 3)
         grads = jax.lax.optimization_barrier(grads)
-        tf = t.astype(jnp.float32)
-        new_p, new_m, new_v = {}, {}, {}
-        for k, p in params.items():
-            g = grads[k].astype(jnp.float32)
-            m = b1 * state["m"][k] + (1 - b1) * g
-            v = b2 * state["v"][k] + (1 - b2) * jnp.square(g)
-            mhat = m / (1 - jnp.power(b1, tf))
-            vhat = v / (1 - jnp.power(b2, tf))
-            upd = mhat / (jnp.sqrt(vhat) + eps)
-            # no decay on bias/LN; stacked per-expert MoE biases are 2D
-            # ([E, d]) but still biases — exempt by name
-            is_bias = p.ndim <= 1 or k.endswith((".b1", ".b2"))
-            if weight_decay and not is_bias:
-                upd = upd + weight_decay * p
-            new_p[k] = p - lr_s * upd
-            new_m[k] = m
-            new_v[k] = v
+        with jax.named_scope("optimizer"):
+            tf = t.astype(jnp.float32)
+            new_p, new_m, new_v = {}, {}, {}
+            for k, p in params.items():
+                g = grads[k].astype(jnp.float32)
+                m = b1 * state["m"][k] + (1 - b1) * g
+                v = b2 * state["v"][k] + (1 - b2) * jnp.square(g)
+                mhat = m / (1 - jnp.power(b1, tf))
+                vhat = v / (1 - jnp.power(b2, tf))
+                upd = mhat / (jnp.sqrt(vhat) + eps)
+                # no decay on bias/LN; stacked per-expert MoE biases are
+                # 2D ([E, d]) but still biases — exempt by name
+                is_bias = p.ndim <= 1 or k.endswith((".b1", ".b2"))
+                if weight_decay and not is_bias:
+                    upd = upd + weight_decay * p
+                new_p[k] = p - lr_s * upd
+                new_m[k] = m
+                new_v[k] = v
         return ({"params": new_p, "m": new_m, "v": new_v, "t": t},
                 loss)
 
@@ -524,9 +527,10 @@ def build_pipeline_pretrain_step(model: BertForPretraining, mesh,
         outs = run(emb_p, block_p, last_p, aux)
         from ..nn.layer.layers import Tensor as _T
 
-        return criterion(_T(outs["mlm"]), _T(outs["nsp"]),
-                         _T(batch["masked_labels"]),
-                         _T(batch["nsp_labels"]))._value
+        with jax.named_scope("loss"):
+            return criterion(_T(outs["mlm"]), _T(outs["nsp"]),
+                             _T(batch["masked_labels"]),
+                             _T(batch["nsp_labels"]))._value
 
     lr = learning_rate
 
@@ -540,12 +544,13 @@ def build_pipeline_pretrain_step(model: BertForPretraining, mesh,
             + g_last["cls"]["decoder_weight"]
         g_emb = dict(g_emb, **{"word_embeddings.weight": tied})
         e_p, b_p, l_p = params
-        new_e = {kk: v - lr * g_emb[kk] for kk, v in e_p.items()}
-        new_b = {kk: v - lr * g_block[kk] for kk, v in b_p.items()}
-        new_l = {
-            grp: {kk: v - lr * g_last[grp][kk]
-                  for kk, v in l_p[grp].items()}
-            for grp in l_p}
+        with jax.named_scope("optimizer"):
+            new_e = {kk: v - lr * g_emb[kk] for kk, v in e_p.items()}
+            new_b = {kk: v - lr * g_block[kk] for kk, v in b_p.items()}
+            new_l = {
+                grp: {kk: v - lr * g_last[grp][kk]
+                      for kk, v in l_p[grp].items()}
+                for grp in l_p}
         new_l["cls"]["decoder_weight"] = new_e["word_embeddings.weight"]
         return {"params": (new_e, new_b, new_l)}, loss
 

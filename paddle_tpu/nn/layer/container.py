@@ -45,6 +45,20 @@ class LayerList(Layer):
             for i, layer in enumerate(sublayers):
                 self.add_sublayer(str(i), layer)
 
+    # a LayerList is iterated, never called: its children carry its
+    # name in theirs (`layers/3`), so a scope path reads as the
+    # attribute path does
+    def _set_scope_name(self, name):
+        super()._set_scope_name(name)
+        for key, layer in self._sub_layers.items():
+            layer._set_scope_name(f"{name}/{key}")
+
+    def add_sublayer(self, name, sublayer):
+        super().add_sublayer(name, sublayer)
+        if self._scope_name:
+            sublayer._set_scope_name(f"{self._scope_name}/{name}")
+        return sublayer
+
     def __getitem__(self, idx):
         if isinstance(idx, slice):
             return LayerList(list(self._sub_layers.values())[idx])
@@ -53,7 +67,7 @@ class LayerList(Layer):
 
     def __setitem__(self, idx, layer):
         keys = list(self._sub_layers)
-        self._sub_layers[keys[idx]] = layer
+        self.add_sublayer(keys[idx], layer)
 
     def __len__(self):
         return len(self._sub_layers)
@@ -70,7 +84,7 @@ class LayerList(Layer):
         layers.insert(index, layer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
     def extend(self, layers):
         for layer in layers:

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from collections import OrderedDict
 
+import jax
 import numpy as np
 
 from ...fluid import core, unique_name
@@ -66,6 +67,7 @@ class Layer:
         if name_scope is None:
             name_scope = self.__class__.__name__.lower()
         self._full_name = unique_name.generate(name_scope)
+        self._scope_name = None      # set by the parent that registers it
         self._dtype = dtype
         self._parameters = OrderedDict()
         self._sub_layers = OrderedDict()
@@ -123,7 +125,14 @@ class Layer:
 
     def add_sublayer(self, name, sublayer):
         self._sub_layers[str(name)] = sublayer
+        if sublayer is not None:
+            sublayer._set_scope_name(str(name))
         return sublayer
+
+    def _set_scope_name(self, name):
+        """The name `__call__` opens its `jax.named_scope` under: the one
+        the parent registered this layer by."""
+        self._scope_name = name
 
     # -- attribute magic ----------------------------------------------------
     def __setattr__(self, name, value):
@@ -146,6 +155,7 @@ class Layer:
                 if d is not None:
                     d.pop(name, None)
             layers[name] = value
+            value._set_scope_name(name)
         elif buffers is not None and name in buffers:
             buffers[name] = value
         else:
@@ -339,7 +349,11 @@ class Layer:
             result = hook(self, inputs)
             if result is not None:
                 inputs = result if isinstance(result, tuple) else (result,)
-        outputs = self.forward(*inputs, **kwargs)
+        # trace-time only under jit: every HLO instruction of `forward`
+        # carries the layer's path in its op_name (obs/opprof.scope_name)
+        with jax.named_scope(self._scope_name
+                             or self.__class__.__name__.lower()):
+            outputs = self.forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
             result = hook(self, inputs, outputs)
             if result is not None:

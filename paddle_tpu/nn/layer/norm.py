@@ -79,7 +79,7 @@ class SyncBatchNorm(_BatchNormBase):
             new._variance = layer._variance
             return new
         for name, sub in list(layer._sub_layers.items()):
-            layer._sub_layers[name] = cls.convert_sync_batchnorm(sub)
+            layer.add_sublayer(name, cls.convert_sync_batchnorm(sub))
         return layer
 
 

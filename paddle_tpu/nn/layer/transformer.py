@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import collections
 
+import jax
 import numpy as np
 
 from ...fluid.dygraph.tracer import trace_fn, trace_op
@@ -106,22 +107,25 @@ def _dense_ffn_block(layer, x):
     layers — routed through F.fused_feedforward (ops/pallas/ffn.py:
     XLA path by default, opt-in Pallas kernel) when the activation is
     gelu/relu and biases exist; otherwise the layer-by-layer path."""
-    if isinstance(layer.activation, GELU):
-        act_name = ("gelu_tanh" if layer.activation._approximate
-                    else "gelu")
-    elif isinstance(layer.activation, ReLU):
-        act_name = "relu"
-    else:
-        act_name = None
-    if act_name is not None and layer.linear1.bias is not None \
-            and layer.linear2.bias is not None:
-        return F.fused_feedforward(
-            x, layer.linear1.weight, layer.linear1.bias,
-            layer.linear2.weight, layer.linear2.bias,
-            activation=act_name, act_dropout=layer.dropout.p,
-            training=layer.training)
-    return layer.linear2(layer.dropout(layer.activation(
-        layer.linear1(x))))
+    # the fused path enters no sublayer: name the block itself, so
+    # its device time reads as `<layer>/ffn` on either path
+    with jax.named_scope("ffn"):
+        if isinstance(layer.activation, GELU):
+            act_name = ("gelu_tanh" if layer.activation._approximate
+                        else "gelu")
+        elif isinstance(layer.activation, ReLU):
+            act_name = "relu"
+        else:
+            act_name = None
+        if act_name is not None and layer.linear1.bias is not None \
+                and layer.linear2.bias is not None:
+            return F.fused_feedforward(
+                x, layer.linear1.weight, layer.linear1.bias,
+                layer.linear2.weight, layer.linear2.bias,
+                activation=act_name, act_dropout=layer.dropout.p,
+                training=layer.training)
+        return layer.linear2(layer.dropout(layer.activation(
+            layer.linear1(x))))
 
 
 class TransformerEncoderLayer(Layer):

@@ -137,10 +137,10 @@ def profile_window(steps: Optional[int] = None,
                    label: Optional[str] = None):
     """Arm a bounded *measured* device-time capture window
     (obs/devprof.py): `jax.profiler` trace around the next dispatches,
-    xplane parse, and the join back onto source Program ops.  Use as a
+    then `devprof.device_time` on what it wrote: device seconds by the
+    program's own names (`result["by_name"]`).  Use as a
     context manager, or pass `steps=N` and let the Executor training
-    loop auto-stop it.  `PADDLE_OBS_DEVPROF=1` arms the same window
-    from the environment."""
+    loop auto-stop it."""
     return devprof.profile_window(steps=steps, label=label)
 
 
@@ -154,7 +154,8 @@ def roofline(program=None, label: Optional[str] = None) \
     profile_window has finished."""
     prog_id = getattr(program, "prog_id", None) \
         if program is not None else None
-    return devprof.roofline_for(prog_id=prog_id, label=label)
+    res = devprof.result_for(prog_id=prog_id, label=label)
+    return res.get("roofline") if res else None
 
 
 def mem_profile(program=None, label: Optional[str] = None) \
@@ -498,20 +499,16 @@ def telemetry_epoch_refresh() -> None:
 def export_trace(path: str, include_snapshot: bool = True) -> int:
     """Write the recorded spans as Chrome-trace/Perfetto JSON.  The
     snapshot rides in otherData so tracetool can summarize MFU and
-    stall attribution from the one file; when a devprof window has
-    captured measured device time, its device op events merge in as
-    their own tracks, flow-linked from the `executor.dispatch` spans
-    that launched them.  Returns the span ("X") event count."""
+    stall attribution from the one file.  The device's timeline is the
+    profiler's own trace, where the Executor's stages appear as `pt.*`
+    annotations (`profiler.stage`).  Returns the span ("X") event
+    count."""
     other = None
     if include_snapshot:
         snap = snapshot()
         snap.pop("spans", None)  # the events ARE the span detail
         other = {"snapshot": snap}
     doc = TRACER.chrome_trace(other_data=other)
-    try:
-        devprof.merge_chrome_trace(doc)
-    except Exception:  # noqa: BLE001 - the host trace must still export
-        pass
     try:
         # ledger samples as a Chrome "C" counter track, aligned with
         # the span timeline (both perf_counter-clocked)

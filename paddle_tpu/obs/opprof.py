@@ -82,6 +82,71 @@ def parse_provenance(s: str) -> Optional[dict]:
             "type": typ, "passes": passes.split(",") if passes else []}
 
 
+# the Program op types that apply an update (ops/optimizer_ops.py)
+_OPTIMIZER_OP_TYPES = frozenset({
+    "sgd", "momentum", "adam", "adamw", "adagrad", "rmsprop", "adadelta",
+    "adamax", "lamb", "lars_momentum", "dpsgd", "dgc", "decayed_adagrad",
+    "proximal_gd", "proximal_adagrad", "ftrl",
+    "check_finite_and_unscale", "update_loss_scaling"})
+# `jit(step)`, `jvp(bert)`, `transpose(jvp(bert))`: a JAX transform
+# around (part of) the scope path
+_TRANSFORM_RE = re.compile(r"^([A-Za-z_][\w.]*)\((.*)\)$")
+_REMAT_SCOPES = ("checkpoint", "rematted_computation")
+
+
+def _scope_parts(text: str, out: List[str]) -> bool:
+    """Appends the scope names of an `op_name` (or of the inside of a
+    transform) to `out`; True when it passed through `transpose(`."""
+    bwd = False
+    depth = start = 0
+    for i, ch in enumerate(text + "/"):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth -= 1
+        elif ch == "/" and depth == 0:
+            part, start = text[start:i], i + 1
+            m = _TRANSFORM_RE.match(part)
+            if m is None:
+                if part and part not in _REMAT_SCOPES:
+                    out.append(part)
+            elif m.group(1) not in ("jit", "pjit"):
+                # jit(f) names a function, not a scope; the others
+                # wrap the scopes that were open when they were applied
+                bwd |= m.group(1) == "transpose"
+                bwd |= _scope_parts(m.group(2), out)
+    return bwd
+
+
+def scope_name(op_name: str) -> Optional[Tuple[str, str]]:
+    """`(phase, path)` of an HLO instruction's `op_name`, or None when
+    the program gave it no name.
+
+    A Program op (`program#…/op<n>:<type>`, ops/registry.py) has its
+    type for a path and its phase from it: `*_grad` is `bwd`, an
+    optimizer op `optimizer`, the rest `fwd`.  Else the path is
+    `op_name` without the `jit(…)` / `jvp(…)` / `transpose(…)` /
+    `checkpoint` wrappers and the trailing primitive
+    (`bert/encoder/layers/3/self_attn/q_proj`); the phase is
+    `optimizer` / `loss` where the path starts so, `bwd` where `op_name`
+    passed through `transpose(`, else `fwd`."""
+    prov = parse_provenance(op_name)
+    if prov is not None:
+        typ = prov["type"]
+        if typ in _OPTIMIZER_OP_TYPES:
+            return "optimizer", typ
+        return ("bwd" if typ.endswith("_grad") else "fwd"), typ
+    parts: List[str] = []
+    bwd = _scope_parts(op_name, parts)
+    if parts and not op_name.endswith(")"):
+        parts.pop()                     # the primitive: `dot_general`
+    if not parts:
+        return None
+    if parts[0] in ("optimizer", "loss"):
+        return parts[0], "/".join(parts)
+    return ("bwd" if bwd else "fwd"), "/".join(parts)
+
+
 # ---------------------------------------------------------------------------
 # HLO text parsing
 # ---------------------------------------------------------------------------
@@ -314,6 +379,71 @@ def _new_row(key: str) -> dict:
             "transpose_bytes": 0.0, "collective_bytes": 0.0}
 
 
+def _format_prov(p: dict) -> str:
+    return format_provenance(p["prog"], p["block"], p["op"], p["type"],
+                             p["passes"])
+
+
+# what XLA inserts without metadata: relayouts, the asynchronous copies
+# and slices that prefetch an operand for the op that reads it, and the
+# custom calls that stitch them (`ConcatBitcast`)
+_MOVES = _RELAYOUT | {"copy-start", "copy-done", "slice-start",
+                      "slice-done", "bitcast"}
+_INHERIT_OPS = _MOVES | {"fusion", "reshape", "broadcast", "convert",
+                         "custom-call"}
+
+
+def _inherit_from_consumers(instrs, fused_comps, consumers, key_of) -> None:
+    """A top-level relayout/fusion/reshape with no key of its own takes
+    its consumers' when they all agree (fixpoint over short
+    copy->fusion->op chains); a pure data movement whose consumers
+    have none or differ (the copy of a result to its output buffer, a
+    prefetch read forward and backward) takes its operands'.  Updates
+    `key_of` in place."""
+    by_name = {ins.name: ins for ins in instrs}
+    for _ in range(8):
+        changed = False
+        for ins in instrs:
+            if key_of.get(ins.name) is not None \
+                    or ins.comp in fused_comps \
+                    or ins.opcode not in _INHERIT_OPS:
+                continue
+            got = {key_of[c] for c in consumers.get(ins.name, ())
+                   if key_of.get(c) is not None}
+            if len(got) != 1 and ins.opcode in _MOVES:
+                got = {key_of[o] for o in ins.operands
+                       if key_of.get(o) is not None
+                       and by_name[o].comp == ins.comp}
+            if len(got) == 1:
+                key_of[ins.name] = got.pop()
+                changed = True
+        if not changed:
+            break
+
+
+def _join_map(instrs, fused_comps, key_of) -> dict:
+    """`{top-level instruction: key}`: its own (or inherited) key; a
+    fusion without one takes the dominant key of its interior
+    instructions; else UNATTRIBUTED."""
+    interior: Dict[str, collections.Counter] = \
+        collections.defaultdict(collections.Counter)
+    for ins in instrs:
+        if ins.comp in fused_comps and key_of.get(ins.name) is not None:
+            interior[ins.comp][key_of[ins.name]] += 1
+    out = {}
+    for ins in instrs:
+        if ins.comp in fused_comps:
+            continue
+        key = key_of.get(ins.name)
+        if key is None and ins.opcode == "fusion":
+            mc = _CALLS_RE.search(ins.line)
+            cnt = interior.get(mc.group(1)) if mc else None
+            if cnt:
+                key = min(cnt.items(), key=lambda kv: (-kv[1], kv[0]))[0]
+        out[ins.name] = key if key is not None else UNATTRIBUTED
+    return out
+
+
 def profile_hlo_text(text: str, label: str = "",
                      cost: Optional[Dict[str, float]] = None) -> dict:
     """Fold an optimized-HLO dump into a per-Program-op cost table.
@@ -343,32 +473,20 @@ def profile_hlo_text(text: str, label: str = "",
     # its own inherits from its consumers when they all agree, so
     # "which op still relayouts" points at the op PAYING for the
     # relayout instead of an anonymous bin
-    prov_of: Dict[str, Optional[dict]] = {
-        i.name: parse_provenance(i.op_name) for i in instrs}
     consumers: Dict[str, List[str]] = collections.defaultdict(list)
     for ins in instrs:
         if ins.comp in fused_comps:
             continue
         for o in ins.operands:
             consumers[o].append(ins.name)
-    _INHERIT_OPS = _RELAYOUT | {"fusion", "bitcast", "reshape",
-                                "broadcast", "convert"}
-    for _ in range(3):  # fixpoint over short copy->fusion->op chains
-        changed = False
-        for ins in instrs:
-            if prov_of.get(ins.name) is not None \
-                    or ins.comp in fused_comps \
-                    or ins.opcode not in _INHERIT_OPS:
-                continue
-            got = {format_provenance(p["prog"], p["block"], p["op"],
-                                     p["type"], p["passes"]): p
-                   for c in consumers.get(ins.name, ())
-                   for p in [prov_of.get(c)] if p is not None}
-            if len(got) == 1:
-                prov_of[ins.name] = next(iter(got.values()))
-                changed = True
-        if not changed:
-            break
+    prov_key: Dict[str, Optional[str]] = {}
+    prov_of_key: Dict[str, dict] = {}
+    for ins in instrs:
+        p = parse_provenance(ins.op_name)
+        prov_key[ins.name] = _format_prov(p) if p else None
+        if p:
+            prov_of_key[prov_key[ins.name]] = p
+    _inherit_from_consumers(instrs, fused_comps, consumers, prov_key)
 
     rows: Dict[str, dict] = collections.OrderedDict()
     fusion_sets: Dict[str, set] = collections.defaultdict(set)
@@ -381,16 +499,14 @@ def profile_hlo_text(text: str, label: str = "",
 
     for ins in instrs:
         in_fused = ins.comp in fused_comps
-        prov = prov_of.get(ins.name)
-        if prov is None and in_fused:
+        key = prov_key.get(ins.name)
+        if key is None and in_fused:
             # interior instruction without metadata: inherit the
             # fusion's representative provenance
             fi = fusion_instr.get(ins.comp)
-            prov = prov_of.get(fi.name) if fi is not None else None
-        key = (format_provenance(prov["prog"], prov["block"],
-                                 prov["op"], prov["type"],
-                                 prov["passes"])
-               if prov else UNATTRIBUTED)
+            key = prov_key.get(fi.name) if fi is not None else None
+        prov = prov_of_key.get(key)
+        key = key or UNATTRIBUTED
 
         flops = _instr_flops(ins, shapes)
         nbytes = 0.0
@@ -430,39 +546,16 @@ def profile_hlo_text(text: str, label: str = "",
     for key, comps in fusion_sets.items():
         rows[key]["fusions"] = max(rows[key]["fusions"], len(comps))
 
-    # instruction-name -> row key for EVERY top-level instruction
-    # (zero-cost ops included): the measured-time join (obs/devprof.py)
-    # resolves runtime thunk names against this map, so it must cover
-    # exactly the instruction set the runtime can emit events for.  A
-    # fusion with no metadata and no consumer-inherited provenance
-    # takes the dominant provenance of its interior instructions —
-    # applied to the join map only, never to the cost rows above.
-    interior_count: Dict[str, collections.Counter] = \
-        collections.defaultdict(collections.Counter)
-    for ins in instrs:
-        if ins.comp in fused_comps:
-            p = prov_of.get(ins.name)
-            if p is not None:
-                interior_count[ins.comp][format_provenance(
-                    p["prog"], p["block"], p["op"], p["type"],
-                    p["passes"])] += 1
-    instr_prov: Dict[str, str] = {}
-    for ins in instrs:
-        if ins.comp in fused_comps:
-            continue
-        p = prov_of.get(ins.name)
-        if p is not None:
-            instr_prov[ins.name] = format_provenance(
-                p["prog"], p["block"], p["op"], p["type"], p["passes"])
-            continue
-        key = UNATTRIBUTED
-        if ins.opcode == "fusion":
-            mc = _CALLS_RE.search(ins.line)
-            cnt = interior_count.get(mc.group(1)) if mc else None
-            if cnt:
-                key = sorted(cnt.items(),
-                             key=lambda kv: (-kv[1], kv[0]))[0][0]
-        instr_prov[ins.name] = key
+    # instruction -> key for EVERY top-level instruction (zero-cost ops
+    # included): obs/devprof.py joins the profiler's event names to
+    # these maps.  `instr_prov` keeps the source op's identity (the
+    # cost rows' and obs/memprof.py's key); `instr_name` is
+    # `scope_name`'s `(phase, path)`, which a functional step has too.
+    instr_prov = _join_map(instrs, fused_comps, prov_key)
+    name_key: Dict[str, Optional[Tuple[str, str]]] = {
+        ins.name: scope_name(ins.op_name) for ins in instrs}
+    _inherit_from_consumers(instrs, fused_comps, consumers, name_key)
+    instr_name = _join_map(instrs, fused_comps, name_key)
 
     cost = cost or {}
     cost_flops = float(cost.get("flops", 0.0) or 0.0)
@@ -484,8 +577,10 @@ def profile_hlo_text(text: str, label: str = "",
         table.append(row)
     table.sort(key=lambda r: -r["flops_raw"])
 
+    module = re.match(r"\s*HloModule\s+([^\s,]+)", text)
     return {
         "label": label,
+        "module": module.group(1) if module else "",
         "rows": table,
         "instruction_count": len(instrs),
         "total_flops": cost_flops or raw_flops_total,
@@ -499,6 +594,7 @@ def profile_hlo_text(text: str, label: str = "",
         "collective_bytes": sum(r["collective_bytes"] for r in table),
         "collective_bytes_by_op": dict(coll_by_op),
         "instr_prov": instr_prov,
+        "instr_name": instr_name,
     }
 
 
@@ -519,7 +615,7 @@ def trim_profile(profile: dict, k: int = 12) -> dict:
               if r["op"] == UNATTRIBUTED]
     # instr_prov is join plumbing for obs/devprof.py, not snapshot data
     out = {kk: v for kk, v in profile.items()
-           if kk not in ("rows", "instr_prov")}
+           if kk not in ("rows", "instr_prov", "instr_name")}
     out["rows"] = [_round_row(r) for r in keep + unattr]
     for f in ("total_flops", "total_flops_raw", "total_bytes",
               "total_bytes_raw", "attributed_flops_pct"):

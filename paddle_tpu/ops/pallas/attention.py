@@ -250,6 +250,7 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
         # accumulates in scratch -> arbitrary
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_fwd",
     )(seed, q, k, v, kbias)
     return out, lse
 
@@ -429,6 +430,7 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
                         pltpu.VMEM((block_h, block_k, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(seed, q, g, lse, delta, k, v, kbias)
 
     dq = pl.pallas_call(
@@ -441,6 +443,7 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
         scratch_shapes=[pltpu.VMEM((block_h, block_q, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(seed, q, g, lse, delta, k, v, kbias)
     return dq, dk, dv
 
@@ -974,6 +977,7 @@ def _ragged_paged_forward(page_rows, lengths, q, k_pages, v_pages,
         # accumulates in scratch -> arbitrary
         compiler_params=_compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention",
     )(page_rows, lengths, q, k_pages, v_pages, qpos)
 
 

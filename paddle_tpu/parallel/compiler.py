@@ -105,7 +105,7 @@ class CompiledProgram:
         return_numpy=True.  No per-step device->host transfer."""
         from ..fluid import executor as exec_mod
         from ..fluid.framework import Variable
-        from ..profiler import timed
+        from ..profiler import stage
 
         scope = scope if scope is not None else exec_mod.global_scope()
         feed = feed or {}
@@ -128,7 +128,7 @@ class CompiledProgram:
                                       fetch_names, scope)
             self._cache.put(key, entry)
 
-        with timed("host_feed_ms"):
+        with stage("executor.feed", "host_feed_ms"):
             feeds = {n: jax.device_put(a, entry.feed_shardings[n])
                      for n, a in feed_arrays.items()}
         fetches = executor._dispatch(entry, scope, feeds)

@@ -286,6 +286,45 @@ def timed(name: str):
         _tracing().TRACER.add_span(name, t0, dt)
 
 
+# the program's stages in a profiler trace are called `pt.<stage>`
+ANNOTATION_PREFIX = "pt."
+
+
+class stage:
+    """The one instrument of a per-step site (the Executor's feed
+    copies, dispatch and host materialisation): the with-block is a
+    `jax.profiler.TraceAnnotation` called `pt.<name>`, so that whoever
+    opened a profiler trace (`jax.profiler.start_trace`, the benchmark,
+    `obs.profile_window`) finds the stage in it on the device's clock;
+    on exit its wall time goes onto the millisecond timer `timer`
+    (`host_feed_ms` / `dispatch_ms` / `sync_ms`) and, when span tracing
+    is on, into the obs trace as a span called `name`.  Outside a
+    profiler session the annotation is a flag test."""
+
+    __slots__ = ("name", "timer", "_annotation", "_t0")
+
+    def __init__(self, name: str, timer: str | None = None):
+        self.name = name
+        self.timer = timer
+
+    def __enter__(self):
+        import jax
+
+        self._annotation = jax.profiler.TraceAnnotation(
+            ANNOTATION_PREFIX + self.name)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        if self.timer is not None:
+            time_add(self.timer, dt * 1e3)
+        _tracing().TRACER.add_span(self.name, self._t0, dt)
+        return False
+
+
 def count_sync(n: int = 1) -> None:
     """Record a device->host materialization on the executor hot path.
     Every sanctioned sync point calls this; the async-loop test asserts

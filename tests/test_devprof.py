@@ -1,24 +1,28 @@
-"""Measured device-time profiling tests (ISSUE 12): obs.devprof.
+"""Measured device time under the program's own names: obs.devprof.
 
-* Wire format: synthetic xplane bytes round-trip through the stdlib
-  encoder/parser with units and stat types intact.
-* Join: containers excluded from the measured denominator, the tiered
-  (exact/order/base) resolution survives runtime thunk renumbering,
-  unknown thunks land in an EXPLICIT unattributed bin, nested run
-  markers dedup and pair with dispatches by order, and the device
-  clock rebases onto the host timeline.
+* Names in the HLO: `Layer.__call__` scopes and the flash kernels'
+  names, seen in lowered text.
+* `devprof.device_time` on a recorded v5e trace (tests/data/devprof):
+  the table sums to the op time, the named share, the flash kernels'
+  time equal to their events'.
+* `profiler.stage`: `pt.executor.*` annotations in a `jax.profiler`
+  trace nobody but JAX opened, the timers advancing as before, and
+  nothing left behind outside a session.
+* The CPU backend's thunk join: containers excluded from the measured
+  denominator, unknown thunks land in an EXPLICIT unattributed bin.
 * End-to-end (acceptance): a profiled window over the transformed toy
   ResNet block attributes >=80% of measured device time to source
-  Program ops, and `obs.export_trace` emits >=1 device track
-  flow-linked from the `executor.dispatch` span — asserted against the
-  real jax.profiler capture under JAX_PLATFORMS=cpu.
-* The PR-7 orphaned-flow suppression still holds with device events
-  merged in.
+  Program ops — asserted against the real jax.profiler capture under
+  JAX_PLATFORMS=cpu.
 """
 
+import gzip
 import os
+import re
 import sys
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -27,8 +31,8 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu import obs
 from paddle_tpu.fluid import framework, unique_name
 from paddle_tpu.fluid.executor import Scope, scope_guard
+from paddle_tpu import profiler
 from paddle_tpu.obs import devprof, opprof
-from paddle_tpu.obs.tracing import Tracer
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
@@ -57,47 +61,293 @@ def _resnet_block_program():
 
 
 # ---------------------------------------------------------------------------
-# wire format (no jax touched)
+# names in the HLO (trace time only; nothing here runs a step)
 # ---------------------------------------------------------------------------
 
-class TestWireFormat:
-    def test_roundtrip_preserves_events_and_stat_types(self):
-        planes = [{"name": "/device:X", "lines": [
-            {"name": "thunks", "timestamp_ns": 12345, "events": [
-                {"name": "dot.4", "offset_ps": 1_000_000,
-                 "duration_ps": 2_000_000,
-                 "stats": {"program_id": 9, "occupancy": 0.25,
-                           "hlo_op": "dot.4"}},
-            ]},
-        ]}]
-        space = devprof.parse_xplane_bytes(devprof.encode_xspace(planes))
-        assert len(space["planes"]) == 1
-        line = space["planes"][0]["lines"][0]
-        assert line["name"] == "thunks"
-        assert line["timestamp_ns"] == 12345
-        ev = line["events"][0]
-        assert ev["name"] == "dot.4"
-        assert ev["offset_ps"] == 1_000_000
-        assert ev["duration_ps"] == 2_000_000
-        assert ev["stats"] == {"program_id": 9, "occupancy": 0.25,
-                               "hlo_op": "dot.4"}
+class TestNamesInHlo:
+    def test_layer_call_scopes_in_lowered_text(self):
+        from paddle_tpu import nn
+        from paddle_tpu.jit import functional_call, functional_state
 
-    def test_parse_dir_walks_profile_session_layout(self, tmp_path):
-        d = tmp_path / "plugins" / "profile" / "2026_08_05"
-        d.mkdir(parents=True)
-        planes = [{"name": "p", "lines": [
-            {"name": "l", "timestamp_ns": 1, "events": [
-                {"name": "e", "offset_ps": 0, "duration_ps": 1,
-                 "stats": {}}]}]}]
-        (d / "host.xplane.pb").write_bytes(
-            devprof.encode_xspace(planes))
-        space = devprof.parse_xplane_dir(str(tmp_path))
-        assert space["files"] == 1
-        assert space["planes"][0]["lines"][0]["events"][0]["name"] == "e"
+        class Block(nn.Layer):
+            def __init__(self):
+                super().__init__()
+                self.layers = nn.LayerList([
+                    nn.TransformerEncoderLayer(16, 2, 32, dropout=0.0)
+                    for _ in range(2)])
 
-    def test_garbage_bytes_raise_cleanly(self):
-        with pytest.raises(ValueError):
-            devprof.parse_xplane_bytes(b"\x07\x01garbage")
+            def forward(self, x):
+                for layer in self.layers:
+                    x = layer(x)
+                return x
+
+        paddle_tpu.seed(0)
+        model = Block()
+        params = functional_state(model)
+
+        def loss(p, x):
+            out, _ = functional_call(model, p, x)
+            return out.sum()
+
+        text = jax.jit(jax.grad(loss)).lower(
+            params, jnp.ones((2, 8, 16), jnp.float32)).as_text(
+                debug_info=True)
+        names = {opprof.scope_name(n)
+                 for n in re.findall(r'loc\("([^"]+)"', text)}
+        paths = {(phase, path) for phase, path in filter(None, names)}
+        # a root layer is its lower-cased class name; a LayerList's
+        # children carry the list's name and their index
+        for want in ("block/layers/0/self_attn/q_proj",
+                     "block/layers/1/self_attn/out_proj",
+                     "block/layers/1/ffn", "block/layers/0/norm1"):
+            assert ("fwd", want) in paths, want
+            assert ("bwd", want) in paths, want
+
+    @pytest.mark.parametrize("registered, want", [
+        ("attribute", "child"), ("add_sublayer", "named"),
+        ("list", "items/1"), ("list_appended_later", "items/2"),
+        ("sequential", "1"), ("root", "linear")])
+    def test_scope_name_is_the_registered_name(self, registered, want):
+        from paddle_tpu import nn
+
+        leaf = nn.Linear(2, 2)
+        parent = nn.Layer()
+        if registered == "attribute":
+            parent.child = leaf
+        elif registered == "add_sublayer":
+            parent.add_sublayer("named", leaf)
+        elif registered == "list":
+            parent.items = nn.LayerList([nn.Linear(2, 2), leaf])
+        elif registered == "list_appended_later":
+            parent.items = nn.LayerList([nn.Linear(2, 2), nn.Linear(2, 2)])
+            parent.items.append(leaf)
+        elif registered == "sequential":
+            parent.seq = nn.Sequential(nn.Linear(2, 2), leaf)
+        from paddle_tpu.nn.layer.layers import Tensor
+
+        text = jax.jit(lambda x: leaf(Tensor(x))._value).lower(
+            jnp.ones((1, 2))).as_text(debug_info=True)
+        assert f'/{want}/dot_general"' in text
+
+    def test_flash_kernels_are_named(self):
+        from paddle_tpu.ops.pallas import attention
+
+        q = jnp.zeros((2, 128, 2, 64), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return attention.flash_attention(
+                q, k, v, interpret=True).astype(jnp.float32).sum()
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, q).as_text(debug_info=True)
+        for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+            assert re.search(rf'[/"]{name}/', text), name
+        # the names the accepted benchmark matches stay
+        assert "_flash_forward" in text and "_flash_backward" in text
+
+
+# ---------------------------------------------------------------------------
+# the measured join on a recorded v5e trace (tests/data/devprof/record.py)
+# ---------------------------------------------------------------------------
+
+DATA = os.path.join(REPO_ROOT, "tests", "data", "devprof")
+STEPS = 4          # record.py's window
+
+
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    path = str(tmp_path_factory.mktemp("devprof") / "toy_bert.xplane.pb")
+    with gzip.open(os.path.join(DATA, "toy_bert.xplane.pb.gz")) as src, \
+            open(path, "wb") as dst:
+        dst.write(src.read())
+    with gzip.open(os.path.join(DATA, "toy_bert.hlo.txt.gz"), "rt") as f:
+        text = f.read()
+    return path, text
+
+
+class _Executable:
+    def __init__(self, text):
+        self._text = text
+
+    def as_text(self):
+        return self._text
+
+
+class TestDeviceTimeOnChipTrace:
+    def _events(self, path, window="window"):
+        trace = devprof.read_trace(path)
+        (ops,) = [d["ops"] for d in trace["devices"].values()]
+        lo, hi = [s[1:] for s in trace["host"] if s[0] == window][-1]
+        return [(n, s, e) for n, s, e in ops if s >= lo and e <= hi], lo, hi
+
+    def test_table_sums_to_the_op_time(self, recorded):
+        path, text = recorded
+        table = devprof.device_time(path, [_Executable(text)],
+                                    window_ns="window")
+        events, lo, hi = self._events(path)
+        assert table["chips"] == 1 and table["window_ns"] == (lo, hi)
+        # no event of this trace nests in another: the op time is the
+        # plain sum of the durations
+        total = sum(e - s for _, s, e in events) / 1e9
+        assert table["op_s"] == pytest.approx(total, rel=1e-9)
+        assert (sum(table["by_name"].values())
+                + sum(table["unattributed"].values())
+                == pytest.approx(table["op_s"], rel=1e-9))
+        assert table["programs"] == {"jit_step": 0}
+        busy = sum(e - s for s, e in table["busy"]["/device:TPU:0"]) / 1e9
+        assert busy == pytest.approx(total, rel=1e-9)
+
+    def test_named_share_and_phases(self, recorded):
+        path, text = recorded
+        table = devprof.device_time(path, {"step": _Executable(text)},
+                                    window_ns="window")
+        named = sum(table["by_name"].values())
+        assert named / table["op_s"] >= 0.85
+        by_phase = {}
+        for (phase, _), s in table["by_name"].items():
+            by_phase[phase] = by_phase.get(phase, 0.0) + s
+        assert set(by_phase) == {"fwd", "bwd", "optimizer", "loss"}
+        assert by_phase["bwd"] > by_phase["fwd"] > by_phase["loss"] > 0
+        # what is left is listed by instruction family, never dropped
+        assert all(re.fullmatch(r"[\w\-]+", k)
+                   for k in table["unattributed"])
+
+    def test_flash_time_is_the_kernel_events(self, recorded):
+        path, text = recorded
+        table = devprof.device_time(path, [_Executable(text)],
+                                    window_ns="window")
+        # independently: the Mosaic calls of the text, by their op_name
+        kernels = {}
+        for line in text.splitlines():
+            if 'custom_call_target="tpu_custom_call"' not in line:
+                continue
+            name = re.match(r"\s*%?([\w.\-]+)\s*=", line).group(1)
+            kernels[name] = re.search(
+                r"/(flash_\w+)/pallas_call", line).group(1)
+        assert sorted(set(kernels.values())) == [
+            "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+        events, _, _ = self._events(path)
+        want = {}
+        for n, s, e in events:
+            if n in kernels:
+                want[kernels[n]] = want.get(kernels[n], 0.0) + (e - s) / 1e9
+        got = {}
+        for (phase, path_), s in table["by_name"].items():
+            leaf = path_.rsplit("/", 1)[-1]
+            if leaf in want:
+                assert "/self_attn/" in path_
+                assert phase == ("fwd" if leaf == "flash_fwd" else "bwd")
+                got[leaf] = got.get(leaf, 0.0) + s
+        assert got == pytest.approx(want, rel=1e-9)
+        # 3 kernels x 2 layers a step; the device's clock runs ~1.3 ms
+        # ahead of the host's in this trace, so the host's window holds
+        # three of the four steps dispatched inside it
+        assert sum(1 for n, _, _ in events if n in kernels) == 3 * 2 * 3
+
+    def test_window_and_annotations(self, recorded):
+        path, text = recorded
+        whole = devprof.device_time(path, [_Executable(text)])
+        table = devprof.device_time(path, [_Executable(text)],
+                                    window_ns="window")
+        # the lead-in step lies outside the window
+        assert whole["op_s"] > table["op_s"] * (STEPS + 0.5) / STEPS
+        lo, hi = table["window_ns"]
+        half = devprof.device_time(path, [_Executable(text)],
+                                   window_ns=(lo, (lo + hi) / 2))
+        assert 0 < half["op_s"] < table["op_s"]
+        spans = table["host_spans"]
+        assert len(spans["pt.executor.dispatch"]) == STEPS
+        assert len(spans["pt.executor.sync"]) == 1
+
+    def test_no_executable_everything_unattributed(self, recorded):
+        path, _ = recorded
+        table = devprof.device_time(path, {}, window_ns="window")
+        assert table["by_name"] == {}
+        assert sum(table["unattributed"].values()) == pytest.approx(
+            table["op_s"])
+        assert table["programs"] == {"jit_step": None}
+
+
+# ---------------------------------------------------------------------------
+# profiler.stage: the Executor's stages in the profiler's own trace
+# ---------------------------------------------------------------------------
+
+def _fc_program():
+    main, startup = framework.Program(), framework.Program()
+    with framework.program_guard(main, startup), unique_name.guard():
+        x = fluid.data("x", [4, 8], "float32")
+        out = fluid.layers.reduce_mean(fluid.layers.fc(x, 4))
+    return main, startup, out
+
+
+class TestStage:
+    def test_executor_stages_in_a_plain_jax_profiler_trace(self, tmp_path):
+        main, startup, out = _fc_program()
+        feed = {"x": np.ones((4, 8), "float32")}
+        with scope_guard(Scope()):
+            exe = fluid.Executor()
+            exe.run(startup)
+            exe.run(main, feed=feed, fetch_list=[out.name])   # compile
+            before = profiler.get_time_stats()
+            syncs = profiler.get_int_stats().get("executor_sync_count", 0)
+            # nobody but JAX opens this trace: no paddle_tpu.profiler
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                for _ in range(3):
+                    exe.run(main, feed=feed, fetch_list=[out.name])
+            finally:
+                jax.profiler.stop_trace()
+        after = profiler.get_time_stats()
+        for timer in ("host_feed_ms", "dispatch_ms", "sync_ms"):
+            assert after[timer] > before.get(timer, 0.0), timer
+        assert after.get("compile_ms") == before.get("compile_ms")
+        assert profiler.get_int_stats()["executor_sync_count"] == syncs + 3
+        host = devprof.read_trace(devprof.find_xplane(str(tmp_path)))["host"]
+        counts = {}
+        for name, _, _ in host:
+            if name.startswith(profiler.ANNOTATION_PREFIX):
+                counts[name] = counts.get(name, 0) + 1
+        assert counts == {"pt.executor.feed": 3, "pt.executor.dispatch": 3,
+                          "pt.executor.sync": 3}
+        # a CPU trace has no device plane: no table comes of it
+        assert devprof.device_time(devprof.find_xplane(str(tmp_path)),
+                                   opprof.profiles()) is None
+
+    def test_first_dispatch_is_booked_as_compile(self):
+        main, startup, out = _fc_program()
+        with scope_guard(Scope()):
+            exe = fluid.Executor()
+            exe.run(startup)
+            before = profiler.get_time_stats()
+            exe.run(main, feed={"x": np.ones((4, 8), "float32")},
+                    fetch_list=[out.name])
+        after = profiler.get_time_stats()
+        assert after["compile_ms"] > before.get("compile_ms", 0.0)
+
+    def test_outside_a_session_nothing_is_left_behind(self):
+        obs.disable()
+        obs.reset()
+        syncs = profiler.get_int_stats().get("executor_sync_count", 0)
+        before = profiler.get_time_stats().get("host_feed_ms", 0.0)
+        with profiler.stage("executor.feed", "host_feed_ms"):
+            pass
+        with profiler.stage("executor.idle"):       # no timer
+            pass
+        assert len(obs.TRACER) == 0
+        assert profiler.get_int_stats().get(
+            "executor_sync_count", 0) == syncs
+        assert profiler.get_time_stats()["host_feed_ms"] >= before
+        assert "executor.idle" not in profiler.get_time_stats()
+
+    def test_stage_records_a_span_when_tracing_is_on(self):
+        obs.enable(reset=True)
+        try:
+            with profiler.stage("executor.feed", "host_feed_ms"):
+                pass
+            assert [r[0] for r in obs.TRACER.records()] == ["executor.feed"]
+        finally:
+            obs.disable()
+            obs.reset()
 
 
 # ---------------------------------------------------------------------------
@@ -111,31 +361,21 @@ def _selftest_profile():
 
 
 def _synthetic_space():
-    """One host line (nested run markers x2 runs) + one thunk line with
-    renumbered leaves + one unmatched line that must be skipped."""
+    """One thunk line + one unmatched line that must be skipped."""
     return {"planes": [{"name": "/host:CPU", "lines": [
-        {"name": "python", "timestamp_ns": 1000, "events": [
-            {"name": devprof.RUN_MARKER, "offset_ps": 0,
-             "duration_ps": 5_000_000, "stats": {}},
-            {"name": devprof.RUN_MARKER, "offset_ps": 50_000,
-             "duration_ps": 4_000_000, "stats": {}},
-            {"name": devprof.RUN_MARKER, "offset_ps": 10_000_000,
-             "duration_ps": 5_000_000, "stats": {}},
-        ]},
-        {"name": "tf_XLATfrtCpuClient/3", "timestamp_ns": 1000,
-         "events": [
+        {"name": "tf_XLATfrtCpuClient/3", "events": [
              {"name": "ThunkExecutor::Execute (wait for completion)",
               "offset_ps": 0, "duration_ps": 9_000_000, "stats": {}},
-             {"name": "dot.10", "offset_ps": 200_000,
+             {"name": "dot.4", "offset_ps": 200_000,
               "duration_ps": 4_000_000, "stats": {"program_id": 7}},
              {"name": "relu_fusion", "offset_ps": 4_400_000,
               "duration_ps": 3_000_000, "stats": {"program_id": 7}},
-             {"name": "all-reduce.3", "offset_ps": 7_600_000,
+             {"name": "all-reduce", "offset_ps": 7_600_000,
               "duration_ps": 2_000_000, "stats": {"program_id": 7}},
              {"name": "custom-call.9", "offset_ps": 9_800_000,
               "duration_ps": 1_000_000, "stats": {"program_id": 7}},
          ]},
-        {"name": "unrelated-daemon", "timestamp_ns": 1000, "events": [
+        {"name": "unrelated-daemon", "events": [
             {"name": "Sleep", "offset_ps": 0, "duration_ps": 50_000_000,
              "stats": {}}]},
     ]}]}
@@ -144,43 +384,25 @@ def _synthetic_space():
 class TestJoin:
     def test_join_tiers_and_explicit_unattributed(self):
         profiles = {"synthetic": _selftest_profile()}
-        disp = [(1, "synthetic", 10.0), (2, "synthetic", 10.001)]
-        join = devprof.join_events(_synthetic_space(), profiles, disp)
+        join = devprof.join_events(_synthetic_space(), profiles, runs=2)
+        assert join["runs"] == 2
         # containers and the skipped daemon line never enter the
         # measured denominator
         assert join["measured_ns"] == 10_000.0
         assert [s["line"] for s in join["skipped_lines"]] \
             == ["/host:CPU/unrelated-daemon"]
         ops = join["ops"]
-        # renumbered dot.10 aligns to dot.4 by suffix rank (order tier)
+        # an event is named by its instruction: the join is exact
         assert ops["program#7/block0/op1:mul"]["time_ns"] == 4_000.0
-        assert ops["program#7/block0/op1:mul"]["match"] == "order"
-        # unchanged name resolves exactly
-        relu = ops["program#7/block0/op2:relu[pass=layout_optimize]"]
-        assert relu["match"] == "exact"
+        assert ops["program#7/block0/op2:relu[pass=layout_optimize]"][
+            "time_ns"] == 3_000.0
         # the unknown thunk is binned EXPLICITLY, never silently spread
         assert ops[devprof.UNATTRIBUTED]["time_ns"] == 1_000.0
-        assert ops[devprof.UNATTRIBUTED]["match"] == "none"
         assert join["attributed_pct"] == pytest.approx(90.0)
-
-    def test_run_dedup_order_pairing_and_rebase(self):
-        profiles = {"synthetic": _selftest_profile()}
-        disp = [(5, "synthetic", 20.0), (6, "synthetic", 20.001)]
-        join = devprof.join_events(_synthetic_space(), profiles, disp)
-        # 3 raw markers -> 2 runs (the nested duplicate collapses), and
-        # the i-th run pairs with the i-th dispatch BY ORDER (the
-        # xplane epoch differs from perf_counter's)
-        assert join["runs"] == 2
-        assert join["run_seqs"] == [5, 6]
-        # rebase anchors the first marker at its dispatch timestamp
-        markers = [t for t in join["trace_events"]
-                   if t["name"] == devprof.RUN_MARKER]
-        assert markers[0]["ts_ns"] == pytest.approx(20.0 * 1e9)
 
     def test_roofline_bounds(self):
         profiles = {"synthetic": _selftest_profile()}
-        join = devprof.join_events(_synthetic_space(), profiles,
-                                   [(1, "synthetic", 1.0)])
+        join = devprof.join_events(_synthetic_space(), profiles)
         roof = devprof.compute_roofline(join, profiles, "cpu-fallback",
                                         pf=2e11, pb=5e10)
         rops = {r["op"]: r for r in roof["ops"]}
@@ -192,13 +414,6 @@ class TestJoin:
         # shares sum to ~100 over the measured denominator
         assert sum(r["share_pct"] for r in roof["ops"]) \
             == pytest.approx(100.0, abs=0.1)
-
-    def test_env_knob_parsing(self, monkeypatch):
-        for raw, want in (("", None), ("0", None), ("off", None),
-                          ("false", None), ("1", 3), ("on", 3),
-                          ("true", 3), ("7", 7)):
-            monkeypatch.setenv("PADDLE_OBS_DEVPROF", raw)
-            assert devprof.devprof_env_steps() == want, raw
 
 
 # ---------------------------------------------------------------------------
@@ -255,44 +470,6 @@ class TestDevprofEndToEnd:
             "devprof_attributed_pct") == int(round(res["attributed_pct"]))
         assert obs.snapshot()["devprof"]["windows"]
 
-    def test_export_trace_device_tracks_and_flow_links(self, tmp_path):
-        self._capture("e2e.trace")
-        path = str(tmp_path / "unified.trace.json")
-        obs.export_trace(path)
-        doc = tracetool.load_trace(path)
-        evs = doc["traceEvents"]
-        # ACCEPTANCE: >=1 device track, flow-linked from the host
-        # executor.dispatch span
-        dev_tracks = {e["tid"]: e["args"]["name"] for e in evs
-                      if e.get("ph") == "M"
-                      and str(e.get("args", {}).get("name", "")
-                              ).startswith("device:")}
-        assert dev_tracks, "no device track in the unified trace"
-        s_evs = [e for e in evs if e.get("ph") == "s"
-                 and str(e.get("id", "")).startswith("devprof:")]
-        f_evs = {e["id"]: e for e in evs if e.get("ph") == "f"
-                 and str(e.get("id", "")).startswith("devprof:")}
-        assert s_evs and all(e["id"] in f_evs for e in s_evs)
-        # every arrow starts ON the dispatch span's thread and ends on
-        # a device track
-        disp_tids = {e["tid"] for e in evs if e.get("ph") == "X"
-                     and (e.get("args") or {}).get("devprof_seq")
-                     is not None and e.get("cat") != "devprof"}
-        assert disp_tids
-        for s in s_evs:
-            assert s["tid"] in disp_tids
-            assert f_evs[s["id"]]["tid"] in dev_tracks
-            assert f_evs[s["id"]]["bp"] == "e"
-        assert doc["otherData"]["devprof"]["flows_linked"] >= 1
-        # tracetool consumes the same file: device tracks are threads,
-        # and the embedded snapshot yields the roofline table
-        s = tracetool.summarize(doc)
-        assert any(str(t["name"]).startswith("device:")
-                   for t in s["threads"])
-        roofs = tracetool.find_rooflines(path)
-        assert roofs
-        assert tracetool.roofline_cmd(path, 5, False) == 0
-
     def test_obs_roofline_api_matches_program(self):
         infer, res = self._capture("e2e.roofline")
         roof = obs.roofline(infer)
@@ -301,43 +478,3 @@ class TestDevprofEndToEnd:
             res["attributed_pct"], abs=1e-6)
         assert obs.roofline(label="e2e.roofline") is not None
         assert obs.roofline(label="no-such-window") is None
-
-
-# ---------------------------------------------------------------------------
-# orphaned-flow suppression (PR 7) survives the device merge
-# ---------------------------------------------------------------------------
-
-class TestOrphansWithDeviceEvents:
-    def test_orphan_still_suppressed_and_devprof_flows_intact(self):
-        tr = Tracer(capacity=2)
-        tr.enable()
-        good = tr.new_flow()
-        with tr.span("keep.a", flow=good):
-            pass
-        with tr.span("executor.dispatch", flow=good) as sp:
-            sp.set_attr("devprof_seq", 41)
-        orphan = tr.new_flow()
-        with tr.span("lost.start", flow=orphan):
-            pass
-        assert tr.dropped == 1
-        tr.capacity = 3
-        tr.add_span("lost.finish", 0.0, 1e-4, flow=orphan)
-        doc = tr.chrome_trace()
-        result = {"label": "t", "attributed_pct": 100.0,
-                  "trace_events": [
-                      {"name": devprof.RUN_MARKER, "ts_ns": 1e9,
-                       "dur_ns": 1e6, "track": "dev", "container": True,
-                       "seq": 41},
-                      {"name": "dot.1", "ts_ns": 1e9, "dur_ns": 5e5,
-                       "track": "dev", "op": "program#1/block0/op0:mul",
-                       "container": False},
-                  ]}
-        devprof.merge_chrome_trace(doc, result)
-        flow_ids = {e["id"] for e in doc["traceEvents"]
-                    if e.get("cat") == "flow"}
-        assert good in flow_ids          # host flow intact
-        assert orphan not in flow_ids    # PR-7 suppression holds
-        assert "devprof:41" in flow_ids  # device arrow drawn
-        assert doc["otherData"]["orphaned_flows"] == 1
-        assert doc["otherData"]["devprof"]["flows_linked"] == 1
-

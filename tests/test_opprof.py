@@ -103,6 +103,113 @@ class TestProvenanceFormat:
 # provenance survives the transform pipeline
 # ---------------------------------------------------------------------------
 
+_LAYER = "jit(step)/jvp(bertforpretraining)/bert/encoder/layers/3"
+_LAYER_T = "jit(step)/transpose(jvp(bertforpretraining))/bert/encoder/layers/3"
+_PATH = "bertforpretraining/bert/encoder/layers/3"
+
+
+class TestScopeName:
+    """`op_name` -> `(phase, path)`: the one map from an HLO
+    instruction to the name the program gave it."""
+
+    @pytest.mark.parametrize("op_name, want", [
+        # a functional step: wrappers and the primitive go
+        (_LAYER + "/self_attn/q_proj/dot_general",
+         ("fwd", _PATH + "/self_attn/q_proj")),
+        (_LAYER_T + "/self_attn/q_proj/dot_general",
+         ("bwd", _PATH + "/self_attn/q_proj")),
+        (_LAYER_T + "/self_attn/jit(_flash_backward)/flash_bwd_dq/"
+         "pallas_call", ("bwd", _PATH + "/self_attn/flash_bwd_dq")),
+        (_LAYER + "/self_attn/jit(_flash_forward)/flash_fwd/pallas_call",
+         ("fwd", _PATH + "/self_attn/flash_fwd")),
+        ("jit(step)/optimizer/sqrt", ("optimizer", "optimizer")),
+        ("jit(step)/jvp(loss)/bertpretrainingcriterion/"
+         "jit(log_softmax)/reduce_sum",
+         ("loss", "loss/bertpretrainingcriterion")),
+        # the loss is one phase, forward and backward
+        ("jit(step)/transpose(jvp(loss))/bertpretrainingcriterion/neg",
+         ("loss", "loss/bertpretrainingcriterion")),
+        ("jit(step)/jvp(cast)/convert_element_type", ("fwd", "cast")),
+        ("jit(step)/transpose(jvp(cast))/convert_element_type",
+         ("bwd", "cast")),
+        # an op_name that ends in a wrapper has no primitive to drop
+        ("jit(step)/jvp(bertforpretraining)/cls/jit(take_along_axis)",
+         ("fwd", "bertforpretraining/cls")),
+        # remat wrappers go too
+        ("jit(step)/jvp(checkpoint)/rematted_computation/bert/encoder/mul",
+         ("fwd", "bert/encoder")),
+        ("jit(step)/transpose(jvp(checkpoint))/bert/encoder/mul",
+         ("bwd", "bert/encoder")),
+        # a Program op: the path is its type, the phase comes from it
+        ("jit(f)/program#7/block0/op2:relu/max", ("fwd", "relu")),
+        ("jit(f)/program#7/block0/op2:relu[pass=layout_optimize]/max",
+         ("fwd", "relu")),
+        ("jit(f)/program#7/block0/op9:conv2d_grad[pass=nhwc,fold_bn]/conv",
+         ("bwd", "conv2d_grad")),
+        ("jit(f)/program#7/block0/op11:momentum/mul",
+         ("optimizer", "momentum")),
+        # nothing the program named
+        ("jit(step)/jvp()/convert_element_type", None),
+        ("jit(step)/add", None),
+        ("state['m']['bert.pooler.dense.weight']", None),
+        ("", None),
+    ])
+    def test_scope_name(self, op_name, want):
+        assert opprof.scope_name(op_name) == want
+
+    _HLO = """\
+HloModule jit_step, entry_computation_layout={(f32[8,8]{1,0})->f32[8,8]{1,0}}
+
+%fused_named (p: f32[8,8]) -> f32[8,8] {
+  %p = f32[8,8]{1,0} parameter(0)
+  %c = f32[] constant(0)
+  %b = f32[8,8]{1,0} broadcast(f32[] %c), dimensions={}, metadata={op_name="jit(step)/jvp(net)/act/max"}
+  %m0 = f32[8,8]{1,0} maximum(f32[8,8]{1,0} %p, f32[8,8]{1,0} %b), metadata={op_name="jit(step)/jvp(net)/act/max"}
+  ROOT %m1 = f32[8,8]{1,0} add(f32[8,8]{1,0} %m0, f32[8,8]{1,0} %p), metadata={op_name="jit(step)/jvp(net)/fc/add"}
+}
+
+%fused_bare (q: f32[8,8]) -> f32[8,8] {
+  %q = f32[8,8]{1,0} parameter(0)
+  ROOT %n = f32[8,8]{1,0} negate(f32[8,8]{1,0} %q)
+}
+
+ENTRY %main (a: f32[8,8]) -> f32[8,8] {
+  %a = f32[8,8]{1,0} parameter(0)
+  %copy.1 = f32[8,8]{0,1} copy(f32[8,8]{1,0} %a)
+  %dot.1 = f32[8,8]{1,0} dot(f32[8,8]{0,1} %copy.1, f32[8,8]{1,0} %a), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(step)/transpose(jvp(net))/fc/dot_general"}
+  %fusion.1 = f32[8,8]{1,0} fusion(f32[8,8]{1,0} %dot.1), kind=kLoop, calls=%fused_named
+  %fusion.2 = f32[8,8]{1,0} fusion(f32[8,8]{1,0} %fusion.1), kind=kLoop, calls=%fused_bare
+  ROOT %custom-call.1 = f32[8,8]{1,0} custom-call(f32[8,8]{1,0} %fusion.2), custom_call_target="X"
+}
+"""
+
+    def test_instr_name_map(self):
+        prof = opprof.profile_hlo_text(self._HLO, label="t")
+        assert prof["module"] == "jit_step"
+        names = prof["instr_name"]
+        assert names["dot.1"] == ("bwd", "net/fc")
+        # a metadata-less relayout inherits its consumer's name
+        assert names["copy.1"] == ("bwd", "net/fc")
+        # a fusion without metadata takes its interior's dominant name
+        assert names["fusion.1"] == ("fwd", "net/act")
+        # no metadata anywhere: the explicit bin
+        assert names["fusion.2"] == opprof.UNATTRIBUTED
+        assert names["custom-call.1"] == opprof.UNATTRIBUTED
+        # the provenance map knows no Program op here
+        assert set(prof["instr_prov"].values()) == {opprof.UNATTRIBUTED}
+        # neither join map rides in a snapshot
+        trimmed = opprof.trim_profile(prof)
+        assert "instr_name" not in trimmed and "instr_prov" not in trimmed
+
+    def test_program_ops_in_both_maps(self):
+        prof = opprof.profile_hlo_text(tracetool._SELFTEST_HLO)
+        assert prof["instr_prov"]["dot.4"] == "program#7/block0/op1:mul"
+        assert prof["instr_name"]["dot.4"] == ("fwd", "mul")
+        assert prof["instr_name"]["transpose.2"] == ("fwd", "mul")
+        assert prof["instr_name"]["relu_fusion"] == ("fwd", "relu")
+        assert prof["instr_name"]["Arg_0.1"] == opprof.UNATTRIBUTED
+
+
 class TestProvenanceThroughTransforms:
     def test_every_transformed_op_resolves_to_source(self):
         main, _startup, out = _resnet_block_program()

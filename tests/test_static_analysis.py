@@ -402,14 +402,13 @@ def test_telemetry_on_hot_path_watchlist():
 def test_devprof_on_hot_path_watchlist():
     """ISSUE 12: the devprof capture path is lint-watched — the
     dispatch hook runs inside every executor.run and the window
-    start/finish + xplane parse sit between profiled steps, so none of
+    start/finish sit between profiled steps, so none of
     them may block on device sync; obs/devprof.py is also in the
     span-leak watched set (profile_window must always close its
     window, even when the capture fails)."""
     watched = set(lint.hot_path_sync.WATCHLIST)
     for qual in ("note_dispatch", "maybe_autostop",
-                 "DevprofWindow.start", "DevprofWindow.finish",
-                 "parse_xplane_bytes"):
+                 "DevprofWindow.start", "DevprofWindow.finish"):
         assert ("paddle_tpu/obs/devprof.py", qual) in watched
     assert "paddle_tpu/obs/devprof.py" in lint.span_leak.WATCHED
 

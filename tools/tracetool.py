@@ -40,9 +40,9 @@ viewer:
   selftest   build a synthetic multi-thread trace through the span
              layer, export it, summarize it, verify the invariants
              end to end, run the op-profile HLO walk + top-ops
-             rendering over a synthetic HLO dump, round-trip
-             synthetic xplane bytes through the devprof wire
-             reader/join/roofline, drive the telemetry
+             rendering over a synthetic HLO dump, run synthetic
+             CPU thunk planes through the devprof join/roofline,
+             drive the telemetry
              collector/watchdog/flight-recorder over scripted
              sources, and exercise the memprof attribution + ledger
              + OOM-report math (wired into tools/ci.sh)
@@ -355,7 +355,7 @@ def find_rooflines(path: str) -> Dict[str, dict]:
     * a trace JSON (otherData.snapshot.devprof.windows...)
     * a BENCH JSON — detail.device_profile is the trimmed form
       (top_time rows with share/bound only)
-    * a bare roofline JSON (`roofline_for()` output saved to a file)
+    * a bare roofline JSON (`obs.roofline()` output saved to a file)
     """
     with open(path) as f:
         doc = json.load(f)
@@ -774,10 +774,11 @@ def _opprof_selftest_checks() -> List[tuple]:
     ]
 
 def _devprof_selftest_checks() -> List[tuple]:
-    """The measured-device-time half of the selftest: synthetic xplane
-    bytes through the wire encoder/parser, the tiered join against the
-    _SELFTEST_HLO profile, the roofline verdicts and the Chrome-trace
-    merge — all by file path, no jax."""
+    """The measured-device-time half of the selftest: synthetic CPU
+    thunk planes through the tiered join against the _SELFTEST_HLO
+    profile, and the roofline verdicts — all by file path, no jax.
+    (A chip's trace goes through `devprof.device_time`, which
+    tests/test_devprof.py checks on a recorded v5e trace.)"""
     devprof = load_devprof()
     opprof = load_opprof()
     checks: List[tuple] = []
@@ -787,63 +788,33 @@ def _devprof_selftest_checks() -> List[tuple]:
                                          "bytes_accessed": 64 * 64 * 8.0})
     profiles = {"selftest": prof}
 
-    # one host line carrying the (nested, duplicated) run markers and
-    # one device thunk line whose leaf names the runtime renumbered
-    planes = [{"name": "/host:CPU", "lines": [
-        {"name": "python", "timestamp_ns": 1000, "events": [
-            {"name": devprof.RUN_MARKER, "offset_ps": 0,
-             "duration_ps": 5_000_000, "stats": {}},
-            {"name": devprof.RUN_MARKER, "offset_ps": 100_000,
-             "duration_ps": 4_000_000, "stats": {}},      # nested dup
-            {"name": devprof.RUN_MARKER, "offset_ps": 10_000_000,
-             "duration_ps": 5_000_000, "stats": {}},      # second run
-        ]},
-        {"name": "tf_XLATfrtCpuClient/7", "timestamp_ns": 1000,
-         "events": [
+    # one device thunk line
+    space = {"planes": [{"name": "/host:CPU", "lines": [
+        {"name": "tf_XLATfrtCpuClient/7", "events": [
              {"name": "ThunkExecutor::Execute (wait for completion)",
               "offset_ps": 0, "duration_ps": 9_000_000, "stats": {}},
-             {"name": "dot.10", "offset_ps": 200_000,
-              "duration_ps": 4_000_000,
-              "stats": {"program_id": 7, "occ": 0.5, "kind": "dot"}},
+             {"name": "dot.4", "offset_ps": 200_000,
+              "duration_ps": 4_000_000, "stats": {"program_id": 7}},
              {"name": "relu_fusion", "offset_ps": 4_400_000,
               "duration_ps": 3_000_000, "stats": {"program_id": 7}},
-             {"name": "all-reduce.3", "offset_ps": 7_600_000,
+             {"name": "all-reduce", "offset_ps": 7_600_000,
               "duration_ps": 2_000_000, "stats": {"program_id": 7}},
              {"name": "custom-call.9", "offset_ps": 9_800_000,
               "duration_ps": 1_000_000, "stats": {"program_id": 7}},
          ]},
-    ]}]
+    ]}]}
 
-    data = devprof.encode_xspace(planes)
-    space = devprof.parse_xplane_bytes(data)
-    rt_line = space["planes"][0]["lines"][1]
-    dot_ev = rt_line["events"][1]
-    checks.append(("devprof: wire roundtrip preserves events + units",
-                   len(space["planes"]) == 1
-                   and rt_line["timestamp_ns"] == 1000
-                   and dot_ev["name"] == "dot.10"
-                   and dot_ev["offset_ps"] == 200_000
-                   and dot_ev["duration_ps"] == 4_000_000))
-    checks.append(("devprof: wire roundtrip preserves stat types",
-                   dot_ev["stats"].get("program_id") == 7
-                   and dot_ev["stats"].get("occ") == 0.5
-                   and dot_ev["stats"].get("kind") == "dot"))
-
-    dispatches = [(1, "selftest", 10.0), (2, "selftest", 10.001)]
-    join = devprof.join_events(space, profiles, dispatches)
+    join = devprof.join_events(space, profiles, runs=2)
     checks.append(("devprof: containers excluded from measured time",
                    join["measured_ns"] == 10_000.0
-                   and join["events"] == 4))
-    checks.append(("devprof: nested run markers dedup, pair by order",
-                   join["runs"] == 2 and join["run_seqs"] == [1, 2]))
+                   and join["events"] == 4 and join["runs"] == 2))
     by_op = join["ops"]
-    checks.append(("devprof: exact + order tiers resolve renumbered "
-                   "thunks",
+    checks.append(("devprof: thunks join their instructions by name",
                    by_op.get("program#7/block0/op1:mul",
                              {}).get("time_ns") == 4_000.0
                    and by_op.get(
                        "program#7/block0/op2:relu[pass=layout_optimize]",
-                       {}).get("match") == "exact"
+                       {}).get("time_ns") == 3_000.0
                    and by_op.get("program#7/block0/op3:c_allreduce_sum",
                                  {}).get("time_ns") == 2_000.0))
     checks.append(("devprof: unknown thunk lands in an explicit "
@@ -864,42 +835,6 @@ def _devprof_selftest_checks() -> List[tuple]:
                    and "layout_optimize" in rops.get(
                        "program#7/block0/op2:relu[pass=layout_optimize]",
                        {}).get("passes", [])))
-
-    # the unified timeline: device tracks + a flow arrow from the host
-    # dispatch span note_dispatch stamped with devprof_seq
-    host_doc = {"traceEvents": [
-        {"ph": "M", "name": "thread_name", "pid": 0, "tid": 0,
-         "args": {"name": "main"}},
-        {"ph": "X", "name": "executor.dispatch", "pid": 0, "tid": 0,
-         "ts": 10.0 * 1e6, "dur": 500.0, "cat": "span",
-         "args": {"devprof_seq": 1}},
-    ], "otherData": {}}
-    result = {"label": "selftest", "trace_events": join["trace_events"],
-              "attributed_pct": join["attributed_pct"]}
-    devprof.merge_chrome_trace(host_doc, result)
-    evs = host_doc["traceEvents"]
-    dev_tracks = [e for e in evs if e.get("ph") == "M"
-                  and str(e.get("args", {}).get("name",
-                                                "")).startswith("device:")]
-    s_evs = [e for e in evs if e.get("ph") == "s"
-             and e.get("id") == "devprof:1"]
-    f_evs = [e for e in evs if e.get("ph") == "f"
-             and e.get("id") == "devprof:1"]
-    dp = host_doc["otherData"].get("devprof", {})
-    checks.append(("devprof: merge adds device tracks + host->device "
-                   "flow",
-                   len(dev_tracks) >= 2 and len(s_evs) == 1
-                   and len(f_evs) == 1 and f_evs[0].get("bp") == "e"
-                   and s_evs[0]["tid"] == 0
-                   and dp.get("flows_linked") == 1))
-    # the rebase anchored run 1 at its dispatch time (10.0 s)
-    marker = next((e for e in evs if e.get("ph") == "X"
-                   and e["name"] == devprof.RUN_MARKER
-                   and e.get("args", {}).get("devprof_seq") == 1), None)
-    checks.append(("devprof: device clock rebased onto the host "
-                   "timeline",
-                   marker is not None
-                   and abs(marker["ts"] - 10.0 * 1e6) < 1.0))
     return checks
 
 
