@@ -296,7 +296,11 @@ def bert_base_step(cfg, batch=32, seq=512, n_masked=76, steps=3, *,
         kw = dict(mesh=mesh, dp_axis="dp", mp_axis="mp") if mesh else {}
         step, state = bert.build_pretrain_step(model, bf16=True, **kw)
         t0 = time.perf_counter()
-        compiled = step.lower(state, b, lr).compile()
+        packed0 = _stats().get("flash_packed_layout_total", 0)
+        lowered = step.lower(state, b, lr)
+        ph.info["flash_packed_layout_total"] = _stats().get(
+            "flash_packed_layout_total", 0) - packed0
+        compiled = lowered.compile()
         ph.compile_s += time.perf_counter() - t0
         losses = []
         for _ in range(n_steps):
@@ -352,6 +356,11 @@ def bert_base_step(cfg, batch=32, seq=512, n_masked=76, steps=3, *,
         ph.check(_fallback_counts()["flash_fallback_total"] == 0,
                  "flash_fallback_total == 0 (no give-way to "
                  "_xla_attention)")
+        packed = ph.info["flash_packed_layout_total"]
+        ph.check(packed == layers,
+                 f"flash_packed_layout_total == {layers}: tracing the "
+                 f"step took the (B, S, H*D) operand layout {packed} "
+                 "times, once a layer (no head transposes)")
     return ph.done()
 
 

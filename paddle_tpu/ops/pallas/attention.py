@@ -24,7 +24,17 @@ Round-2 upgrades (VERDICT.md "weak" #3, ADVICE #1):
     O(S^2)-materializing XLA recompute.
 
 Layout contract (paddle 2.x MultiHeadAttention): q/k/v are
-(batch, seq, num_heads, head_dim); internally (B*H, S, D).
+(batch, seq, num_heads, head_dim).  The kernels take them as the
+projections write them, (B, S, H*D) — a free reshape — wherever a grid
+step's heads fill whole 128-lane blocks: head pairs at D = 64 (two
+heads share a block and are told apart in-kernel by a lane mask),
+single heads at D a multiple of 128.  No transpose of q, k, v, o or
+their gradients is left around the calls then
+(`flash_packed_layout_total` counts the instances).  Every other shape
+(an odd head count at D = 64, D = 32/80/96) is merged to (B*H, S, D) by
+an XLA transpose, as all shapes were before.  One set of kernel bodies
+serves both: only the BlockSpecs and `_load_heads` / `_store_heads`
+know the layout.
 
 On non-TPU backends (CPU test meshes) the public entry point uses a
 plain XLA implementation with identical semantics.  On a TPU, a shape
@@ -111,6 +121,90 @@ def _keep_mask3(seed, bh0, q0, k0, block_h, block_q, block_k, dropout_p):
     return x >= thresh
 
 
+# -- operand layouts ----------------------------------------------------------
+
+def _load_heads(ref, block_h, mask=False):
+    """A q/k/v-like block as (block_h, rows, lanes), one entry a head.
+
+    A merged (block_h, rows, d) block is that already.  A packed
+    (1, rows, block_h * d) block holds the heads side by side on the
+    lane axis: where d is a multiple of 128 a head is an aligned static
+    lane slice.  At d = 64 two heads share a 128-lane block and are
+    separated without a relayout: each head takes its pair's whole
+    block (lanes = 128) — with `mask`, the sibling's 64 lanes zeroed,
+    so that a contraction over lanes (q k^T, g v^T) sees this head
+    alone; without, as it is, for operands contracted over rows (p v,
+    p^T g, ds^T q, ds k), whose result holds the head in its own 64
+    lanes, which is what _store_heads keeps.  Either way the MXU does
+    the passes it did at K = N = 64 (it is 128 deep and wide)."""
+    if ref.shape[0] == block_h:
+        return ref[...]
+    d = ref.shape[2] // block_h
+    if d % 128 == 0:
+        return jnp.stack([ref[0, :, h * d:(h + 1) * d]
+                          for h in range(block_h)])
+    lane = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    heads = []
+    for h in range(block_h):
+        x = ref[0, :, h // 2 * 128:(h // 2 + 1) * 128]
+        if mask:
+            x = jnp.where((lane >= 64) == bool(h % 2), x, 0)
+        heads.append(x)
+    return jnp.stack(heads)
+
+
+def _store_heads(ref, x):
+    """Inverse of _load_heads: x is (block_h, rows, lanes); of a head
+    pair's two 128-lane results each head's own 64 lanes are kept."""
+    if ref.shape[0] == x.shape[0]:
+        ref[...] = x.astype(ref.dtype)
+        return
+    d = ref.shape[2] // x.shape[0]
+    if d % 128 == 0:
+        for h in range(x.shape[0]):
+            ref[0, :, h * d:(h + 1) * d] = x[h].astype(ref.dtype)
+        return
+    lane = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    for pair in range(x.shape[0] // 2):
+        ref[0, :, pair * 128:(pair + 1) * 128] = jnp.where(
+            lane < 64, x[2 * pair], x[2 * pair + 1]).astype(ref.dtype)
+
+
+# A packed step holds up to 4 heads' (512, 512) f32 score tiles and
+# their temporaries: 17.5 MB in flash_bwd_dkv, over the compiler's
+# default scoped-VMEM budget of 16 MiB, well inside the v5e's 128.
+_PACKED_VMEM_LIMIT = 32 * 1024 * 1024
+_PACKED_MAX_SCORES = 4 * 512 * 512
+
+
+def _layout(q, k, kbias, heads):
+    """(B*H, Sq, Sk, D, packed, lanes) of the kernels' q/k operands:
+    merged (B*H, S, D), or packed (B, S, H*D) when the leading dim is
+    kbias's B (one head: the two coincide).  `lanes` is the width of a
+    head as _load_heads hands it out (and of its f32 accumulators)."""
+    packed = heads > 1 and q.shape[0] == kbias.shape[0]
+    bh, d = (q.shape[0] * heads, q.shape[2] // heads) if packed \
+        else (q.shape[0], q.shape[2])
+    lanes = max(d, 128) if packed else d
+    return bh, q.shape[1], k.shape[1], d, packed, lanes
+
+
+def _heads_spec(packed, heads, block_h, rows, d, seq_axis):
+    """BlockSpec of a q/k/v-like operand on the (batch-head block,
+    i, j) grids: block_h heads x `rows` positions, the position block
+    taken from grid axis `seq_axis`.  Merged: (block_h, rows, d) of
+    (B*H, S, D).  Packed: the same heads as (1, rows, block_h * d) of
+    (B, S, H*D): batch n // groups, lane block n % groups."""
+    if not packed:
+        return pl.BlockSpec(
+            (block_h, rows, d),
+            lambda n, i, j: (n, (n, i, j)[seq_axis], 0))
+    groups = heads // block_h
+    return pl.BlockSpec(
+        (1, rows, block_h * d),
+        lambda n, i, j: (n // groups, (n, i, j)[seq_axis], n % groups))
+
+
 # -- Pallas forward kernel ----------------------------------------------------
 
 def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, kbias_ref,
@@ -128,8 +222,8 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, kbias_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[...]  # (block_h, block_q, d)
-    k = k_ref[...]  # (block_h, block_k, d)
+    q = _load_heads(q_ref, block_h, mask=True)  # (block_h, block_q, d)
+    k = _load_heads(k_ref, block_h)             # (block_h, block_k, d)
     # batched over the head-block dim: one grid step feeds the MXU
     # block_h (q, k) panels instead of one, amortizing the ~2us
     # per-grid-step overhead that dominated the (BH, 1, 1) grid
@@ -168,7 +262,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, kbias_ref,
     m_scr[:] = m_new
     l_scr[:] = l_new
     pv = jax.lax.dot_general(
-        p_drop.astype(v_ref.dtype), v_ref[...],
+        p_drop.astype(v_ref.dtype), _load_heads(v_ref, block_h),
         (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)
     acc_scr[:] = acc_scr[:] * alpha + pv
@@ -176,7 +270,7 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, kbias_ref,
     @pl.when(ik == nk - 1)
     def _finalize():
         l = l_scr[:]
-        o_ref[...] = (acc_scr[:] / l).astype(o_ref.dtype)
+        _store_heads(o_ref, acc_scr[:] / l)
         lse_ref[...] = m_scr[:] + jnp.log(l)  # (block_h, block_q, 1)
 
 
@@ -186,13 +280,16 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, kbias_ref,
 def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
                    dropout_p=0.0, block_h=1, block_q=128, block_k=128,
                    interpret=False, causal_offset=None):
-    """q,k,v: (BH, S, D); kbias: (B, 1, Sk) f32; seed: (1,) i32
-    -> (out (BH, Sq, D), lse (BH, Sq, 1)).  Shapes must be pre-padded to
-    block multiples (flash_attention() handles that).
+    """q,k,v: merged (BH, S, D) or packed (B, S, H*D) — told apart by
+    the leading dim, kbias carrying B; kbias: (B, 1, Sk) f32; seed:
+    (1,) i32 -> (out like q, lse (BH, Sq, 1)).  Shapes must be
+    pre-padded to block multiples (flash_attention() handles that).
 
     block_h batches consecutive batch-heads into one grid step; it must
     divide heads so a head block never spans two batch elements (the
-    kbias block is per batch element).
+    kbias block is per batch element).  On the packed layout a step's
+    block_h heads are block_h * D adjacent lanes of one batch element,
+    a multiple of 128.
 
     Row-vector operands are laid out with a unit SUBLANE dim ((B, 1, Sk)
     bias blocks (1, 1, block_k); (BH, Sq, 1) lse blocks (block_h,
@@ -200,8 +297,7 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
     be divisible by (8, 128) or equal to the array dims — the round-2
     rank-2 row blocks (1, block_k) were illegal on real TPU (BENCH_r02
     failure)."""
-    bh, sq, d = q.shape
-    sk = k.shape[1]
+    bh, sq, sk, d, packed, lanes = _layout(q, k, kbias, heads)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     assert sq % block_q == 0 and sk % block_k == 0, (sq, sk)
     assert bh % block_h == 0 and heads % block_h == 0, (bh, heads, block_h)
@@ -213,42 +309,39 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
         _flash_fwd_kernel, scale=scale, block_h=block_h, block_q=block_q,
         block_k=block_k, causal=is_causal, causal_offset=causal_offset,
         dropout_p=dropout_p)
+    q_spec = _heads_spec(packed, heads, block_h, block_q, d, 1)
+    k_spec = _heads_spec(packed, heads, block_h, block_k, d, 2)
 
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_h, block_q, d),
-                         lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((block_h, block_k, d),
-                         lambda b, iq, ik: (b, ik, 0)),
-            pl.BlockSpec((block_h, block_k, d),
-                         lambda b, iq, ik: (b, ik, 0)),
+            q_spec, k_spec, k_spec,
             pl.BlockSpec((1, 1, block_k),
                          lambda b, iq, ik, h=heads, bh_=block_h:
                          ((b * bh_) // h, 0, ik)),
         ],
         out_specs=[
-            pl.BlockSpec((block_h, block_q, d),
-                         lambda b, iq, ik: (b, iq, 0)),
+            q_spec,
             pl.BlockSpec((block_h, block_q, 1),
                          lambda b, iq, ik: (b, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_h, block_q, 1), jnp.float32),
             pltpu.VMEM((block_h, block_q, 1), jnp.float32),
-            pltpu.VMEM((block_h, block_q, d), jnp.float32),
+            pltpu.VMEM((block_h, block_q, lanes), jnp.float32),
         ],
         # bh/iq steps write disjoint outputs -> parallel lets Mosaic
         # double-buffer DMA across grid steps (the (bh, 1, 1) grid at
         # 512-blocks is otherwise serialized per-step overhead); ik
         # accumulates in scratch -> arbitrary
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(
+            vmem_limit=_PACKED_VMEM_LIMIT if packed else None),
         interpret=interpret,
         name="flash_fwd",
     )(seed, q, k, v, kbias)
@@ -272,10 +365,10 @@ def _flash_bwd_dkv_kernel(seed_ref, q_ref, g_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    q = q_ref[...]          # (block_h, block_q, d)
-    g = g_ref[...]          # (block_h, block_q, d)
-    k = k_ref[...]          # (block_h, block_k, d)
-    v = v_ref[...]          # (block_h, block_k, d)
+    q = _load_heads(q_ref, block_h, mask=True)  # (block_h, block_q, d)
+    g = _load_heads(g_ref, block_h, mask=True)  # (block_h, block_q, d)
+    k = _load_heads(k_ref, block_h)             # (block_h, block_k, d)
+    v = _load_heads(v_ref, block_h)             # (block_h, block_k, d)
     lse = lse_ref[...]      # (block_h, block_q, 1)
     delta = delta_ref[...]  # (block_h, block_q, 1)
 
@@ -321,8 +414,8 @@ def _flash_bwd_dkv_kernel(seed_ref, q_ref, g_ref, lse_ref, delta_ref,
 
     @pl.when(iq == nq - 1)
     def _finalize():
-        dk_ref[...] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[...] = dv_scr[:].astype(dv_ref.dtype)
+        _store_heads(dk_ref, dk_scr[:])
+        _store_heads(dv_ref, dv_scr[:])
 
 
 def _flash_bwd_dq_kernel(seed_ref, q_ref, g_ref, lse_ref, delta_ref,
@@ -338,10 +431,10 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, g_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    q = q_ref[...]
-    g = g_ref[...]
-    k = k_ref[...]
-    v = v_ref[...]
+    q = _load_heads(q_ref, block_h, mask=True)
+    g = _load_heads(g_ref, block_h, mask=True)
+    k = _load_heads(k_ref, block_h)
+    v = _load_heads(v_ref, block_h)
     lse = lse_ref[...]      # (block_h, block_q, 1)
     delta = delta_ref[...]  # (block_h, block_q, 1)
 
@@ -375,7 +468,7 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, g_ref, lse_ref, delta_ref,
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        dq_ref[...] = dq_scr[:].astype(dq_ref.dtype)
+        _store_heads(dq_ref, dq_scr[:])
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -385,31 +478,36 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
                     is_causal=False, scale=None, dropout_p=0.0,
                     block_h=1, block_q=128, block_k=128, interpret=False,
                     causal_offset=None):
-    bh, sq, d = q.shape
-    sk = k.shape[1]
+    bh, sq, sk, d, packed, lanes = _layout(q, k, kbias, heads)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     assert bh % block_h == 0 and heads % block_h == 0, (bh, heads, block_h)
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)  # (BH, Sq, 1)
+    go = g.astype(jnp.float32) * out.astype(jnp.float32)
+    if packed:
+        # per-head sums of a (B, Sq, H*D) product: a reduce over a
+        # reshape to (..., H, D) costs an f32 relayout of the product;
+        # the MXU sums each head's D lanes in place instead
+        heads_of = jnp.repeat(jnp.eye(heads, dtype=jnp.float32), d, axis=0)
+        delta = jnp.dot(go.reshape(-1, heads * d), heads_of,
+                        precision=lax.Precision.HIGH)
+        delta = jnp.transpose(delta.reshape(-1, sq, heads),
+                              (0, 2, 1)).reshape(bh, sq, 1)
+    else:
+        delta = jnp.sum(go, axis=-1, keepdims=True)  # (BH, Sq, 1)
     if causal_offset is None:
         causal_offset = sk - sq
     kw = dict(scale=scale, block_h=block_h, block_q=block_q,
               block_k=block_k, causal=is_causal,
               causal_offset=causal_offset, dropout_p=dropout_p)
 
-    q_spec = pl.BlockSpec((block_h, block_q, d),
-                          lambda b, i, j: (b, i, 0))
+    q_spec = _heads_spec(packed, heads, block_h, block_q, d, 1)
     row_spec = pl.BlockSpec((block_h, block_q, 1),
                             lambda b, i, j: (b, i, 0))
     # dkv grid iterates (bh, ik, iq): swap index maps for q-side inputs
-    q_spec_t = pl.BlockSpec((block_h, block_q, d),
-                            lambda b, i, j: (b, j, 0))
+    q_spec_t = _heads_spec(packed, heads, block_h, block_q, d, 2)
     row_spec_t = pl.BlockSpec((block_h, block_q, 1),
                               lambda b, i, j: (b, j, 0))
-    k_spec = pl.BlockSpec((block_h, block_k, d),
-                          lambda b, i, j: (b, j, 0))
-    k_spec_t = pl.BlockSpec((block_h, block_k, d),
-                            lambda b, i, j: (b, i, 0))
+    k_spec = _heads_spec(packed, heads, block_h, block_k, d, 2)
+    k_spec_t = _heads_spec(packed, heads, block_h, block_k, d, 1)
     kb_spec = pl.BlockSpec((1, 1, block_k),
                            lambda b, i, j, h=heads, bh_=block_h:
                            ((b * bh_) // h, 0, j))
@@ -417,6 +515,8 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
                              lambda b, i, j, h=heads, bh_=block_h:
                              ((b * bh_) // h, 0, i))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    params = _compiler_params(
+        vmem_limit=_PACKED_VMEM_LIMIT if packed else None)
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **kw),
@@ -424,11 +524,11 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
         in_specs=[smem, q_spec_t, q_spec_t, row_spec_t, row_spec_t,
                   k_spec_t, k_spec_t, kb_spec_t],
         out_specs=[k_spec_t, k_spec_t],
-        out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, sk, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_h, block_k, d), jnp.float32),
-                        pltpu.VMEM((block_h, block_k, d), jnp.float32)],
-        compiler_params=_compiler_params(),
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_h, block_k, lanes), jnp.float32),
+                        pltpu.VMEM((block_h, block_k, lanes), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dkv",
     )(seed, q, g, lse, delta, k, v, kbias)
@@ -439,9 +539,9 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
         in_specs=[smem, q_spec, q_spec, row_spec, row_spec,
                   k_spec, k_spec, kb_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_h, block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_h, block_q, lanes), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dq",
     )(seed, q, g, lse, delta, k, v, kbias)
@@ -523,15 +623,27 @@ def _pick_blocks(sq, sk, d, block_q=None, block_k=None,
     return block_q, block_k
 
 
-def _block_h_ladder(heads):
+def _packs(heads, d):
+    """Whether the kernels can take q/k/v as the projections write
+    them, (B, S, H*D): a grid step's heads must be whole 128-lane
+    blocks, a head pair at D = 64, single heads at D a multiple of
+    128.  Every other shape keeps the merged (B*H, S, D) operands."""
+    return d % 128 == 0 or (d == 64 and heads % 2 == 0)
+
+
+def _block_h_ladder(heads, lane_d=None, max_h=8):
     """Candidate head-block sizes, largest first, ending in the
-    always-valid 1.  Batching block_h (q, k) panels per grid step
+    smallest valid one.  Batching block_h (q, k) panels per grid step
     amortizes the fixed per-grid-step cost of the (BH, 1, 1) grid at
     512-blocks.  Each candidate must divide `heads` (a head block must
-    not span batch elements — the kbias block is per batch element).
-    Whether a rung fits VMEM is Mosaic's call: the caller
-    compile-probes each rung and takes the first one accepted."""
-    return [B for B in (8, 6, 4, 3, 2) if heads % B == 0] + [1]
+    not span batch elements — the kbias block is per batch element)
+    and, on the packed layout (`lane_d` = its D), fill whole 128-lane
+    blocks: head pairs at D = 64.  Whether a rung fits VMEM is Mosaic's
+    call: the caller compile-probes each rung and takes the first one
+    accepted (`max_h` spares it the probes of rungs known too large)."""
+    return [B for B in (8, 6, 4, 3, 2, 1) if heads % B == 0
+            and (lane_d is None or B * lane_d % 128 == 0)
+            and B <= max_h]
 
 
 def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
@@ -551,6 +663,14 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
     multiples, padded keys are masked via the bias, and the output is
     sliced back (ADVICE round-1 #1: the unpadded kernel read garbage
     K/V columns for non-block-multiple lengths).
+
+    Operand layout, chosen from the shape (`_packs`): where a grid
+    step's heads fill whole 128-lane blocks the kernels read q/k/v and
+    write the output (and dq/dk/dv) as (B, S, H*D), a free reshape of
+    what the projections produce, so no transpose surrounds the calls
+    (`flash_packed_layout_total` counts these instances); otherwise
+    heads are merged into (B*H, S, D) by an XLA transpose, D padded to
+    a multiple of 64.
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -559,10 +679,14 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
     block_q, block_k = _pick_blocks(sq, sk, d, block_q, block_k)
     sq_p = round_up(sq, block_q)
     sk_p = round_up(sk, block_k)
-    d_p = round_up(d, 64)
+    packed = _packs(h, d)
+    d_p = d if packed else round_up(d, 64)
 
-    merge = lambda x, s: jnp.transpose(x, (0, 2, 1, 3)).reshape(
-        b * h, s, x.shape[-1])
+    if packed:
+        merge = lambda x, s: x.reshape(b, s, h * d)
+    else:
+        merge = lambda x, s: jnp.transpose(x, (0, 2, 1, 3)).reshape(
+            b * h, s, d)
     qm, km, vm = merge(q, sq), merge(k, sk), merge(v, sk)
     if sq_p != sq or d_p != d:
         qm = jnp.pad(qm, ((0, 0), (0, sq_p - sq), (0, d_p - d)))
@@ -585,7 +709,11 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
         seed = jnp.zeros((1,), jnp.int32)
     seed_f = lax.bitcast_convert_type(seed, jnp.float32)
 
-    ladder = _block_h_ladder(h)
+    # packed steps are sized to _PACKED_VMEM_LIMIT: at most 4 heads'
+    # (512, 512) score tiles; the merged ladder is left to the probes
+    ladder = _block_h_ladder(
+        h, d, _PACKED_MAX_SCORES // (block_q * block_k)) if packed \
+        else _block_h_ladder(h)
     if interpret:
         # exercise the head-blocked (3D-batched) kernel path in CPU
         # interpret tests too — same grid validity rules, no probing
@@ -599,10 +727,11 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
         block_h = None
         if on_tpu():
             for cand in ladder:
-                if _probe_exact(qm.shape, km.shape, h, is_causal,
-                                float(dropout_p), qm.dtype, cand,
-                                block_q, block_k, sk - sq,
-                                final_rung=(cand == ladder[-1])):
+                if _probe_exact((b * h, sq_p, d_p), (b * h, sk_p, d_p), h,
+                                is_causal, float(dropout_p), qm.dtype,
+                                cand, block_q, block_k, sk - sq,
+                                final_rung=(cand == ladder[-1]),
+                                packed=packed):
                     block_h = cand
                     break
         if block_h is None:
@@ -620,6 +749,11 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
     out = _flash_attention(qm, km, vm, bias, seed_f, h, is_causal, scale,
                            float(dropout_p), interpret, sk - sq,
                            block_h, block_q, block_k)
+    if packed:
+        from ...profiler import stat_add
+
+        stat_add("flash_packed_layout_total")
+        return out[:, :sq].reshape(b, sq, h, d)
     out = out[:, :sq, :d]
     return jnp.transpose(out.reshape(b, h, sq, d), (0, 2, 1, 3))
 
@@ -629,22 +763,26 @@ _EXACT_PROBE_CACHE = {}
 
 def _probe_exact(q_shape, k_shape, heads, is_causal, dropout_p, dtype,
                  block_h, block_q, block_k, causal_offset,
-                 final_rung=True):
+                 final_rung=True, packed=False):
     """Compile (never run) the exact kernel instances flash_attention is
-    about to stage, once per configuration.  Returns False if Mosaic
+    about to stage, once per configuration.  q_shape / k_shape are the
+    padded (B*H, S, D) whichever the operand layout; `packed` probes
+    the (B, S, H*D) instance of them.  Returns False if Mosaic
     refuses them, so the caller can take a smaller head-block rung (or
     XLA) instead of poisoning the surrounding jit compile.
     final_rung=False marks a speculative head-block ladder rung: its
     refusal is routine and stays silent and uncounted."""
     key = (q_shape, k_shape, heads, is_causal, dropout_p,
            jnp.dtype(dtype).name, block_h, block_q, block_k,
-           causal_offset)
+           causal_offset, packed)
     if key not in _EXACT_PROBE_CACHE:
         def compile_probe():
             bh, sq, d = q_shape
             sk = k_shape[1]
-            x = probe_struct(q_shape, dtype)
-            kv = probe_struct(k_shape, dtype)
+            fold = (lambda s: (bh // heads, s, heads * d)) if packed \
+                else (lambda s: (bh, s, d))
+            x = probe_struct(fold(sq), dtype)
+            kv = probe_struct(fold(sk), dtype)
             kb = probe_struct((bh // heads, 1, sk), jnp.float32)
             seed = probe_struct((1,), jnp.int32)
             kw = dict(is_causal=is_causal, dropout_p=dropout_p,
@@ -713,10 +851,14 @@ def _try_compile(compile_fn, cache, key, fail_msg, count):
     return cache[key]
 
 
-def _compiler_params(semantics=("parallel", "parallel", "arbitrary")):
+def _compiler_params(semantics=("parallel", "parallel", "arbitrary"),
+                     vmem_limit=None):
     """Grid dimension semantics: parallel over independent output
-    blocks, arbitrary over accumulation axes."""
-    return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
+    blocks, arbitrary over accumulation axes.  vmem_limit: the kernel's
+    scoped-VMEM budget in bytes where the compiler's default (16 MiB of
+    the v5e's 128) is not to decide."""
+    return pltpu.CompilerParams(dimension_semantics=tuple(semantics),
+                                vmem_limit_bytes=vmem_limit)
 
 
 def _probe_flash_kernel(block_q=128, block_k=128, d=128,

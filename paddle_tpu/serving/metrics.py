@@ -47,6 +47,9 @@ Int stats (get_int_stats):
 |                               | refused shape at trace time, never per  |
 |                               | step; chip_smoke.py and the TPU lane    |
 |                               | fail on a non-zero count of either      |
+| flash_packed_layout_total     | flash-attention instances traced on the |
+|                               | projections' (B, S, H*D) layout (head   |
+|                               | pairs at D = 64): no head transposes    |
 | serving_decode_steps          | decode-step dispatches (autoregressive) |
 
 Per-tenant series (multi-tenant fleet, serving/registry.py): every

@@ -72,23 +72,25 @@ class TestFlashForward:
         assert A._mask_as_key_bias(per_head, 2, 128) is None
 
 
-class TestFlashBackward:
-    def _grads(self, fn, q, k, v):
-        def loss(q, k, v):
-            out = fn(q, k, v)
-            # non-uniform cotangent exercises all grad paths
-            w = jnp.arange(out.size, dtype=jnp.float32).reshape(out.shape)
-            return jnp.sum(out * w) / out.size
-        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+def _grads(fn, q, k, v):
+    """dq, dk, dv of `fn` under a non-uniform cotangent (exercises all
+    grad paths)."""
+    def loss(q, k, v):
+        out = fn(q, k, v)
+        w = jnp.arange(out.size, dtype=jnp.float32).reshape(out.shape)
+        return jnp.sum(out * w) / out.size
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
+
+class TestFlashBackward:
     @pytest.mark.parametrize("causal", [False, True])
     def test_grads_vs_oracle(self, causal):
         rng = np.random.RandomState(4)
         q, k, v = _rand_qkv(rng, b=1, sq=128, sk=128, h=2, d=64)
-        g_ref = self._grads(
+        g_ref = _grads(
             lambda q, k, v: A._xla_attention(q, k, v, is_causal=causal),
             q, k, v)
-        g_out = self._grads(
+        g_out = _grads(
             lambda q, k, v: A.flash_attention(q, k, v, is_causal=causal,
                                               interpret=True),
             q, k, v)
@@ -103,10 +105,10 @@ class TestFlashBackward:
         lens = np.array([88, 41])
         bool_mask = jnp.asarray(np.arange(sk)[None, :] < lens[:, None])
         bias = jnp.where(bool_mask, 0.0, A.DEFAULT_MASK_VALUE)
-        g_ref = self._grads(
+        g_ref = _grads(
             lambda q, k, v: A._xla_attention(
                 q, k, v, mask=bool_mask[:, None, None, :]), q, k, v)
-        g_out = self._grads(
+        g_out = _grads(
             lambda q, k, v: A.flash_attention(q, k, v, key_bias=bias,
                                               interpret=True), q, k, v)
         for a, b_ in zip(g_out, g_ref):
@@ -182,6 +184,182 @@ class TestFlashDropout:
         np.testing.assert_array_equal(sub, keep[128:, 64:192])
 
 
+def _packed_count():
+    from paddle_tpu import profiler
+
+    return profiler.get_int_stats().get("flash_packed_layout_total", 0)
+
+
+class TestPackedLayout:
+    """Where a grid step's heads fill whole 128-lane blocks (head pairs
+    at D = 64, heads at D % 128 == 0) the kernels read q/k/v and write
+    o/dq/dk/dv as (B, S, H*D), the projections' own layout: same
+    results as the XLA oracle and as the merged (B*H, S, D) path, no
+    transpose around the calls, the same dropout bits."""
+
+    @pytest.fixture
+    def merged(self, monkeypatch):
+        """Force the merged operand layout for any shape."""
+        def force():
+            monkeypatch.setattr(A, "_packs", lambda h, d: False)
+        return force
+
+    @pytest.mark.parametrize("h,d", [(12, 64), (4, 64), (4, 128)])
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_forward_and_grads(self, h, d, causal, merged):
+        rng = np.random.RandomState(20 + h + d)
+        q, k, v = _rand_qkv(rng, b=2, sq=128, sk=128, h=h, d=d)
+        assert A._packs(h, d)
+        flash = lambda q, k, v: A.flash_attention(
+            q, k, v, is_causal=causal, interpret=True)
+        oracle = lambda q, k, v: A._xla_attention(q, k, v,
+                                                  is_causal=causal)
+        before = _packed_count()
+        out, g_out = flash(q, k, v), _grads(flash, q, k, v)
+        assert _packed_count() == before + 2
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(oracle(q, k, v)),
+                                   rtol=1e-3, atol=1e-3)
+        for a, b in zip(g_out, _grads(oracle, q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-3)
+        merged()
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(flash(q, k, v)),
+                                   rtol=1e-5, atol=1e-5)
+        for a, b in zip(g_out, _grads(flash, q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-5)
+        assert _packed_count() == before + 2
+
+    @pytest.mark.parametrize("h,d", [(4, 64), (2, 128)])
+    def test_cross_attention_causal_offset(self, h, d):
+        rng = np.random.RandomState(31)
+        q, k, v = _rand_qkv(rng, sq=128, sk=256, h=h, d=d)
+        flash = lambda q, k, v: A.flash_attention(
+            q, k, v, is_causal=True, interpret=True)
+        oracle = lambda q, k, v: A._xla_attention(q, k, v, is_causal=True)
+        np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                                   np.asarray(oracle(q, k, v)),
+                                   rtol=1e-3, atol=1e-3)
+        for a, b in zip(_grads(flash, q, k, v), _grads(oracle, q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-3)
+
+    @pytest.mark.parametrize("h,d", [(4, 64), (2, 128)])
+    def test_unaligned_lengths_with_key_bias(self, h, d):
+        """The padding shim pads the sequence axis of (B, S, H*D)."""
+        rng = np.random.RandomState(32)
+        b, sq, sk = 2, 70, 90
+        q, k, v = _rand_qkv(rng, b=b, sq=sq, sk=sk, h=h, d=d)
+        lens = np.array([88, 41])
+        keep = jnp.asarray(np.arange(sk)[None, :] < lens[:, None])
+        bias = jnp.where(keep, 0.0, A.DEFAULT_MASK_VALUE)
+        flash = lambda q, k, v: A.flash_attention(
+            q, k, v, key_bias=bias, interpret=True)
+        oracle = lambda q, k, v: A._xla_attention(
+            q, k, v, mask=keep[:, None, None, :])
+        np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                                   np.asarray(oracle(q, k, v)),
+                                   rtol=1e-3, atol=1e-3)
+        for a, b_ in zip(_grads(flash, q, k, v), _grads(oracle, q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       rtol=2e-3, atol=2e-3)
+
+    @pytest.mark.parametrize("h,d", [(4, 64), (12, 64), (2, 128)])
+    def test_dropout_bits_are_keep_mask3_at_absolute_head(self, h, d):
+        """The packed grid passes bh0 = b*H + first head of the step:
+        the keep mask is `_keep_mask3` over (b*H + h, q, k), the bits
+        the merged grid draws."""
+        rng = np.random.RandomState(33)
+        b, s, p_drop, seed = 2, 128, 0.3, 13
+        q, k, v = _rand_qkv(rng, b=b, sq=s, sk=s, h=h, d=d)
+        keep = A._keep_mask3(jnp.int32(seed), 0, 0, 0, b * h, s, s,
+                             p_drop).reshape(b, h, s, s)
+
+        def oracle(q, k, v):
+            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (d ** 0.5)
+            probs = jax.nn.softmax(logits, axis=-1)
+            probs = jnp.where(keep, probs / (1.0 - p_drop), 0.0)
+            return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+        flash = lambda q, k, v: A.flash_attention(
+            q, k, v, dropout_p=p_drop, dropout_seed=seed, interpret=True)
+        np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                                   np.asarray(oracle(q, k, v)),
+                                   rtol=1e-3, atol=1e-3)
+        for a, b_ in zip(_grads(flash, q, k, v), _grads(oracle, q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       rtol=2e-3, atol=2e-3)
+
+    @pytest.mark.parametrize("h,d", [(3, 64), (2, 32), (2, 80)])
+    def test_other_shapes_stay_merged(self, h, d):
+        """Odd head counts at D = 64 and head dims that fill no
+        128-lane block keep the merged path, and still match."""
+        assert not A._packs(h, d)
+        rng = np.random.RandomState(34)
+        q, k, v = _rand_qkv(rng, b=1, sq=128, sk=128, h=h, d=d)
+        flash = lambda q, k, v: A.flash_attention(
+            q, k, v, is_causal=True, interpret=True)
+        oracle = lambda q, k, v: A._xla_attention(q, k, v, is_causal=True)
+        before = _packed_count()
+        np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                                   np.asarray(oracle(q, k, v)),
+                                   rtol=1e-3, atol=1e-3)
+        for a, b in zip(_grads(flash, q, k, v), _grads(oracle, q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-3)
+        assert _packed_count() == before
+
+    def test_no_rank4_transpose_around_the_kernels(self):
+        """Two BERT-width attention blocks (projections -> flash ->
+        out projection), forward and backward: the jaxpr holds the six
+        kernel calls and no transpose of a (B, S, H, D) operand."""
+        b, s, h, d = 2, 128, 12, 64
+        rng = np.random.RandomState(35)
+        x = jnp.asarray(rng.randn(b, s, h * d), jnp.float32)
+        ws = [jnp.asarray(rng.randn(4, h * d, h * d) / 28, jnp.float32)
+              for _ in range(2)]
+
+        def blocks(x, ws):
+            for w in ws:
+                q, k, v = ((x @ w[i]).reshape(b, s, h, d)
+                           for i in range(3))
+                o = A.flash_attention(q, k, v, dropout_p=0.1,
+                                      dropout_seed=3, interpret=True)
+                x = x + o.reshape(b, s, h * d) @ w[3]
+            return jnp.sum(x * x)
+
+        seen = {"pallas_call": 0, "rank4_transpose": 0}
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    seen["pallas_call"] += 1
+                    continue            # the kernel body is Mosaic's
+                if eqn.primitive.name == "transpose" and any(
+                        getattr(v.aval, "ndim", 0) == 4
+                        for v in eqn.invars):
+                    seen["rank4_transpose"] += 1
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jax.make_jaxpr(jax.grad(blocks, argnums=(0, 1)))(x, ws).jaxpr)
+        assert seen == {"pallas_call": 6, "rank4_transpose": 0}
+
+    def test_packed_head_block_ladder(self):
+        """Packed rungs hold whole 128-lane blocks (head pairs at
+        D = 64) and at most the heads `max_h` allows; the merged ladder
+        is what it was."""
+        assert A._block_h_ladder(12) == [6, 4, 3, 2, 1]
+        assert A._block_h_ladder(12, 64, 4) == [4, 2]
+        assert A._block_h_ladder(12, 64, 64) == [6, 4, 2]
+        assert A._block_h_ladder(6, 64, 4) == [2]
+        assert A._block_h_ladder(8, 64, 8) == [8, 4, 2]
+        assert A._block_h_ladder(4, 128, 4) == [4, 2, 1]
+        assert A._block_h_ladder(3, 128, 4) == [3, 1]
+
+
 class TestMosaicAcceptsForV5e:
     """Interpret mode proves the arithmetic and nothing about Mosaic.
     libtpu compiles for a v5e TOPOLOGY on a host without a chip, so
@@ -236,6 +414,26 @@ class TestMosaicAcceptsForV5e:
         assert A._flash_ok(q, q)
         assert A._probe_exact((8, 512, 64), (8, 512, 64), 4, False, 0.1,
                               jnp.bfloat16, 2, 512, 512, 0)
+        # the packed (B, S, H*D) instances of the two BERT cells, at
+        # the rung flash_attention() tries first there
+        assert A._probe_exact((24, 512, 64), (24, 512, 64), 12, False,
+                              0.1, jnp.bfloat16, 4, 512, 512, 0,
+                              packed=True)
+        assert A._probe_exact((24, 128, 64), (24, 128, 64), 12, False,
+                              0.1, jnp.bfloat16, 6, 128, 128, 0,
+                              packed=True)
+
+    @pytest.mark.parametrize("bh,sq,sk,d,heads,block_h,causal", [
+        (8, 512, 512, 128, 4, 4, False),    # one head a lane block
+        (16, 256, 512, 64, 8, 8, True),     # wmt: cross, causal offset
+        (12, 512, 512, 64, 6, 2, False),    # dp2 x mp2: 6 local heads
+    ])
+    def test_flash_pair_packed(self, v5e, bh, sq, sk, d, heads, block_h,
+                               causal):
+        assert A._probe_exact((bh, sq, d), (bh, sk, d), heads, causal,
+                              0.1, jnp.bfloat16, block_h,
+                              min(sq, 512), min(sk, 512), sk - sq,
+                              packed=True)
 
 
 def test_flash_per_shard_matches_unsharded():

@@ -151,6 +151,69 @@ def test_flash_pair_at_bench_shape():
     assert rungs
 
 
+@pytest.mark.parametrize("b,s,h,d", [
+    (128, 128, 12, 64),     # bert_base.pretrain_s128's instance
+    (8, 512, 4, 128),       # one head a 128-lane block
+])
+def test_flash_pair_packed_at_bench_shapes(b, s, h, d):
+    """The packed (B, S, H*D) instances beside the s512 one above:
+    bf16, key-padding bias, dropout 0.1, forward and both backward
+    kernels against the same-keep-mask oracle; each traced instance
+    counts in `flash_packed_layout_total`."""
+    p_drop, seed = 0.1, 7
+    q, k, v, g = (_rand((b, s, h, d), i, jnp.bfloat16)
+                  for i in (30, 31, 32, 33))
+    lens = np.random.RandomState(34).randint(s // 2, s + 1, (b,))
+    kb = jnp.where(jnp.arange(s)[None, :] < lens[:, None], 0.0,
+                   -1e9).astype(jnp.float32)
+    keep = A._keep_mask3(jnp.int32(seed), 0, 0, 0, b * h, s, s,
+                         p_drop).reshape(b, h, s, s)
+
+    def oracle(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (d ** 0.5)
+        probs = jax.nn.softmax(logits + kb[:, None, None, :], axis=-1)
+        probs = jnp.where(keep, probs / (1.0 - p_drop), 0.0)
+        return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, key_bias=kb, dropout_p=p_drop,
+                               dropout_seed=seed).astype(jnp.float32)
+
+    gf = g.astype(jnp.float32)
+    before = profiler.get_int_stats().get("flash_packed_layout_total", 0)
+    out, grads = jax.value_and_grad(
+        lambda *a: jnp.sum(kernel(*a) * gf), argnums=(0, 1, 2))(q, k, v)
+    assert profiler.get_int_stats()["flash_packed_layout_total"] \
+        == before + 1
+    ref, rgrads = jax.value_and_grad(
+        lambda *a: jnp.sum(oracle(*a) * gf), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(out), float(ref), rtol=2e-2)
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
+                               np.asarray(oracle(q, k, v)),
+                               atol=3e-2, rtol=3e-2)
+    for name, a, r in zip("qkv", grads, rgrads):
+        np.testing.assert_allclose(
+            np.asarray(a.astype(jnp.float32)),
+            np.asarray(r.astype(jnp.float32)), atol=5e-2, rtol=5e-2,
+            err_msg=f"d{name} mismatch at {(b, s, h, d)}")
+
+
+def test_merged_shapes_on_tpu():
+    """Shapes outside the packed rule (odd head count at D = 64, a head
+    dim that fills no lane block) keep the merged (B*H, S, D) kernels:
+    parity with XLA, and no packed instance counted."""
+    before = profiler.get_int_stats().get("flash_packed_layout_total", 0)
+    for h, d in ((3, 64), (4, 80)):
+        q, k, v = (_rand((2, 256, h, d), s) for s in (40, 41, 42))
+        out = flash_attention(q, k, v, is_causal=True)
+        ref = _xla_attention(q, k, v, is_causal=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-2, rtol=2e-2)
+    assert profiler.get_int_stats().get(
+        "flash_packed_layout_total", 0) == before
+
+
 # -- ragged paged attention (serving decode / chunked prefill) --------------
 
 def _paged_case(lengths, t, dtype, seed=0):
@@ -261,3 +324,9 @@ def test_no_kernel_gave_way():
     stats = profiler.get_int_stats()
     assert stats.get("flash_fallback_total", 0) == 0
     assert stats.get("serving_ragged_fallback_total", 0) == 0
+
+
+def test_packed_layout_engaged():
+    """The H x 64 and D = 128 instances above took the kernels on the
+    projections' own (B, S, H*D) layout, not the merged one."""
+    assert profiler.get_int_stats().get("flash_packed_layout_total", 0) > 0
