@@ -14,6 +14,7 @@ from .layer.activation import (ELU, GELU, SELU, Hardshrink, Hardsigmoid,
                                Silu, Softmax, Softplus, Softshrink, Swish,
                                Tanh, Tanhshrink, ThresholdedReLU)
 from .layer.common import (Bilinear, CosineSimilarity, Dropout, Dropout2D, SwitchMoE,
+                           RoutedMoE,
                            Embedding, Flatten, Linear, Pad1D, Pad2D, Pad3D,
                            PixelShuffle, Upsample, UpsamplingBilinear2D,
                            UpsamplingNearest2D)
@@ -25,13 +26,14 @@ from .layer.loss import (BCELoss, BCEWithLogitsLoss, CrossEntropyLoss,
                          NLLLoss, SmoothL1Loss)
 from .layer.norm import (BatchNorm, BatchNorm1D, BatchNorm2D, BatchNorm3D,
                          GroupNorm, InstanceNorm1D, InstanceNorm2D,
-                         InstanceNorm3D, LayerNorm, LocalResponseNorm,
+                         InstanceNorm3D, LayerNorm, LocalResponseNorm, RMSNorm,
                          SpectralNorm, SyncBatchNorm)
 from .layer.pooling import (AdaptiveAvgPool2D, AdaptiveMaxPool2D, AvgPool1D,
                             AvgPool2D, MaxPool1D, MaxPool2D)
 from .layer.rnn import (RNN, BiRNN, GRU, GRUCell, LSTM, LSTMCell,
                         RNNCellBase, SimpleRNN, SimpleRNNCell)
-from .layer.transformer import (MultiHeadAttention, Transformer,
+from .layer.transformer import (GatedFFN, GroupedQueryAttention,
+                                MultiHeadAttention, Transformer,
                                 TransformerDecoder, TransformerDecoderLayer,
                                 TransformerEncoder, TransformerEncoderLayer)
 

@@ -288,6 +288,55 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5,
     return outs["Y"][0]
 
 
+def rms_norm(x, weight=None, epsilon=1e-6, name=None):
+    """x * rsqrt(mean(x^2, -1) + epsilon) * weight over the last axis
+    (Zhang & Sennrich 2019), computed in float32 and returned in x's
+    dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    def f(x, w=None):
+        y = x.astype(jnp.float32)
+        y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                              + epsilon)
+        if w is not None:
+            y = y * w.astype(jnp.float32)
+        return y.astype(x.dtype)
+
+    ins = {"x": x}
+    if weight is not None:
+        ins["w"] = weight
+    return trace_fn(f, ins)
+
+
+def rotary_embedding(q, k, positions, theta=10000.0, name=None):
+    """Rotary position embedding (Su et al. 2021) over the whole head
+    width, rotate-half convention: q, k (batch, seq, heads, head_dim),
+    positions (batch, seq) or (seq,) integers -> (q, k) rotated, with
+    angle positions * theta^(-2i / head_dim) on the pair (i, i +
+    head_dim / 2).  Angles in float32; the results keep their dtypes."""
+    import jax.numpy as jnp
+
+    def f(q, k, pos):
+        d = q.shape[-1]
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        ang = pos.astype(jnp.float32)[..., None] * inv
+        if ang.ndim == 2:
+            ang = ang[None]
+        cos = jnp.cos(ang)[:, :, None, :]
+        sin = jnp.sin(ang)[:, :, None, :]
+
+        def rot(x):
+            x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+            return jnp.concatenate(
+                [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                axis=-1).astype(x.dtype)
+
+        return rot(q), rot(k)
+
+    return trace_fn(f, {"q": q, "k": k, "pos": positions}, multi_out=True)
+
+
 def instance_norm(x, running_mean=None, running_var=None, weight=None,
                   bias=None, use_input_stats=True, momentum=0.9, eps=1e-5,
                   data_format="NCHW", name=None):

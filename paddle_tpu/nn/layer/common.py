@@ -305,3 +305,50 @@ class SwitchMoE(Layer):
         if items is not None:
             items.append(aux)
         return out
+
+
+class RoutedMoE(Layer):
+    """Dropless top-k routed experts (gated SiLU FFNs, softmax router),
+    told which experts it holds: the model-side face of
+    `parallel.moe.routed_moe_local`.  It routes over all `num_experts`,
+    computes the visits that land on the `held = (first, count)` experts
+    whose weights it has (default: all) and leaves out what absent
+    experts would have added; on one chip there is no exchange.
+
+    forward(x (..., H)) -> (out (..., H), stats, experts):
+    stats is the layer's (count + 2,) int32 count vector (rows per held
+    expert, visits routed, held visits computed), experts (rows, k)
+    what the router chose."""
+
+    def __init__(self, d_model, d_ff, num_experts, top_k, held=None,
+                 norm_topk_prob=True, weight_attr=None):
+        super().__init__()
+        self._top_k, self._renormalize = top_k, norm_topk_prob
+        self._held = held
+        count = num_experts if held is None else held[1]
+        self.gate_weight = self.create_parameter(
+            shape=[d_model, num_experts], attr=weight_attr,
+            default_initializer=XavierInitializer())
+        fans = dict(attr=weight_attr, default_initializer=XavierInitializer(
+            fan_in=d_model, fan_out=d_ff))
+        self.w_gate = self.create_parameter(
+            shape=[count, d_model, d_ff], **fans)
+        self.w_up = self.create_parameter(
+            shape=[count, d_model, d_ff], **fans)
+        self.w_down = self.create_parameter(
+            shape=[count, d_ff, d_model], **fans)
+
+    def forward(self, x):
+        from ...fluid.dygraph.tracer import trace_fn
+        from ...parallel.moe import routed_moe_local
+
+        def f(x, wr, wg, wu, wd):
+            out, stats, experts = routed_moe_local(
+                {"wr": wr, "wg": wg, "wu": wu, "wd": wd},
+                x.reshape(-1, x.shape[-1]), self._top_k, held=self._held,
+                renormalize=self._renormalize)
+            return out.reshape(x.shape), stats, experts
+
+        return trace_fn(
+            f, {"x": x, "wr": self.gate_weight, "wg": self.w_gate,
+                "wu": self.w_up, "wd": self.w_down}, multi_out=True)

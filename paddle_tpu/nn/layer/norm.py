@@ -105,6 +105,26 @@ class LayerNorm(Layer):
         return f"normalized_shape={self._normalized_shape}"
 
 
+class RMSNorm(Layer):
+    """Root-mean-square norm over the last axis with a learned scale
+    and no bias (F.rms_norm)."""
+
+    def __init__(self, hidden_size, epsilon=1e-6, weight_attr=None,
+                 name=None):
+        super().__init__()
+        self._hidden_size = hidden_size
+        self._epsilon = epsilon
+        self.weight = self.create_parameter(
+            shape=[hidden_size], attr=weight_attr,
+            default_initializer=ConstantInitializer(1.0))
+
+    def forward(self, input):
+        return F.rms_norm(input, self.weight, self._epsilon)
+
+    def extra_repr(self):
+        return f"hidden_size={self._hidden_size}"
+
+
 class InstanceNorm2D(Layer):
     def __init__(self, num_features, epsilon=1e-5, momentum=0.9,
                  weight_attr=None, bias_attr=None, data_format="NCHW",

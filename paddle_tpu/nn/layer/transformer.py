@@ -22,7 +22,7 @@ from .activation import GELU, ReLU
 from .common import Dropout, Linear
 from .container import LayerList
 from .layers import Layer
-from .norm import LayerNorm
+from .norm import LayerNorm, RMSNorm
 
 
 class MultiHeadAttention(Layer):
@@ -100,6 +100,71 @@ class MultiHeadAttention(Layer):
         if cache is not None and not isinstance(cache, self.StaticCache):
             return out, cache
         return out
+
+
+class GroupedQueryAttention(Layer):
+    """Self-attention with fewer key/value heads than query heads,
+    rotary positions and (optionally) an RMSNorm of every query and key
+    head over head_dim before the rotation — the attention block of
+    today's decoder models.  No biases.
+
+    forward(x (B, S, E), positions (B, S) | (S,), attn_mask=None,
+    is_causal=False) -> (B, S, E).  Query head j reads key/value head
+    j // (num_heads / num_kv_heads); the kernels get the num_kv_heads
+    heads as they are (ops/pallas/attention.py).  `attn_mask` is what
+    F.scaled_dot_product_attention takes, a `BlockDiffusionMask`
+    among it."""
+
+    def __init__(self, embed_dim, num_heads, num_kv_heads, head_dim=None,
+                 qk_norm=True, rope_theta=10000.0, epsilon=1e-6,
+                 weight_attr=None):
+        super().__init__()
+        assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim = head_dim or embed_dim // num_heads
+        self.rope_theta = rope_theta
+        q_out, kv_out = (n * self.head_dim for n in (num_heads,
+                                                     num_kv_heads))
+        self.q_proj = Linear(embed_dim, q_out, weight_attr, False)
+        self.k_proj = Linear(embed_dim, kv_out, weight_attr, False)
+        self.v_proj = Linear(embed_dim, kv_out, weight_attr, False)
+        self.out_proj = Linear(q_out, embed_dim, weight_attr, False)
+        self.q_norm = RMSNorm(self.head_dim, epsilon) if qk_norm else None
+        self.k_norm = RMSNorm(self.head_dim, epsilon) if qk_norm else None
+
+    def forward(self, x, positions, attn_mask=None, is_causal=False):
+        d = self.head_dim
+        split = lambda y, n: trace_fn(
+            lambda y: y.reshape(y.shape[0], y.shape[1], n, d), {"y": y})
+        q = split(self.q_proj(x), self.num_heads)
+        k = split(self.k_proj(x), self.num_kv_heads)
+        v = split(self.v_proj(x), self.num_kv_heads)
+        if self.q_norm is not None:
+            q, k = self.q_norm(q), self.k_norm(k)
+        q, k = F.rotary_embedding(q, k, positions, self.rope_theta)
+        out = F.scaled_dot_product_attention(
+            q, k, v, attn_mask=attn_mask, is_causal=is_causal,
+            training=self.training)
+        out = trace_fn(
+            lambda o: o.reshape(o.shape[0], o.shape[1], -1), {"o": out})
+        return self.out_proj(out)
+
+
+class GatedFFN(Layer):
+    """down(act(gate(x)) * up(x)), no biases: the gated (SwiGLU for
+    SiLU) feed-forward block (Shazeer 2020)."""
+
+    def __init__(self, d_model, d_ff, activation="silu", weight_attr=None):
+        super().__init__()
+        self.gate_proj = Linear(d_model, d_ff, weight_attr, False)
+        self.up_proj = Linear(d_model, d_ff, weight_attr, False)
+        self.down_proj = Linear(d_ff, d_model, weight_attr, False)
+        self.activation = getattr(F, activation)
+
+    def forward(self, x):
+        gated = trace_fn(lambda g, u: g * u, {
+            "g": self.activation(self.gate_proj(x)), "u": self.up_proj(x)})
+        return self.down_proj(gated)
 
 
 def _dense_ffn_block(layer, x):

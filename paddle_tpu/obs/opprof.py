@@ -421,6 +421,40 @@ def _inherit_from_consumers(instrs, fused_comps, consumers, key_of) -> None:
             break
 
 
+def _name_xla_kernels(instrs, fused_comps, consumers, key_of) -> None:
+    """A Mosaic kernel XLA itself made (`jax.lax.ragged_dot` becomes
+    `ragged-dot-none` / `ragged-dot-metadata` calls) carries XLA's
+    `op_name` and no scope of the program's.  It is named by that
+    `op_name` under the scope its neighbours share: the longest common
+    path of the instructions that make its operands and read its
+    result — `…/moe/…/experts/ragged-dot-none`, or one level up where a
+    neighbour was fused into another scope's instruction — `bwd` where
+    any of them is.  Which neighbour a fusion swallowed no longer
+    decides where a kernel's time is booked.  Updates `key_of`."""
+    by_name = {ins.name: ins for ins in instrs}
+    kernels = {
+        ins.name for ins in instrs
+        if ins.comp not in fused_comps and ins.opcode == "custom-call"
+        and re.fullmatch(r"[\w.\-]+", ins.op_name or "")
+        and 'custom_call_target="tpu_custom_call"' in ins.line}
+    for name in kernels:
+        ins = by_name[name]
+        near = [key_of[n] for n in list(ins.operands)
+                + consumers.get(name, [])
+                if n in by_name and n not in kernels
+                and by_name[n].comp == ins.comp
+                and isinstance(key_of.get(n), tuple)]
+        if not near:
+            continue        # `ragged-dot-metadata`: its consumer's name
+        common = []
+        for parts in zip(*(k[1].split("/") for k in near)):
+            if len(set(parts)) != 1:
+                break
+            common.append(parts[0])
+        phase = "bwd" if any(k[0] == "bwd" for k in near) else near[0][0]
+        key_of[name] = (phase, "/".join(common + [ins.op_name]))
+
+
 def _join_map(instrs, fused_comps, key_of) -> dict:
     """`{top-level instruction: key}`: its own (or inherited) key; a
     fusion without one takes the dominant key of its interior
@@ -554,6 +588,7 @@ def profile_hlo_text(text: str, label: str = "",
     instr_prov = _join_map(instrs, fused_comps, prov_key)
     name_key: Dict[str, Optional[Tuple[str, str]]] = {
         ins.name: scope_name(ins.op_name) for ins in instrs}
+    _name_xla_kernels(instrs, fused_comps, consumers, name_key)
     _inherit_from_consumers(instrs, fused_comps, consumers, name_key)
     instr_name = _join_map(instrs, fused_comps, name_key)
 

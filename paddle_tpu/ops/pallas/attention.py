@@ -46,11 +46,13 @@ lane fail on a non-zero count.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -60,14 +62,100 @@ from ._common import on_tpu, probe_struct, round_up
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+# -- structured masks ---------------------------------------------------------
+
+_NEVER_LE = 2 ** 30     # a column code no row threshold reaches
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusionMask:
+    """The block-diffusion training mask (BD3-LM, Arriola et al. 2025)
+    over the 2 * seq_len rows `[x_t ‖ x_0]` of one sequence: a noisy
+    copy followed by the clean one, both cut into blocks of
+    `block_length`.  With blk(i) = (i mod seq_len) // block_length, row
+    i sees column j iff
+
+        i noisy, j noisy:  blk(j) == blk(i)   (bidirectional in-block)
+        i noisy, j clean:  blk(j) <  blk(i)   (every earlier clean block)
+        i clean, j clean:  blk(j) <= blk(i)   (block-causal)
+        i clean, j noisy:  never
+
+    The kernels take the family this belongs to — row i sees column j
+    iff `c_le[j] <= r_le[i] or c_eq[j] == r_eq[i]` for four int32 code
+    vectors — as those vectors plus a table of live tiles, both built
+    here at trace time from the two integers; nothing of size
+    (2 * seq_len)^2 reaches the device.  Hashable: a static argument."""
+    seq_len: int
+    block_length: int
+
+    @property
+    def rows(self) -> int:
+        return 2 * self.seq_len
+
+    def codes(self, n_rows: int, n_cols: int):
+        """`(r_le, r_eq, c_le, c_eq)` int32, padded to the kernels'
+        sizes: a padding row sees nothing, a padding column is seen by
+        nothing."""
+        i = np.arange(self.rows)
+        blk = (i % self.seq_len) // self.block_length
+        noisy = i < self.seq_len
+        pad = lambda x, n, fill: np.concatenate(
+            [x, np.full(n - self.rows, fill)]).astype(np.int32)
+        return (pad(np.where(noisy, blk - 1, blk), n_rows, -1),
+                pad(np.where(noisy, blk, -2), n_rows, -2),
+                pad(np.where(noisy, _NEVER_LE, blk), n_cols, _NEVER_LE),
+                pad(np.where(noisy, blk, -1), n_cols, -1))
+
+    def dense(self) -> np.ndarray:
+        """(2 * seq_len, 2 * seq_len) bool, for the XLA path and tests."""
+        r_le, r_eq, c_le, c_eq = self.codes(self.rows, self.rows)
+        return ((c_le[None, :] <= r_le[:, None])
+                | (c_eq[None, :] == r_eq[:, None]))
+
+    @functools.lru_cache(maxsize=None)
+    def tiles(self, n_rows: int, n_cols: int, block_q: int, block_k: int):
+        """`(live, k_fetch, q_fetch)`: which (q tile, k tile) pairs hold
+        a live pair, and for the two grid orders the tile to have in
+        VMEM at each step — the step's own where it is live, else the
+        nearest live one before it (the first live one where none is),
+        so that a dead step moves nothing."""
+        r_le, r_eq, c_le, c_eq = self.codes(n_rows, n_cols)
+        nq, nk = n_rows // block_q, n_cols // block_k
+        live = np.zeros((nq, nk), bool)
+        for iq in range(nq):
+            rows = slice(iq * block_q, (iq + 1) * block_q)
+            m = ((c_le[None, :] <= r_le[rows, None])
+                 | (c_eq[None, :] == r_eq[rows, None]))
+            live[iq] = m.reshape(block_q, nk, block_k).any(axis=(0, 2))
+
+        def fetch(lv):
+            out = np.zeros(lv.shape, np.int32)
+            for a, row in enumerate(lv):
+                alive = np.flatnonzero(row)
+                cur = alive[0] if len(alive) else 0
+                for b, on in enumerate(row):
+                    cur = b if on else cur
+                    out[a, b] = cur
+            return out
+
+        return live.astype(np.int32), fetch(live), fetch(live.T)
+
+
 # -- XLA reference path -------------------------------------------------------
 
 def _xla_attention(q, k, v, mask=None, is_causal=False, scale=None,
                    dropout_p=0.0, dropout_key=None):
     """(B, S, H, D) attention in plain XLA; used off-TPU, for masks the
-    kernel cannot express, and as the numerical oracle in tests."""
+    kernel cannot express, and as the numerical oracle in tests.  Fewer
+    key/value heads than query heads (grouped-query attention) are
+    repeated here; a `BlockDiffusionMask` becomes its dense form."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    if k.shape[2] != q.shape[2]:
+        group = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    if isinstance(mask, BlockDiffusionMask):
+        mask = jnp.asarray(mask.dense())[None, None]
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if mask is not None:
@@ -170,11 +258,99 @@ def _store_heads(ref, x):
             lane < 64, x[2 * pair], x[2 * pair + 1]).astype(ref.dtype)
 
 
+# Grouped-query attention: the block_h query heads of a grid step share
+# ONE key/value head, so they are laid on the ROW axis — (1, block_h *
+# rows, d) against the (1, block_k, d) key tile — and every product of
+# the step is one 2-D matmul; dK and dV sum over the group inside the
+# contraction.  Packed layout, d a multiple of 128 only.
+
+def _load_rows(ref, block_h):
+    d = ref.shape[2] // block_h
+    return jnp.concatenate([ref[0, :, h * d:(h + 1) * d]
+                            for h in range(block_h)], axis=0)[None]
+
+
+def _store_rows(ref, x):
+    rows, d = ref.shape[1], x.shape[2]
+    for h in range(ref.shape[2] // d):
+        ref[0, :, h * d:(h + 1) * d] = x[
+            0, h * rows:(h + 1) * rows].astype(ref.dtype)
+
+
+def _load_row_vec(ref, grouped):
+    """A (block_h, rows, 1) log-sum-exp / delta block; grouped: as
+    (1, block_h * rows, 1)."""
+    if not grouped:
+        return ref[...]
+    return jnp.concatenate([ref[h] for h in range(ref.shape[0])],
+                           axis=0)[None]
+
+
+def _tile_rows(x, reps):
+    return x if reps == 1 else jnp.concatenate([x] * reps, axis=0)
+
+
+def _code_mask(codes, reps):
+    """(1, reps * block_q, block_k) bool of a tile from the four code
+    blocks (BlockDiffusionMask): rows (1, block_q, 1), columns
+    (1, 1, block_k)."""
+    r_le, r_eq, c_le, c_eq = codes
+    return ((c_le[0] <= _tile_rows(r_le[0], reps))
+            | (c_eq[0] == _tile_rows(r_eq[0], reps)))[None]
+
+
+def _causal_rows(iq, ik, block_q, block_k, reps, causal_offset):
+    """The causal mask of a grouped tile, (1, reps * block_q, block_k)."""
+    q_idx = _tile_rows(iq * block_q + lax.broadcasted_iota(
+        jnp.int32, (block_q, 1), 0), reps)
+    k_idx = ik * block_k + lax.broadcasted_iota(
+        jnp.int32, (1, block_k), 1)
+    return (q_idx + causal_offset >= k_idx)[None]
+
+
+def _mask_scores(s, iq, ik, codes, *, block_h, block_q, block_k, causal,
+                 causal_offset, grouped):
+    """The score tile `s` with what the step's masks hide set to
+    DEFAULT_MASK_VALUE: causal (query i attends keys <= i +
+    causal_offset, offset = sk - sq, matching the XLA path's
+    jnp.tril(..., k=sk - sq)) and the block mask's codes."""
+    reps = block_h if grouped else 1
+    if causal and grouped:
+        s = jnp.where(_causal_rows(iq, ik, block_q, block_k, reps,
+                                   causal_offset), s, DEFAULT_MASK_VALUE)
+    elif causal:
+        q_idx = iq * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_h, block_q, block_k), 1)
+        k_idx = ik * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_h, block_q, block_k), 2)
+        s = jnp.where(q_idx + causal_offset >= k_idx, s,
+                      DEFAULT_MASK_VALUE)
+    if codes is not None:
+        s = jnp.where(_code_mask(codes, reps), s, DEFAULT_MASK_VALUE)
+    return s
+
+
+def _split_refs(refs, masked, n_in):
+    """The kernels' operands: with a block mask the live-tile table
+    leads (scalar prefetch, beside the fetch table only the index maps
+    read) and the four code blocks follow the `n_in` inputs."""
+    live = None
+    if masked:
+        live, _, *refs = refs
+    ins, rest = refs[:n_in], refs[n_in:]
+    codes = None
+    if masked:
+        codes, rest = rest[:4], rest[4:]
+    return live, ins, codes, rest
+
+
 # A packed step holds up to 4 heads' (512, 512) f32 score tiles and
 # their temporaries: 17.5 MB in flash_bwd_dkv, over the compiler's
 # default scoped-VMEM budget of 16 MiB, well inside the v5e's 128.
 _PACKED_VMEM_LIMIT = 32 * 1024 * 1024
 _PACKED_MAX_SCORES = 4 * 512 * 512
+# a grouped step's q, g and f32 accumulators span the whole group
+_GROUPED_VMEM_LIMIT = 48 * 1024 * 1024
 
 
 def _layout(q, k, kbias, heads):
@@ -189,28 +365,50 @@ def _layout(q, k, kbias, heads):
     return bh, q.shape[1], k.shape[1], d, packed, lanes
 
 
-def _heads_spec(packed, heads, block_h, rows, d, seq_axis):
+def _heads_spec(packed, heads, block_h, rows, d, seq_axis, fetch=None):
     """BlockSpec of a q/k/v-like operand on the (batch-head block,
     i, j) grids: block_h heads x `rows` positions, the position block
-    taken from grid axis `seq_axis`.  Merged: (block_h, rows, d) of
-    (B*H, S, D).  Packed: the same heads as (1, rows, block_h * d) of
-    (B, S, H*D): batch n // groups, lane block n % groups."""
+    taken from grid axis `seq_axis` — through `fetch(i, j, tables)`
+    where a block mask redirects dead steps.  Merged: (block_h, rows,
+    d) of (B*H, S, D).  Packed: the same heads as (1, rows, block_h *
+    d) of (B, S, H*D): batch n // groups, lane block n % groups."""
+    def seq(n, i, j, tables):
+        return fetch(i, j, tables) if fetch else (n, i, j)[seq_axis]
+
     if not packed:
         return pl.BlockSpec(
             (block_h, rows, d),
-            lambda n, i, j: (n, (n, i, j)[seq_axis], 0))
+            lambda n, i, j, *t: (n, seq(n, i, j, t), 0))
     groups = heads // block_h
     return pl.BlockSpec(
         (1, rows, block_h * d),
-        lambda n, i, j: (n // groups, (n, i, j)[seq_axis], n % groups))
+        lambda n, i, j, *t: (n // groups, seq(n, i, j, t), n % groups))
+
+
+def _pallas_call(kernel, grid, in_specs, out_specs, out_shape,
+                 scratch_shapes, tables, **kw):
+    """`pl.pallas_call`, with `tables` (the block mask's live and fetch
+    tables) as scalar-prefetch operands where there are any."""
+    if not tables:
+        return pl.pallas_call(
+            kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+            out_shape=out_shape, scratch_shapes=scratch_shapes, **kw)
+    call = pl.pallas_call(
+        kernel, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables), grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes), **kw)
+    return functools.partial(call, *tables)
 
 
 # -- Pallas forward kernel ----------------------------------------------------
 
-def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, kbias_ref,
-                      o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                      *, scale, block_h, block_q, block_k, causal,
-                      causal_offset, dropout_p):
+def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
+                      causal_offset, dropout_p, grouped=False,
+                      masked=False):
+    live_ref, (seed_ref, q_ref, k_ref, v_ref, kbias_ref), codes, \
+        (o_ref, lse_ref, m_scr, l_scr, acc_scr) = _split_refs(
+            refs, masked, 5)
     b = pl.program_id(0)
     iq = pl.program_id(1)
     ik = pl.program_id(2)
@@ -222,64 +420,105 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, kbias_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q = _load_heads(q_ref, block_h, mask=True)  # (block_h, block_q, d)
-    k = _load_heads(k_ref, block_h)             # (block_h, block_k, d)
-    # batched over the head-block dim: one grid step feeds the MXU
-    # block_h (q, k) panels instead of one, amortizing the ~2us
-    # per-grid-step overhead that dominated the (BH, 1, 1) grid
-    # (profiled 0.9 ms/layer fwd vs a 0.13 ms compute floor)
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale  # (bh, bq, bk)
-    s = s + kbias_ref[...]  # additive key bias (1, 1, block_k) broadcast
+    def _tile():
+        if grouped:
+            q = _load_rows(q_ref, block_h)   # (1, block_h * block_q, d)
+            k = _load_heads(k_ref, 1)        # (1, block_k, d)
+        else:
+            q = _load_heads(q_ref, block_h, mask=True)
+            k = _load_heads(k_ref, block_h)  # (block_h, block_k, d)
+        # batched over the head-block dim: one grid step feeds the MXU
+        # block_h (q, k) panels instead of one, amortizing the ~2us
+        # per-grid-step overhead that dominated the (BH, 1, 1) grid
+        # (profiled 0.9 ms/layer fwd vs a 0.13 ms compute floor)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # (bh, bq, bk)
+        s = s + kbias_ref[...]  # additive key bias (1, 1, block_k)
 
-    if causal:
-        # query i attends keys <= i + causal_offset (offset = sk - sq,
-        # matching the XLA path's jnp.tril(..., k=sk - sq))
-        q_idx = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_h, block_q, block_k), 1)
-        k_idx = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_h, block_q, block_k), 2)
-        s = jnp.where(q_idx + causal_offset >= k_idx, s,
-                      DEFAULT_MASK_VALUE)
+        s = _mask_scores(s, iq, ik, codes, block_h=block_h, block_q=block_q,
+                         block_k=block_k, causal=causal,
+                         causal_offset=causal_offset, grouped=grouped)
 
-    m_prev = m_scr[:]          # (block_h, block_q, 1)
-    l_prev = l_scr[:]
-    m_cur = jnp.max(s, axis=2, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)                      # (block_h, bq, bk)
-    alpha = jnp.exp(m_prev - m_new)             # (block_h, bq, 1)
-    l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
+        m_prev = m_scr[:]          # (block_h, block_q, 1)
+        l_prev = l_scr[:]
+        m_cur = jnp.max(s, axis=2, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)                      # (block_h, bq, bk)
+        alpha = jnp.exp(m_prev - m_new)             # (block_h, bq, 1)
+        l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
 
-    if dropout_p > 0.0:
-        keep = _keep_mask3(seed_ref[0], b * block_h, iq * block_q,
-                           ik * block_k, block_h, block_q, block_k,
-                           dropout_p)
-        p_drop = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
+        if dropout_p > 0.0:
+            keep = _keep_mask3(seed_ref[0], b * block_h, iq * block_q,
+                               ik * block_k, block_h, block_q, block_k,
+                               dropout_p)
+            p_drop = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
+        else:
+            p_drop = p
+
+        m_scr[:] = m_new
+        l_scr[:] = l_new
+        pv = jax.lax.dot_general(
+            p_drop.astype(v_ref.dtype),
+            _load_heads(v_ref, 1 if grouped else block_h),
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        acc_scr[:] = acc_scr[:] * alpha + pv
+
+    if masked:      # a dead tile is skipped, not computed and masked
+        pl.when(live_ref[iq * nk + ik] != 0)(_tile)
     else:
-        p_drop = p
-
-    m_scr[:] = m_new
-    l_scr[:] = l_new
-    pv = jax.lax.dot_general(
-        p_drop.astype(v_ref.dtype), _load_heads(v_ref, block_h),
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    acc_scr[:] = acc_scr[:] * alpha + pv
+        _tile()
 
     @pl.when(ik == nk - 1)
     def _finalize():
         l = l_scr[:]
-        _store_heads(o_ref, acc_scr[:] / l)
-        lse_ref[...] = m_scr[:] + jnp.log(l)  # (block_h, block_q, 1)
+        lse = m_scr[:] + jnp.log(l)
+        if grouped:
+            _store_rows(o_ref, acc_scr[:] / l)
+            for h in range(block_h):
+                lse_ref[h] = lse[0, h * block_q:(h + 1) * block_q]
+        else:
+            _store_heads(o_ref, acc_scr[:] / l)
+            lse_ref[...] = lse  # (block_h, block_q, 1)
+
+
+def _mask_operands(block_mask, sq, sk, block_q, block_k, order):
+    """What a block mask adds to a call on the (n, i, j) grid whose
+    axes are (q, k) tiles in `order` "qk", (k, (head block, q)) tiles
+    in "kq": `(tables, code arrays, code specs, fetch of the operand of
+    the inner axis, index of the inner axis' q or k tile)`."""
+    live, k_fetch, q_fetch = block_mask.tiles(sq, sk, block_q, block_k)
+    r_le, r_eq, c_le, c_eq = block_mask.codes(sq, sk)
+    nq, nk = live.shape
+    arrays = [jnp.asarray(r_le).reshape(1, sq, 1),
+              jnp.asarray(r_eq).reshape(1, sq, 1),
+              jnp.asarray(c_le).reshape(1, 1, sk),
+              jnp.asarray(c_eq).reshape(1, 1, sk)]
+    if order == "qk":
+        tables = (jnp.asarray(live.reshape(-1)),
+                  jnp.asarray(k_fetch.reshape(-1)))
+        fetch = lambda i, j, t: t[1][i * nk + j]
+        row = lambda n, i, j, *t: (0, i, 0)
+        col = lambda n, i, j, *t: (0, 0, j)
+    else:
+        tables = (jnp.asarray(live.reshape(-1)),
+                  jnp.asarray(q_fetch.reshape(-1)))
+        fetch = lambda i, j, t: t[1][i * nq + j % nq]
+        row = lambda n, i, j, *t: (0, j % nq, 0)
+        col = lambda n, i, j, *t: (0, 0, i)
+    specs = [pl.BlockSpec((1, block_q, 1), row)] * 2 \
+        + [pl.BlockSpec((1, 1, block_k), col)] * 2
+    return tables, arrays, specs, fetch
 
 
 @functools.partial(jax.jit, static_argnames=(
     "heads", "is_causal", "scale", "dropout_p", "block_h", "block_q",
-    "block_k", "interpret", "causal_offset"))
+    "block_k", "interpret", "causal_offset", "kv_heads", "block_mask"))
 def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
                    dropout_p=0.0, block_h=1, block_q=128, block_k=128,
-                   interpret=False, causal_offset=None):
+                   interpret=False, causal_offset=None, kv_heads=None,
+                   block_mask=None):
     """q,k,v: merged (BH, S, D) or packed (B, S, H*D) — told apart by
     the leading dim, kbias carrying B; kbias: (B, 1, Sk) f32; seed:
     (1,) i32 -> (out like q, lse (BH, Sq, 1)).  Shapes must be
@@ -290,6 +529,13 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
     kbias block is per batch element).  On the packed layout a step's
     block_h heads are block_h * D adjacent lanes of one batch element,
     a multiple of 128.
+
+    kv_heads < heads (grouped-query attention; packed, D a multiple of
+    128): k and v are (B, Sk, kv_heads * D), a step's block_h query
+    heads lie inside one group and read its one key/value tile.
+    block_mask (a BlockDiffusionMask over the padded rows): the mask is
+    applied from code vectors in-kernel and the tiles its table marks
+    dead are skipped, their k/v blocks not fetched.
 
     Row-vector operands are laid out with a unit SUBLANE dim ((B, 1, Sk)
     bias blocks (1, 1, block_k); (BH, Sq, 1) lse blocks (block_h,
@@ -302,126 +548,164 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
     assert sq % block_q == 0 and sk % block_k == 0, (sq, sk)
     assert bh % block_h == 0 and heads % block_h == 0, (bh, heads, block_h)
     grid = (bh // block_h, sq // block_q, sk // block_k)
+    grouped = kv_heads is not None and kv_heads != heads
+    masked = block_mask is not None
 
     if causal_offset is None:
         causal_offset = sk - sq
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, block_h=block_h, block_q=block_q,
         block_k=block_k, causal=is_causal, causal_offset=causal_offset,
-        dropout_p=dropout_p)
+        dropout_p=dropout_p, grouped=grouped, masked=masked)
+    tables, codes, code_specs, fetch = _mask_operands(
+        block_mask, sq, sk, block_q, block_k, "qk") if masked \
+        else ((), [], [], None)
     q_spec = _heads_spec(packed, heads, block_h, block_q, d, 1)
-    k_spec = _heads_spec(packed, heads, block_h, block_k, d, 2)
+    if grouped:
+        assert packed and d % 128 == 0 and dropout_p == 0.0
+        group = heads // kv_heads
+        assert group % block_h == 0, (group, block_h)
+        per_b = heads // block_h
+        k_spec = pl.BlockSpec(
+            (1, block_k, d), lambda n, i, j, *t: (
+                n // per_b, fetch(i, j, t) if fetch else j,
+                (n % per_b) * block_h // group))
+        rows = block_h * block_q
+        scratch = [pltpu.VMEM((1, rows, 1), jnp.float32),
+                   pltpu.VMEM((1, rows, 1), jnp.float32),
+                   pltpu.VMEM((1, rows, d), jnp.float32)]
+    else:
+        k_spec = _heads_spec(packed, heads, block_h, block_k, d, 2, fetch)
+        scratch = [pltpu.VMEM((block_h, block_q, 1), jnp.float32),
+                   pltpu.VMEM((block_h, block_q, 1), jnp.float32),
+                   pltpu.VMEM((block_h, block_q, lanes), jnp.float32)]
 
-    out, lse = pl.pallas_call(
+    out, lse = _pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             q_spec, k_spec, k_spec,
             pl.BlockSpec((1, 1, block_k),
-                         lambda b, iq, ik, h=heads, bh_=block_h:
+                         lambda b, iq, ik, *t, h=heads, bh_=block_h:
                          ((b * bh_) // h, 0, ik)),
-        ],
+        ] + code_specs,
         out_specs=[
             q_spec,
             pl.BlockSpec((block_h, block_q, 1),
-                         lambda b, iq, ik: (b, iq, 0)),
+                         lambda b, iq, ik, *t: (b, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_h, block_q, 1), jnp.float32),
-            pltpu.VMEM((block_h, block_q, 1), jnp.float32),
-            pltpu.VMEM((block_h, block_q, lanes), jnp.float32),
-        ],
+        scratch_shapes=scratch,
+        tables=tables,
         # bh/iq steps write disjoint outputs -> parallel lets Mosaic
         # double-buffer DMA across grid steps (the (bh, 1, 1) grid at
         # 512-blocks is otherwise serialized per-step overhead); ik
         # accumulates in scratch -> arbitrary
         compiler_params=_compiler_params(
-            vmem_limit=_PACKED_VMEM_LIMIT if packed else None),
+            vmem_limit=_GROUPED_VMEM_LIMIT if grouped
+            else _PACKED_VMEM_LIMIT if packed else None),
         interpret=interpret,
         name="flash_fwd",
-    )(seed, q, k, v, kbias)
+    )(seed, q, k, v, kbias, *codes)
     return out, lse
 
 
 # -- Pallas backward kernels --------------------------------------------------
 
-def _flash_bwd_dkv_kernel(seed_ref, q_ref, g_ref, lse_ref, delta_ref,
-                          k_ref, v_ref, kbias_ref, dk_ref, dv_ref,
-                          dk_scr, dv_scr,
-                          *, scale, block_h, block_q, block_k, causal,
-                          causal_offset, dropout_p):
+def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
+                          causal_offset, dropout_p, grouped=False,
+                          masked=False, q_tiles=None):
+    live_ref, (seed_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref,
+               kbias_ref), codes, (dk_ref, dv_ref, dk_scr, dv_scr) = \
+        _split_refs(refs, masked, 8)
     b = pl.program_id(0)
     ik = pl.program_id(1)
     iq = pl.program_id(2)
     nq = pl.num_programs(2)
+    nk = pl.num_programs(1)
+    # a grouped grid's last axis runs over (head block of the group,
+    # q tile): all of them add into this key tile's one dK, dV
+    last = iq == nq - 1
+    first = iq == 0
+    if grouped:
+        iq = iq % q_tiles
 
-    @pl.when(iq == 0)
+    @pl.when(first)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    q = _load_heads(q_ref, block_h, mask=True)  # (block_h, block_q, d)
-    g = _load_heads(g_ref, block_h, mask=True)  # (block_h, block_q, d)
-    k = _load_heads(k_ref, block_h)             # (block_h, block_k, d)
-    v = _load_heads(v_ref, block_h)             # (block_h, block_k, d)
-    lse = lse_ref[...]      # (block_h, block_q, 1)
-    delta = delta_ref[...]  # (block_h, block_q, 1)
+    def _tile():
+        if grouped:
+            q = _load_rows(q_ref, block_h)   # (1, block_h * block_q, d)
+            g = _load_rows(g_ref, block_h)
+            k = _load_heads(k_ref, 1)        # (1, block_k, d)
+            v = _load_heads(v_ref, 1)
+        else:
+            q = _load_heads(q_ref, block_h, mask=True)
+            g = _load_heads(g_ref, block_h, mask=True)
+            k = _load_heads(k_ref, block_h)  # (block_h, block_k, d)
+            v = _load_heads(v_ref, block_h)
+        lse = _load_row_vec(lse_ref, grouped)      # (block_h, block_q, 1)
+        delta = _load_row_vec(delta_ref, grouped)
 
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale
-    s = s + kbias_ref[...]
-    if causal:
-        q_idx = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_h, block_q, block_k), 1)
-        k_idx = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_h, block_q, block_k), 2)
-        s = jnp.where(q_idx + causal_offset >= k_idx, s,
-                      DEFAULT_MASK_VALUE)
-    p = jnp.exp(s - lse)      # softmax probs, (block_h, bq, bk)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale
+        s = s + kbias_ref[...]
+        s = _mask_scores(s, iq, ik, codes, block_h=block_h, block_q=block_q,
+                         block_k=block_k, causal=causal,
+                         causal_offset=causal_offset, grouped=grouped)
+        p = jnp.exp(s - lse)      # softmax probs, (block_h, bq, bk)
 
-    if dropout_p > 0.0:
-        keep = _keep_mask3(seed_ref[0], b * block_h, iq * block_q,
-                           ik * block_k, block_h, block_q, block_k,
-                           dropout_p)
-        inv = 1.0 / (1.0 - dropout_p)
-        p_drop = jnp.where(keep, p * inv, 0.0)
+        if dropout_p > 0.0:
+            keep = _keep_mask3(seed_ref[0], b * block_h, iq * block_q,
+                               ik * block_k, block_h, block_q, block_k,
+                               dropout_p)
+            inv = 1.0 / (1.0 - dropout_p)
+            p_drop = jnp.where(keep, p * inv, 0.0)
+        else:
+            p_drop = p
+
+        # dV += P~^T g
+        dv_scr[:] += jax.lax.dot_general(
+            p_drop.astype(g.dtype), g, (((1,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        # dP~ = g V^T ; dP = dP~ * keep/(1-r) ; dS = P (dP - delta) scale
+        dp_drop = jax.lax.dot_general(
+            g, v, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        if dropout_p > 0.0:
+            dp = jnp.where(keep, dp_drop * inv, 0.0)
+        else:
+            dp = dp_drop
+        ds = p * (dp - delta) * scale
+        # dK += dS^T q
+        dk_scr[:] += jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((1,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+
+    if masked:
+        pl.when(live_ref[iq * nk + ik] != 0)(_tile)
     else:
-        p_drop = p
+        _tile()
 
-    # dV += P~^T g
-    dv_scr[:] += jax.lax.dot_general(
-        p_drop.astype(g.dtype), g, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    # dP~ = g V^T ; dP = dP~ * keep/(1-r) ; dS = P (dP - delta) scale
-    dp_drop = jax.lax.dot_general(
-        g, v, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    if dropout_p > 0.0:
-        dp = jnp.where(keep, dp_drop * inv, 0.0)
-    else:
-        dp = dp_drop
-    ds = p * (dp - delta) * scale
-    # dK += dS^T q
-    dk_scr[:] += jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(iq == nq - 1)
+    @pl.when(last)
     def _finalize():
         _store_heads(dk_ref, dk_scr[:])
         _store_heads(dv_ref, dv_scr[:])
 
 
-def _flash_bwd_dq_kernel(seed_ref, q_ref, g_ref, lse_ref, delta_ref,
-                         k_ref, v_ref, kbias_ref, dq_ref, dq_scr,
-                         *, scale, block_h, block_q, block_k, causal,
-                         causal_offset, dropout_p):
+def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
+                         causal_offset, dropout_p, grouped=False,
+                         masked=False):
+    live_ref, (seed_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref,
+               kbias_ref), codes, (dq_ref, dq_scr) = _split_refs(
+        refs, masked, 8)
     b = pl.program_id(0)
     iq = pl.program_id(1)
     ik = pl.program_id(2)
@@ -431,56 +715,69 @@ def _flash_bwd_dq_kernel(seed_ref, q_ref, g_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    q = _load_heads(q_ref, block_h, mask=True)
-    g = _load_heads(g_ref, block_h, mask=True)
-    k = _load_heads(k_ref, block_h)
-    v = _load_heads(v_ref, block_h)
-    lse = lse_ref[...]      # (block_h, block_q, 1)
-    delta = delta_ref[...]  # (block_h, block_q, 1)
+    def _tile():
+        if grouped:
+            q = _load_rows(q_ref, block_h)
+            g = _load_rows(g_ref, block_h)
+            k = _load_heads(k_ref, 1)
+            v = _load_heads(v_ref, 1)
+        else:
+            q = _load_heads(q_ref, block_h, mask=True)
+            g = _load_heads(g_ref, block_h, mask=True)
+            k = _load_heads(k_ref, block_h)
+            v = _load_heads(v_ref, block_h)
+        lse = _load_row_vec(lse_ref, grouped)      # (block_h, block_q, 1)
+        delta = _load_row_vec(delta_ref, grouped)
 
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * scale
-    s = s + kbias_ref[...]
-    if causal:
-        q_idx = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_h, block_q, block_k), 1)
-        k_idx = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_h, block_q, block_k), 2)
-        s = jnp.where(q_idx + causal_offset >= k_idx, s,
-                      DEFAULT_MASK_VALUE)
-    p = jnp.exp(s - lse)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale
+        s = s + kbias_ref[...]
+        s = _mask_scores(s, iq, ik, codes, block_h=block_h, block_q=block_q,
+                         block_k=block_k, causal=causal,
+                         causal_offset=causal_offset, grouped=grouped)
+        p = jnp.exp(s - lse)
 
-    dp_drop = jax.lax.dot_general(
-        g, v, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    if dropout_p > 0.0:
-        keep = _keep_mask3(seed_ref[0], b * block_h, iq * block_q,
-                           ik * block_k, block_h, block_q, block_k,
-                           dropout_p)
-        dp = jnp.where(keep, dp_drop / (1.0 - dropout_p), 0.0)
+        dp_drop = jax.lax.dot_general(
+            g, v, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        if dropout_p > 0.0:
+            keep = _keep_mask3(seed_ref[0], b * block_h, iq * block_q,
+                               ik * block_k, block_h, block_q, block_k,
+                               dropout_p)
+            dp = jnp.where(keep, dp_drop / (1.0 - dropout_p), 0.0)
+        else:
+            dp = dp_drop
+        ds = p * (dp - delta) * scale
+        dq_scr[:] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+
+    if masked:
+        pl.when(live_ref[iq * nk + ik] != 0)(_tile)
     else:
-        dp = dp_drop
-    ds = p * (dp - delta) * scale
-    dq_scr[:] += jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+        _tile()
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        _store_heads(dq_ref, dq_scr[:])
+        if grouped:
+            _store_rows(dq_ref, dq_scr[:])
+        else:
+            _store_heads(dq_ref, dq_scr[:])
 
 
 @functools.partial(jax.jit, static_argnames=(
     "heads", "is_causal", "scale", "dropout_p", "block_h", "block_q",
-    "block_k", "interpret", "causal_offset"))
+    "block_k", "interpret", "causal_offset", "kv_heads", "block_mask"))
 def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
                     is_causal=False, scale=None, dropout_p=0.0,
                     block_h=1, block_q=128, block_k=128, interpret=False,
-                    causal_offset=None):
+                    causal_offset=None, kv_heads=None, block_mask=None):
     bh, sq, sk, d, packed, lanes = _layout(q, k, kbias, heads)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     assert bh % block_h == 0 and heads % block_h == 0, (bh, heads, block_h)
+    grouped = kv_heads is not None and kv_heads != heads
+    masked = block_mask is not None
     go = g.astype(jnp.float32) * out.astype(jnp.float32)
     if packed:
         # per-head sums of a (B, Sq, H*D) product: a reduce over a
@@ -497,64 +794,112 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
         causal_offset = sk - sq
     kw = dict(scale=scale, block_h=block_h, block_q=block_q,
               block_k=block_k, causal=is_causal,
-              causal_offset=causal_offset, dropout_p=dropout_p)
+              causal_offset=causal_offset, dropout_p=dropout_p,
+              grouped=grouped, masked=masked)
+    nq, nk = sq // block_q, sk // block_k
+    t_qk, codes, specs_qk, fetch_k = _mask_operands(
+        block_mask, sq, sk, block_q, block_k, "qk") if masked \
+        else ((), [], [], None)
+    t_kq, _, specs_kq, fetch_q = _mask_operands(
+        block_mask, sq, sk, block_q, block_k, "kq") if masked \
+        else ((), [], [], None)
 
     q_spec = _heads_spec(packed, heads, block_h, block_q, d, 1)
     row_spec = pl.BlockSpec((block_h, block_q, 1),
-                            lambda b, i, j: (b, i, 0))
-    # dkv grid iterates (bh, ik, iq): swap index maps for q-side inputs
-    q_spec_t = _heads_spec(packed, heads, block_h, block_q, d, 2)
-    row_spec_t = pl.BlockSpec((block_h, block_q, 1),
-                              lambda b, i, j: (b, j, 0))
-    k_spec = _heads_spec(packed, heads, block_h, block_k, d, 2)
-    k_spec_t = _heads_spec(packed, heads, block_h, block_k, d, 1)
+                            lambda b, i, j, *t: (b, i, 0))
     kb_spec = pl.BlockSpec((1, 1, block_k),
-                           lambda b, i, j, h=heads, bh_=block_h:
+                           lambda b, i, j, *t, h=heads, bh_=block_h:
                            ((b * bh_) // h, 0, j))
-    kb_spec_t = pl.BlockSpec((1, 1, block_k),
-                             lambda b, i, j, h=heads, bh_=block_h:
-                             ((b * bh_) // h, 0, i))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    params = _compiler_params(
-        vmem_limit=_PACKED_VMEM_LIMIT if packed else None)
+    if grouped:
+        assert packed and d % 128 == 0 and dropout_p == 0.0
+        group = heads // kv_heads
+        assert group % block_h == 0, (group, block_h)
+        per_b, per_g = heads // block_h, group // block_h
+        rows = block_h * block_q
+        k_spec = pl.BlockSpec(
+            (1, block_k, d), lambda n, i, j, *t: (
+                n // per_b, fetch_k(i, j, t) if masked else j,
+                (n % per_b) * block_h // group))
+        # the dkv grid: (batch x kv head, k tile, head block x q tile)
+        dkv_grid = (bh // group, nk, per_g * nq)
+        q_tile = (lambda i, j, t: fetch_q(i, j, t)) if masked \
+            else (lambda i, j, t: j % nq)
+        q_spec_t = pl.BlockSpec(
+            (1, block_q, block_h * d), lambda n, i, j, *t: (
+                n // kv_heads, q_tile(i, j, t),
+                (n % kv_heads) * per_g + j // nq))
+        row_spec_t = pl.BlockSpec(
+            (block_h, block_q, 1), lambda n, i, j, *t: (
+                (n // kv_heads) * per_b + (n % kv_heads) * per_g + j // nq,
+                q_tile(i, j, t), 0))
+        k_spec_t = pl.BlockSpec(
+            (1, block_k, d),
+            lambda n, i, j, *t: (n // kv_heads, i, n % kv_heads))
+        kb_spec_t = pl.BlockSpec(
+            (1, 1, block_k), lambda n, i, j, *t: (n // kv_heads, 0, i))
+        dkv_scratch = [pltpu.VMEM((1, block_k, d), jnp.float32)] * 2
+        dq_scratch = [pltpu.VMEM((1, rows, d), jnp.float32)]
+        vmem = _GROUPED_VMEM_LIMIT
+    else:
+        k_spec = _heads_spec(packed, heads, block_h, block_k, d, 2,
+                             fetch_k)
+        # dkv grid iterates (bh, ik, iq): swap index maps for q-side
+        # inputs
+        dkv_grid = (bh // block_h, nk, nq)
+        q_spec_t = _heads_spec(packed, heads, block_h, block_q, d, 2,
+                               fetch_q)
+        row_spec_t = pl.BlockSpec(
+            (block_h, block_q, 1), lambda b, i, j, *t: (
+                b, fetch_q(i, j, t) if masked else j, 0))
+        k_spec_t = _heads_spec(packed, heads, block_h, block_k, d, 1)
+        kb_spec_t = pl.BlockSpec((1, 1, block_k),
+                                 lambda b, i, j, *t, h=heads, bh_=block_h:
+                                 ((b * bh_) // h, 0, i))
+        dkv_scratch = [pltpu.VMEM((block_h, block_k, lanes),
+                                  jnp.float32)] * 2
+        dq_scratch = [pltpu.VMEM((block_h, block_q, lanes), jnp.float32)]
+        vmem = _PACKED_VMEM_LIMIT if packed else None
+    params = _compiler_params(vmem_limit=vmem)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, **kw),
-        grid=(bh // block_h, sk // block_k, sq // block_q),
+    dk, dv = _pallas_call(
+        functools.partial(_flash_bwd_dkv_kernel, q_tiles=nq, **kw),
+        grid=dkv_grid,
         in_specs=[smem, q_spec_t, q_spec_t, row_spec_t, row_spec_t,
-                  k_spec_t, k_spec_t, kb_spec_t],
+                  k_spec_t, k_spec_t, kb_spec_t] + specs_kq,
         out_specs=[k_spec_t, k_spec_t],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_h, block_k, lanes), jnp.float32),
-                        pltpu.VMEM((block_h, block_k, lanes), jnp.float32)],
+        scratch_shapes=dkv_scratch,
+        tables=t_kq,
         compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(seed, q, g, lse, delta, k, v, kbias)
+    )(seed, q, g, lse, delta, k, v, kbias, *codes)
 
-    dq = pl.pallas_call(
+    dq = _pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **kw),
-        grid=(bh // block_h, sq // block_q, sk // block_k),
+        grid=(bh // block_h, nq, nk),
         in_specs=[smem, q_spec, q_spec, row_spec, row_spec,
-                  k_spec, k_spec, kb_spec],
+                  k_spec, k_spec, kb_spec] + specs_qk,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_h, block_q, lanes), jnp.float32)],
+        scratch_shapes=dq_scratch,
+        tables=t_qk,
         compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dq",
-    )(seed, q, g, lse, delta, k, v, kbias)
+    )(seed, q, g, lse, delta, k, v, kbias, *codes)
     return dq, dk, dv
 
 
 # -- custom VJP over the kernels ----------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12, 13))
+                   nondiff_argnums=tuple(range(5, 16)))
 def _flash_attention(q, k, v, kbias, seed_f, heads, is_causal, scale,
                      dropout_p, interpret, causal_offset, block_h,
-                     block_q, block_k):
+                     block_q, block_k, kv_heads, block_mask):
     """seed_f: (1,) float32 — a bitcast int32 dropout seed (float so the
     custom_vjp machinery sees only inexact primals).  causal_offset is
     the ORIGINAL sk - sq (pre-padding): the shim pads seq lengths, so it
@@ -564,31 +909,34 @@ def _flash_attention(q, k, v, kbias, seed_f, heads, is_causal, scale,
                             is_causal=is_causal, scale=scale,
                             dropout_p=dropout_p, interpret=interpret,
                             causal_offset=causal_offset, block_h=block_h,
-                            block_q=block_q, block_k=block_k)
+                            block_q=block_q, block_k=block_k,
+                            kv_heads=kv_heads, block_mask=block_mask)
     return out
 
 
 def _flash_fwd_rule(q, k, v, kbias, seed_f, heads, is_causal, scale,
                     dropout_p, interpret, causal_offset, block_h,
-                    block_q, block_k):
+                    block_q, block_k, kv_heads, block_mask):
     seed = lax.bitcast_convert_type(seed_f, jnp.int32)
     out, lse = _flash_forward(q, k, v, kbias, seed, heads,
                               is_causal=is_causal, scale=scale,
                               dropout_p=dropout_p, interpret=interpret,
                               causal_offset=causal_offset,
                               block_h=block_h, block_q=block_q,
-                              block_k=block_k)
+                              block_k=block_k, kv_heads=kv_heads,
+                              block_mask=block_mask)
     return out, (q, k, v, kbias, seed, out, lse)
 
 
 def _flash_bwd_rule(heads, is_causal, scale, dropout_p, interpret,
-                    causal_offset, block_h, block_q, block_k, res, g):
+                    causal_offset, block_h, block_q, block_k, kv_heads,
+                    block_mask, res, g):
     q, k, v, kbias, seed, out, lse = res
     dq, dk, dv = _flash_backward(
         q, k, v, kbias, seed, out, lse, g, heads, is_causal=is_causal,
         scale=scale, dropout_p=dropout_p, interpret=interpret,
         causal_offset=causal_offset, block_h=block_h, block_q=block_q,
-        block_k=block_k)
+        block_k=block_k, kv_heads=kv_heads, block_mask=block_mask)
     # key-bias grads are not needed (masks are constants); seed is rng
     return dq, dk, dv, jnp.zeros_like(kbias), jnp.zeros_like(
         lse, shape=(1,))
@@ -648,7 +996,7 @@ def _block_h_ladder(heads, lane_d=None, max_h=8):
 
 def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
                     dropout_p=0.0, dropout_seed=None, block_q=None,
-                    block_k=None, interpret=False):
+                    block_k=None, interpret=False, block_mask=None):
     """(B, S, H, D) flash attention via the Pallas kernels.
 
     key_bias: optional (B, Sk) float32 additive bias applied to every
@@ -671,15 +1019,44 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
     (`flash_packed_layout_total` counts these instances); otherwise
     heads are merged into (B*H, S, D) by an XLA transpose, D padded to
     a multiple of 64.
+
+    Grouped-query attention: k and v may hold fewer heads, (B, Sk, Hkv,
+    D) with Hkv dividing H; query head j reads key/value head j //
+    (H / Hkv).  On the packed layout at D a multiple of 128 the kernels
+    read the Hkv heads as they are, one key/value tile a group and grid
+    step; any other shape, and attention dropout, repeats them in HBM
+    first.
+
+    block_mask: a `BlockDiffusionMask` whose rows are q's and k's (self
+    attention over `[x_t ‖ x_0]`).  The kernels apply it from index
+    codes and skip its dead tiles (`flash_tiles_live_total` of
+    `flash_tiles_total`, per head, counted here at trace time with
+    `flash_block_mask_total` instances); no dense mask exists.
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    kv_heads = k.shape[2]
+    packed = _packs(h, d)
+    grouped = kv_heads != h
+    if grouped and not (packed and d % 128 == 0 and dropout_p == 0.0):
+        k = jnp.repeat(k, h // kv_heads, axis=2)
+        v = jnp.repeat(v, h // kv_heads, axis=2)
+        kv_heads, grouped = h, False
+    if block_mask is not None and not (sq == sk == block_mask.rows):
+        raise ValueError(
+            f"block_mask covers {block_mask.rows} rows, q has {sq} and "
+            f"k {sk}")
 
     block_q, block_k = _pick_blocks(sq, sk, d, block_q, block_k)
+    if grouped:
+        # the whole group in one step where its score tiles fit: its
+        # key/value tile is then read once a group
+        while (h // kv_heads) * block_q * block_k > _PACKED_MAX_SCORES \
+                and block_q > 128:
+            block_q //= 2
     sq_p = round_up(sq, block_q)
     sk_p = round_up(sk, block_k)
-    packed = _packs(h, d)
     d_p = d if packed else round_up(d, 64)
 
     if packed:
@@ -687,7 +1064,11 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
     else:
         merge = lambda x, s: jnp.transpose(x, (0, 2, 1, 3)).reshape(
             b * h, s, d)
-    qm, km, vm = merge(q, sq), merge(k, sk), merge(v, sk)
+    qm = merge(q, sq)
+    if grouped:
+        km, vm = (x.reshape(b, sk, kv_heads * d) for x in (k, v))
+    else:
+        km, vm = merge(k, sk), merge(v, sk)
     if sq_p != sq or d_p != d:
         qm = jnp.pad(qm, ((0, 0), (0, sq_p - sq), (0, d_p - d)))
     if sk_p != sk or d_p != d:
@@ -712,7 +1093,8 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
     # packed steps are sized to _PACKED_VMEM_LIMIT: at most 4 heads'
     # (512, 512) score tiles; the merged ladder is left to the probes
     ladder = _block_h_ladder(
-        h, d, _PACKED_MAX_SCORES // (block_q * block_k)) if packed \
+        h // kv_heads if grouped else h, d,
+        _PACKED_MAX_SCORES // (block_q * block_k)) if packed \
         else _block_h_ladder(h)
     if interpret:
         # exercise the head-blocked (3D-batched) kernel path in CPU
@@ -731,12 +1113,17 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
                                 is_causal, float(dropout_p), qm.dtype,
                                 cand, block_q, block_k, sk - sq,
                                 final_rung=(cand == ladder[-1]),
-                                packed=packed):
+                                packed=packed, kv_heads=kv_heads,
+                                block_mask=block_mask):
                     block_h = cand
                     break
         if block_h is None:
             mask = None if key_bias is None \
                 else lax.stop_gradient(key_bias)[:, None, None, :]
+            if block_mask is not None:
+                dense = jnp.asarray(block_mask.dense())[None, None]
+                mask = dense if mask is None else jnp.where(
+                    dense, mask, DEFAULT_MASK_VALUE)
             # carry the caller's per-step seed into the XLA path, else
             # its default PRNGKey(0) would reuse one dropout mask every
             # step
@@ -746,12 +1133,17 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
                                   is_causal=is_causal, scale=scale,
                                   dropout_p=dropout_p, dropout_key=dk)
 
+    from ...profiler import stat_add
+
+    if block_mask is not None:
+        live = block_mask.tiles(sq_p, sk_p, block_q, block_k)[0]
+        stat_add("flash_block_mask_total")
+        stat_add("flash_tiles_live_total", int(live.sum()))
+        stat_add("flash_tiles_total", live.size)
     out = _flash_attention(qm, km, vm, bias, seed_f, h, is_causal, scale,
                            float(dropout_p), interpret, sk - sq,
-                           block_h, block_q, block_k)
+                           block_h, block_q, block_k, kv_heads, block_mask)
     if packed:
-        from ...profiler import stat_add
-
         stat_add("flash_packed_layout_total")
         return out[:, :sq].reshape(b, sq, h, d)
     out = out[:, :sq, :d]
@@ -763,7 +1155,8 @@ _EXACT_PROBE_CACHE = {}
 
 def _probe_exact(q_shape, k_shape, heads, is_causal, dropout_p, dtype,
                  block_h, block_q, block_k, causal_offset,
-                 final_rung=True, packed=False):
+                 final_rung=True, packed=False, kv_heads=None,
+                 block_mask=None):
     """Compile (never run) the exact kernel instances flash_attention is
     about to stage, once per configuration.  q_shape / k_shape are the
     padded (B*H, S, D) whichever the operand layout; `packed` probes
@@ -774,20 +1167,21 @@ def _probe_exact(q_shape, k_shape, heads, is_causal, dropout_p, dtype,
     refusal is routine and stays silent and uncounted."""
     key = (q_shape, k_shape, heads, is_causal, dropout_p,
            jnp.dtype(dtype).name, block_h, block_q, block_k,
-           causal_offset, packed)
+           causal_offset, packed, kv_heads, block_mask)
     if key not in _EXACT_PROBE_CACHE:
         def compile_probe():
             bh, sq, d = q_shape
             sk = k_shape[1]
-            fold = (lambda s: (bh // heads, s, heads * d)) if packed \
-                else (lambda s: (bh, s, d))
+            fold = (lambda s, n=heads: (bh // heads, s, n * d)) if packed \
+                else (lambda s, n=heads: (bh, s, d))
             x = probe_struct(fold(sq), dtype)
-            kv = probe_struct(fold(sk), dtype)
+            kv = probe_struct(fold(sk, kv_heads or heads), dtype)
             kb = probe_struct((bh // heads, 1, sk), jnp.float32)
             seed = probe_struct((1,), jnp.int32)
             kw = dict(is_causal=is_causal, dropout_p=dropout_p,
                       block_h=block_h, block_q=block_q, block_k=block_k,
-                      causal_offset=causal_offset)
+                      causal_offset=causal_offset, kv_heads=kv_heads,
+                      block_mask=block_mask)
             _flash_forward.lower(x, kv, kv, kb, seed, heads,
                                  **kw).compile()
             lse = probe_struct((bh, sq, 1), jnp.float32)
@@ -968,7 +1362,7 @@ def sharded_attention_scope(mesh, batch_axis="dp", head_axis=None):
 
 
 def _flash_per_shard(spec, q, k, v, key_bias, is_causal, scale,
-                     dropout_p, seed, interpret=False):
+                     dropout_p, seed, interpret=False, block_mask=None):
     """flash_attention under shard_map over `spec` = (mesh, batch_axis,
     head_axis); q/k/v (B, S, H, D) global, key_bias (B, Sk) or None."""
     from jax.sharding import PartitionSpec as P
@@ -989,7 +1383,8 @@ def _flash_per_shard(spec, q, k, v, key_bias, is_causal, scale,
                 seed = seed * jnp.int32(1000003) + lax.axis_index(ax)
         return flash_attention(q, k, v, key_bias=kb, is_causal=is_causal,
                                scale=scale, dropout_p=dropout_p,
-                               dropout_seed=seed, interpret=interpret)
+                               dropout_seed=seed, interpret=interpret,
+                               block_mask=block_mask)
 
     return jax.shard_map(
         local, mesh=mesh,
@@ -1232,10 +1627,17 @@ def scaled_dot_product_attention(q, k, v, mask=None, is_causal=False,
                                  scale=None, dropout_p=0.0,
                                  dropout_key=None):
     """Dispatcher: ring attention inside ring_attention_scope (sequence
-    parallel), Pallas flash kernel on TPU (key-padding masks and
-    attention dropout run in-kernel), XLA path otherwise (arbitrary
-    dense masks, tiny shapes, non-TPU backends).
-    q/k/v: (batch, seq, heads, head_dim)."""
+    parallel), Pallas flash kernel on TPU (key-padding masks, a
+    `BlockDiffusionMask`, grouped key/value heads and attention dropout
+    run in-kernel), XLA path otherwise (arbitrary dense masks, tiny
+    shapes, non-TPU backends).
+    q/k/v: (batch, seq, heads, head_dim); k/v may hold fewer heads."""
+    block_mask = mask if isinstance(mask, BlockDiffusionMask) else None
+    if block_mask is not None and (
+            getattr(_ULYSSES_CTX, "mesh", None) is not None
+            or getattr(_RING_CTX, "mesh", None) is not None):
+        raise ValueError("a BlockDiffusionMask cannot be routed through "
+                         "the ring / all-to-all sequence-parallel paths")
     uly_mesh = getattr(_ULYSSES_CTX, "mesh", None)
     if uly_mesh is not None:
         if dropout_p != 0.0:
@@ -1272,17 +1674,19 @@ def scaled_dot_product_attention(q, k, v, mask=None, is_causal=False,
         return ring_attention(ring_mesh, _RING_CTX.axis)(
             q, k, v, is_causal=is_causal, scale=scale)
     if _flash_ok(q, k):
-        key_bias = _mask_as_key_bias(mask, q.shape[0], k.shape[1])
-        if mask is None or key_bias is not None:
+        dense = None if block_mask is not None else mask
+        key_bias = _mask_as_key_bias(dense, q.shape[0], k.shape[1])
+        if dense is None or key_bias is not None:
             seed = _seed_from_key(dropout_key)
             spec = getattr(_MESH_CTX, "spec", None)
             if spec is not None:
                 return _flash_per_shard(spec, q, k, v, key_bias,
                                         is_causal, scale, dropout_p,
-                                        seed)
+                                        seed, block_mask=block_mask)
             return flash_attention(
                 q, k, v, key_bias=key_bias, is_causal=is_causal,
-                scale=scale, dropout_p=dropout_p, dropout_seed=seed)
+                scale=scale, dropout_p=dropout_p, dropout_seed=seed,
+                block_mask=block_mask)
     return _xla_attention(q, k, v, mask=mask, is_causal=is_causal,
                           scale=scale, dropout_p=dropout_p,
                           dropout_key=dropout_key)

@@ -27,6 +27,7 @@ under jit on one device (ep_size=1) or under shard_map on a pod axis.
 
 from __future__ import annotations
 
+import functools
 import math
 
 
@@ -180,3 +181,298 @@ def build_switch_moe(mesh, n_experts, d_model, d_ff, ep_axis="ep",
         return shard_apply(params, x)
 
     return apply, params
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k routing over the experts a device holds
+# ---------------------------------------------------------------------------
+#
+# The layer of today's open expert models (gated SiLU experts, softmax
+# router, k of n_routed per token, the k weights renormalised), told
+# which experts it HOLDS: it routes over all n_routed, computes the
+# visits that land on its own `count` experts starting at `first`, and
+# leaves out what absent experts would have added.  One chip of an
+# expert-parallel group runs it as it is, without an exchange; under
+# `ep_axis` the shards exchange rows with `all_to_all`.
+#
+# No (T, E, C) tensor, no capacity, no dropped token: the T*k visits
+# are sorted by expert (absent ones last) and the sorted order is
+# walked in chunks of `chunk` visits — gather the chunk's rows, three
+# grouped matmuls over its ragged groups (`jax.lax.ragged_dot`, a
+# Mosaic grouped-matmul call on a TPU), scatter-add the weighted
+# results.  A chunk past the last held visit is skipped by `lax.cond`,
+# so the work follows the visits that landed here while every shape
+# stays static, and the memory is a chunk's whatever the routing does.
+# The backward pass walks the same chunks (`jax.vjp` of one chunk's
+# function), which is why the walk is a `custom_vjp`: reverse mode
+# through the scan would store a dense expert-weight cotangent per
+# chunk, skipped or not.
+
+def init_routed_moe_params(rng, n_routed, d_model, d_ff, held=None,
+                           dtype=None):
+    """{wr (H, n_routed), wg / wu (count, H, F), wd (count, F, H)} for
+    the experts `held = (first, count)` (default: all)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    r = np.random.RandomState(rng) if isinstance(rng, int) else rng
+    count = n_routed if held is None else held[1]
+    s1, s2 = d_model ** -0.5, d_ff ** -0.5
+    p = {"wr": r.normal(0, s1, (d_model, n_routed)),
+         "wg": r.normal(0, s1, (count, d_model, d_ff)),
+         "wu": r.normal(0, s1, (count, d_model, d_ff)),
+         "wd": r.normal(0, s2, (count, d_ff, d_model))}
+    return {k: jnp.asarray(v, dtype or jnp.float32) for k, v in p.items()}
+
+
+def route_top_k(x, wr, top_k, renormalize=True):
+    """x (T, H), wr (H, n_routed) -> (experts (T, k) int32, weights
+    (T, k) float32): softmax over all n_routed and top-k in float32,
+    the k weights divided by their sum where `renormalize`."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("router"):
+        logits = jnp.dot(x, wr.astype(x.dtype),
+                         preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, top_k)
+        if renormalize:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), weights
+
+
+def _chunk_ffn(xs, eid, w, wg, wu, wd):
+    """w * down(silu(gate(x)) * up(x)) in float32, of rows `xs` (C, H)
+    sorted by local expert id `eid` (C,), absent rows (id == count)
+    last; `w` (C,) the rows' weights.  Rows past the groups are
+    whatever the grouped matmul left there: every product is masked, so
+    that they are zeros in and out, forward and backward.
+
+    All of it under the scope `experts`, the masks and the weighting
+    too: XLA makes the grouped-matmul kernels without the program's
+    names, and `obs.opprof` names such an instruction after the one
+    that reads its result."""
+    import jax
+    import jax.numpy as jnp
+
+    count = wg.shape[0]
+    valid = (eid < count)[:, None]
+    with jax.named_scope("experts"):
+        sizes = jnp.bincount(eid, length=count + 1)[:count].astype(
+            jnp.int32)
+        xs = jnp.where(valid, xs, 0)
+        gate = jnp.where(valid, jax.lax.ragged_dot(xs, wg, sizes), 0)
+        up = jnp.where(valid, jax.lax.ragged_dot(xs, wu, sizes), 0)
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(xs.dtype)
+        y = jnp.where(valid, jax.lax.ragged_dot(act, wd, sizes), 0)
+        return y.astype(jnp.float32) * w[:, None]
+
+
+def _walk(visits, chunk, carry, active):
+    """`active(carry, start)` for every chunk of the sorted visits that
+    holds a held one; the others leave `carry` as it is."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    n_valid, total = visits
+
+    def body(carry, c):
+        start = c * chunk
+        return lax.cond(start < n_valid, lambda cr: active(cr, start),
+                        lambda cr: cr, carry), None
+
+    return lax.scan(body, carry, jnp.arange(total // chunk))[0]
+
+
+@functools.cache
+def _make_visits_ffn():
+    """The chunk walk as a `custom_vjp` (built on first use: this
+    module imports jax inside its functions)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def cut(a, start, chunk):
+        return lax.dynamic_slice_in_dim(a, start, chunk)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+    def visits_ffn(x, w, wg, wu, wd, tok, eid, n_valid, chunk):
+        """out[t] = sum over held visits v of token t of w[v] *
+        ffn_{eid[v]}(x[t]): x (T, H); w, tok, eid (M,) in expert-sorted
+        order, M a multiple of `chunk`; -> (out (T, H) in x's dtype,
+        visits computed)."""
+        count = wg.shape[0]
+
+        def active(carry, start):
+            out, done = carry
+            tok_c, eid_c = cut(tok, start, chunk), cut(eid, start, chunk)
+            with jax.named_scope("dispatch"):
+                xs = x[tok_c]
+            y = _chunk_ffn(xs, eid_c, cut(w, start, chunk), wg, wu, wd)
+            with jax.named_scope("combine"):
+                out = out.at[tok_c].add(y)
+            return out, done + jnp.sum(eid_c < count, dtype=jnp.int32)
+
+        out, done = _walk((n_valid, tok.shape[0]), chunk,
+                          (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)),
+                          active)
+        return out.astype(x.dtype), done
+
+    def fwd(x, w, wg, wu, wd, tok, eid, n_valid, chunk):
+        return (visits_ffn(x, w, wg, wu, wd, tok, eid, n_valid, chunk),
+                (x, w, wg, wu, wd, tok, eid, n_valid))
+
+    def bwd(chunk, res, cts):
+        x, w, wg, wu, wd, tok, eid, n_valid = res
+        dout = cts[0]
+
+        def active(carry, start):
+            dx, dw, dwg, dwu, dwd = carry
+            tok_c, eid_c = cut(tok, start, chunk), cut(eid, start, chunk)
+            with jax.named_scope("dispatch"):
+                xs = x[tok_c]
+            with jax.named_scope("combine"):
+                dy = dout[tok_c]
+            _, vjp = jax.vjp(
+                lambda xs, p: _chunk_ffn(xs, eid_c, **p), xs,
+                {"w": cut(w, start, chunk), "wg": wg, "wu": wu, "wd": wd})
+            with jax.named_scope("experts"):
+                dxs, grads = vjp(dy.astype(jnp.float32))
+                dxs = dxs.astype(jnp.float32)
+                dwg, dwu, dwd = (acc + grads[k].astype(jnp.float32)
+                                 for acc, k in ((dwg, "wg"), (dwu, "wu"),
+                                                (dwd, "wd")))
+            with jax.named_scope("dispatch"):
+                dx = dx.at[tok_c].add(dxs)
+            return (dx, lax.dynamic_update_slice_in_dim(
+                dw, grads["w"], start, 0), dwg, dwu, dwd)
+
+        f32 = lambda a: jnp.zeros(a.shape, jnp.float32)
+        dx, dw, dwg, dwu, dwd = _walk(
+            (n_valid, tok.shape[0]), chunk,
+            (f32(x), f32(w), f32(wg), f32(wu), f32(wd)), active)
+        return (dx.astype(x.dtype), dw.astype(w.dtype),
+                dwg.astype(wg.dtype), dwu.astype(wu.dtype),
+                dwd.astype(wd.dtype), None, None, None)
+
+    visits_ffn.defvjp(fwd, bwd)
+    return visits_ffn
+
+
+def _visits_ffn(*args):
+    return _make_visits_ffn()(*args)
+
+
+def _sorted_visits(local_expert, count, chunk):
+    """`local_expert` (M,) int32, `count` for a visit that lands
+    elsewhere -> (order, sorted ids, held visits, chunk): the stable
+    sort by expert, padded with absent visits to a multiple of the
+    chunk."""
+    import jax.numpy as jnp
+
+    m = local_expert.shape[0]
+    chunk = min(chunk, m)
+    pad = -m % chunk
+    order = jnp.argsort(local_expert, stable=True).astype(jnp.int32)
+    eid = local_expert[order]
+    if pad:
+        order = jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
+        eid = jnp.concatenate([eid, jnp.full((pad,), count, jnp.int32)])
+    return order, eid, jnp.sum(local_expert < count, dtype=jnp.int32), chunk
+
+
+def default_chunk(visits, held_share):
+    """Visits a chunk of the walk: a fair router's held visits (`visits`
+    x `held_share`) fit in two chunks with a quarter to spare, rounded
+    up to 1024.  A chunk costs its full gather and scatter however few
+    of its rows are held, so the expected load must not sit AT a chunk
+    boundary, where every layer of every step tosses a coin for one
+    more chunk (half the expected load a chunk did that at the sdar
+    cell: 1% of spread in the step rate; my chip runs, PR 28).  Other
+    loads pass `chunk`."""
+    two_chunks = 1.25 * visits * held_share
+    return min(visits, max(1024, -(-int(two_chunks / 2) // 1024) * 1024))
+
+
+def routed_moe_local(params, x, top_k, held=None, ep_axis=None,
+                     renormalize=True, chunk=None, routing=None):
+    """The routed expert layer on LOCAL rows x (T, H) -> (out (T, H),
+    stats, experts (T, k) the router chose).
+
+    params: `wr` (H, n_routed) and the held experts' `wg`, `wu`
+    (count, H, F), `wd` (count, F, H).  `held = (first, count)` says
+    which of the n_routed experts those are (default: all); the result
+    is the part of the layer's output the held experts give, weighted
+    by the w_i normalised over all k chosen.  With `ep_axis` (inside
+    shard_map): shard i holds experts [i * count, (i + 1) * count),
+    every row's visits go to their owners through `all_to_all` and
+    come back, and the result is the whole layer's.  `chunk`: visits a
+    step of the walk (default: `default_chunk`; tests pass a small one
+    to walk several chunks).  `routing`: (experts, weights) to use
+    instead of the router's own — only the dropless test's skewed
+    routing comes in this way; no layer or model hands it on.
+
+    stats (count + 2,) int32: rows each held expert computed, the
+    visits routed (T * k), the held visits computed — what the
+    `moe_*` counters are fed from; held visits dropped = 0 follows
+    from the first and the last."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    t, h = x.shape
+    n_routed = params["wr"].shape[1]
+    count = params["wg"].shape[0]
+    experts, weights = routing if routing is not None else route_top_k(
+        x, params["wr"], top_k, renormalize)
+    wg, wu, wd = (params[k].astype(x.dtype) for k in ("wg", "wu", "wd"))
+    if chunk is None:
+        chunk = default_chunk(t * top_k, count / n_routed)
+
+    if ep_axis is None:
+        first = 0 if held is None else held[0]
+        assert held is None or held[1] == count, (held, count)
+        with jax.named_scope("dispatch"):
+            local = jnp.where((experts >= first) & (experts < first + count),
+                              experts - first, count).reshape(-1)
+            order, eid, n_valid, chunk = _sorted_visits(local, count, chunk)
+            w_sorted = weights.reshape(-1)[order]
+            tok = order // top_k
+        out, done = _visits_ffn(x, w_sorted, wg, wu, wd, tok, eid,
+                                n_valid, chunk)
+        rows = jnp.bincount(local, length=count + 1)[:count]
+    else:
+        ep = lax.psum(1, ep_axis)
+        assert n_routed == ep * count, (n_routed, ep, count)
+        # a destination's buffer holds every visit a shard could send it
+        cap = t * min(top_k, count)
+        flat_e, flat_w = experts.reshape(-1), weights.reshape(-1)
+        with jax.named_scope("dispatch"):
+            sent = []
+            for d in range(ep):
+                key = jnp.where(flat_e // count == d, flat_e % count, count)
+                order = jnp.argsort(key, stable=True)[:cap]
+                sent.append((order, key[order]))
+            rows_x = lax.all_to_all(
+                jnp.stack([x[o // top_k] for o, _ in sent]), ep_axis, 0, 0)
+            rows_e = lax.all_to_all(
+                jnp.stack([e for _, e in sent]), ep_axis, 0, 0).reshape(-1)
+            order, eid, n_valid, chunk = _sorted_visits(rows_e, count, chunk)
+        y, done = _visits_ffn(
+            rows_x.reshape(ep * cap, h), jnp.ones(order.shape, jnp.float32),
+            wg, wu, wd, order, eid, n_valid, chunk)
+        with jax.named_scope("combine"):
+            back = lax.all_to_all(y.reshape(ep, cap, h), ep_axis, 0, 0)
+            out = jnp.zeros((t, h), jnp.float32)
+            for (o, e), y_d in zip(sent, back):
+                out = out.at[o // top_k].add(jnp.where(
+                    (e < count)[:, None],
+                    y_d.astype(jnp.float32) * flat_w[o][:, None], 0))
+            out = out.astype(x.dtype)
+        rows = jnp.bincount(rows_e, length=count + 1)[:count]
+    stats = jnp.concatenate([
+        rows.astype(jnp.int32),
+        jnp.stack([jnp.int32(t * top_k), done])])
+    return out, lax.stop_gradient(stats), experts

@@ -183,6 +183,37 @@ ENTRY %main (a: f32[8,8]) -> f32[8,8] {
 }
 """
 
+    _XLA_KERNEL_HLO = """\
+HloModule jit_step
+
+ENTRY %main (a: bf16[8,8], w: bf16[2,8,8], g: s32[2]) -> f32[8,8] {
+  %a = bf16[8,8]{1,0} parameter(0)
+  %w = bf16[2,8,8]{2,1,0} parameter(1)
+  %g = s32[2]{0} parameter(2)
+  %sel = bf16[8,8]{1,0} negate(bf16[8,8]{1,0} %a), metadata={op_name="jit(step)/jvp(net)/moe/while/body/experts/select_n"}
+  %ragged-dot-metadata = s32[3]{0} custom-call(s32[2]{0} %g), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-metadata"}
+  %ragged-dot-none.1 = bf16[8,8]{1,0} custom-call(s32[3]{0} %ragged-dot-metadata, bf16[8,8]{1,0} %sel, bf16[2,8,8]{2,1,0} %w), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %scat = f32[8,8]{1,0} convert(bf16[8,8]{1,0} %ragged-dot-none.1), metadata={op_name="jit(step)/jvp(net)/moe/while/body/combine/scatter-add"}
+  %flash = f32[8,8]{1,0} custom-call(f32[8,8]{1,0} %scat), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(net)/self_attn/flash_fwd/pallas_call"}
+  ROOT %other = f32[8,8]{1,0} custom-call(f32[8,8]{1,0} %flash), custom_call_target="Sharding", metadata={op_name="bare"}
+}
+"""
+
+    def test_xla_made_kernels_are_named_under_their_neighbours_scope(self):
+        """`jax.lax.ragged_dot` reaches the TPU as Mosaic calls with
+        XLA's own `op_name`: they are booked under the scope their
+        operands' producers and their consumers share, by that name —
+        not under whichever neighbour a fusion swallowed."""
+        names = opprof.profile_hlo_text(self._XLA_KERNEL_HLO)["instr_name"]
+        assert names["ragged-dot-none.1"] == (
+            "fwd", "net/moe/while/body/ragged-dot-none")
+        # the metadata call feeds only the kernel: its consumer's name
+        assert names["ragged-dot-metadata"] == names["ragged-dot-none.1"]
+        # a kernel the program named keeps its name; other custom
+        # calls are not touched
+        assert names["flash"] == ("fwd", "net/self_attn/flash_fwd")
+        assert names["other"] == opprof.UNATTRIBUTED
+
     def test_instr_name_map(self):
         prof = opprof.profile_hlo_text(self._HLO, label="t")
         assert prof["module"] == "jit_step"

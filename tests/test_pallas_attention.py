@@ -436,6 +436,26 @@ class TestMosaicAcceptsForV5e:
                               packed=True)
 
 
+    def test_flash_pair_grouped_block_mask(self, v5e):
+        """The `sdar_30b_a3b.blockdiff_s4096` instances: 32 query heads
+        on 4 key/value heads at D = 128, 2 x 4096 rows under the
+        block-diffusion mask (live-tile tables as scalar prefetch), the
+        whole group of 8 heads a step on (256, 512) tiles — the rung
+        flash_attention() tries first there."""
+        mask = A.BlockDiffusionMask(4096, 4)
+        assert A._probe_exact((32, 8192, 128), (32, 8192, 128), 32, False,
+                              0.0, jnp.bfloat16, 8, 256, 512, 0,
+                              packed=True, kv_heads=4, block_mask=mask)
+        # grouped heads alone (causal), and the mask on plain heads
+        assert A._probe_exact((16, 1024, 128), (16, 1024, 128), 16, True,
+                              0.0, jnp.bfloat16, 4, 512, 512, 0,
+                              packed=True, kv_heads=4)
+        assert A._probe_exact((24, 1024, 64), (24, 1024, 64), 12, False,
+                              0.1, jnp.bfloat16, 4, 512, 512, 0,
+                              packed=True, kv_heads=12,
+                              block_mask=A.BlockDiffusionMask(512, 32))
+
+
 def test_flash_per_shard_matches_unsharded():
     """`sharded_attention_scope`'s kernel path: flash attention under
     shard_map over (batch, heads) equals the unsharded kernel — the
