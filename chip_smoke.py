@@ -1,12 +1,14 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
-Drives the three main paths once, in ONE process, through the entry
+Drives the four main paths once, in ONE process, through the entry
 points a user calls, at published widths, on seeded random weights:
 
   executor_resnet50   models/resnet.build_train_program -> fluid.Executor
   bert_base_step      models/bert.build_pretrain_step (flash kernels)
   generation_engine   serving.AutoregressiveEngine over a LayeredDecoder
+  sdar_moe_step       models/sdar_moe.build_blockdiff_train_step (the
+                      masked flash kernels, the routed expert layer)
 
     python chip_smoke.py            # one chip (what the driver runs)
     python chip_smoke.py --chips 4  # the four-chip host: data-parallel
@@ -530,6 +532,73 @@ def generation_engine(vocab=30000, d_model=512, n_head=8, d_ff=2048,
 
 
 # ---------------------------------------------------------------------------
+# phase 4: block-diffusion training over the dropless routed expert layer
+# ---------------------------------------------------------------------------
+
+def sdar_moe_step(cfg, batch=2, seq=1024, steps=3, *, platform="tpu"):
+    """`sdar_moe.build_blockdiff_train_step` with per-layer
+    recomputation: the expert layers' counts say that no held visit was
+    dropped, and the trace-time counters which visit plan every expert
+    layer got (`parallel/moe.py`: one packed sort key where the shapes
+    allow it)."""
+    import jax.numpy as jnp
+
+    import paddle_tpu
+    from paddle_tpu.models import sdar_moe
+
+    ph = _Phase("sdar_moe_step")
+    paddle_tpu.seed(SEED)
+    model = sdar_moe.SdarMoeForBlockDiffusion(cfg)
+    sparse = sum(layer.sparse for layer in model.model.layers)
+    step, state = sdar_moe.build_blockdiff_train_step(model)
+    b = sdar_moe.fake_batch(cfg, batch, seq, seed=SEED)
+    lr = jnp.float32(1e-3)
+    plans = ("moe_plan_packed_total", "moe_plan_two_operand_total")
+    s0 = _stats()
+    t0 = time.perf_counter()
+    compiled = step.lower(state, b, lr).compile()
+    ph.compile_s = time.perf_counter() - t0
+    for k in plans:
+        ph.info[k] = _stats().get(k, 0) - s0.get(k, 0)
+    losses, ces = [], []
+    for _ in range(steps):
+        state, loss, aux = compiled(state, b, lr)
+        losses.append(float(loss))
+        ces.append(float(aux["ce"]))
+        sdar_moe.record_moe_stats(np.asarray(aux["moe_stats"]))
+    s1 = _stats()
+    ph.info["losses"] = [round(v, 4) for v in losses]
+    ph.info["moe_rows_held_total"] = (s1.get("moe_rows_held_total", 0)
+                                      - s0.get("moe_rows_held_total", 0))
+    ph.check(all(math.isfinite(v) for v in losses),
+             f"{steps} losses finite")
+    ph.check(losses[-1] < losses[0], "loss falls on the repeated batch")
+    ph.check(abs(ces[0] - math.log(cfg.vocab_size)) < 1.0,
+             f"first mean cross-entropy {ces[0]:.3f} near ln(vocab) = "
+             f"{math.log(cfg.vocab_size):.3f}")
+    ph.check(ph.info["moe_rows_held_total"] > 0
+             and s1.get("moe_dropped_total", 0)
+             == s0.get("moe_dropped_total", 0),
+             "held visits computed, moe_dropped_total did not move")
+    ph.check((ph.info[plans[0]], ph.info[plans[1]]) == (sparse, 0),
+             f"{plans[0]} == {sparse}, {plans[1]} == 0: every expert "
+             "layer's visit plan was traced once, from the packed key")
+    ph.check(_device_platforms(state["params"]["lm_head.weight"])
+             == {platform}, f"parameters sit on platform {platform!r}")
+    if platform == "tpu":
+        text = compiled.as_text()
+        grouped = len(re.findall(
+            r'custom_call_target="tpu_custom_call"[^\n]*'
+            r'op_name="ragged-dot-none', text))
+        ph.check(grouped >= 9 * sparse,
+                 f"{grouped} grouped-matmul Mosaic calls for {sparse} "
+                 "expert layers")
+        ph.check(_fallback_counts()["flash_fallback_total"] == 0,
+                 "flash_fallback_total == 0")
+    return ph.done()
+
+
+# ---------------------------------------------------------------------------
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -542,7 +611,7 @@ def main(argv=None) -> int:
     device, count = require_chip(args.chips)
 
     from paddle_tpu.fluid.compile_cache import enable_persistent_cache
-    from paddle_tpu.models import bert
+    from paddle_tpu.models import bert, sdar_moe
 
     print(f"chip_smoke: compile cache at {enable_persistent_cache()}")
     phases = []
@@ -552,6 +621,11 @@ def main(argv=None) -> int:
         phases.append(executor_resnet50(128))
         phases.append(bert_base_step(bert.BertConfig.base()))
         phases.append(generation_engine())
+        # SDAR-30B-A3B's widths, two layers, one chip's 16 of the 128
+        # experts and an eighth of the vocabulary
+        phases.append(sdar_moe_step(sdar_moe.SdarMoeConfig(
+            num_hidden_layers=2, experts_held=(0, 16), vocab_size=18992,
+            recompute=True)))
     else:
         phases.append(executor_resnet50(4 * 128, data_parallel=4))
         phases.append(bert_base_step(bert.BertConfig.base(),

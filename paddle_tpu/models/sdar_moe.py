@@ -165,7 +165,12 @@ class SdarMoeModel(nn.Layer):
                     return out._value, None if st is None else tuple(
                         t._value for t in st)
 
-                xv, st = jax.checkpoint(run)(x._value)
+                # all of a layer is recomputed in the backward pass but
+                # the expert layer's visit plan (parallel/moe.py): its
+                # sort runs once a layer a step
+                xv, st = jax.checkpoint(
+                    run, policy=jax.checkpoint_policies
+                    .save_only_these_names("moe_plan"))(x._value)
                 x = Tensor(xv)
                 st = None if st is None else tuple(Tensor(t) for t in st)
             else:

@@ -1,9 +1,19 @@
-"""Expert parallelism — Switch-style top-1 MoE over a mesh axis.
+"""Expert parallelism: two expert layers over a mesh axis.
 
 The reference has NO MoE/expert parallelism (SURVEY.md §2.9 "NOT
 present in the reference"); like ring attention (§5.7) this is part of
-the TPU-native scale story the survey calls for.  Design (Switch
-Transformer, Fedus et al. 2021, and the GShard dispatch algebra):
+the TPU-native scale story the survey calls for.
+
+`routed_moe_local` (`nn.RoutedMoE`; the second half of this file) is
+the layer of today's open expert models: top-k of n_routed, dropless,
+told which experts it holds — a sort of the visits by expert, grouped
+matmuls over a walk of the sorted order, no capacity and no (T, E, C)
+tensor.  New models use it.
+
+`switch_moe_local` (`nn.SwitchMoE`; the first half) is kept for the
+capacity-drop semantics of the Switch Transformer (Fedus et al. 2021,
+with the GShard dispatch algebra) only — top-1, a capacity, dropped
+tokens — and shares no code with the other:
 
   * experts are sharded over the `ep` mesh axis (each device holds
     n_experts / ep_size expert FFNs);
@@ -20,8 +30,8 @@ Transformer, Fedus et al. 2021, and the GShard dispatch algebra):
     LOCAL shard (Switch eq. 4); psum-averaging it over the axis is the
     caller's choice when composing the total loss.
 
-Everything is einsum/one-hot algebra on static shapes: XLA tiles the
-dispatch/combine contractions onto the MXU, and the same code runs
+There everything is einsum/one-hot algebra on static shapes: XLA tiles
+the dispatch/combine contractions onto the MXU, and the same code runs
 under jit on one device (ep_size=1) or under shard_map on a pod axis.
 """
 
@@ -200,13 +210,26 @@ def build_switch_moe(mesh, n_experts, d_model, d_ff, ep_axis="ep",
 # walked in chunks of `chunk` visits — gather the chunk's rows, three
 # grouped matmuls over its ragged groups (`jax.lax.ragged_dot`, a
 # Mosaic grouped-matmul call on a TPU), scatter-add the weighted
-# results.  A chunk past the last held visit is skipped by `lax.cond`,
-# so the work follows the visits that landed here while every shape
-# stays static, and the memory is a chunk's whatever the routing does.
-# The backward pass walks the same chunks (`jax.vjp` of one chunk's
-# function), which is why the walk is a `custom_vjp`: reverse mode
-# through the scan would store a dense expert-weight cotangent per
-# chunk, skipped or not.
+# results.  The walk is a loop over the ceil(held visits / chunk)
+# chunks that hold a held visit and no further, so the work follows the
+# visits that landed here while every shape stays static, and the
+# memory is a chunk's whatever the routing does.  The backward pass
+# walks the same chunks (`jax.vjp` of one chunk's function), which is
+# why the walk is a `custom_vjp`: reverse mode through the loop would
+# store a dense expert-weight cotangent per chunk.
+#
+# The visit PLAN — the sorted order, its rows and expert ids, the
+# number of held visits, the visits' weights — is what the walk reads
+# and all it keeps of the routing.  Where the shapes allow, it comes
+# from ONE sort of one int32 key a visit, `(expert << bits) | index`
+# (`_packed_key_bits`): the keys are distinct, so the order is the
+# stable one, and order and expert id are bit fields of the sorted key.
+# Its arrays, and the router's choice they are made from, carry the
+# `jax.ad_checkpoint.checkpoint_name` "moe_plan": a model that
+# recomputes its layers under `jax.checkpoint(policy=
+# save_only_these_names("moe_plan"))` chooses and sorts once a layer a
+# step, not once a pass, and its backward pass differentiates the
+# choice its forward pass made.
 
 def init_routed_moe_params(rng, n_routed, d_model, d_ff, held=None,
                            dtype=None):
@@ -228,18 +251,32 @@ def init_routed_moe_params(rng, n_routed, d_model, d_ff, held=None,
 def route_top_k(x, wr, top_k, renormalize=True):
     """x (T, H), wr (H, n_routed) -> (experts (T, k) int32, weights
     (T, k) float32): softmax over all n_routed and top-k in float32,
-    the k weights divided by their sum where `renormalize`."""
+    the k weights divided by their sum where `renormalize`.
+
+    The choice is made once: `experts` carries the visit plan's name
+    ("moe_plan"), and the weights are read from the probabilities AT
+    those ids, so a layer recomputed under a policy that keeps the plan
+    differentiates the router at the forward pass's choice — a second
+    top-k over recomputed probabilities may order near-ties otherwise,
+    and the kept plan's visits would meet another expert's weight."""
     import jax
     import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
 
     with jax.named_scope("router"):
         logits = jnp.dot(x, wr.astype(x.dtype),
                          preferred_element_type=jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = jax.lax.top_k(probs, top_k)
+        experts = checkpoint_name(
+            jax.lax.top_k(probs, top_k)[1].astype(jnp.int32), "moe_plan")
+        # compares and sums, forward and backward: no T*k-sized gather
+        # or scatter-add
+        chosen = experts[:, :, None] == jnp.arange(wr.shape[1],
+                                                   dtype=jnp.int32)
+        weights = jnp.sum(jnp.where(chosen, probs[:, None, :], 0), axis=-1)
         if renormalize:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return experts.astype(jnp.int32), weights
+    return experts, weights
 
 
 def _chunk_ffn(xs, eid, w, wg, wu, wd):
@@ -270,20 +307,14 @@ def _chunk_ffn(xs, eid, w, wg, wu, wd):
         return y.astype(jnp.float32) * w[:, None]
 
 
-def _walk(visits, chunk, carry, active):
-    """`active(carry, start)` for every chunk of the sorted visits that
-    holds a held one; the others leave `carry` as it is."""
-    import jax.numpy as jnp
+def _walk(n_valid, chunk, carry, active):
+    """`carry = active(carry, start)` for the ceil(n_valid / chunk)
+    chunks of the sorted visits that hold a held one, in order; the
+    chunks after them are not visited."""
     from jax import lax
 
-    n_valid, total = visits
-
-    def body(carry, c):
-        start = c * chunk
-        return lax.cond(start < n_valid, lambda cr: active(cr, start),
-                        lambda cr: cr, carry), None
-
-    return lax.scan(body, carry, jnp.arange(total // chunk))[0]
+    return lax.fori_loop(0, (n_valid + chunk - 1) // chunk,
+                         lambda c, carry: active(carry, c * chunk), carry)
 
 
 @functools.cache
@@ -294,50 +325,51 @@ def _make_visits_ffn():
     import jax.numpy as jnp
     from jax import lax
 
-    def cut(a, start, chunk):
-        return lax.dynamic_slice_in_dim(a, start, chunk)
+    def cuts(start, chunk, *arrays):
+        return (lax.dynamic_slice_in_dim(a, start, chunk) for a in arrays)
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-    def visits_ffn(x, w, wg, wu, wd, tok, eid, n_valid, chunk):
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
+    def visits_ffn(x, wg, wu, wd, w, order, tok, eid, n_valid, chunk):
         """out[t] = sum over held visits v of token t of w[v] *
-        ffn_{eid[v]}(x[t]): x (T, H); w, tok, eid (M,) in expert-sorted
-        order, M a multiple of `chunk`; -> (out (T, H) in x's dtype,
-        visits computed)."""
+        ffn_{eid[v]}(x[t]): x (T, H); `order`, `tok`, `eid` (M,) the
+        plan's visits, their rows and expert ids in expert-sorted
+        order, M a multiple of `chunk`; w (M,) the weights BY VISIT
+        (unsorted: a chunk gathers its own) -> (out (T, H) in x's
+        dtype, visits computed)."""
         count = wg.shape[0]
 
         def active(carry, start):
             out, done = carry
-            tok_c, eid_c = cut(tok, start, chunk), cut(eid, start, chunk)
+            order_c, tok_c, eid_c = cuts(start, chunk, order, tok, eid)
             with jax.named_scope("dispatch"):
-                xs = x[tok_c]
-            y = _chunk_ffn(xs, eid_c, cut(w, start, chunk), wg, wu, wd)
+                xs, w_c = x[tok_c], w[order_c]
+            y = _chunk_ffn(xs, eid_c, w_c, wg, wu, wd)
             with jax.named_scope("combine"):
                 out = out.at[tok_c].add(y)
             return out, done + jnp.sum(eid_c < count, dtype=jnp.int32)
 
-        out, done = _walk((n_valid, tok.shape[0]), chunk,
+        out, done = _walk(n_valid, chunk,
                           (jnp.zeros(x.shape, jnp.float32), jnp.int32(0)),
                           active)
         return out.astype(x.dtype), done
 
-    def fwd(x, w, wg, wu, wd, tok, eid, n_valid, chunk):
-        return (visits_ffn(x, w, wg, wu, wd, tok, eid, n_valid, chunk),
-                (x, w, wg, wu, wd, tok, eid, n_valid))
+    def fwd(*args):
+        return visits_ffn(*args), args[:-1]
 
     def bwd(chunk, res, cts):
-        x, w, wg, wu, wd, tok, eid, n_valid = res
+        x, wg, wu, wd, w, order, tok, eid, n_valid = res
         dout = cts[0]
 
         def active(carry, start):
             dx, dw, dwg, dwu, dwd = carry
-            tok_c, eid_c = cut(tok, start, chunk), cut(eid, start, chunk)
+            order_c, tok_c, eid_c = cuts(start, chunk, order, tok, eid)
             with jax.named_scope("dispatch"):
-                xs = x[tok_c]
+                xs, w_c = x[tok_c], w[order_c]
             with jax.named_scope("combine"):
                 dy = dout[tok_c]
             _, vjp = jax.vjp(
                 lambda xs, p: _chunk_ffn(xs, eid_c, **p), xs,
-                {"w": cut(w, start, chunk), "wg": wg, "wu": wu, "wd": wd})
+                {"w": w_c, "wg": wg, "wu": wu, "wd": wd})
             with jax.named_scope("experts"):
                 dxs, grads = vjp(dy.astype(jnp.float32))
                 dxs = dxs.astype(jnp.float32)
@@ -346,16 +378,18 @@ def _make_visits_ffn():
                                                 (dwd, "wd")))
             with jax.named_scope("dispatch"):
                 dx = dx.at[tok_c].add(dxs)
-            return (dx, lax.dynamic_update_slice_in_dim(
-                dw, grads["w"], start, 0), dwg, dwu, dwd)
+                # a visit sits in one chunk: the walked chunks' weights
+                # get their cotangent, the others keep their zero
+                dw = dw.at[order_c].set(grads["w"], unique_indices=True)
+            return dx, dw, dwg, dwu, dwd
 
         f32 = lambda a: jnp.zeros(a.shape, jnp.float32)
         dx, dw, dwg, dwu, dwd = _walk(
-            (n_valid, tok.shape[0]), chunk,
-            (f32(x), f32(w), f32(wg), f32(wu), f32(wd)), active)
-        return (dx.astype(x.dtype), dw.astype(w.dtype),
-                dwg.astype(wg.dtype), dwu.astype(wu.dtype),
-                dwd.astype(wd.dtype), None, None, None)
+            n_valid, chunk, (f32(x), f32(w), f32(wg), f32(wu), f32(wd)),
+            active)
+        return (dx.astype(x.dtype), dwg.astype(wg.dtype),
+                dwu.astype(wu.dtype), dwd.astype(wd.dtype),
+                dw.astype(w.dtype), None, None, None, None)
 
     visits_ffn.defvjp(fwd, bwd)
     return visits_ffn
@@ -365,22 +399,65 @@ def _visits_ffn(*args):
     return _make_visits_ffn()(*args)
 
 
-def _sorted_visits(local_expert, count, chunk):
-    """`local_expert` (M,) int32, `count` for a visit that lands
-    elsewhere -> (order, sorted ids, held visits, chunk): the stable
-    sort by expert, padded with absent visits to a multiple of the
-    chunk."""
+def _packed_key_bits(count, visits):
+    """Bits a visit's index takes in the packed sort key `(expert <<
+    bits) | index`, or None where the largest key — expert `count`, a
+    visit that lands elsewhere — does not fit int32: the plan is then
+    made by a stable two-operand sort."""
+    bits = max(visits - 1, 1).bit_length()
+    return bits if (count + 1) << bits <= 1 << 31 else None
+
+
+def _visit_plan(local_expert, weights, count, chunk, per_row=1):
+    """The plan the walk reads.  `local_expert` (M,) int32 — `count`
+    for a visit that lands elsewhere — and `weights` (M,), both by
+    visit, visit v belonging to row v // per_row -> ((w, order, tok,
+    eid, n_valid), chunk): the visits in the stable order of their
+    expert, the rows and expert ids in that order, padded with absent
+    visits of row 0 to a multiple of the chunk; the weights by visit,
+    padded alike (a padding visit's `order` is its own index there, so
+    that the order holds no index twice); the number of held visits.
+    Every array is named "moe_plan" for `jax.checkpoint` policies."""
     import jax.numpy as jnp
+    from jax.ad_checkpoint import checkpoint_name
+
+    from ..profiler import stat_add
 
     m = local_expert.shape[0]
     chunk = min(chunk, m)
     pad = -m % chunk
-    order = jnp.argsort(local_expert, stable=True).astype(jnp.int32)
-    eid = local_expert[order]
+    bits = _packed_key_bits(count, m)
+    if bits is not None:
+        stat_add("moe_plan_packed_total")
+        key = jnp.sort((local_expert << bits)
+                       | jnp.arange(m, dtype=jnp.int32))
+        order, eid = key & ((1 << bits) - 1), key >> bits
+    else:
+        stat_add("moe_plan_two_operand_total")
+        order = jnp.argsort(local_expert, stable=True).astype(jnp.int32)
+        eid = local_expert[order]
+    tok = order // per_row
     if pad:
-        order = jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
+        order = jnp.concatenate([order, jnp.arange(m, m + pad,
+                                                   dtype=jnp.int32)])
+        tok = jnp.concatenate([tok, jnp.zeros((pad,), jnp.int32)])
         eid = jnp.concatenate([eid, jnp.full((pad,), count, jnp.int32)])
-    return order, eid, jnp.sum(local_expert < count, dtype=jnp.int32), chunk
+        weights = jnp.concatenate([weights,
+                                   jnp.zeros((pad,), weights.dtype)])
+    n_valid = jnp.sum(local_expert < count, dtype=jnp.int32)
+    return tuple(checkpoint_name(a, "moe_plan") for a in (
+        weights, order, tok, eid, n_valid)), chunk
+
+
+def _rows_per_expert(local_expert, count):
+    """(count,) int32, the visits of `local_expert` (M,) on each held
+    expert: `count` compares and sums over the visits (a `bincount`
+    is a scatter-add of M ones)."""
+    import jax.numpy as jnp
+
+    held = jnp.arange(count, dtype=local_expert.dtype)
+    return jnp.sum(local_expert[None, :] == held[:, None], axis=1,
+                   dtype=jnp.int32)
 
 
 def default_chunk(visits, held_share):
@@ -437,12 +514,10 @@ def routed_moe_local(params, x, top_k, held=None, ep_axis=None,
         with jax.named_scope("dispatch"):
             local = jnp.where((experts >= first) & (experts < first + count),
                               experts - first, count).reshape(-1)
-            order, eid, n_valid, chunk = _sorted_visits(local, count, chunk)
-            w_sorted = weights.reshape(-1)[order]
-            tok = order // top_k
-        out, done = _visits_ffn(x, w_sorted, wg, wu, wd, tok, eid,
-                                n_valid, chunk)
-        rows = jnp.bincount(local, length=count + 1)[:count]
+            plan, chunk = _visit_plan(local, weights.reshape(-1), count,
+                                      chunk, per_row=top_k)
+        out, done = _visits_ffn(x, wg, wu, wd, *plan, chunk)
+        rows = _rows_per_expert(local, count)
     else:
         ep = lax.psum(1, ep_axis)
         assert n_routed == ep * count, (n_routed, ep, count)
@@ -459,10 +534,10 @@ def routed_moe_local(params, x, top_k, held=None, ep_axis=None,
                 jnp.stack([x[o // top_k] for o, _ in sent]), ep_axis, 0, 0)
             rows_e = lax.all_to_all(
                 jnp.stack([e for _, e in sent]), ep_axis, 0, 0).reshape(-1)
-            order, eid, n_valid, chunk = _sorted_visits(rows_e, count, chunk)
-        y, done = _visits_ffn(
-            rows_x.reshape(ep * cap, h), jnp.ones(order.shape, jnp.float32),
-            wg, wu, wd, order, eid, n_valid, chunk)
+            plan, chunk = _visit_plan(
+                rows_e, jnp.ones(rows_e.shape, jnp.float32), count, chunk)
+        y, done = _visits_ffn(rows_x.reshape(ep * cap, h), wg, wu, wd,
+                              *plan, chunk)
         with jax.named_scope("combine"):
             back = lax.all_to_all(y.reshape(ep, cap, h), ep_axis, 0, 0)
             out = jnp.zeros((t, h), jnp.float32)
@@ -471,8 +546,7 @@ def routed_moe_local(params, x, top_k, held=None, ep_axis=None,
                     (e < count)[:, None],
                     y_d.astype(jnp.float32) * flat_w[o][:, None], 0))
             out = out.astype(x.dtype)
-        rows = jnp.bincount(rows_e, length=count + 1)[:count]
+        rows = _rows_per_expert(rows_e, count)
     stats = jnp.concatenate([
-        rows.astype(jnp.int32),
-        jnp.stack([jnp.int32(t * top_k), done])])
+        rows, jnp.stack([jnp.int32(t * top_k), done])])
     return out, lax.stop_gradient(stats), experts

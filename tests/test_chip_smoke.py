@@ -45,6 +45,16 @@ class TestPhasesTiny:
             prompt_buckets=(8,), platform="cpu")
         assert out["decode_steps"] > 0
 
+    def test_sdar_moe_step(self):
+        from paddle_tpu.models import sdar_moe
+
+        out = chip_smoke.sdar_moe_step(
+            sdar_moe.SdarMoeConfig.tiny(experts_held=(0, 4),
+                                        recompute=True),
+            batch=2, seq=16, steps=3, platform="cpu")
+        assert out["moe_plan_packed_total"] == 2
+        assert out["moe_plan_two_operand_total"] == 0
+
     def test_failed_check_raises(self):
         ph = chip_smoke._Phase("x")
         ph.check(True, "fine")

@@ -294,6 +294,189 @@ def test_routed_moe_layer_matches_function():
     assert stats.shape == (6,) and experts.shape == (20, 2)
 
 
+# -- the visit plan and the walk ------------------------------------------------
+
+def _plan_case(name):
+    """(local expert ids by visit, count, chunk, rows a visit)."""
+    rng = np.random.RandomState(11)
+    return {
+        # 96 visits in chunks of 40: 24 of padding
+        "random_padded": (rng.randint(0, 6, 96), 5, 40, 3),
+        "random_unpadded": (rng.randint(0, 6, 96), 5, 32, 3),
+        "one_chunk": (rng.randint(0, 6, 30), 5, 64, 2),
+        "all_on_one_held_expert": (np.full(64, 2), 4, 24, 4),
+        "none_held": (np.full(64, 4), 4, 24, 4),
+        "one_expert_held": (rng.randint(0, 2, 50), 1, 16, 1),
+    }[name]
+
+
+@pytest.mark.parametrize("sort", ["packed", "two_operand"])
+@pytest.mark.parametrize("case", [
+    "random_padded", "random_unpadded", "one_chunk",
+    "all_on_one_held_expert", "none_held", "one_expert_held"])
+def test_visit_plan_is_the_stable_sort(case, sort, monkeypatch):
+    """Both forms of the plan, bit for bit: the stable argsort of the
+    expert ids, the rows and ids gathered in that order, the weights
+    left by visit; padding visits are absent, of row 0, and own the
+    padded weights' indices."""
+    local, count, chunk, per_row = _plan_case(case)
+    m = len(local)
+    weights = np.random.RandomState(12).rand(m).astype(np.float32)
+    if sort == "two_operand":
+        monkeypatch.setattr(moe, "_packed_key_bits", lambda c, v: None)
+    before = paddle_tpu.profiler.get_int_stats().get(
+        f"moe_plan_{sort}_total", 0)
+    (w, order, tok, eid, n_valid), chunk = jax.jit(
+        lambda l, w: moe._visit_plan(l, w, count, chunk, per_row))(
+        jnp.asarray(local, jnp.int32), jnp.asarray(weights))
+    assert paddle_tpu.profiler.get_int_stats()[
+        f"moe_plan_{sort}_total"] == before + 1
+    want = np.argsort(local, kind="stable")
+    pad = -m % chunk
+    assert len(order) == m + pad and (m + pad) % chunk == 0
+    assert all(a.dtype == jnp.int32 for a in (order, tok, eid, n_valid))
+    np.testing.assert_array_equal(order[:m], want)
+    np.testing.assert_array_equal(tok[:m], want // per_row)
+    np.testing.assert_array_equal(eid[:m], local[want])
+    np.testing.assert_array_equal(order[m:], np.arange(m, m + pad))
+    assert (np.asarray(tok[m:]) == 0).all()
+    assert (np.asarray(eid[m:]) == count).all()
+    np.testing.assert_array_equal(w[:m], weights)
+    assert (np.asarray(w[m:]) == 0).all()
+    assert int(n_valid) == (local < count).sum()
+
+
+@pytest.mark.parametrize("count,visits,bits", [
+    (16, 262144, 18),           # the sdar cell's layer
+    (16, 262145, 19),
+    (128, 1, 1),
+    (127, 1 << 24, 24),         # the largest key is 2**31 - 1
+    (128, 1 << 24, None),       # one expert more: int32 overflows
+    (16, (1 << 27) + 1, None),
+    (3, 1 << 29, 29),
+    (4, 1 << 29, None),
+])
+def test_plan_form_follows_the_shapes(count, visits, bits):
+    """The packed key is taken exactly where (absent id << bits) |
+    (visits - 1) fits int32; nothing is allocated to find out."""
+    assert moe._packed_key_bits(count, visits) == bits
+    if bits is not None:
+        assert visits - 1 < 1 << bits
+        assert (count << bits) | (visits - 1) <= np.iinfo(np.int32).max
+
+
+@pytest.mark.parametrize("n_valid,bodies", [
+    (0, 0), (1, 1), (31, 1), (32, 1), (33, 2), (64, 2), (65, 3), (96, 3)])
+def test_walk_visits_the_chunks_that_hold_work(n_valid, bodies):
+    starts = jax.jit(lambda n: moe._walk(
+        n, 32, (jnp.int32(0), jnp.full((3,), -1, jnp.int32)),
+        lambda c, start: (c[0] + 1, c[1].at[c[0]].set(start))))(
+        jnp.int32(n_valid))
+    assert int(starts[0]) == bodies
+    assert starts[1].tolist() == [0, 32, 64][:bodies] + [-1] * (3 - bodies)
+
+
+def test_no_held_visit_is_an_empty_walk(monkeypatch):
+    """Every visit lands on an expert held elsewhere: no chunk is
+    walked, the output is zero, and so are the gradients (finite: the
+    walk's carries start at zero)."""
+    t, k = 40, 2
+    p = moe.init_routed_moe_params(0, 8, 16, 24, held=(0, 4))
+    x = jnp.asarray(np.random.RandomState(1).normal(size=(t, 16)),
+                    jnp.float32)
+    rng = np.random.RandomState(2)
+    experts = jnp.asarray(rng.randint(4, 8, (t, k)), jnp.int32)
+    weights = jnp.asarray(rng.dirichlet([1, 1], t), jnp.float32)
+    walked = []
+    walk = moe._walk
+
+    def counting(n_valid, chunk, carry, active):
+        walked.append((n_valid + chunk - 1) // chunk)
+        return walk(n_valid, chunk, carry, active)
+
+    def f(p, x, weights):
+        out, stats, _ = moe.routed_moe_local(
+            p, x, k, held=(0, 4), routing=(experts, weights), chunk=16)
+        return jnp.sum(out * jnp.cos(x)), (out, stats)
+
+    monkeypatch.setattr(moe, "_walk", counting)
+    grads, (out, stats) = jax.grad(f, (0, 1, 2), has_aux=True)(
+        p, x, weights)
+    assert [int(n) for n in walked] == [0, 0]     # forward, backward
+    assert not np.asarray(out).any()
+    assert np.asarray(stats).tolist() == [0, 0, 0, 0, t * k, 0]
+    for g in jax.tree.leaves(grads):
+        assert np.isfinite(np.asarray(g)).all() and not np.asarray(g).any()
+
+
+def _count_primitive(jaxpr, name):
+    """Equations of primitive `name` in `jaxpr` and every jaxpr its
+    equations carry (checkpoint, custom_vjp, while, pjit bodies)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _count_primitive(sub, name)
+    return n
+
+
+class TestPlanBuiltOnce:
+    """One tiny decoder layer under the model's own per-layer
+    `jax.checkpoint`: the policy keeps the expert layer's visit plan,
+    so the gradient sorts once, and recomputing changes no bit."""
+
+    def _grads(self, recompute):
+        paddle_tpu.seed(4)
+        cfg = M.SdarMoeConfig.tiny(num_hidden_layers=1, experts_held=(2, 4),
+                                   recompute=recompute)
+        model = M.SdarMoeModel(cfg)
+        params = {k: jnp.array(v)
+                  for k, v in functional_state(model).items()}
+        ids = jnp.asarray(np.random.RandomState(5).randint(0, 96, (2, 24)),
+                          jnp.int32)
+
+        def loss(params):
+            (hidden, _), _ = functional_call(model, params, ids,
+                                             np.arange(24, dtype=np.int32))
+            return jnp.sum(jnp.sin(hidden))
+
+        return jax.grad(loss), params
+
+    def test_gradient_holds_one_sort_a_layer(self):
+        grad, params = self._grads(recompute=True)
+        stats = paddle_tpu.profiler.get_int_stats
+        before = stats().get("moe_plan_packed_total", 0), stats().get(
+            "moe_plan_two_operand_total", 0)
+        jaxpr = jax.make_jaxpr(grad)(params).jaxpr
+        assert _count_primitive(jaxpr, "sort") == 1
+        # nor a second choice of experts: the router's backward reads
+        # the forward pass's ids, as the kept plan does
+        assert _count_primitive(jaxpr, "top_k") == 1
+        assert _count_primitive(jaxpr, "remat2") >= 1     # jax.checkpoint
+        assert (stats()["moe_plan_packed_total"],
+                stats().get("moe_plan_two_operand_total", 0)) \
+            == (before[0] + 1, before[1])
+
+    def test_a_checkpoint_without_the_policy_sorts_twice(self):
+        """What a model that wraps the layer in a plain `jax.checkpoint`
+        gets: the behaviour before the plan had a name."""
+        p = moe.init_routed_moe_params(0, 8, 16, 24)
+        x = jnp.ones((12, 16), jnp.float32)
+        f = jax.checkpoint(lambda p, x: jnp.sum(
+            moe.routed_moe_local(p, x, 2, chunk=8)[0]))
+        assert _count_primitive(
+            jax.make_jaxpr(jax.grad(f))(p, x).jaxpr, "sort") == 2
+
+    def test_gradients_bit_equal_to_no_recomputation(self):
+        grad, params = self._grads(recompute=True)
+        plain, _ = self._grads(recompute=False)
+        got, want = jax.jit(grad)(params), jax.jit(plain)(params)
+        assert sorted(got) == sorted(want)
+        for k in want:
+            np.testing.assert_array_equal(np.asarray(got[k]),
+                                          np.asarray(want[k]), err_msg=k)
+
+
 # -- the small parts -----------------------------------------------------------
 
 def test_rms_norm():

@@ -318,6 +318,56 @@ def test_launcher_recognises_the_tpu_host(monkeypatch):
         (glob.glob("/dev/accel*"), seen)
 
 
+def test_routed_moe_packed_plan_on_tpu():
+    """The dropless expert layer through XLA:TPU's sort, grouped
+    matmuls and dynamic-trip-count walk, under the checkpoint policy
+    that keeps its visit plan: forward and gradients against the
+    gather-free dense oracle, nothing dropped, and the plan counted
+    under `moe_plan_packed_total` (one packed int32 key a visit)."""
+    from paddle_tpu.parallel import moe
+
+    n, held, k, t, h, f = 32, (8, 8), 4, 2048, 256, 128
+    p = moe.init_routed_moe_params(0, n, h, f, held=held)
+    x = _rand((t, h), 50)
+
+    def layer(p, x):
+        out, stats, experts = moe.routed_moe_local(p, x, k, held=held,
+                                                   chunk=1024)
+        return jnp.sum(jnp.sin(out)), (out, stats, experts)
+
+    def oracle(p, x, experts):
+        _, weights = moe.route_top_k(x, p["wr"], k)
+        out = jnp.zeros_like(x)
+        for e in range(held[1]):
+            y = (jax.nn.silu(x @ p["wg"][e]) * (x @ p["wu"][e])) @ p["wd"][e]
+            w = jnp.sum(jnp.where(experts == held[0] + e, weights, 0), 1)
+            out = out + y * w[:, None]
+        return jnp.sum(jnp.sin(out)), out
+
+    before = profiler.get_int_stats()
+    remat = jax.checkpoint(
+        layer, policy=jax.checkpoint_policies.save_only_these_names(
+            "moe_plan"))
+    with jax.default_matmul_precision("highest"):
+        grads, (out, stats, experts) = jax.jit(jax.grad(
+            remat, (0, 1), has_aux=True))(p, x)
+        want, ref = jax.jit(jax.grad(oracle, (0, 1), has_aux=True))(
+            p, x, experts)
+    after = profiler.get_int_stats()
+    assert after["moe_plan_packed_total"] \
+        == before.get("moe_plan_packed_total", 0) + 1
+    assert after.get("moe_plan_two_operand_total", 0) \
+        == before.get("moe_plan_two_operand_total", 0)
+    stats = np.asarray(stats)
+    assert stats[:-2].sum() == stats[-1] > 0 and stats[-2] == t * k
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-3, rtol=2e-3)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        scale = float(jnp.abs(b).max())
+        np.testing.assert_allclose(np.asarray(a) / scale,
+                                   np.asarray(b) / scale, atol=5e-3)
+
+
 def test_no_kernel_gave_way():
     """Runs last: nothing above (and no other tpu-marked test before
     it) may have pushed a kernel onto its XLA path."""
