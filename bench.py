@@ -1207,127 +1207,6 @@ def bench_fleet(jax, jnp, cold_start):
         reg.close(drain=False)
 
 
-def bench_autotune(jax, jnp):
-    """`--mode autotune` (docs/autotune.md): default-vs-tuned step-time
-    ladder on a toy conv+bn inference trunk.
-
-    Phase 1 measures the untuned steady state (PADDLE_AUTOTUNE=off,
-    byte-identical bypass); phase 2 points the tuner at a fresh record
-    dir, forces the measured candidate search on the first compile,
-    and measures the committed winner's steady state.  The headline is
-    default_step_ms / tuned_step_ms — >= 1.0 by the tuner's own
-    winner-never-slower contract, which tools/bench_diff.py enforces
-    from the emitted detail."""
-    import shutil
-    import statistics
-    import tempfile
-
-    import paddle_tpu
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu import profiler
-    from paddle_tpu.fluid import framework
-    from paddle_tpu.fluid.executor import Scope, scope_guard
-
-    c, hw, batch, steps = 32, 32, 32, 10
-
-    def build():
-        main_p, startup = framework.Program(), framework.Program()
-        with framework.program_guard(main_p, startup):
-            x = fluid.data("x", [batch, 3, hw, hw], "float32")
-            y = fluid.layers.conv2d(x, c, 3, padding=1, bias_attr=True)
-            y = fluid.layers.batch_norm(y, act="relu", is_test=True)
-            y = fluid.layers.conv2d(y, c, 3, padding=1,
-                                    bias_attr=False)
-            y = fluid.layers.batch_norm(y, act="relu", is_test=True)
-        return main_p, startup, y.name
-
-    rng = np.random.RandomState(0)
-    feed = {"x": rng.rand(batch, 3, hw, hw).astype(np.float32)}
-
-    def steady_ms(exe, prog, yname, scope):
-        times = []
-        for k in range(steps + 1):
-            t0 = time.perf_counter()
-            outs = exe.run(prog, feed=feed, fetch_list=[yname],
-                           scope=scope, return_numpy=False)
-            for o in outs:  # materialize = the sanctioned sync point
-                np.asarray(o)
-            dt = (time.perf_counter() - t0) * 1e3
-            if k > 0:  # first call compiles / warms
-                times.append(dt)
-        return statistics.median(times)
-
-    tdir = tempfile.mkdtemp(prefix="paddle_autotune_bench_")
-    old_flags = {
-        "FLAGS_autotune": paddle_tpu.fluid.flags.flag("autotune"),
-        "FLAGS_autotune_dir": paddle_tpu.fluid.flags.flag(
-            "autotune_dir"),
-        "FLAGS_autotune_trial_steps": paddle_tpu.fluid.flags.flag(
-            "autotune_trial_steps"),
-    }
-    try:
-        # phase 1: untuned baseline under the byte-identical bypass
-        paddle_tpu.set_flags({"FLAGS_autotune": "off"})
-        prog, startup, yname = build()
-        scope = Scope()
-        with scope_guard(scope):
-            exe = fluid.Executor()
-            exe.run(startup)
-            default_ms = steady_ms(exe, prog, yname, scope)
-
-        # phase 2: forced search into a fresh record dir, then the
-        # tuned steady state (same process: the winner is primed)
-        paddle_tpu.set_flags({"FLAGS_autotune": "force",
-                              "FLAGS_autotune_dir": tdir,
-                              "FLAGS_autotune_trial_steps":
-                              max(5, steps // 2)})
-        from paddle_tpu import tune
-        tune.reset_memo()
-        s0 = profiler.get_int_stats()
-        prog2, startup2, yname2 = build()
-        scope2 = Scope()
-        with scope_guard(scope2):
-            exe2 = fluid.Executor()
-            exe2.run(startup2)
-            tuned_ms = steady_ms(exe2, prog2, yname2, scope2)
-        s1 = profiler.get_int_stats()
-
-        def moved(name):
-            return s1.get(name, 0) - s0.get(name, 0)
-
-        winner = "default"
-        recs = [n for n in os.listdir(tdir) if n.endswith(".json")]
-        if recs:
-            with open(os.path.join(tdir, recs[0])) as f:
-                rec = json.load(f)
-            from paddle_tpu.tune import TunedConfig
-            winner = TunedConfig.from_dict(rec["config"]).label()
-        speedup = default_ms / tuned_ms if tuned_ms > 0 else 0.0
-        return {
-            "metric": "autotune_speedup",
-            "value": round(speedup, 4),
-            "unit": "x",
-            "vs_baseline": round(speedup, 4),
-            "detail": {
-                "device_class": "tpu",
-                "autotune": {
-                    "default_step_ms": round(default_ms, 3),
-                    "tuned_step_ms": round(tuned_ms, 3),
-                    "winner": winner,
-                    "searches": moved("autotune_searches"),
-                    "trials": moved("autotune_trials"),
-                    "commits": moved("autotune_commits"),
-                    "compiles": moved("executor_compile_count"),
-                    "records_committed": len(recs),
-                    "trial_steps": int(paddle_tpu.fluid.flags.flag(
-                        "autotune_trial_steps", 3)),
-                },
-            }}
-    finally:
-        paddle_tpu.set_flags(old_flags)
-        shutil.rmtree(tdir, ignore_errors=True)
-
-
 def main():
     import argparse
 
@@ -1335,18 +1214,14 @@ def main():
     ap.add_argument("--model", choices=["bert", "resnet50", "both"],
                     default="both")
     ap.add_argument("--mode",
-                    choices=["train", "serving", "collective", "fleet",
-                             "autotune"],
+                    choices=["train", "serving", "collective", "fleet"],
                     default="train",
                     help="train: MFU bench (default); serving: "
                     "continuous-batching latency/occupancy bench; "
                     "collective: ring all-reduce microbench, full-width "
                     "vs int8 blockwise (docs/spmd.md); fleet: "
                     "multi-tenant co-tenancy latency + persistent "
-                    "AOT-cache cold-start ladder (docs/serving.md); "
-                    "autotune: default-vs-tuned step-time ladder for "
-                    "the measured compile-config search "
-                    "(docs/autotune.md)")
+                    "AOT-cache cold-start ladder (docs/serving.md)")
     args = ap.parse_args()
 
     # a chip belongs to one process: the fleet ladder's worker
@@ -1377,9 +1252,6 @@ def main():
 
     if args.mode == "fleet":
         return emit(bench_fleet(jax, jnp, cold_start))
-
-    if args.mode == "autotune":
-        return emit(bench_autotune(jax, jnp))
 
     if args.mode == "collective":
         det = bench_collective(jax, jnp)

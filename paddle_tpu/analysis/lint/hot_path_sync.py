@@ -112,16 +112,6 @@ WATCHLIST: List[Tuple[str, str]] = [
     ("paddle_tpu/fluid/aot_cache.py", "try_load"),
     ("paddle_tpu/fluid/aot_cache.py", "try_store"),
     ("paddle_tpu/fluid/aot_cache.py", "compile_entry_with_cache"),
-    # autotuner (ISSUE 19): trials dispatch through the REAL executor
-    # hot path — the only sanctioned sync is the per-trial
-    # block_until_ready in tuner._sync ('# sync-ok: trial measurement
-    # boundary'); the record store/load path is compile-miss disk I/O
-    # with the same never-touch-device contract as the AOT cache
-    ("paddle_tpu/tune/tuner.py", "_sync"),
-    ("paddle_tpu/tune/tuner.py", "_measure_program"),
-    ("paddle_tpu/tune/tuner.py", "search_program"),
-    ("paddle_tpu/tune/record.py", "try_load"),
-    ("paddle_tpu/tune/record.py", "try_store"),
     ("paddle_tpu/inference/c_bridge.py", "run_f32"),
     # obs span/cost layer (ISSUE 6): these run INSIDE every watched loop
     # above — a sync creeping into the tracer or the live-MFU gauge

@@ -63,9 +63,6 @@ WATCHED = [
     # paged-attention dispatch seam (ISSUE 20) traces inside the
     # decode jit — a leaked span there would wrap device-side kernel
     # work in a host timer on every decoded token
-    "paddle_tpu/tune",  # autotuner (ISSUE 19): search/trial spans wrap
-    # measured executor dispatches — a leaked span would fold a whole
-    # search into whatever profile runs next
     "paddle_tpu/transforms/__init__.py",
     "paddle_tpu/analysis/verifier.py",
     "bench.py",

@@ -1184,19 +1184,12 @@ class Executor:
 
         feed_sig = tuple(sorted(
             (n, tuple(a.shape), str(a.dtype)) for n, a in feed_arrays.items()))
-        # self-tuning compile pipeline (docs/autotune.md): the
-        # effective tuned config — a trial's thread-local override or
-        # the persisted per-program winner — decides pass toggles and
-        # kernel choices, so its content hash is part of the program
-        # identity too.  () under PADDLE_AUTOTUNE=off and for untuned
-        # programs: the key is then byte-identical to pre-autotune.
-        from .. import tune
         # the NaN scan is compiled INTO the step and the transform
         # pipeline decides WHAT gets lowered, so both flags are part of
         # the program identity — flipping them must be a cache miss
         return (id(program), program.version, feed_sig, tuple(fetch_names),
                 id(scope), bool(flag("check_nan_inf")),
-                enabled_signature(), tune.cache_token(program))
+                enabled_signature())
 
     def _prepare(self, program: Program, feed_arrays, fetch_names,
                  scope: Scope) -> _CompiledEntry:
@@ -1204,21 +1197,8 @@ class Executor:
         entry = self._cache.get(key)
         if entry is not None:
             return entry
-        from .. import obs, tune
+        from .. import obs
         from ..profiler import stat_add
-        # FLAGS_autotune='force' + no persisted winner: run the
-        # measured candidate search NOW, on the first compile-cache
-        # miss (docs/autotune.md).  The search dispatches trials
-        # through this same run() path under thread-local candidate
-        # overrides (recursion-guarded); a committed winner changes
-        # the tuned-config token, so the key is rebuilt — and the
-        # winner's trial entry is usually already cached under it.
-        if tune.maybe_search(self, program, feed_arrays, fetch_names,
-                             scope):
-            key = self._cache_key(program, feed_arrays, fetch_names, scope)
-            entry = self._cache.get(key)
-            if entry is not None:
-                return entry
         stat_add("executor_compile_count")
         with obs.span("executor.prepare"):
             return self._prepare_miss(program, feed_arrays, fetch_names,
@@ -1300,23 +1280,6 @@ class Executor:
                 extra.append(stats)
             return (fetches, new_state, *extra)
 
-        # tuned kernel choices (docs/autotune.md) are read at TRACE
-        # time by the ops/pallas dispatch seams through the
-        # thread-local tune scope — re-enter it around the traced body
-        # so a persisted kernel winner replays on a retrace in any
-        # later process/thread, not just inside the trial that found
-        # it.  Configs without kernel choices skip the wrapper: the
-        # traced computation is then byte-identical to pre-autotune.
-        from .. import tune as _tune
-        _tuned_cfg = _tune._effective(program)
-        if _tuned_cfg is not None and _tuned_cfg.kernels:
-            _inner_step_fn, _kernel_cfg = step_fn, _tuned_cfg
-
-            def step_fn(mutable_state, const_state, feeds, seed):
-                with _tune.config_override(_kernel_cfg):
-                    return _inner_step_fn(mutable_state, const_state,
-                                          feeds, seed)
-
         entry = _CompiledEntry()
         entry.program = program
         entry.scope = scope
@@ -1365,13 +1328,6 @@ class Executor:
             if tok is not None:
                 entry.aot_sig = [tok, entry.feed_names,
                                  entry.fetch_names]
-                # the tuned-config token joins the AOT stable half too
-                # (docs/autotune.md): flipping any tuned dimension can
-                # never load a stale executable — trial entries and
-                # steady-state entries for the SAME config share it
-                tune_tok = _tune.aot_token_component(program)
-                if tune_tok:
-                    entry.aot_sig.append(tune_tok)
         self._cache.put(key, entry)
         return entry
 

@@ -191,34 +191,6 @@ _define("aot_cache_dir", persistent_cache_dirs()[1],
         "the default follows compile_cache.persistent_cache_dirs(); "
         "empty disables the cache like FLAGS_aot_cache='off'",
         env_var="PADDLE_AOT_CACHE_DIR")
-# -- self-tuning compile pipeline (paddle_tpu.tune, docs/autotune.md):
-# per-program-signature search over compile configurations (transform
-# pass toggles, Pallas-vs-XLA kernel choice, serving bucket ladders,
-# mesh shapes), winners persisted alongside the AOT cache
-_define("autotune", "on",
-        "self-tuning compile pipeline (docs/autotune.md): 'on' resolves "
-        "persisted tuned winners on compile-cache misses (zero search "
-        "cost, record hit or nothing); 'force' additionally runs the "
-        "measured candidate search on a miss with no persisted record; "
-        "'off' is a byte-identical bypass — no token joins any "
-        "signature, lowered HLO matches the pre-autotune behavior",
-        env_var="PADDLE_AUTOTUNE")
-_define("autotune_dir", "",
-        "tuning-record root (one JSON record per program signature, "
-        "tmp + os.replace commit); empty derives "
-        "<FLAGS_aot_cache_dir>/tuning so winners ride next to the AOT "
-        "executables they key",
-        env_var="PADDLE_AUTOTUNE_DIR")
-_define("autotune_trial_steps", 3,
-        "measured steps dispatched per candidate config during a "
-        "'force' search (median scored; first step is discarded as the "
-        "compile step when >1)",
-        env_var="PADDLE_AUTOTUNE_TRIAL_STEPS")
-_define("autotune_max_candidates", 6,
-        "cap on candidate configs per search (default config is always "
-        "candidate 0 and never dropped, so the committed winner can "
-        "never be slower than the default)",
-        env_var="PADDLE_AUTOTUNE_MAX_CANDIDATES")
 
 
 def get_flags(flags):

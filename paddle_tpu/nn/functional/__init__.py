@@ -763,19 +763,15 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
 def fused_feedforward(x, w1, b1, w2, b2, activation="gelu",
                       act_dropout=0.0, training=True, name=None):
-    """Fused transformer FFN: dropout(act(x@w1+b1), p) @ w2 + b2.
-
-    Routes through paddle_tpu/ops/pallas/ffn.py (the reference's
-    fused_feedforward_op.cu counterpart): by default an XLA path —
-    measured FASTER than the Pallas kernel on v5e (2026-07-31 A/B,
-    artifacts/FFN_AB_r19.md; not measured since) — with
-    the VMEM-resident kernel opt-in
-    via PADDLE_TPU_FUSED_FFN=1 for memory-limited configs.  Both use
-    the SAME stateless hash dropout, so trajectories agree across
-    paths, backends and shardings."""
+    """The transformer's dense FFN as one call:
+    dropout(act(x@w1+b1), p) @ w2 + b2 (the reference's
+    fused_feedforward_op.cu counterpart; here plain XLA ops,
+    `dense_ffn.dense_ffn`).  The dropout mask is a stateless hash of
+    position and seed, so trajectories agree across backends and
+    shardings."""
     from ...fluid.dygraph.tracer import _next_func_key, _tracer
-    from ...ops.pallas import ffn as _ffn
     from ...ops.pallas.attention import _seed_from_key
+    from .dense_ffn import dense_ffn
 
     p = act_dropout if training else 0.0
     seed = None
@@ -790,8 +786,8 @@ def fused_feedforward(x, w1, b1, w2, b2, activation="gelu",
         seed = _seed_from_key(dkey)
 
     def f(x, w1, b1, w2, b2):
-        return _ffn.fused_ffn(x, w1, b1, w2, b2, activation=activation,
-                              dropout_p=p, dropout_seed=seed)
+        return dense_ffn(x, w1, b1, w2, b2, activation=activation,
+                         dropout_p=p, dropout_seed=seed)
 
     return trace_fn(f, {"x": x, "w1": w1, "b1": b1, "w2": w2,
                         "b2": b2})

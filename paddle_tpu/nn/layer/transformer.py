@@ -169,8 +169,7 @@ class GatedFFN(Layer):
 
 def _dense_ffn_block(layer, x):
     """linear2(dropout(act(linear1(x)))) for encoder AND decoder
-    layers — routed through F.fused_feedforward (ops/pallas/ffn.py:
-    XLA path by default, opt-in Pallas kernel) when the activation is
+    layers — one F.fused_feedforward call when the activation is
     gelu/relu and biases exist; otherwise the layer-by-layer path."""
     # the fused path enters no sublayer: name the block itself, so
     # its device time reads as `<layer>/ffn` on either path

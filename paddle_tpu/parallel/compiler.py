@@ -215,17 +215,6 @@ class CompiledProgram:
             if tok is not None:
                 entry.aot_sig = ["compiled_program", tok,
                                  entry.feed_names, entry.fetch_names]
-                # tuned-config token (docs/autotune.md), same join as
-                # Executor._prepare_miss: a tuned dimension flip is an
-                # AOT hard miss, never a stale executable
-                try:
-                    from .. import tune as _tune
-
-                    tune_tok = _tune.aot_token_component(program)
-                except Exception:  # noqa: BLE001 - tune unavailable
-                    tune_tok = None
-                if tune_tok:
-                    entry.aot_sig.append(tune_tok)
         return entry
 
     def _quant_grad_split(self, block, mesh, feed_arrays, mutable_out):

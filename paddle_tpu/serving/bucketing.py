@@ -123,21 +123,6 @@ class BucketedRunner:
         # callables must supply their own — a reused token would load
         # another model's executable)
         self.aot_token = aot_token
-        # tuned bucket ladder (docs/autotune.md): a persisted winner
-        # committed by tune.tuner.tune_buckets for this model token
-        # replaces the caller's ladder — construction-time only, one
-        # record probe, and the bucket is part of every compile key
-        # (in-memory AND aot_cache.runner_stable_key) so a ladder
-        # change can never reuse a stale executable
-        if aot_token and bucketed:
-            try:
-                from .. import tune as _tune
-
-                tuned = _tune.buckets_for(aot_token)
-            except Exception:  # noqa: BLE001 - tune unavailable
-                tuned = None
-            if tuned:
-                self.buckets = sorted(set(int(b) for b in tuned))
         # bucket key -> obs ProgramCost gauge (flops from the AOT
         # entry's cost_analysis; run() feeds it dispatch intervals)
         self._costs: dict = {}

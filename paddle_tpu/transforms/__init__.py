@@ -326,25 +326,7 @@ def maybe_transform_program(program, feed_names=None, fetch_names=None,
     `transform_ms` profiler timer plus per-pass
     `transform_<pass>_rewrites` counters so tests can assert the hot
     path pays zero transform time."""
-    wanted = enabled_passes()
-    # self-tuning compile pipeline (docs/autotune.md): the effective
-    # tuned config for THIS program — a trial's thread-local override
-    # or the persisted winner — flips passes over the flag defaults.
-    # The config's token is part of the compile-cache key
-    # (Executor._cache_key), so a different override set can never
-    # reuse this miss's entry; PADDLE_AUTOTUNE=off contributes nothing
-    # and this path is byte-identical to the pre-autotune pipeline.
-    try:
-        from .. import tune as _tune
-
-        overrides = _tune.pass_overrides(program)
-    except Exception:  # noqa: BLE001 - tune unavailable (minimal env)
-        overrides = None
-    if overrides:
-        wanted = dict(wanted)
-        wanted.update({n: bool(v) for n, v in overrides.items()
-                       if n in wanted})
-    enabled = [n for n, on in wanted.items() if on]
+    enabled = [n for n, on in enabled_passes().items() if on]
     if not enabled:
         return program
     from ..obs import span as obs_span

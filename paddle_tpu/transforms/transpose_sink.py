@@ -21,10 +21,9 @@ Two rewrites, looped to fixpoint over the global block:
    reader of `u` re-points at `a` and both ops vanish.
 
 Off by default: whether eliminating the permutes beats XLA's own
-fusion of them is a MEASURED question per program — this pass is a
-tunable candidate dimension of the autotune search (paddle_tpu/tune,
-docs/autotune.md), which commits it only when the measured step time
-says so.  Like fold_bn, programs carrying grad ops are never touched
+fusion of them is a MEASURED question per program, and no chip run
+has asked it yet: opt in with FLAGS_graph_transforms and compare the
+step time.  Like fold_bn, programs carrying grad ops are never touched
 (the backward replays jax.vjp of the forward, but declared `@GRAD`
 shape metadata would drift).
 """
@@ -184,8 +183,7 @@ def _cancel_one(ctx: TransformContext, external: Set[str]) -> bool:
 @register_transform(
     "transpose_sink", default=False,
     help_str="sink transpose2 ops through elementwise chains and "
-             "cancel inverse pairs at NCHW-external boundaries; a "
-             "tunable autotune candidate (docs/autotune.md), opt in "
+             "cancel inverse pairs at NCHW-external boundaries; opt in "
              "via FLAGS_graph_transforms='transpose_sink=on'")
 def run(ctx: TransformContext) -> int:
     prog = ctx.program

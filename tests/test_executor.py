@@ -190,3 +190,78 @@ def test_feed_rank_and_shape_mismatch_raise_crisply(fresh_programs):
     (out,) = exe.run(main, feed={"x": np.ones((3, 4), "float32")},
                      fetch_list=[y])
     assert np.asarray(out).shape == (3, 4)
+
+
+@pytest.mark.parametrize("what", [
+    "feed_shape", "feed_dtype", "fetch_list", "program_version", "scope",
+    "check_nan_inf", "graph_transforms", "numerics_mode"])
+def test_compiled_program_identity(fresh_programs, monkeypatch, what):
+    """What a compile-cache hit is (Executor._cache_key): the program
+    and its version, the feeds' shapes and dtypes, the fetch list, the
+    scope, FLAGS_check_nan_inf, FLAGS_graph_transforms and the
+    obs.numerics mode.  Changing one of them compiles exactly once;
+    changing it back finds the first entry again."""
+    import paddle_tpu
+    from paddle_tpu import profiler
+    from paddle_tpu.fluid.executor import Scope
+
+    main, startup, scope = fresh_programs
+    x = fluid.data("x", [-1, 4], "float32")
+    y = fluid.layers.fc(x, 3)
+    z = fluid.layers.scale(y, 2.0)
+    exe = fluid.Executor()
+    other_scope = Scope()
+    exe.run(startup, scope=scope)
+    exe.run(startup, scope=other_scope)
+
+    run = {"feed": {"x": np.ones((2, 4), "float32")}, "fetch_list": [y],
+           "scope": scope}
+    if what == "feed_dtype":
+        # a declared variable's feed is cast to the declared dtype; one
+        # the block does not declare keeps its own
+        run["feed"]["aux"] = np.ones((2,), "float32")
+    first = dict(run, feed=dict(run["feed"]))
+
+    def change():
+        if what == "feed_shape":
+            run["feed"]["x"] = np.ones((3, 4), "float32")
+        elif what == "feed_dtype":
+            run["feed"]["aux"] = np.ones((2,), "int32")
+        elif what == "fetch_list":
+            run["fetch_list"] = [y, z]
+        elif what == "program_version":
+            main._bump_version()  # what every mutation of it calls
+        elif what == "scope":
+            run["scope"] = other_scope
+        elif what == "check_nan_inf":
+            paddle_tpu.set_flags({"FLAGS_check_nan_inf": True})
+        elif what == "graph_transforms":
+            paddle_tpu.set_flags(
+                {"FLAGS_graph_transforms": "dead_op_elim=off"})
+        elif what == "numerics_mode":
+            monkeypatch.setenv("PADDLE_OBS_NUMERICS", "on")
+
+    def change_back():
+        run.update(first, feed=dict(first["feed"]))
+        if what == "program_version":
+            main._version -= 1
+        paddle_tpu.set_flags({"FLAGS_check_nan_inf": False,
+                              "FLAGS_graph_transforms": "on"})
+        monkeypatch.delenv("PADDLE_OBS_NUMERICS", raising=False)
+
+    def compiles_of_a_run():
+        before = profiler.get_int_stats().get("executor_compile_count", 0)
+        exe.run(main, **run)
+        return profiler.get_int_stats()["executor_compile_count"] - before
+
+    monkeypatch.delenv("PADDLE_OBS_NUMERICS", raising=False)
+    try:
+        assert compiles_of_a_run() == 1
+        assert compiles_of_a_run() == 0
+        change()
+        assert compiles_of_a_run() == 1
+        assert compiles_of_a_run() == 0
+        change_back()
+        assert compiles_of_a_run() == 0
+    finally:
+        change_back()

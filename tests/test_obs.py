@@ -473,8 +473,7 @@ class TestSpanLeakRule:
             "    return obs.span('deleg')\n")  # delegation: allowed
         # the other watched paths must exist for the rule to walk
         for rel in ("paddle_tpu/profiler", "paddle_tpu/serving",
-                    "paddle_tpu/transforms", "paddle_tpu/ckpt",
-                    "paddle_tpu/tune"):
+                    "paddle_tpu/transforms", "paddle_tpu/ckpt"):
             (tmp_path / rel).mkdir(parents=True, exist_ok=True)
         for rel in ("paddle_tpu/fluid/executor.py",
                     "paddle_tpu/parallel/compiler.py",
@@ -506,8 +505,7 @@ class TestSpanLeakRule:
             "    s = obs.span('x')  # span-ok: closed by caller\n"
             "    return [s]\n")
         for rel in ("paddle_tpu/profiler", "paddle_tpu/serving",
-                    "paddle_tpu/transforms", "paddle_tpu/ckpt",
-                    "paddle_tpu/tune"):
+                    "paddle_tpu/transforms", "paddle_tpu/ckpt"):
             (tmp_path / rel).mkdir(parents=True, exist_ok=True)
         for rel in ("paddle_tpu/fluid/executor.py",
                     "paddle_tpu/parallel/compiler.py",

@@ -269,10 +269,11 @@ def test_lowered_hlo_identical_when_off_or_unset():
         return re.sub(r"program#\d+", "program#N",
                       entry.fn_compiled.as_text())
 
-    t_unset = _compiled_text(None)
-    t_off = _compiled_text("off")
+    # one call site: the compiled text carries the source location of
+    # every frame, this test's own among them
+    t_unset, t_off, t_int8 = [_compiled_text(v)
+                              for v in (None, "off", "int8")]
     assert t_unset == t_off
-    t_int8 = _compiled_text("int8")
     assert t_int8 != t_off  # sanity: the flag really changes the HLO
     assert "s8" in t_int8  # int8 payloads on the wire
 

@@ -484,26 +484,6 @@ def test_fleet_and_aot_cache_on_hot_path_watchlist():
     assert "paddle_tpu/serving" in lint.span_leak.WATCHED
 
 
-def test_autotune_on_hot_path_watchlist():
-    """ISSUE 19: the autotuner's trial/commit entry points are lint-
-    watched — trials dispatch through the real executor hot path where
-    the ONLY sanctioned sync is the per-trial block_until_ready in
-    tuner._sync ('# sync-ok: trial measurement boundary'), and the
-    record store/load path is compile-miss disk I/O with the same
-    never-touch-device contract as the AOT cache; paddle_tpu/tune is
-    also in the span-leak watched set (a leaked autotune.search span
-    would fold a whole search into the next profile)."""
-    watched = set(lint.hot_path_sync.WATCHLIST)
-    for rel, qual in (
-            ("paddle_tpu/tune/tuner.py", "_sync"),
-            ("paddle_tpu/tune/tuner.py", "_measure_program"),
-            ("paddle_tpu/tune/tuner.py", "search_program"),
-            ("paddle_tpu/tune/record.py", "try_load"),
-            ("paddle_tpu/tune/record.py", "try_store")):
-        assert (rel, qual) in watched
-    assert "paddle_tpu/tune" in lint.span_leak.WATCHED
-
-
 def test_shard_check_on_hot_path_watchlist():
     """ISSUE 18: the static sharding analyzer's entry points are
     lint-watched — shard_consistency_pass runs on the compile-cache-

@@ -41,17 +41,6 @@ Metrics compared (each only when present in BOTH files):
                          collection must stay a fused-reduction tax,
                          not a sync; under cpu-fallback the usual
                          warn-only regime applies)
-  autotune_tuned_step_ms  detail.autotune.tuned_step_ms (rise > 10%
-                          rel — the tuned steady-state step slowed vs
-                          the committed baseline run)
-
-One extra row is computed from the CURRENT doc alone:
-autotune_tuned_vs_default compares detail.autotune.tuned_step_ms
-against the SAME run's detail.autotune.default_step_ms — the tuner's
-winner-never-slower contract means the tuned config must not regress
-the untuned baseline it displaced (>5% rel and >0.25 ms); warn-only
-under cpu-fallback like everything else.
-
 Exit status: 1 when any regression fires AND the current run is
 on-chip; under `device_class: cpu-fallback` (or a stale re-emitted
 on-chip record — detail.stale_s / detail.cpu_fallback_now) the gate is
@@ -115,16 +104,7 @@ DEFAULT_THRESHOLDS = {
     # drifted away from what XLA actually inserts
     "predicted_collective_bytes": ("down", 0.10, 1024.0),
     "sharding_pred_err_pct": ("down", 0.5, 10.0),
-    # self-tuning compile pipeline (ISSUE 19): the tuned steady-state
-    # step time against the committed baseline run
-    "autotune_tuned_step_ms": ("down", 0.10, 0.25),
 }
-
-# within-run invariant (ISSUE 19), checked on the CURRENT doc alone:
-# the tuner's winner-never-slower contract means tuned_step_ms must
-# not regress the SAME RUN's untuned baseline beyond noise.  (rel,
-# floor) — warn-only under cpu-fallback like every other gate.
-_AUTOTUNE_VS_DEFAULT = (0.05, 0.25)
 
 # metrics whose value moves BY DESIGN when FLAGS_quant_collectives
 # flips: the baseline comparison is reset rather than gated
@@ -206,12 +186,6 @@ def extract_metrics(doc: dict) -> Dict[str, float]:
     dt = _get(detail, "decode", "decode_token_ms")
     if isinstance(dt, (int, float)) and dt > 0:
         out["decode_token_ms"] = float(dt)
-    at_t = _get(detail, "autotune", "tuned_step_ms")
-    if isinstance(at_t, (int, float)):
-        out["autotune_tuned_step_ms"] = float(at_t)
-    at_d = _get(detail, "autotune", "default_step_ms")
-    if isinstance(at_d, (int, float)):
-        out["autotune_default_step_ms"] = float(at_d)
     return out
 
 
@@ -268,27 +242,6 @@ def diff(baseline: dict, current: dict,
                      "delta": round(delta, 4),
                      "rel_pct": round(rel_delta * 100.0, 2),
                      "direction": direction, "regressed": regressed})
-    # autotune within-run invariant (ISSUE 19): compares the CURRENT
-    # run against ITSELF (tuned vs untuned arm of the same bench), so
-    # it fires even on the very first run with no committed baseline —
-    # a tuned config slower than the default it displaced means the
-    # tuner's winner-never-slower guard or record replay broke.
-    at_d = cur_m.get("autotune_default_step_ms")
-    at_t = cur_m.get("autotune_tuned_step_ms")
-    if at_d is not None and at_t is not None:
-        rel, floor = _AUTOTUNE_VS_DEFAULT
-        delta = at_t - at_d
-        rel_delta = abs(delta) / abs(at_d) if at_d else \
-            (1.0 if delta else 0.0)
-        regressed = bool(delta > 0 and abs(delta) > floor
-                         and rel_delta > rel)
-        rows.append({"metric": "autotune_tuned_vs_default",
-                     "baseline": at_d, "current": at_t,
-                     "delta": round(delta, 4),
-                     "rel_pct": round(rel_delta * 100.0, 2),
-                     "direction": "down", "regressed": regressed,
-                     "info": "within-run: current tuned vs current "
-                             "default"})
     return rows
 
 
@@ -351,8 +304,6 @@ def _synthetic(mfu: float, step_ms: float, transposes: int = 0,
                cold_start_ms: float = 50.0,
                pred_bytes: int = 411720,
                pred_err: float = 15.0,
-               tuned_ms: float = 9.0,
-               default_ms: float = 10.0,
                decode_ms: float = 1.0) -> dict:
     return {
         "metric": "bert_base_pretrain_mfu",
@@ -383,10 +334,6 @@ def _synthetic(mfu: float, step_ms: float, transposes: int = 0,
                              {"c_allreduce_sum": coll_bytes}}},
             "fleet": {"cold_start":
                       {"cold_start_compile_ms": cold_start_ms}},
-            "autotune": {"default_step_ms": default_ms,
-                         "tuned_step_ms": tuned_ms,
-                         "winner": "fold_bn=on", "searches": 1,
-                         "trials": 12, "commits": 1},
             "decode": {"decode_token_ms": decode_ms,
                        "decode_token_p99_ms": decode_ms * 1.5,
                        "prefill_chunk_ms": 0.3,
@@ -577,39 +524,7 @@ def selftest(verbose: bool = True) -> int:
     checks.append(("quant flip resets predicted bytes baseline",
                    not any(r["metric"] == "predicted_collective_bytes"
                            and r["regressed"] for r in rows)))
-    # 17. autotune gates (ISSUE 19): the WITHIN-RUN invariant — a tuned
-    # config slower than the same run's untuned default fires even
-    # against an identical baseline (the winner-never-slower guard or
-    # the record replay broke); tuned equal-or-faster passes; a
-    # baseline-vs-current tuned step-time blowup also fires on its own
-    cur_at_bad = _synthetic(mfu=42.0, step_ms=100.0,
-                            tuned_ms=12.0, default_ms=10.0)
-    rows = diff(cur_at_bad, cur_at_bad)
-    checks.append(("tuned slower than own default fires",
-                   any(r["metric"] == "autotune_tuned_vs_default"
-                       and r["regressed"] for r in rows)))
-    cur_at_eq = _synthetic(mfu=42.0, step_ms=100.0,
-                           tuned_ms=10.0, default_ms=10.0)
-    rows = diff(base, cur_at_eq)
-    checks.append(("tuned equal to default passes",
-                   not any(r["metric"] == "autotune_tuned_vs_default"
-                           and r["regressed"] for r in rows)))
-    cur_at_slow = _synthetic(mfu=42.0, step_ms=100.0,
-                             tuned_ms=13.0, default_ms=14.0)
-    rows = diff(base, cur_at_slow)
-    checks.append(("tuned step-time blowup vs baseline fires",
-                   any(r["metric"] == "autotune_tuned_step_ms"
-                       and r["regressed"] for r in rows)))
-    cur_at_cpu = _synthetic(mfu=42.0, step_ms=100.0,
-                            tuned_ms=12.0, default_ms=10.0,
-                            device_class="cpu-fallback")
-    rows = diff(base, cur_at_cpu)
-    checks.append(("cpu-fallback tuned regression is warn-only",
-                   any(r["metric"] == "autotune_tuned_vs_default"
-                       and r["regressed"] for r in rows)
-                   and is_fallback(cur_at_cpu)))
-
-    # 18. fast-decode gate (ISSUE 20): a >10% decode-step latency rise
+    # 17. fast-decode gate (ISSUE 20): a >10% decode-step latency rise
     # fires on-chip; a sub-floor wiggle passes; under cpu-fallback the
     # same regression is warn-only (decode timings on CPU are noise)
     cur_dec = _synthetic(mfu=42.0, step_ms=100.0, decode_ms=1.25)

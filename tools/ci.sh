@@ -83,55 +83,6 @@ EOF
 done
 rm -rf "$FLEET_DIR"
 
-echo "== autotune smoke: force-search, persist winner, warm replay (docs/autotune.md) =="
-# a tiny conv+bn program force-searched in one process (>=2 candidates
-# measured, winner committed), then a FRESH process in 'on' mode must
-# resolve the persisted record with ZERO trial dispatches
-AT_DIR=$(mktemp -d /tmp/ci_autotune.XXXXXX)
-for AT_RUN in cold warm; do
-  AT_MODE=force; [ "$AT_RUN" = warm ] && AT_MODE=on
-  PADDLE_AUTOTUNE="$AT_MODE" PADDLE_AUTOTUNE_DIR="$AT_DIR" \
-  PADDLE_AUTOTUNE_TRIAL_STEPS=2 PADDLE_AOT_CACHE=off \
-  AT_RUN="$AT_RUN" python - <<'EOF'
-import os
-import numpy as np
-from paddle_tpu import fluid
-from paddle_tpu.profiler import get_int_stats
-
-main, startup = fluid.Program(), fluid.Program()
-with fluid.program_guard(main, startup):
-    x = fluid.data("x", [2, 3, 8, 8], "float32")
-    y = fluid.layers.conv2d(x, 8, 3, padding=1, bias_attr=True)
-    out = fluid.layers.batch_norm(y, act="relu", is_test=True)
-exe = fluid.Executor()
-exe.run(startup)
-feed = {"x": np.linspace(-1, 1, 2 * 3 * 8 * 8, dtype=np.float32)
-        .reshape(2, 3, 8, 8)}
-for _ in range(3):
-    res = exe.run(main, feed=feed, fetch_list=[out])
-assert np.all(np.isfinite(res[0]))
-s = get_int_stats()
-run = os.environ["AT_RUN"]
-print(f"autotune smoke [{run}]:"
-      f" searches={s.get('autotune_searches', 0)}"
-      f" trials={s.get('autotune_trials', 0)}"
-      f" commits={s.get('autotune_commits', 0)}"
-      f" record_hits={s.get('autotune_record_hits', 0)}")
-if run == "cold":
-    assert s.get("autotune_searches", 0) == 1, "force mode did not search"
-    assert s.get("autotune_trials", 0) >= 2, "fewer than 2 candidates measured"
-    assert s.get("autotune_commits", 0) == 1, "winner was not committed"
-else:
-    assert s.get("autotune_trials", 0) == 0, \
-        "warm process re-ran trials instead of resolving the record"
-    assert s.get("autotune_record_hits", 0) >= 1, \
-        "warm process did not read the persisted winner"
-EOF
-done
-N_REC=$(ls "$AT_DIR"/*.json 2>/dev/null | wc -l)
-[ "$N_REC" -ge 1 ] || { echo "autotune smoke: no record persisted"; exit 1; }
-rm -rf "$AT_DIR"
-
 echo "== fast-decode smoke: chunked prefill + decode flood, zero per-token d2h (docs/serving.md) =="
 # a long prompt admitted during a decode flood must prefill in chunks
 # (serving_prefill_chunks >= 2) while the flood keeps decoding, and
