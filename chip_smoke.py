@@ -540,7 +540,8 @@ def sdar_moe_step(cfg, batch=2, seq=1024, steps=3, *, platform="tpu"):
     recomputation: the expert layers' counts say that no held visit was
     dropped, and the trace-time counters which visit plan every expert
     layer got (`parallel/moe.py`: one packed sort key where the shapes
-    allow it)."""
+    allow it) and how many of the mask's tiles the flash kernels class
+    full, partial or dead (`BlockDiffusionMask.tiles`)."""
     import jax.numpy as jnp
 
     import paddle_tpu
@@ -554,11 +555,13 @@ def sdar_moe_step(cfg, batch=2, seq=1024, steps=3, *, platform="tpu"):
     b = sdar_moe.fake_batch(cfg, batch, seq, seed=SEED)
     lr = jnp.float32(1e-3)
     plans = ("moe_plan_packed_total", "moe_plan_two_operand_total")
+    tiles = ("flash_tiles_full_total", "flash_tiles_live_total",
+             "flash_tiles_total", "flash_block_mask_total")
     s0 = _stats()
     t0 = time.perf_counter()
     compiled = step.lower(state, b, lr).compile()
     ph.compile_s = time.perf_counter() - t0
-    for k in plans:
+    for k in plans + tiles:
         ph.info[k] = _stats().get(k, 0) - s0.get(k, 0)
     losses, ces = [], []
     for _ in range(steps):
@@ -595,6 +598,15 @@ def sdar_moe_step(cfg, batch=2, seq=1024, steps=3, *, platform="tpu"):
                  "expert layers")
         ph.check(_fallback_counts()["flash_fallback_total"] == 0,
                  "flash_fallback_total == 0")
+        # 2 x 1024 rows on (256, 512) tiles: 32 a head, 16 of them
+        # live, 4 of those full (clean or noisy rows x earlier clean)
+        calls = len(model.model.layers)
+        ph.check(tuple(ph.info[k] for k in tiles)
+                 == (4 * calls, 16 * calls, 32 * calls, calls),
+                 f"{tiles[0]} / live / all == 4 / 16 / 32 in each of "
+                 f"{calls} masked flash instances: the backward kernels "
+                 "run full tiles without the code mask, dead ones are "
+                 "skipped")
     return ph.done()
 
 

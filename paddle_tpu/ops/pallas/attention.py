@@ -82,9 +82,10 @@ class BlockDiffusionMask:
 
     The kernels take the family this belongs to — row i sees column j
     iff `c_le[j] <= r_le[i] or c_eq[j] == r_eq[i]` for four int32 code
-    vectors — as those vectors plus a table of live tiles, both built
-    here at trace time from the two integers; nothing of size
-    (2 * seq_len)^2 reaches the device.  Hashable: a static argument."""
+    vectors — as those vectors plus a table of tile classes (dead,
+    partial, full), both built here at trace time from the two
+    integers; nothing of size (2 * seq_len)^2 reaches the device.
+    Hashable: a static argument."""
     seq_len: int
     block_length: int
 
@@ -114,19 +115,27 @@ class BlockDiffusionMask:
 
     @functools.lru_cache(maxsize=None)
     def tiles(self, n_rows: int, n_cols: int, block_q: int, block_k: int):
-        """`(live, k_fetch, q_fetch)`: which (q tile, k tile) pairs hold
-        a live pair, and for the two grid orders the tile to have in
-        VMEM at each step — the step's own where it is live, else the
-        nearest live one before it (the first live one where none is),
-        so that a dead step moves nothing."""
+        """`(cls, k_fetch, q_fetch)`.  `cls[iq, ik]` is the class of
+        the (q tile, k tile) pair over the padded rows and columns: 0
+        dead (no pair live: the kernels skip it), 1 partial (live and
+        dead pairs: the tile body runs with the code mask), 2 full
+        (every pair live, so no padding either: the mask could change
+        nothing, and the backward kernels run the body without it —
+        `_by_class`).  The fetch tables give,
+        for the two grid orders, the tile to have in VMEM at each step
+        — the step's own where it is not dead, else the nearest such
+        one before it (the first where none is), so that a dead step
+        moves nothing."""
         r_le, r_eq, c_le, c_eq = self.codes(n_rows, n_cols)
         nq, nk = n_rows // block_q, n_cols // block_k
-        live = np.zeros((nq, nk), bool)
+        cls = np.zeros((nq, nk), np.int32)
         for iq in range(nq):
             rows = slice(iq * block_q, (iq + 1) * block_q)
             m = ((c_le[None, :] <= r_le[rows, None])
-                 | (c_eq[None, :] == r_eq[rows, None]))
-            live[iq] = m.reshape(block_q, nk, block_k).any(axis=(0, 2))
+                 | (c_eq[None, :] == r_eq[rows, None])).reshape(
+                     block_q, nk, block_k)
+            cls[iq] = m.any(axis=(0, 2)).astype(np.int32) \
+                + m.all(axis=(0, 2))
 
         def fetch(lv):
             out = np.zeros(lv.shape, np.int32)
@@ -138,7 +147,7 @@ class BlockDiffusionMask:
                     out[a, b] = cur
             return out
 
-        return live.astype(np.int32), fetch(live), fetch(live.T)
+        return cls, fetch(cls != 0), fetch(cls.T != 0)
 
 
 # -- XLA reference path -------------------------------------------------------
@@ -313,7 +322,9 @@ def _mask_scores(s, iq, ik, codes, *, block_h, block_q, block_k, causal,
     """The score tile `s` with what the step's masks hide set to
     DEFAULT_MASK_VALUE: causal (query i attends keys <= i +
     causal_offset, offset = sk - sq, matching the XLA path's
-    jnp.tril(..., k=sk - sq)) and the block mask's codes."""
+    jnp.tril(..., k=sk - sq)) and the block mask's codes.  `codes` is
+    None where there is no block mask, and on a tile the mask's table
+    classes full (`_by_class`): the select would return `s` itself."""
     reps = block_h if grouped else 1
     if causal and grouped:
         s = jnp.where(_causal_rows(iq, ik, block_q, block_k, reps,
@@ -331,17 +342,35 @@ def _mask_scores(s, iq, ik, codes, *, block_h, block_q, block_k, causal,
 
 
 def _split_refs(refs, masked, n_in):
-    """The kernels' operands: with a block mask the live-tile table
+    """The kernels' operands: with a block mask the tile-class table
     leads (scalar prefetch, beside the fetch table only the index maps
     read) and the four code blocks follow the `n_in` inputs."""
-    live = None
+    cls = None
     if masked:
-        live, _, *refs = refs
+        cls, _, *refs = refs
     ins, rest = refs[:n_in], refs[n_in:]
     codes = None
     if masked:
         codes, rest = rest[:4], rest[4:]
-    return live, ins, codes, rest
+    return cls, ins, codes, rest
+
+
+def _by_class(tile, cls_ref, iq, ik, nk, codes, unmask_full=True):
+    """Run the tile body `tile(codes)` with the masking that can change
+    the tile: all of it where there is no block mask (`cls_ref` None);
+    else by the class of tile (iq, ik) in the (nq, nk) table of
+    `BlockDiffusionMask.tiles` — a dead tile is skipped, not computed
+    and masked; a partial one gets the code mask; a full one runs the
+    same body without it (`unmask_full` False: with it, as a partial
+    one)."""
+    if cls_ref is None:
+        tile(codes)
+        return
+    cls = cls_ref[iq * nk + ik]
+    pl.when(cls == 1 if unmask_full else cls != 0)(
+        functools.partial(tile, codes))
+    if unmask_full:
+        pl.when(cls == 2)(functools.partial(tile, None))
 
 
 # A packed step holds up to 4 heads' (512, 512) f32 score tiles and
@@ -387,7 +416,7 @@ def _heads_spec(packed, heads, block_h, rows, d, seq_axis, fetch=None):
 
 def _pallas_call(kernel, grid, in_specs, out_specs, out_shape,
                  scratch_shapes, tables, **kw):
-    """`pl.pallas_call`, with `tables` (the block mask's live and fetch
+    """`pl.pallas_call`, with `tables` (the block mask's class and fetch
     tables) as scalar-prefetch operands where there are any."""
     if not tables:
         return pl.pallas_call(
@@ -405,8 +434,8 @@ def _pallas_call(kernel, grid, in_specs, out_specs, out_shape,
 
 def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
                       causal_offset, dropout_p, grouped=False,
-                      masked=False):
-    live_ref, (seed_ref, q_ref, k_ref, v_ref, kbias_ref), codes, \
+                      masked=False, biased=True):
+    cls_ref, (seed_ref, q_ref, k_ref, v_ref, kbias_ref), codes, \
         (o_ref, lse_ref, m_scr, l_scr, acc_scr) = _split_refs(
             refs, masked, 5)
     b = pl.program_id(0)
@@ -420,7 +449,7 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _tile():
+    def _tile(codes):
         if grouped:
             q = _load_rows(q_ref, block_h)   # (1, block_h * block_q, d)
             k = _load_heads(k_ref, 1)        # (1, block_k, d)
@@ -434,8 +463,8 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale  # (bh, bq, bk)
-        s = s + kbias_ref[...]  # additive key bias (1, 1, block_k)
-
+        if biased:
+            s = s + kbias_ref[...]  # additive key bias (1, 1, block_k)
         s = _mask_scores(s, iq, ik, codes, block_h=block_h, block_q=block_q,
                          block_k=block_k, causal=causal,
                          causal_offset=causal_offset, grouped=grouped)
@@ -465,10 +494,10 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
             preferred_element_type=jnp.float32)
         acc_scr[:] = acc_scr[:] * alpha + pv
 
-    if masked:      # a dead tile is skipped, not computed and masked
-        pl.when(live_ref[iq * nk + ik] != 0)(_tile)
-    else:
-        _tile()
+    # full tiles keep the select here: on the v5e this body is 8% slower
+    # without it (20.05 against 18.48 ms a call at the SDAR cell's shapes,
+    # PERF.md §6, PR 31), where the backward bodies are 1-3% faster
+    _by_class(_tile, cls_ref, iq, ik, nk, codes, unmask_full=False)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -488,21 +517,21 @@ def _mask_operands(block_mask, sq, sk, block_q, block_k, order):
     axes are (q, k) tiles in `order` "qk", (k, (head block, q)) tiles
     in "kq": `(tables, code arrays, code specs, fetch of the operand of
     the inner axis, index of the inner axis' q or k tile)`."""
-    live, k_fetch, q_fetch = block_mask.tiles(sq, sk, block_q, block_k)
+    cls, k_fetch, q_fetch = block_mask.tiles(sq, sk, block_q, block_k)
     r_le, r_eq, c_le, c_eq = block_mask.codes(sq, sk)
-    nq, nk = live.shape
+    nq, nk = cls.shape
     arrays = [jnp.asarray(r_le).reshape(1, sq, 1),
               jnp.asarray(r_eq).reshape(1, sq, 1),
               jnp.asarray(c_le).reshape(1, 1, sk),
               jnp.asarray(c_eq).reshape(1, 1, sk)]
     if order == "qk":
-        tables = (jnp.asarray(live.reshape(-1)),
+        tables = (jnp.asarray(cls.reshape(-1)),
                   jnp.asarray(k_fetch.reshape(-1)))
         fetch = lambda i, j, t: t[1][i * nk + j]
         row = lambda n, i, j, *t: (0, i, 0)
         col = lambda n, i, j, *t: (0, 0, j)
     else:
-        tables = (jnp.asarray(live.reshape(-1)),
+        tables = (jnp.asarray(cls.reshape(-1)),
                   jnp.asarray(q_fetch.reshape(-1)))
         fetch = lambda i, j, t: t[1][i * nq + j % nq]
         row = lambda n, i, j, *t: (0, j % nq, 0)
@@ -514,11 +543,12 @@ def _mask_operands(block_mask, sq, sk, block_q, block_k, order):
 
 @functools.partial(jax.jit, static_argnames=(
     "heads", "is_causal", "scale", "dropout_p", "block_h", "block_q",
-    "block_k", "interpret", "causal_offset", "kv_heads", "block_mask"))
+    "block_k", "interpret", "causal_offset", "kv_heads", "block_mask",
+    "biased"))
 def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
                    dropout_p=0.0, block_h=1, block_q=128, block_k=128,
                    interpret=False, causal_offset=None, kv_heads=None,
-                   block_mask=None):
+                   block_mask=None, biased=True):
     """q,k,v: merged (BH, S, D) or packed (B, S, H*D) — told apart by
     the leading dim, kbias carrying B; kbias: (B, 1, Sk) f32; seed:
     (1,) i32 -> (out like q, lse (BH, Sq, 1)).  Shapes must be
@@ -534,8 +564,10 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
     128): k and v are (B, Sk, kv_heads * D), a step's block_h query
     heads lie inside one group and read its one key/value tile.
     block_mask (a BlockDiffusionMask over the padded rows): the mask is
-    applied from code vectors in-kernel and the tiles its table marks
-    dead are skipped, their k/v blocks not fetched.
+    applied from code vectors in-kernel, on the tiles its table does
+    not class dead; those are skipped, their k/v blocks not fetched.
+    biased=False (the caller's promise that kbias is all zeros): the
+    kernels are built without the `s + kbias` line.
 
     Row-vector operands are laid out with a unit SUBLANE dim ((B, 1, Sk)
     bias blocks (1, 1, block_k); (BH, Sq, 1) lse blocks (block_h,
@@ -556,7 +588,7 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, block_h=block_h, block_q=block_q,
         block_k=block_k, causal=is_causal, causal_offset=causal_offset,
-        dropout_p=dropout_p, grouped=grouped, masked=masked)
+        dropout_p=dropout_p, grouped=grouped, masked=masked, biased=biased)
     tables, codes, code_specs, fetch = _mask_operands(
         block_mask, sq, sk, block_q, block_k, "qk") if masked \
         else ((), [], [], None)
@@ -618,8 +650,8 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
 
 def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
                           causal_offset, dropout_p, grouped=False,
-                          masked=False, q_tiles=None):
-    live_ref, (seed_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref,
+                          masked=False, biased=True, q_tiles=None):
+    cls_ref, (seed_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref,
                kbias_ref), codes, (dk_ref, dv_ref, dk_scr, dv_scr) = \
         _split_refs(refs, masked, 8)
     b = pl.program_id(0)
@@ -639,7 +671,7 @@ def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _tile():
+    def _tile(codes):
         if grouped:
             q = _load_rows(q_ref, block_h)   # (1, block_h * block_q, d)
             g = _load_rows(g_ref, block_h)
@@ -656,7 +688,8 @@ def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale
-        s = s + kbias_ref[...]
+        if biased:
+            s = s + kbias_ref[...]
         s = _mask_scores(s, iq, ik, codes, block_h=block_h, block_q=block_q,
                          block_k=block_k, causal=causal,
                          causal_offset=causal_offset, grouped=grouped)
@@ -689,10 +722,7 @@ def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
             ds.astype(q.dtype), q, (((1,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
-    if masked:
-        pl.when(live_ref[iq * nk + ik] != 0)(_tile)
-    else:
-        _tile()
+    _by_class(_tile, cls_ref, iq, ik, nk, codes)
 
     @pl.when(last)
     def _finalize():
@@ -702,8 +732,8 @@ def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
 
 def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
                          causal_offset, dropout_p, grouped=False,
-                         masked=False):
-    live_ref, (seed_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref,
+                         masked=False, biased=True):
+    cls_ref, (seed_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref,
                kbias_ref), codes, (dq_ref, dq_scr) = _split_refs(
         refs, masked, 8)
     b = pl.program_id(0)
@@ -715,7 +745,7 @@ def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _tile():
+    def _tile(codes):
         if grouped:
             q = _load_rows(q_ref, block_h)
             g = _load_rows(g_ref, block_h)
@@ -732,7 +762,8 @@ def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale
-        s = s + kbias_ref[...]
+        if biased:
+            s = s + kbias_ref[...]
         s = _mask_scores(s, iq, ik, codes, block_h=block_h, block_q=block_q,
                          block_k=block_k, causal=causal,
                          causal_offset=causal_offset, grouped=grouped)
@@ -753,10 +784,7 @@ def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
             ds.astype(k.dtype), k, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
-    if masked:
-        pl.when(live_ref[iq * nk + ik] != 0)(_tile)
-    else:
-        _tile()
+    _by_class(_tile, cls_ref, iq, ik, nk, codes)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -768,11 +796,13 @@ def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
 
 @functools.partial(jax.jit, static_argnames=(
     "heads", "is_causal", "scale", "dropout_p", "block_h", "block_q",
-    "block_k", "interpret", "causal_offset", "kv_heads", "block_mask"))
+    "block_k", "interpret", "causal_offset", "kv_heads", "block_mask",
+    "biased"))
 def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
                     is_causal=False, scale=None, dropout_p=0.0,
                     block_h=1, block_q=128, block_k=128, interpret=False,
-                    causal_offset=None, kv_heads=None, block_mask=None):
+                    causal_offset=None, kv_heads=None, block_mask=None,
+                    biased=True):
     bh, sq, sk, d, packed, lanes = _layout(q, k, kbias, heads)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     assert bh % block_h == 0 and heads % block_h == 0, (bh, heads, block_h)
@@ -795,7 +825,7 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
     kw = dict(scale=scale, block_h=block_h, block_q=block_q,
               block_k=block_k, causal=is_causal,
               causal_offset=causal_offset, dropout_p=dropout_p,
-              grouped=grouped, masked=masked)
+              grouped=grouped, masked=masked, biased=biased)
     nq, nk = sq // block_q, sk // block_k
     t_qk, codes, specs_qk, fetch_k = _mask_operands(
         block_mask, sq, sk, block_q, block_k, "qk") if masked \
@@ -896,10 +926,10 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
 # -- custom VJP over the kernels ----------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=tuple(range(5, 16)))
+                   nondiff_argnums=tuple(range(5, 17)))
 def _flash_attention(q, k, v, kbias, seed_f, heads, is_causal, scale,
                      dropout_p, interpret, causal_offset, block_h,
-                     block_q, block_k, kv_heads, block_mask):
+                     block_q, block_k, kv_heads, block_mask, biased):
     """seed_f: (1,) float32 — a bitcast int32 dropout seed (float so the
     custom_vjp machinery sees only inexact primals).  causal_offset is
     the ORIGINAL sk - sq (pre-padding): the shim pads seq lengths, so it
@@ -910,13 +940,14 @@ def _flash_attention(q, k, v, kbias, seed_f, heads, is_causal, scale,
                             dropout_p=dropout_p, interpret=interpret,
                             causal_offset=causal_offset, block_h=block_h,
                             block_q=block_q, block_k=block_k,
-                            kv_heads=kv_heads, block_mask=block_mask)
+                            kv_heads=kv_heads, block_mask=block_mask,
+                            biased=biased)
     return out
 
 
 def _flash_fwd_rule(q, k, v, kbias, seed_f, heads, is_causal, scale,
                     dropout_p, interpret, causal_offset, block_h,
-                    block_q, block_k, kv_heads, block_mask):
+                    block_q, block_k, kv_heads, block_mask, biased):
     seed = lax.bitcast_convert_type(seed_f, jnp.int32)
     out, lse = _flash_forward(q, k, v, kbias, seed, heads,
                               is_causal=is_causal, scale=scale,
@@ -924,19 +955,20 @@ def _flash_fwd_rule(q, k, v, kbias, seed_f, heads, is_causal, scale,
                               causal_offset=causal_offset,
                               block_h=block_h, block_q=block_q,
                               block_k=block_k, kv_heads=kv_heads,
-                              block_mask=block_mask)
+                              block_mask=block_mask, biased=biased)
     return out, (q, k, v, kbias, seed, out, lse)
 
 
 def _flash_bwd_rule(heads, is_causal, scale, dropout_p, interpret,
                     causal_offset, block_h, block_q, block_k, kv_heads,
-                    block_mask, res, g):
+                    block_mask, biased, res, g):
     q, k, v, kbias, seed, out, lse = res
     dq, dk, dv = _flash_backward(
         q, k, v, kbias, seed, out, lse, g, heads, is_causal=is_causal,
         scale=scale, dropout_p=dropout_p, interpret=interpret,
         causal_offset=causal_offset, block_h=block_h, block_q=block_q,
-        block_k=block_k, kv_heads=kv_heads, block_mask=block_mask)
+        block_k=block_k, kv_heads=kv_heads, block_mask=block_mask,
+        biased=biased)
     # key-bias grads are not needed (masks are constants); seed is rng
     return dq, dk, dv, jnp.zeros_like(kbias), jnp.zeros_like(
         lse, shape=(1,))
@@ -1000,7 +1032,9 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
     """(B, S, H, D) flash attention via the Pallas kernels.
 
     key_bias: optional (B, Sk) float32 additive bias applied to every
-    query row (the in-kernel form of a key-padding mask).  It is
+    query row (the in-kernel form of a key-padding mask).  Without one,
+    and with no key to pad, the bias would be all zeros: the kernels
+    are then built without the line that adds it.  It is
     treated as a CONSTANT (stop_gradient): masks are the use case; a
     *learned* bias would silently get zero gradient here, so pass those
     through `scaled_dot_product_attention`'s XLA path instead.
@@ -1028,8 +1062,12 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
     first.
 
     block_mask: a `BlockDiffusionMask` whose rows are q's and k's (self
-    attention over `[x_t ‖ x_0]`).  The kernels apply it from index
-    codes and skip its dead tiles (`flash_tiles_live_total` of
+    attention over `[x_t ‖ x_0]`).  Its tile table gives every (q
+    tile, k tile) pair a class: dead tiles are skipped, partial ones
+    masked from index codes, and full ones (every pair live) run
+    without the mask in the two backward kernels — the forward kernel
+    keeps it, being slower without on the v5e
+    (`flash_tiles_full_total` of `flash_tiles_live_total` of
     `flash_tiles_total`, per head, counted here at trace time with
     `flash_block_mask_total` instances); no dense mask exists.
     """
@@ -1082,6 +1120,7 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
         valid = jnp.arange(sk_p) < sk
         bias = jnp.where(valid[None, :], bias, DEFAULT_MASK_VALUE)
     bias = bias[:, None, :]  # (B, 1, Sk_p): unit sublane dim for Mosaic
+    biased = key_bias is not None or sk_p != sk     # else: all zeros
 
     if dropout_p > 0.0:
         seed = (jnp.zeros((1,), jnp.int32) if dropout_seed is None
@@ -1114,7 +1153,7 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
                                 cand, block_q, block_k, sk - sq,
                                 final_rung=(cand == ladder[-1]),
                                 packed=packed, kv_heads=kv_heads,
-                                block_mask=block_mask):
+                                block_mask=block_mask, biased=biased):
                     block_h = cand
                     break
         if block_h is None:
@@ -1136,13 +1175,15 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
     from ...profiler import stat_add
 
     if block_mask is not None:
-        live = block_mask.tiles(sq_p, sk_p, block_q, block_k)[0]
+        cls = block_mask.tiles(sq_p, sk_p, block_q, block_k)[0]
         stat_add("flash_block_mask_total")
-        stat_add("flash_tiles_live_total", int(live.sum()))
-        stat_add("flash_tiles_total", live.size)
+        stat_add("flash_tiles_full_total", int((cls == 2).sum()))
+        stat_add("flash_tiles_live_total", int((cls != 0).sum()))
+        stat_add("flash_tiles_total", cls.size)
     out = _flash_attention(qm, km, vm, bias, seed_f, h, is_causal, scale,
                            float(dropout_p), interpret, sk - sq,
-                           block_h, block_q, block_k, kv_heads, block_mask)
+                           block_h, block_q, block_k, kv_heads, block_mask,
+                           biased)
     if packed:
         stat_add("flash_packed_layout_total")
         return out[:, :sq].reshape(b, sq, h, d)
@@ -1156,7 +1197,7 @@ _EXACT_PROBE_CACHE = {}
 def _probe_exact(q_shape, k_shape, heads, is_causal, dropout_p, dtype,
                  block_h, block_q, block_k, causal_offset,
                  final_rung=True, packed=False, kv_heads=None,
-                 block_mask=None):
+                 block_mask=None, biased=True):
     """Compile (never run) the exact kernel instances flash_attention is
     about to stage, once per configuration.  q_shape / k_shape are the
     padded (B*H, S, D) whichever the operand layout; `packed` probes
@@ -1167,7 +1208,7 @@ def _probe_exact(q_shape, k_shape, heads, is_causal, dropout_p, dtype,
     refusal is routine and stays silent and uncounted."""
     key = (q_shape, k_shape, heads, is_causal, dropout_p,
            jnp.dtype(dtype).name, block_h, block_q, block_k,
-           causal_offset, packed, kv_heads, block_mask)
+           causal_offset, packed, kv_heads, block_mask, biased)
     if key not in _EXACT_PROBE_CACHE:
         def compile_probe():
             bh, sq, d = q_shape
@@ -1181,7 +1222,7 @@ def _probe_exact(q_shape, k_shape, heads, is_causal, dropout_p, dtype,
             kw = dict(is_causal=is_causal, dropout_p=dropout_p,
                       block_h=block_h, block_q=block_q, block_k=block_k,
                       causal_offset=causal_offset, kv_heads=kv_heads,
-                      block_mask=block_mask)
+                      block_mask=block_mask, biased=biased)
             _flash_forward.lower(x, kv, kv, kb, seed, heads,
                                  **kw).compile()
             lse = probe_struct((bh, sq, 1), jnp.float32)

@@ -52,9 +52,10 @@ class TestMaskFamily:
 
     def test_tile_tables(self):
         mask = A.BlockDiffusionMask(4096, 4)
-        live, k_fetch, q_fetch = mask.tiles(8192, 8192, 512, 512)
+        cls, k_fetch, q_fetch = mask.tiles(8192, 8192, 512, 512)
+        live = cls != 0
         assert live.sum() == 80 and live.size == 256
-        assert mask.tiles(8192, 8192, 256, 256)[0].sum() == 288
+        assert (mask.tiles(8192, 8192, 256, 256)[0] != 0).sum() == 288
         # a dead step keeps the last live tile of its row / column
         for table, lv in ((k_fetch, live), (q_fetch, live.T)):
             for a in range(lv.shape[0]):
@@ -62,6 +63,51 @@ class TestMaskFamily:
                     assert lv[a, table[a, b]]
                     if lv[a, b]:
                         assert table[a, b] == b
+
+    @pytest.mark.parametrize("seq,block,block_q,block_k", [
+        (384, 4, 128, 128),     # 768 rows: whole tiles, no padding
+        (330, 4, 128, 128),     # 660 -> 768: the halves meet mid-tile
+        (200, 4, 128, 256),     # 400 -> 512: too short for a full tile
+        (160, 32, 64, 128),     # 320 -> 384, block 32
+        (1024, 4, 256, 512),    # the cell's tile shape
+    ])
+    def test_tile_classes_are_the_dense_mask(self, seq, block, block_q,
+                                             block_k):
+        """0 dead: no pair live; 1 partial: both kinds; 2 full: every
+        pair live — over the padded rows and columns, where a padding
+        row sees nothing and a padding column is seen by nothing, so a
+        tile that touches padding is never full."""
+        mask = A.BlockDiffusionMask(seq, block)
+        n_rows = -(-mask.rows // block_q) * block_q
+        n_cols = -(-mask.rows // block_k) * block_k
+        dense = np.zeros((n_rows, n_cols), bool)
+        dense[:mask.rows, :mask.rows] = mask.dense()
+        cls = mask.tiles(n_rows, n_cols, block_q, block_k)[0]
+        assert cls.shape == (n_rows // block_q, n_cols // block_k)
+        assert cls.dtype == np.int32
+        for iq in range(cls.shape[0]):
+            for ik in range(cls.shape[1]):
+                t = dense[iq * block_q:(iq + 1) * block_q,
+                          ik * block_k:(ik + 1) * block_k]
+                want = 2 if t.all() else 1 if t.any() else 0
+                assert cls[iq, ik] == want, (iq, ik)
+                if (iq + 1) * block_q > mask.rows \
+                        or (ik + 1) * block_k > mask.rows:
+                    assert cls[iq, ik] != 2, (iq, ik)
+
+    def test_tile_classes_of_the_sdar_cell(self):
+        """`sdar_30b_a3b.blockdiff_s4096`: 2 x 4096 rows on (256, 512)
+        tiles: 160 of 512 live, 112 of them full; of the 48 partial, 16
+        noisy x noisy tiles hold 4 live columns a row."""
+        mask = A.BlockDiffusionMask(4096, 4)
+        cls = mask.tiles(8192, 8192, 256, 512)[0]
+        assert cls.size == 512
+        assert ((cls != 0).sum(), (cls == 2).sum(), (cls == 1).sum()) \
+            == (160, 112, 48)
+        noisy = cls[:16, :8]
+        assert (noisy == 1).sum() == 16 and (noisy == 2).sum() == 0
+        dense = mask.dense()
+        assert dense[:256, :512].mean() == 4 / 512
 
 
 class TestGroupedQuery:
@@ -124,3 +170,116 @@ class TestBlockDiffusionKernels:
         with pytest.raises(ValueError, match="block_mask covers"):
             A.flash_attention(q, k, v, interpret=True,
                               block_mask=A.BlockDiffusionMask(16, 4))
+
+
+def _all_live_partial(tiles):
+    """`BlockDiffusionMask.tiles` as it was before a tile had a class:
+    every live tile runs the masked body."""
+    def patched(self, *a):
+        cls, k_fetch, q_fetch = tiles(self, *a)
+        return np.minimum(cls, 1), k_fetch, q_fetch
+    return patched
+
+
+def _assert_same_bits(got, want):
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+class TestMaskOnlyWhereItCanChangeATile:
+    """The backward kernels run a full tile without the code mask and
+    no kernel adds an all-zero key bias: `where(True, s, .)` is `s` and
+    `s + 0.0` is `s`, so every output is the masked, biased kernels' to
+    the bit."""
+
+    @pytest.fixture
+    def run(self, monkeypatch):
+        """`run(q, k, v, **flash_attention kwargs)` -> ([out, lse, dq,
+        dk, dv], the `biased` the kernels were built with).
+
+        The kernels are jitted on static arguments that a patched
+        `tiles` does not change, so no traced instance may outlive a
+        call.  The interpreter's arithmetic is compiled without XLA's
+        fusion pass: which elementwise operations the CPU backend fuses
+        into a row sum decides the order it adds in, so two programs
+        that differ by an identity would else differ in last bits that
+        are the CPU compiler's, not the kernels'."""
+        forward = A._flash_forward
+        seen = {}
+
+        def spy(*a, **kw):
+            out, lse = forward(*a, **kw)
+            seen.update(lse=lse, biased=kw["biased"])
+            return out, lse
+
+        monkeypatch.setattr(A, "_flash_forward", spy)
+
+        def run(q, k, v, **kw):
+            def f(q, k, v):
+                out, vjp = jax.vjp(lambda q, k, v: A.flash_attention(
+                    q, k, v, block_q=128, block_k=128, interpret=True,
+                    **kw), q, k, v)
+                w = jax.random.normal(jax.random.PRNGKey(9), out.shape)
+                return (out, seen["lse"], *vjp(w))
+
+            jax.clear_caches()
+            got = jax.jit(f).lower(q, k, v).compile(compiler_options={
+                "xla_disable_hlo_passes": "fusion"})(q, k, v)
+            jax.clear_caches()
+            return [np.asarray(x) for x in got], seen["biased"]
+
+        return run
+
+    @pytest.mark.parametrize("seq", [384, 330])
+    @pytest.mark.parametrize("h,hkv,d", [(8, 2, 128), (4, 4, 64)])
+    def test_full_tiles_unmasked_bit_equal(self, run, monkeypatch, seq,
+                                           h, hkv, d):
+        """768 rows on 128-tiles (660 pad to them): dead, partial and
+        full tiles, against the same call with every live tile classed
+        partial."""
+        mask = A.BlockDiffusionMask(seq, 4)
+        cls = mask.tiles(768, 768, 128, 128)[0]
+        assert (cls == 2).any() and (cls == 1).any() and (cls == 0).any()
+        q, k, v = _qkv(5, 1, 2 * seq, h, hkv, d)
+        got, _ = run(q, k, v, block_mask=mask)
+        monkeypatch.setattr(A.BlockDiffusionMask, "tiles",
+                            _all_live_partial(A.BlockDiffusionMask.tiles))
+        assert (mask.tiles(768, 768, 128, 128)[0] == 2).sum() == 0
+        want, _ = run(q, k, v, block_mask=mask)
+        _assert_same_bits(got, want)
+
+    @pytest.mark.parametrize("masked", [False, True])
+    @pytest.mark.parametrize("h,hkv,d", [(8, 2, 128), (4, 4, 64)])
+    def test_zero_key_bias_not_added_bit_equal(self, run, masked, h, hkv,
+                                               d):
+        """No key bias and no key to pad: the kernels lose the bias
+        line; an all-zero bias passed in keeps it.  Same bits."""
+        mask = A.BlockDiffusionMask(128, 4) if masked else None
+        q, k, v = _qkv(6, 2, 256, h, hkv, d)
+        got, biased = run(q, k, v, block_mask=mask)
+        assert biased is False
+        want, biased = run(q, k, v, block_mask=mask,
+                           key_bias=jnp.zeros((2, 256), jnp.float32))
+        assert biased is True
+        _assert_same_bits(got, want)
+
+    def test_padded_keys_keep_the_bias(self, run):
+        """Padding keys are hidden through the bias: it stays."""
+        q, k, v = _qkv(7, 1, 200, 4, 4, 64)
+        assert run(q, k, v)[1] is True
+
+    def test_tile_counters(self):
+        """384 + 384 rows, block 4, on 128-tiles: 36 tiles a head, 15
+        live (3 noisy x noisy, 3 + 3 on the two block-causal diagonals,
+        3 + 3 below them), those 6 full."""
+        mask = A.BlockDiffusionMask(384, 4)
+        q, k, v = _qkv(8, 1, 768, 2, 2, 64)
+        before = profiler.get_int_stats()
+        A.flash_attention(q, k, v, block_mask=mask, block_q=128,
+                          block_k=128, interpret=True)
+        after = profiler.get_int_stats()
+        delta = lambda n: after.get(n, 0) - before.get(n, 0)
+        assert (delta("flash_tiles_full_total"),
+                delta("flash_tiles_live_total"),
+                delta("flash_tiles_total"),
+                delta("flash_block_mask_total")) == (6, 15, 36, 1)

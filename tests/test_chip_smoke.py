@@ -54,6 +54,8 @@ class TestPhasesTiny:
             batch=2, seq=16, steps=3, platform="cpu")
         assert out["moe_plan_packed_total"] == 2
         assert out["moe_plan_two_operand_total"] == 0
+        # off the chip attention takes the XLA path: no kernel, no tile
+        assert out["flash_tiles_full_total"] == 0
 
     def test_failed_check_raises(self):
         ph = chip_smoke._Phase("x")

@@ -439,13 +439,17 @@ class TestMosaicAcceptsForV5e:
     def test_flash_pair_grouped_block_mask(self, v5e):
         """The `sdar_30b_a3b.blockdiff_s4096` instances: 32 query heads
         on 4 key/value heads at D = 128, 2 x 4096 rows under the
-        block-diffusion mask (live-tile tables as scalar prefetch), the
-        whole group of 8 heads a step on (256, 512) tiles — the rung
-        flash_attention() tries first there."""
+        block-diffusion mask (tile-class tables as scalar prefetch; in
+        the backward kernels a masked and an unmasked copy of the tile
+        body), the whole group
+        of 8 heads a step on (256, 512) tiles — the rung
+        flash_attention() tries first there — and, as the cell passes
+        no key bias and pads no key, without the bias add."""
         mask = A.BlockDiffusionMask(4096, 4)
         assert A._probe_exact((32, 8192, 128), (32, 8192, 128), 32, False,
                               0.0, jnp.bfloat16, 8, 256, 512, 0,
-                              packed=True, kv_heads=4, block_mask=mask)
+                              packed=True, kv_heads=4, block_mask=mask,
+                              biased=False)
         # grouped heads alone (causal), and the mask on plain heads
         assert A._probe_exact((16, 1024, 128), (16, 1024, 128), 16, True,
                               0.0, jnp.bfloat16, 4, 512, 512, 0,

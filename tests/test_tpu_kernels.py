@@ -368,6 +368,47 @@ def test_routed_moe_packed_plan_on_tpu():
                                    np.asarray(b) / scale, atol=5e-3)
 
 
+def test_block_diffusion_mask_grouped_on_tpu():
+    """The masked kernels at the SDAR cell's head shape (8 query heads
+    a key/value head, D = 128) through Mosaic, against `_xla_attention`
+    with the dense mask: 2 x 1024 rows on (256, 512) tiles hold dead
+    tiles (skipped), partial ones (masked from the codes) and full ones
+    (in the backward kernels the same body without the mask); no key
+    bias, no padded key, so the kernels are built without the bias
+    add."""
+    mask = A.BlockDiffusionMask(1024, 4)
+    q = _rand((2, 2048, 16, 128), 60, jnp.bfloat16)
+    k, v = (_rand((2, 2048, 2, 128), s, jnp.bfloat16) for s in (61, 62))
+    w = _rand((2, 2048, 16, 128), 63, jnp.bfloat16)
+    before = profiler.get_int_stats()
+
+    def out_and_grads(attention):
+        def f(q, k, v):
+            out, vjp = jax.vjp(attention, q, k, v)
+            return out, vjp(w)
+        return jax.jit(f)(q, k, v)
+
+    out, got = out_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, block_mask=mask))
+    ref, want = out_and_grads(
+        lambda q, k, v: _xla_attention(q, k, v, mask=mask))
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=2e-2, rtol=2e-2)
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a / np.abs(b).max(),
+                                   b / np.abs(b).max(), atol=2e-2)
+    after = profiler.get_int_stats()
+    delta = lambda n: after.get(n, 0) - before.get(n, 0)
+    calls = delta("flash_block_mask_total")
+    assert calls > 0
+    assert (delta("flash_tiles_full_total"),
+            delta("flash_tiles_live_total"),
+            delta("flash_tiles_total")) == (4 * calls, 16 * calls,
+                                            32 * calls)
+
+
 def test_no_kernel_gave_way():
     """Runs last: nothing above (and no other tpu-marked test before
     it) may have pushed a kernel onto its XLA path."""
