@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
-Drives the four main paths once, in ONE process, through the entry
+Drives the five main paths once, in ONE process, through the entry
 points a user calls, at published widths, on seeded random weights:
 
   executor_resnet50   models/resnet.build_train_program -> fluid.Executor
@@ -9,6 +9,9 @@ points a user calls, at published widths, on seeded random weights:
   generation_engine   serving.AutoregressiveEngine over a LayeredDecoder
   sdar_moe_step       models/sdar_moe.build_blockdiff_train_step (the
                       masked flash kernels, the routed expert layer)
+  joyai_flash_step    models/joyai_flash.build_train_step (latent
+                      attention on the split-width causal flash kernels,
+                      the sigmoid router with its selection bias, MTP)
 
     python chip_smoke.py            # one chip (what the driver runs)
     python chip_smoke.py --chips 4  # the four-chip host: data-parallel
@@ -610,6 +613,98 @@ def sdar_moe_step(cfg, batch=2, seq=1024, steps=3, *, platform="tpu"):
     return ph.done()
 
 
+def joyai_flash_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
+    """`joyai_flash.build_train_step` with per-layer recomputation: the
+    trace-time counters say that every attention layer's flash instance
+    has v heads narrower than its q/k heads and goes by the causal tile
+    classes, and that every expert layer got the sigmoid router; the
+    run-time counters, fed from what the step returns, that no held
+    visit was dropped, that the router's count covers every visit and
+    that the selection biases moved."""
+    import jax.numpy as jnp
+
+    import paddle_tpu
+    from paddle_tpu.models import joyai_flash
+
+    ph = _Phase("joyai_flash_step")
+    paddle_tpu.seed(SEED)
+    model = joyai_flash.JoyAIFlashForCausalLMWithMTP(cfg)
+    attn = cfg.num_hidden_layers + 1            # the MTP block's too
+    sparse = sum(layer.sparse for layer in model.model.layers) + 1
+    step, state = joyai_flash.build_train_step(model)
+    biases = joyai_flash.bias_names(state["params"])
+    b = joyai_flash.fake_batch(cfg, batch, seq, seed=SEED)
+    lr = jnp.float32(1e-3)
+    traced = ("flash_split_value_total", "flash_tiles_full_total",
+              "flash_tiles_live_total", "flash_tiles_total",
+              "moe_sigmoid_router_total", "moe_plan_packed_total")
+    ran = ("moe_router_rows_total", "moe_router_rows_max_total",
+           "moe_bias_updates_total", "moe_rows_routed_total",
+           "moe_rows_held_total", "moe_dropped_total")
+    s0 = _stats()
+    t0 = time.perf_counter()
+    compiled = step.lower(state, b, lr).compile()
+    ph.compile_s = time.perf_counter() - t0
+    for k in traced:
+        ph.info[k] = _stats().get(k, 0) - s0.get(k, 0)
+    losses = []
+    for _ in range(steps):
+        state, loss, aux = compiled(state, b, lr)
+        losses.append(float(loss))
+        joyai_flash.record_moe_stats(np.asarray(aux["moe_stats"]),
+                                     np.asarray(aux["moe_load"]),
+                                     bias_updates=len(biases))
+    s1 = _stats()
+    for k in ran:
+        ph.info[k] = s1.get(k, 0) - s0.get(k, 0)
+    ph.info["losses"] = [round(v, 4) for v in losses]
+    untrained = (1 + cfg.mtp_loss_weight) * math.log(cfg.vocab_size)
+    ph.check(all(math.isfinite(v) for v in losses),
+             f"{steps} losses finite")
+    ph.check(losses[-1] < losses[0], "loss falls on the repeated batch")
+    ph.check(abs(losses[0] - untrained) < 1.0,
+             f"first loss {losses[0]:.3f} near (1 + lambda) ln(vocab) = "
+             f"{untrained:.3f}")
+    ph.check(ph.info["moe_rows_held_total"] > 0
+             and ph.info["moe_dropped_total"] == 0,
+             "held visits computed, moe_dropped_total did not move")
+    ph.check(ph.info["moe_router_rows_total"]
+             == ph.info["moe_rows_routed_total"]
+             == steps * sparse * batch * seq * cfg.num_experts_per_tok,
+             "the routers' loads count every visit, held here or not")
+    ph.check(ph.info["moe_bias_updates_total"] == steps * sparse
+             and all(float(jnp.abs(state["params"][k]).max()) > 0
+                     for k in biases),
+             f"{sparse} selection biases moved a step, outside AdamW")
+    ph.check((ph.info["moe_sigmoid_router_total"],
+              ph.info["moe_plan_packed_total"]) == (sparse, sparse),
+             f"{sparse} sigmoid routers traced once, each with a packed "
+             "visit plan")
+    ph.check(_device_platforms(state["params"]["lm_head.weight"])
+             == {platform}, f"parameters sit on platform {platform!r}")
+    if platform == "tpu":
+        text = compiled.as_text()
+        ops = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
+                         r'op_name="([^"]*)"', text)
+        fwd = sum("_flash_forward" in op for op in ops)
+        bwd = sum("_flash_backward" in op for op in ops)
+        ph.check((fwd, bwd) == (2 * attn, 2 * attn),
+                 f"{fwd} forward (with the recomputed ones) and {bwd} "
+                 f"backward flash calls for {attn} attention layers")
+        ph.check(_fallback_counts()["flash_fallback_total"] == 0,
+                 "flash_fallback_total == 0")
+        # seq rows on (512, 512) tiles, causal: n (n + 1) / 2 live of
+        # n^2, the n on the diagonal partial, the rest of them full
+        n = -(-seq // 512)
+        ph.check(tuple(ph.info[k] for k in traced[:4]) == (
+            attn, attn * n * (n - 1) // 2, attn * n * (n + 1) // 2,
+            attn * n * n),
+            f"{attn} flash instances with v narrower than q/k; full / "
+            f"live / all tiles == {n * (n - 1) // 2} / "
+            f"{n * (n + 1) // 2} / {n * n} in each: dead tiles skipped")
+    return ph.done()
+
+
 # ---------------------------------------------------------------------------
 
 def main(argv=None) -> int:
@@ -623,7 +718,7 @@ def main(argv=None) -> int:
     device, count = require_chip(args.chips)
 
     from paddle_tpu.fluid.compile_cache import enable_persistent_cache
-    from paddle_tpu.models import bert, sdar_moe
+    from paddle_tpu.models import bert, joyai_flash, sdar_moe
 
     print(f"chip_smoke: compile cache at {enable_persistent_cache()}")
     phases = []
@@ -637,6 +732,12 @@ def main(argv=None) -> int:
         # experts and an eighth of the vocabulary
         phases.append(sdar_moe_step(sdar_moe.SdarMoeConfig(
             num_hidden_layers=2, experts_held=(0, 16), vocab_size=18992,
+            recompute=True)))
+        # JoyAI-LLM-Flash's widths: the dense layer, one sparse layer
+        # and the MTP block, one chip's 16 of the 256 experts and an
+        # eighth of the vocabulary
+        phases.append(joyai_flash_step(joyai_flash.JoyAIFlashConfig(
+            num_hidden_layers=2, experts_held=(0, 16), vocab_size=16160,
             recompute=True)))
     else:
         phases.append(executor_resnet50(4 * 128, data_parallel=4))

@@ -309,12 +309,20 @@ def rms_norm(x, weight=None, epsilon=1e-6, name=None):
     return trace_fn(f, ins)
 
 
-def rotary_embedding(q, k, positions, theta=10000.0, name=None):
+def rotary_embedding(q, k, positions, theta=10000.0, interleaved=False,
+                     name=None):
     """Rotary position embedding (Su et al. 2021) over the whole head
     width, rotate-half convention: q, k (batch, seq, heads, head_dim),
     positions (batch, seq) or (seq,) integers -> (q, k) rotated, with
     angle positions * theta^(-2i / head_dim) on the pair (i, i +
-    head_dim / 2).  Angles in float32; the results keep their dtypes."""
+    head_dim / 2).  Angles in float32; the results keep their dtypes.
+
+    `interleaved`: the pair with angle i is (2i, 2i + 1), as checkpoints
+    with `rope_interleave` store it.  The lanes are sorted into halves
+    first (evens, then odds) and stay so: q and k come out under the
+    same fixed permutation, so every q · k is the interleaved
+    rotation's (the released modeling code of those models does the
+    same)."""
     import jax.numpy as jnp
 
     def f(q, k, pos):
@@ -327,7 +335,9 @@ def rotary_embedding(q, k, positions, theta=10000.0, name=None):
         sin = jnp.sin(ang)[:, :, None, :]
 
         def rot(x):
-            x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+            xf = x.astype(jnp.float32)
+            x1, x2 = (xf[..., 0::2], xf[..., 1::2]) if interleaved \
+                else jnp.split(xf, 2, axis=-1)
             return jnp.concatenate(
                 [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                 axis=-1).astype(x.dtype)

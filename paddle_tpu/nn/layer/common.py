@@ -308,23 +308,45 @@ class SwitchMoE(Layer):
 
 
 class RoutedMoE(Layer):
-    """Dropless top-k routed experts (gated SiLU FFNs, softmax router),
-    told which experts it holds: the model-side face of
+    """Dropless top-k routed experts (gated SiLU FFNs), told which
+    experts it holds: the model-side face of
     `parallel.moe.routed_moe_local`.  It routes over all `num_experts`,
     computes the visits that land on the `held = (first, count)` experts
     whose weights it has (default: all) and leaves out what absent
     experts would have added; on one chip there is no exchange.
 
-    forward(x (..., H)) -> (out (..., H), stats, experts):
+    `scoring` "softmax" (default) or "sigmoid" (DeepSeek-V3's router:
+    sigmoid scores, the k weights renormalised and times
+    `routed_scaling_factor`).  `selection_bias`: the buffer
+    `e_score_correction_bias` (num_experts,) is added to the scores for
+    the CHOICE alone — never to the weights, and outside the gradient;
+    a train step moves it by `parallel.moe.update_selection_bias` from
+    the `load` this layer then returns.  `n_shared_experts` > 0: one
+    `GatedFFN` of width `n_shared_experts * d_ff` (sublayer
+    `shared_experts`) that every row passes, added to the routed
+    output.  `n_group`, `topk_group`: 1, no group limit on the choice
+    (group-limited top-k is not built: anything else raises).
+
+    forward(x (..., H)) -> (out (..., H), stats, experts[, load]):
     stats is the layer's (count + 2,) int32 count vector (rows per held
     expert, visits routed, held visits computed), experts (rows, k)
-    what the router chose."""
+    what the router chose, load — with `selection_bias` — the
+    (num_experts,) int32 rows of every router output."""
 
     def __init__(self, d_model, d_ff, num_experts, top_k, held=None,
-                 norm_topk_prob=True, weight_attr=None):
+                 norm_topk_prob=True, weight_attr=None, scoring="softmax",
+                 routed_scaling_factor=1.0, selection_bias=False,
+                 n_shared_experts=0, n_group=1, topk_group=1):
         super().__init__()
+        if n_group != 1 or topk_group != 1:
+            raise NotImplementedError(
+                f"group-limited routing (n_group {n_group}, topk_group "
+                f"{topk_group}) is not built: 1 and 1, no limit, only")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
         self._top_k, self._renormalize = top_k, norm_topk_prob
         self._held = held
+        self._scoring, self._scale = scoring, float(routed_scaling_factor)
         count = num_experts if held is None else held[1]
         self.gate_weight = self.create_parameter(
             shape=[d_model, num_experts], attr=weight_attr,
@@ -337,18 +359,42 @@ class RoutedMoE(Layer):
             shape=[count, d_model, d_ff], **fans)
         self.w_down = self.create_parameter(
             shape=[count, d_ff, d_model], **fans)
+        self._biased, self._shared = selection_bias, bool(n_shared_experts)
+        if selection_bias:
+            import jax.numpy as jnp
+
+            self.register_buffer("e_score_correction_bias",
+                                 jnp.zeros((num_experts,), jnp.float32))
+        if n_shared_experts:
+            from .transformer import GatedFFN
+
+            self.shared_experts = GatedFFN(
+                d_model, n_shared_experts * d_ff, "silu", weight_attr)
 
     def forward(self, x):
         from ...fluid.dygraph.tracer import trace_fn
-        from ...parallel.moe import routed_moe_local
+        from ...parallel.moe import routed_moe_local, router_load
 
-        def f(x, wr, wg, wu, wd):
+        biased = self._biased
+
+        def f(x, wr, wg, wu, wd, br=None):
+            params = {"wr": wr, "wg": wg, "wu": wu, "wd": wd}
+            if br is not None:
+                params["br"] = br
             out, stats, experts = routed_moe_local(
-                {"wr": wr, "wg": wg, "wu": wu, "wd": wd},
-                x.reshape(-1, x.shape[-1]), self._top_k, held=self._held,
-                renormalize=self._renormalize)
-            return out.reshape(x.shape), stats, experts
+                params, x.reshape(-1, x.shape[-1]), self._top_k,
+                held=self._held, renormalize=self._renormalize,
+                scoring=self._scoring, scale=self._scale)
+            out = out.reshape(x.shape), stats, experts
+            if biased:
+                out += (router_load(experts, wr.shape[1]),)
+            return out
 
-        return trace_fn(
-            f, {"x": x, "wr": self.gate_weight, "wg": self.w_gate,
-                "wu": self.w_up, "wd": self.w_down}, multi_out=True)
+        ins = {"x": x, "wr": self.gate_weight, "wg": self.w_gate,
+               "wu": self.w_up, "wd": self.w_down}
+        if biased:
+            ins["br"] = self.e_score_correction_bias
+        out, *rest = trace_fn(f, ins, multi_out=True)
+        if self._shared:
+            out = out + self.shared_experts(x)
+        return (out, *rest)

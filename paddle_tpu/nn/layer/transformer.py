@@ -14,6 +14,7 @@ from __future__ import annotations
 import collections
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ...fluid.dygraph.tracer import trace_fn, trace_op
@@ -148,6 +149,85 @@ class GroupedQueryAttention(Layer):
         out = trace_fn(
             lambda o: o.reshape(o.shape[0], o.shape[1], -1), {"o": out})
         return self.out_proj(out)
+
+
+class LatentAttention(Layer):
+    """Multi-head latent attention (DeepSeek-V2 §2.1; the V3 family's
+    attention block) in its expanded, training form: queries and
+    keys/values are projected through low-rank latents, a head's query
+    and key are a position-free part of `qk_nope_head_dim` beside a
+    rotated part of `qk_rope_head_dim`, and the rotated KEY part is ONE
+    head shared by all heads; the value heads have a width of their
+    own (`v_head_dim`).  No biases.
+
+        c_q = RMSNorm(x W_qa);  q = c_q W_qb  -> H x (nope ‖ rope)
+        [c_kv ‖ k_r] = x W_kva;  c_kv = RMSNorm(c_kv)
+        [k_nope ‖ v] = c_kv W_kvb  -> H x (nope ‖ v)
+        q = [q_nope ‖ RoPE(q_r)],  k = [k_nope ‖ RoPE(k_r)]
+        out = concat_h softmax(q_h k_h^T / sqrt(nope + rope)) v_h  W_o
+
+    `rope_interleave`: rotary pairs are (2i, 2i + 1)
+    (F.rotary_embedding).  The absorbed decode form and its latent
+    cache row are not built.
+
+    forward(x (B, S, E), positions (B, S) | (S,), is_causal=True) ->
+    (B, S, E); the flash kernels take the (nope + rope)-wide q/k heads
+    over the v_head_dim-wide v heads as they are
+    (ops/pallas/attention.py)."""
+
+    def __init__(self, embed_dim, num_heads, q_lora_rank, kv_lora_rank,
+                 qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                 rope_theta=10000.0, rope_interleave=True, epsilon=1e-6,
+                 weight_attr=None):
+        super().__init__()
+        self.num_heads = num_heads
+        self.kv_lora_rank = kv_lora_rank
+        self.nope, self.rope, self.v_dim = (qk_nope_head_dim,
+                                            qk_rope_head_dim, v_head_dim)
+        self.rope_theta, self.rope_interleave = rope_theta, rope_interleave
+        q_out = num_heads * (qk_nope_head_dim + qk_rope_head_dim)
+        self.q_a_proj = Linear(embed_dim, q_lora_rank, weight_attr, False)
+        self.q_a_layernorm = RMSNorm(q_lora_rank, epsilon)
+        self.q_b_proj = Linear(q_lora_rank, q_out, weight_attr, False)
+        self.kv_a_proj_with_mqa = Linear(
+            embed_dim, kv_lora_rank + qk_rope_head_dim, weight_attr, False)
+        self.kv_a_layernorm = RMSNorm(kv_lora_rank, epsilon)
+        self.kv_b_proj = Linear(
+            kv_lora_rank, num_heads * (qk_nope_head_dim + v_head_dim),
+            weight_attr, False)
+        self.o_proj = Linear(num_heads * v_head_dim, embed_dim, weight_attr,
+                             False)
+
+    def forward(self, x, positions, is_causal=True):
+        h, nope, rope, rank = (self.num_heads, self.nope, self.rope,
+                               self.kv_lora_rank)
+        q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
+        kv_a = self.kv_a_proj_with_mqa(x)
+        c_kv, k_r = trace_fn(
+            lambda a: (a[..., :rank], a[..., None, rank:]), {"a": kv_a},
+            multi_out=True)
+        kv = self.kv_b_proj(self.kv_a_layernorm(c_kv))
+        q_nope, q_r = trace_fn(
+            lambda q: tuple(jnp.split(
+                q.reshape(q.shape[:2] + (h, nope + rope)), [nope], axis=-1)),
+            {"q": q}, multi_out=True)
+        q_r, k_r = F.rotary_embedding(q_r, k_r, positions, self.rope_theta,
+                                      interleaved=self.rope_interleave)
+
+        def assemble(q_nope, q_r, kv, k_r):
+            kv = kv.reshape(kv.shape[:2] + (h, nope + self.v_dim))
+            k_r = jnp.broadcast_to(k_r, k_r.shape[:2] + (h, rope))
+            return (jnp.concatenate([q_nope, q_r], axis=-1),
+                    jnp.concatenate([kv[..., :nope], k_r], axis=-1),
+                    kv[..., nope:])
+
+        q, k, v = trace_fn(assemble, {"q_nope": q_nope, "q_r": q_r,
+                                      "kv": kv, "k_r": k_r}, multi_out=True)
+        out = F.scaled_dot_product_attention(
+            q, k, v, is_causal=is_causal, training=self.training)
+        out = trace_fn(
+            lambda o: o.reshape(o.shape[0], o.shape[1], -1), {"o": out})
+        return self.o_proj(out)
 
 
 class GatedFFN(Layer):

@@ -136,18 +136,46 @@ class BlockDiffusionMask:
                      block_q, nk, block_k)
             cls[iq] = m.any(axis=(0, 2)).astype(np.int32) \
                 + m.all(axis=(0, 2))
+        return _with_fetch_tables(cls)
 
-        def fetch(lv):
-            out = np.zeros(lv.shape, np.int32)
-            for a, row in enumerate(lv):
-                alive = np.flatnonzero(row)
-                cur = alive[0] if len(alive) else 0
-                for b, on in enumerate(row):
-                    cur = b if on else cur
-                    out[a, b] = cur
-            return out
 
-        return cls, fetch(cls != 0), fetch(cls.T != 0)
+def _with_fetch_tables(cls):
+    """`(cls, k_fetch, q_fetch)` of a (nq, nk) tile-class table: what
+    `BlockDiffusionMask.tiles` documents."""
+    def fetch(lv):
+        out = np.zeros(lv.shape, np.int32)
+        for a, row in enumerate(lv):
+            alive = np.flatnonzero(row)
+            cur = alive[0] if len(alive) else 0
+            for b, on in enumerate(row):
+                cur = b if on else cur
+                out[a, b] = cur
+        return out
+
+    return cls, fetch(cls != 0), fetch(cls.T != 0)
+
+
+@dataclasses.dataclass(frozen=True)
+class _CausalTiles:
+    """The tile classes of a plain causal mask — row i sees column j
+    iff j <= i + offset, offset = sk - sq before padding — in closed
+    form: a tile above the diagonal band is dead, one below it full,
+    one it crosses partial (the kernels mask those by their index
+    compare; no code vector exists).  Key padding is the key bias's
+    and no class's.  A q tile that holds a row which sees no key at
+    all (offset < 0) has no dead tile: such a row's result is the mean
+    over every key, as the XLA path's is."""
+    offset: int
+
+    @functools.lru_cache(maxsize=None)
+    def tiles(self, n_rows: int, n_cols: int, block_q: int, block_k: int):
+        r0 = np.arange(n_rows // block_q)[:, None] * block_q
+        c0 = np.arange(n_cols // block_k)[None, :] * block_k
+        some = c0 <= r0 + block_q - 1 + self.offset
+        every = c0 + block_k - 1 <= r0 + self.offset
+        cls = some.astype(np.int32) + every
+        cls = np.where(r0 + self.offset < 0, np.maximum(cls, 1), cls)
+        return _with_fetch_tables(cls)
 
 
 # -- XLA reference path -------------------------------------------------------
@@ -233,13 +261,30 @@ def _load_heads(ref, block_h, mask=False):
     alone; without, as it is, for operands contracted over rows (p v,
     p^T g, ds^T q, ds k), whose result holds the head in its own 64
     lanes, which is what _store_heads keeps.  Either way the MXU does
-    the passes it did at K = N = 64 (it is 128 deep and wide)."""
+    the passes it did at K = N = 64 (it is 128 deep and wide).
+
+    At any other d = 64 mod 128 (192: latent attention's q/k heads) a
+    head pair is 2 d lanes, whole 128-lane blocks, and the two heads
+    share the middle one: the even head takes the pair's first d + 64
+    lanes, the odd head its last d + 64 — aligned windows that hold the
+    head and 64 lanes of its sibling, zeroed with `mask` as above (192:
+    3 lane blocks a pair, windows of 256)."""
     if ref.shape[0] == block_h:
         return ref[...]
     d = ref.shape[2] // block_h
     if d % 128 == 0:
         return jnp.stack([ref[0, :, h * d:(h + 1) * d]
                           for h in range(block_h)])
+    if d != 64:
+        lane = lax.broadcasted_iota(jnp.int32, (1, d + 64), 1)
+        heads = []
+        for h in range(block_h):
+            start = h // 2 * 2 * d + (h % 2) * (d - 64)
+            x = ref[0, :, start:start + d + 64]
+            if mask:
+                x = jnp.where(lane >= 64 if h % 2 else lane < d, x, 0)
+            heads.append(x)
+        return jnp.stack(heads)
     lane = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
     heads = []
     for h in range(block_h):
@@ -252,7 +297,8 @@ def _load_heads(ref, block_h, mask=False):
 
 def _store_heads(ref, x):
     """Inverse of _load_heads: x is (block_h, rows, lanes); of a head
-    pair's two 128-lane results each head's own 64 lanes are kept."""
+    pair's two results each head's own lanes are kept (the 128-lane
+    block the two windows share: its first 64 from the even head)."""
     if ref.shape[0] == x.shape[0]:
         ref[...] = x.astype(ref.dtype)
         return
@@ -262,6 +308,16 @@ def _store_heads(ref, x):
             ref[0, :, h * d:(h + 1) * d] = x[h].astype(ref.dtype)
         return
     lane = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    if d != 64:
+        for pair in range(x.shape[0] // 2):
+            base, even, odd = pair * 2 * d, x[2 * pair], x[2 * pair + 1]
+            ref[0, :, base:base + d - 64] = even[:, :d - 64].astype(
+                ref.dtype)
+            ref[0, :, base + d - 64:base + d + 64] = jnp.where(
+                lane < 64, even[:, d - 64:], odd[:, :128]).astype(ref.dtype)
+            ref[0, :, base + d + 64:base + 2 * d] = odd[:, 128:].astype(
+                ref.dtype)
+        return
     for pair in range(x.shape[0] // 2):
         ref[0, :, pair * 128:(pair + 1) * 128] = jnp.where(
             lane < 64, x[2 * pair], x[2 * pair + 1]).astype(ref.dtype)
@@ -317,15 +373,21 @@ def _causal_rows(iq, ik, block_q, block_k, reps, causal_offset):
     return (q_idx + causal_offset >= k_idx)[None]
 
 
-def _mask_scores(s, iq, ik, codes, *, block_h, block_q, block_k, causal,
-                 causal_offset, grouped):
+def _mask_scores(s, iq, ik, codes, full, *, block_h, block_q, block_k,
+                 causal, causal_offset, grouped):
     """The score tile `s` with what the step's masks hide set to
     DEFAULT_MASK_VALUE: causal (query i attends keys <= i +
     causal_offset, offset = sk - sq, matching the XLA path's
-    jnp.tril(..., k=sk - sq)) and the block mask's codes.  `codes` is
-    None where there is no block mask, and on a tile the mask's table
-    classes full (`_by_class`): the select would return `s` itself."""
+    jnp.tril(..., k=sk - sq)) and the block mask's codes (`codes` None:
+    no block mask).  `full`: the tile's table classes it full
+    (`_by_class`) — the select of the mask the table is of (the block
+    mask's where there is one, else the causal one's) would return `s`
+    itself, and is left out."""
     reps = block_h if grouped else 1
+    if full and codes is None:
+        causal = False
+    if full:
+        codes = None
     if causal and grouped:
         s = jnp.where(_causal_rows(iq, ik, block_q, block_k, reps,
                                    causal_offset), s, DEFAULT_MASK_VALUE)
@@ -341,12 +403,13 @@ def _mask_scores(s, iq, ik, codes, *, block_h, block_q, block_k, causal,
     return s
 
 
-def _split_refs(refs, masked, n_in):
-    """The kernels' operands: with a block mask the tile-class table
-    leads (scalar prefetch, beside the fetch table only the index maps
-    read) and the four code blocks follow the `n_in` inputs."""
+def _split_refs(refs, tabled, masked, n_in):
+    """The kernels' operands: with a tile-class table (`tabled`: a
+    block mask's or a causal mask's) it leads (scalar prefetch, beside
+    the fetch table only the index maps read); with a block mask
+    (`masked`) the four code blocks follow the `n_in` inputs."""
     cls = None
-    if masked:
+    if tabled:
         cls, _, *refs = refs
     ins, rest = refs[:n_in], refs[n_in:]
     codes = None
@@ -355,22 +418,22 @@ def _split_refs(refs, masked, n_in):
     return cls, ins, codes, rest
 
 
-def _by_class(tile, cls_ref, iq, ik, nk, codes, unmask_full=True):
-    """Run the tile body `tile(codes)` with the masking that can change
-    the tile: all of it where there is no block mask (`cls_ref` None);
-    else by the class of tile (iq, ik) in the (nq, nk) table of
-    `BlockDiffusionMask.tiles` — a dead tile is skipped, not computed
-    and masked; a partial one gets the code mask; a full one runs the
-    same body without it (`unmask_full` False: with it, as a partial
-    one)."""
+def _by_class(tile, cls_ref, iq, ik, nk, unmask_full=True):
+    """Run the tile body `tile(full)` with the masking that can change
+    the tile: all of it where there is no table (`cls_ref` None); else
+    by the class of tile (iq, ik) in the (nq, nk) table of
+    `BlockDiffusionMask.tiles` / `_CausalTiles.tiles` — a dead tile is
+    skipped, not computed and masked; a partial one gets the table's
+    mask (`full` False); a full one runs the same body without it
+    (`unmask_full` False: with it, as a partial one)."""
     if cls_ref is None:
-        tile(codes)
+        tile(False)
         return
     cls = cls_ref[iq * nk + ik]
     pl.when(cls == 1 if unmask_full else cls != 0)(
-        functools.partial(tile, codes))
+        functools.partial(tile, False))
     if unmask_full:
-        pl.when(cls == 2)(functools.partial(tile, None))
+        pl.when(cls == 2)(functools.partial(tile, True))
 
 
 # A packed step holds up to 4 heads' (512, 512) f32 score tiles and
@@ -390,8 +453,16 @@ def _layout(q, k, kbias, heads):
     packed = heads > 1 and q.shape[0] == kbias.shape[0]
     bh, d = (q.shape[0] * heads, q.shape[2] // heads) if packed \
         else (q.shape[0], q.shape[2])
-    lanes = max(d, 128) if packed else d
+    lanes = round_up(d, 128) if packed else d
     return bh, q.shape[1], k.shape[1], d, packed, lanes
+
+
+def _value_width(v, packed, kv_heads):
+    """(Dv, lanes) of the value heads, whose width need not be the
+    query/key heads' (latent attention: q/k heads of 192 over v heads
+    of 128); o, g and dV are value-shaped."""
+    dv = v.shape[2] // kv_heads if packed else v.shape[2]
+    return dv, round_up(dv, 128) if packed else dv
 
 
 def _heads_spec(packed, heads, block_h, rows, d, seq_axis, fetch=None):
@@ -434,10 +505,10 @@ def _pallas_call(kernel, grid, in_specs, out_specs, out_shape,
 
 def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
                       causal_offset, dropout_p, grouped=False,
-                      masked=False, biased=True):
+                      tabled=False, masked=False, biased=True):
     cls_ref, (seed_ref, q_ref, k_ref, v_ref, kbias_ref), codes, \
         (o_ref, lse_ref, m_scr, l_scr, acc_scr) = _split_refs(
-            refs, masked, 5)
+            refs, tabled, masked, 5)
     b = pl.program_id(0)
     iq = pl.program_id(1)
     ik = pl.program_id(2)
@@ -449,7 +520,7 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _tile(codes):
+    def _tile(full):
         if grouped:
             q = _load_rows(q_ref, block_h)   # (1, block_h * block_q, d)
             k = _load_heads(k_ref, 1)        # (1, block_k, d)
@@ -465,8 +536,8 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
             preferred_element_type=jnp.float32) * scale  # (bh, bq, bk)
         if biased:
             s = s + kbias_ref[...]  # additive key bias (1, 1, block_k)
-        s = _mask_scores(s, iq, ik, codes, block_h=block_h, block_q=block_q,
-                         block_k=block_k, causal=causal,
+        s = _mask_scores(s, iq, ik, codes, full, block_h=block_h,
+                         block_q=block_q, block_k=block_k, causal=causal,
                          causal_offset=causal_offset, grouped=grouped)
 
         m_prev = m_scr[:]          # (block_h, block_q, 1)
@@ -497,7 +568,7 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
     # full tiles keep the select here: on the v5e this body is 8% slower
     # without it (20.05 against 18.48 ms a call at the SDAR cell's shapes,
     # PERF.md §6, PR 31), where the backward bodies are 1-3% faster
-    _by_class(_tile, cls_ref, iq, ik, nk, codes, unmask_full=False)
+    _by_class(_tile, cls_ref, iq, ik, nk, unmask_full=False)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -512,18 +583,31 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
             lse_ref[...] = lse  # (block_h, block_q, 1)
 
 
-def _mask_operands(block_mask, sq, sk, block_q, block_k, order):
-    """What a block mask adds to a call on the (n, i, j) grid whose
-    axes are (q, k) tiles in `order` "qk", (k, (head block, q)) tiles
-    in "kq": `(tables, code arrays, code specs, fetch of the operand of
-    the inner axis, index of the inner axis' q or k tile)`."""
-    cls, k_fetch, q_fetch = block_mask.tiles(sq, sk, block_q, block_k)
-    r_le, r_eq, c_le, c_eq = block_mask.codes(sq, sk)
+def _tiler(block_mask, is_causal, causal_offset):
+    """Whose tile-class table a call's kernels go by: the block mask's,
+    else a causal mask's, else None (every tile runs)."""
+    if block_mask is not None:
+        return block_mask
+    return _CausalTiles(causal_offset) if is_causal else None
+
+
+def _mask_operands(tiler, block_mask, sq, sk, block_q, block_k, order):
+    """What a tile-class table adds to a call on the (n, i, j) grid
+    whose axes are (q, k) tiles in `order` "qk", (k, (head block, q))
+    tiles in "kq": `(tables, code arrays, code specs, fetch of the
+    operand of the inner axis)` — the code arrays and specs a block
+    mask's alone."""
+    if tiler is None:
+        return (), [], [], None
+    cls, k_fetch, q_fetch = tiler.tiles(sq, sk, block_q, block_k)
     nq, nk = cls.shape
-    arrays = [jnp.asarray(r_le).reshape(1, sq, 1),
-              jnp.asarray(r_eq).reshape(1, sq, 1),
-              jnp.asarray(c_le).reshape(1, 1, sk),
-              jnp.asarray(c_eq).reshape(1, 1, sk)]
+    arrays = []
+    if block_mask is not None:
+        r_le, r_eq, c_le, c_eq = block_mask.codes(sq, sk)
+        arrays = [jnp.asarray(r_le).reshape(1, sq, 1),
+                  jnp.asarray(r_eq).reshape(1, sq, 1),
+                  jnp.asarray(c_le).reshape(1, 1, sk),
+                  jnp.asarray(c_eq).reshape(1, 1, sk)]
     if order == "qk":
         tables = (jnp.asarray(cls.reshape(-1)),
                   jnp.asarray(k_fetch.reshape(-1)))
@@ -537,7 +621,7 @@ def _mask_operands(block_mask, sq, sk, block_q, block_k, order):
         row = lambda n, i, j, *t: (0, j % nq, 0)
         col = lambda n, i, j, *t: (0, 0, i)
     specs = [pl.BlockSpec((1, block_q, 1), row)] * 2 \
-        + [pl.BlockSpec((1, 1, block_k), col)] * 2
+        + [pl.BlockSpec((1, 1, block_k), col)] * 2 if arrays else []
     return tables, arrays, specs, fetch
 
 
@@ -576,6 +660,7 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
     rank-2 row blocks (1, block_k) were illegal on real TPU (BENCH_r02
     failure)."""
     bh, sq, sk, d, packed, lanes = _layout(q, k, kbias, heads)
+    dv, v_lanes = _value_width(v, packed, kv_heads or heads)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     assert sq % block_q == 0 and sk % block_k == 0, (sq, sk)
     assert bh % block_h == 0 and heads % block_h == 0, (bh, heads, block_h)
@@ -585,20 +670,22 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
 
     if causal_offset is None:
         causal_offset = sk - sq
+    tiler = _tiler(block_mask, is_causal, causal_offset)
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, block_h=block_h, block_q=block_q,
         block_k=block_k, causal=is_causal, causal_offset=causal_offset,
-        dropout_p=dropout_p, grouped=grouped, masked=masked, biased=biased)
+        dropout_p=dropout_p, grouped=grouped, tabled=tiler is not None,
+        masked=masked, biased=biased)
     tables, codes, code_specs, fetch = _mask_operands(
-        block_mask, sq, sk, block_q, block_k, "qk") if masked \
-        else ((), [], [], None)
+        tiler, block_mask, sq, sk, block_q, block_k, "qk")
     q_spec = _heads_spec(packed, heads, block_h, block_q, d, 1)
+    o_spec = _heads_spec(packed, heads, block_h, block_q, dv, 1)
     if grouped:
-        assert packed and d % 128 == 0 and dropout_p == 0.0
+        assert packed and d % 128 == 0 and dv == d and dropout_p == 0.0
         group = heads // kv_heads
         assert group % block_h == 0, (group, block_h)
         per_b = heads // block_h
-        k_spec = pl.BlockSpec(
+        k_spec = v_spec = pl.BlockSpec(
             (1, block_k, d), lambda n, i, j, *t: (
                 n // per_b, fetch(i, j, t) if fetch else j,
                 (n % per_b) * block_h // group))
@@ -608,27 +695,29 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
                    pltpu.VMEM((1, rows, d), jnp.float32)]
     else:
         k_spec = _heads_spec(packed, heads, block_h, block_k, d, 2, fetch)
+        v_spec = _heads_spec(packed, heads, block_h, block_k, dv, 2, fetch)
         scratch = [pltpu.VMEM((block_h, block_q, 1), jnp.float32),
                    pltpu.VMEM((block_h, block_q, 1), jnp.float32),
-                   pltpu.VMEM((block_h, block_q, lanes), jnp.float32)]
+                   pltpu.VMEM((block_h, block_q, v_lanes), jnp.float32)]
 
     out, lse = _pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            q_spec, k_spec, k_spec,
+            q_spec, k_spec, v_spec,
             pl.BlockSpec((1, 1, block_k),
                          lambda b, iq, ik, *t, h=heads, bh_=block_h:
                          ((b * bh_) // h, 0, ik)),
         ] + code_specs,
         out_specs=[
-            q_spec,
+            o_spec,
             pl.BlockSpec((block_h, block_q, 1),
                          lambda b, iq, ik, *t: (b, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(q.shape[:2] + (q.shape[2] // d * dv,),
+                                 q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         scratch_shapes=scratch,
@@ -650,10 +739,11 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
 
 def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
                           causal_offset, dropout_p, grouped=False,
-                          masked=False, biased=True, q_tiles=None):
+                          tabled=False, masked=False, biased=True,
+                          q_tiles=None):
     cls_ref, (seed_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref,
                kbias_ref), codes, (dk_ref, dv_ref, dk_scr, dv_scr) = \
-        _split_refs(refs, masked, 8)
+        _split_refs(refs, tabled, masked, 8)
     b = pl.program_id(0)
     ik = pl.program_id(1)
     iq = pl.program_id(2)
@@ -671,7 +761,7 @@ def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _tile(codes):
+    def _tile(full):
         if grouped:
             q = _load_rows(q_ref, block_h)   # (1, block_h * block_q, d)
             g = _load_rows(g_ref, block_h)
@@ -690,8 +780,8 @@ def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
             preferred_element_type=jnp.float32) * scale
         if biased:
             s = s + kbias_ref[...]
-        s = _mask_scores(s, iq, ik, codes, block_h=block_h, block_q=block_q,
-                         block_k=block_k, causal=causal,
+        s = _mask_scores(s, iq, ik, codes, full, block_h=block_h,
+                         block_q=block_q, block_k=block_k, causal=causal,
                          causal_offset=causal_offset, grouped=grouped)
         p = jnp.exp(s - lse)      # softmax probs, (block_h, bq, bk)
 
@@ -722,7 +812,7 @@ def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
             ds.astype(q.dtype), q, (((1,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
-    _by_class(_tile, cls_ref, iq, ik, nk, codes)
+    _by_class(_tile, cls_ref, iq, ik, nk)
 
     @pl.when(last)
     def _finalize():
@@ -732,10 +822,10 @@ def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
 
 def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
                          causal_offset, dropout_p, grouped=False,
-                         masked=False, biased=True):
+                         tabled=False, masked=False, biased=True):
     cls_ref, (seed_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref,
                kbias_ref), codes, (dq_ref, dq_scr) = _split_refs(
-        refs, masked, 8)
+        refs, tabled, masked, 8)
     b = pl.program_id(0)
     iq = pl.program_id(1)
     ik = pl.program_id(2)
@@ -745,7 +835,7 @@ def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _tile(codes):
+    def _tile(full):
         if grouped:
             q = _load_rows(q_ref, block_h)
             g = _load_rows(g_ref, block_h)
@@ -764,8 +854,8 @@ def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
             preferred_element_type=jnp.float32) * scale
         if biased:
             s = s + kbias_ref[...]
-        s = _mask_scores(s, iq, ik, codes, block_h=block_h, block_q=block_q,
-                         block_k=block_k, causal=causal,
+        s = _mask_scores(s, iq, ik, codes, full, block_h=block_h,
+                         block_q=block_q, block_k=block_k, causal=causal,
                          causal_offset=causal_offset, grouped=grouped)
         p = jnp.exp(s - lse)
 
@@ -784,7 +874,7 @@ def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
             ds.astype(k.dtype), k, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
-    _by_class(_tile, cls_ref, iq, ik, nk, codes)
+    _by_class(_tile, cls_ref, iq, ik, nk)
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -804,6 +894,7 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
                     causal_offset=None, kv_heads=None, block_mask=None,
                     biased=True):
     bh, sq, sk, d, packed, lanes = _layout(q, k, kbias, heads)
+    dv, v_lanes = _value_width(v, packed, kv_heads or heads)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     assert bh % block_h == 0 and heads % block_h == 0, (bh, heads, block_h)
     grouped = kv_heads is not None and kv_heads != heads
@@ -813,8 +904,8 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
         # per-head sums of a (B, Sq, H*D) product: a reduce over a
         # reshape to (..., H, D) costs an f32 relayout of the product;
         # the MXU sums each head's D lanes in place instead
-        heads_of = jnp.repeat(jnp.eye(heads, dtype=jnp.float32), d, axis=0)
-        delta = jnp.dot(go.reshape(-1, heads * d), heads_of,
+        heads_of = jnp.repeat(jnp.eye(heads, dtype=jnp.float32), dv, axis=0)
+        delta = jnp.dot(go.reshape(-1, heads * dv), heads_of,
                         precision=lax.Precision.HIGH)
         delta = jnp.transpose(delta.reshape(-1, sq, heads),
                               (0, 2, 1)).reshape(bh, sq, 1)
@@ -822,19 +913,20 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
         delta = jnp.sum(go, axis=-1, keepdims=True)  # (BH, Sq, 1)
     if causal_offset is None:
         causal_offset = sk - sq
+    tiler = _tiler(block_mask, is_causal, causal_offset)
+    tabled = tiler is not None
     kw = dict(scale=scale, block_h=block_h, block_q=block_q,
               block_k=block_k, causal=is_causal,
               causal_offset=causal_offset, dropout_p=dropout_p,
-              grouped=grouped, masked=masked, biased=biased)
+              grouped=grouped, tabled=tabled, masked=masked, biased=biased)
     nq, nk = sq // block_q, sk // block_k
     t_qk, codes, specs_qk, fetch_k = _mask_operands(
-        block_mask, sq, sk, block_q, block_k, "qk") if masked \
-        else ((), [], [], None)
+        tiler, block_mask, sq, sk, block_q, block_k, "qk")
     t_kq, _, specs_kq, fetch_q = _mask_operands(
-        block_mask, sq, sk, block_q, block_k, "kq") if masked \
-        else ((), [], [], None)
+        tiler, block_mask, sq, sk, block_q, block_k, "kq")
 
     q_spec = _heads_spec(packed, heads, block_h, block_q, d, 1)
+    g_spec = _heads_spec(packed, heads, block_h, block_q, dv, 1)
     row_spec = pl.BlockSpec((block_h, block_q, 1),
                             lambda b, i, j, *t: (b, i, 0))
     kb_spec = pl.BlockSpec((1, 1, block_k),
@@ -842,20 +934,20 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
                            ((b * bh_) // h, 0, j))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     if grouped:
-        assert packed and d % 128 == 0 and dropout_p == 0.0
+        assert packed and d % 128 == 0 and dv == d and dropout_p == 0.0
         group = heads // kv_heads
         assert group % block_h == 0, (group, block_h)
         per_b, per_g = heads // block_h, group // block_h
         rows = block_h * block_q
-        k_spec = pl.BlockSpec(
+        k_spec = v_spec = pl.BlockSpec(
             (1, block_k, d), lambda n, i, j, *t: (
-                n // per_b, fetch_k(i, j, t) if masked else j,
+                n // per_b, fetch_k(i, j, t) if tabled else j,
                 (n % per_b) * block_h // group))
         # the dkv grid: (batch x kv head, k tile, head block x q tile)
         dkv_grid = (bh // group, nk, per_g * nq)
-        q_tile = (lambda i, j, t: fetch_q(i, j, t)) if masked \
+        q_tile = (lambda i, j, t: fetch_q(i, j, t)) if tabled \
             else (lambda i, j, t: j % nq)
-        q_spec_t = pl.BlockSpec(
+        q_spec_t = g_spec_t = pl.BlockSpec(
             (1, block_q, block_h * d), lambda n, i, j, *t: (
                 n // kv_heads, q_tile(i, j, t),
                 (n % kv_heads) * per_g + j // nq))
@@ -863,7 +955,7 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
             (block_h, block_q, 1), lambda n, i, j, *t: (
                 (n // kv_heads) * per_b + (n % kv_heads) * per_g + j // nq,
                 q_tile(i, j, t), 0))
-        k_spec_t = pl.BlockSpec(
+        k_spec_t = v_spec_t = pl.BlockSpec(
             (1, block_k, d),
             lambda n, i, j, *t: (n // kv_heads, i, n % kv_heads))
         kb_spec_t = pl.BlockSpec(
@@ -874,30 +966,36 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
     else:
         k_spec = _heads_spec(packed, heads, block_h, block_k, d, 2,
                              fetch_k)
+        v_spec = _heads_spec(packed, heads, block_h, block_k, dv, 2,
+                             fetch_k)
         # dkv grid iterates (bh, ik, iq): swap index maps for q-side
         # inputs
         dkv_grid = (bh // block_h, nk, nq)
         q_spec_t = _heads_spec(packed, heads, block_h, block_q, d, 2,
                                fetch_q)
+        g_spec_t = _heads_spec(packed, heads, block_h, block_q, dv, 2,
+                               fetch_q)
         row_spec_t = pl.BlockSpec(
             (block_h, block_q, 1), lambda b, i, j, *t: (
-                b, fetch_q(i, j, t) if masked else j, 0))
+                b, fetch_q(i, j, t) if tabled else j, 0))
         k_spec_t = _heads_spec(packed, heads, block_h, block_k, d, 1)
+        v_spec_t = _heads_spec(packed, heads, block_h, block_k, dv, 1)
         kb_spec_t = pl.BlockSpec((1, 1, block_k),
                                  lambda b, i, j, *t, h=heads, bh_=block_h:
                                  ((b * bh_) // h, 0, i))
-        dkv_scratch = [pltpu.VMEM((block_h, block_k, lanes),
-                                  jnp.float32)] * 2
+        dkv_scratch = [
+            pltpu.VMEM((block_h, block_k, lanes), jnp.float32),
+            pltpu.VMEM((block_h, block_k, v_lanes), jnp.float32)]
         dq_scratch = [pltpu.VMEM((block_h, block_q, lanes), jnp.float32)]
         vmem = _PACKED_VMEM_LIMIT if packed else None
     params = _compiler_params(vmem_limit=vmem)
 
-    dk, dv = _pallas_call(
+    dk, dv_out = _pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, q_tiles=nq, **kw),
         grid=dkv_grid,
-        in_specs=[smem, q_spec_t, q_spec_t, row_spec_t, row_spec_t,
-                  k_spec_t, k_spec_t, kb_spec_t] + specs_kq,
-        out_specs=[k_spec_t, k_spec_t],
+        in_specs=[smem, q_spec_t, g_spec_t, row_spec_t, row_spec_t,
+                  k_spec_t, v_spec_t, kb_spec_t] + specs_kq,
+        out_specs=[k_spec_t, v_spec_t],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=dkv_scratch,
@@ -910,8 +1008,8 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
     dq = _pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **kw),
         grid=(bh // block_h, nq, nk),
-        in_specs=[smem, q_spec, q_spec, row_spec, row_spec,
-                  k_spec, k_spec, kb_spec] + specs_qk,
+        in_specs=[smem, q_spec, g_spec, row_spec, row_spec,
+                  k_spec, v_spec, kb_spec] + specs_qk,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=dq_scratch,
@@ -920,7 +1018,7 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
         interpret=interpret,
         name="flash_bwd_dq",
     )(seed, q, g, lse, delta, k, v, kbias, *codes)
-    return dq, dk, dv
+    return dq, dk, dv_out
 
 
 # -- custom VJP over the kernels ----------------------------------------------
@@ -1006,9 +1104,10 @@ def _pick_blocks(sq, sk, d, block_q=None, block_k=None,
 def _packs(heads, d):
     """Whether the kernels can take q/k/v as the projections write
     them, (B, S, H*D): a grid step's heads must be whole 128-lane
-    blocks, a head pair at D = 64, single heads at D a multiple of
-    128.  Every other shape keeps the merged (B*H, S, D) operands."""
-    return d % 128 == 0 or (d == 64 and heads % 2 == 0)
+    blocks, a head pair at D = 64 mod 128 (64; 192, three blocks a
+    pair), single heads at D a multiple of 128.  Every other shape
+    keeps the merged (B*H, S, D) operands."""
+    return d % 128 == 0 or (d % 128 == 64 and heads % 2 == 0)
 
 
 def _block_h_ladder(heads, lane_d=None, max_h=8):
@@ -1072,12 +1171,13 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
     `flash_block_mask_total` instances); no dense mask exists.
     """
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[3]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     kv_heads = k.shape[2]
-    packed = _packs(h, d)
+    packed = _packs(h, d) and _packs(h, dv)
     grouped = kv_heads != h
-    if grouped and not (packed and d % 128 == 0 and dropout_p == 0.0):
+    if grouped and not (packed and d % 128 == 0 and dv == d
+                        and dropout_p == 0.0):
         k = jnp.repeat(k, h // kv_heads, axis=2)
         v = jnp.repeat(v, h // kv_heads, axis=2)
         kv_heads, grouped = h, False
@@ -1095,13 +1195,13 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
             block_q //= 2
     sq_p = round_up(sq, block_q)
     sk_p = round_up(sk, block_k)
-    d_p = d if packed else round_up(d, 64)
+    d_p, dv_p = (d, dv) if packed else (round_up(d, 64), round_up(dv, 64))
 
     if packed:
-        merge = lambda x, s: x.reshape(b, s, h * d)
+        merge = lambda x, s: x.reshape(b, s, -1)
     else:
         merge = lambda x, s: jnp.transpose(x, (0, 2, 1, 3)).reshape(
-            b * h, s, d)
+            b * h, s, -1)
     qm = merge(q, sq)
     if grouped:
         km, vm = (x.reshape(b, sk, kv_heads * d) for x in (k, v))
@@ -1111,7 +1211,8 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
         qm = jnp.pad(qm, ((0, 0), (0, sq_p - sq), (0, d_p - d)))
     if sk_p != sk or d_p != d:
         km = jnp.pad(km, ((0, 0), (0, sk_p - sk), (0, d_p - d)))
-        vm = jnp.pad(vm, ((0, 0), (0, sk_p - sk), (0, d_p - d)))
+    if sk_p != sk or dv_p != dv:
+        vm = jnp.pad(vm, ((0, 0), (0, sk_p - sk), (0, dv_p - dv)))
 
     bias = jnp.zeros((b, sk_p), jnp.float32) if key_bias is None \
         else jnp.pad(lax.stop_gradient(key_bias).astype(jnp.float32),
@@ -1153,7 +1254,8 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
                                 cand, block_q, block_k, sk - sq,
                                 final_rung=(cand == ladder[-1]),
                                 packed=packed, kv_heads=kv_heads,
-                                block_mask=block_mask, biased=biased):
+                                block_mask=block_mask, biased=biased,
+                                v_dim=dv_p):
                     block_h = cand
                     break
         if block_h is None:
@@ -1174,21 +1276,25 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
 
     from ...profiler import stat_add
 
-    if block_mask is not None:
-        cls = block_mask.tiles(sq_p, sk_p, block_q, block_k)[0]
-        stat_add("flash_block_mask_total")
+    tiler = _tiler(block_mask, is_causal, sk - sq)
+    if tiler is not None:
+        cls = tiler.tiles(sq_p, sk_p, block_q, block_k)[0]
+        if block_mask is not None:
+            stat_add("flash_block_mask_total")
         stat_add("flash_tiles_full_total", int((cls == 2).sum()))
         stat_add("flash_tiles_live_total", int((cls != 0).sum()))
         stat_add("flash_tiles_total", cls.size)
+    if dv != d:
+        stat_add("flash_split_value_total")
     out = _flash_attention(qm, km, vm, bias, seed_f, h, is_causal, scale,
                            float(dropout_p), interpret, sk - sq,
                            block_h, block_q, block_k, kv_heads, block_mask,
                            biased)
     if packed:
         stat_add("flash_packed_layout_total")
-        return out[:, :sq].reshape(b, sq, h, d)
-    out = out[:, :sq, :d]
-    return jnp.transpose(out.reshape(b, h, sq, d), (0, 2, 1, 3))
+        return out[:, :sq].reshape(b, sq, h, dv)
+    out = out[:, :sq, :dv]
+    return jnp.transpose(out.reshape(b, h, sq, dv), (0, 2, 1, 3))
 
 
 _EXACT_PROBE_CACHE = {}
@@ -1197,7 +1303,7 @@ _EXACT_PROBE_CACHE = {}
 def _probe_exact(q_shape, k_shape, heads, is_causal, dropout_p, dtype,
                  block_h, block_q, block_k, causal_offset,
                  final_rung=True, packed=False, kv_heads=None,
-                 block_mask=None, biased=True):
+                 block_mask=None, biased=True, v_dim=None):
     """Compile (never run) the exact kernel instances flash_attention is
     about to stage, once per configuration.  q_shape / k_shape are the
     padded (B*H, S, D) whichever the operand layout; `packed` probes
@@ -1208,25 +1314,28 @@ def _probe_exact(q_shape, k_shape, heads, is_causal, dropout_p, dtype,
     refusal is routine and stays silent and uncounted."""
     key = (q_shape, k_shape, heads, is_causal, dropout_p,
            jnp.dtype(dtype).name, block_h, block_q, block_k,
-           causal_offset, packed, kv_heads, block_mask, biased)
+           causal_offset, packed, kv_heads, block_mask, biased, v_dim)
     if key not in _EXACT_PROBE_CACHE:
         def compile_probe():
             bh, sq, d = q_shape
             sk = k_shape[1]
-            fold = (lambda s, n=heads: (bh // heads, s, n * d)) if packed \
-                else (lambda s, n=heads: (bh, s, d))
-            x = probe_struct(fold(sq), dtype)
-            kv = probe_struct(fold(sk, kv_heads or heads), dtype)
+            dv = v_dim or d
+            fold = (lambda s, w, n=heads: (bh // heads, s, n * w)) \
+                if packed else (lambda s, w, n=heads: (bh, s, w))
+            x = probe_struct(fold(sq, d), dtype)
+            o = probe_struct(fold(sq, dv), dtype)
+            kk = probe_struct(fold(sk, d, kv_heads or heads), dtype)
+            vv = probe_struct(fold(sk, dv, kv_heads or heads), dtype)
             kb = probe_struct((bh // heads, 1, sk), jnp.float32)
             seed = probe_struct((1,), jnp.int32)
             kw = dict(is_causal=is_causal, dropout_p=dropout_p,
                       block_h=block_h, block_q=block_q, block_k=block_k,
                       causal_offset=causal_offset, kv_heads=kv_heads,
                       block_mask=block_mask, biased=biased)
-            _flash_forward.lower(x, kv, kv, kb, seed, heads,
+            _flash_forward.lower(x, kk, vv, kb, seed, heads,
                                  **kw).compile()
             lse = probe_struct((bh, sq, 1), jnp.float32)
-            _flash_backward.lower(x, kv, kv, kb, seed, x, lse, x, heads,
+            _flash_backward.lower(x, kk, vv, kb, seed, o, lse, o, heads,
                                   **kw).compile()
 
         _try_compile(

@@ -248,17 +248,27 @@ def init_routed_moe_params(rng, n_routed, d_model, d_ff, held=None,
     return {k: jnp.asarray(v, dtype or jnp.float32) for k, v in p.items()}
 
 
-def route_top_k(x, wr, top_k, renormalize=True):
+def route_top_k(x, wr, top_k, renormalize=True, scoring="softmax",
+                bias=None, scale=1.0):
     """x (T, H), wr (H, n_routed) -> (experts (T, k) int32, weights
-    (T, k) float32): softmax over all n_routed and top-k in float32,
+    (T, k) float32): scores over all n_routed and top-k in float32,
     the k weights divided by their sum where `renormalize`.
 
+    `scoring` "softmax": the scores are the softmax of the logits.
+    "sigmoid" (DeepSeek-V3 §2.1.2): each expert's score is the sigmoid
+    of its logit; the k experts are the top-k of `score + bias` —
+    `bias` (n_routed,) the selection bias the training step moves
+    towards a balanced load, a constant here: no gradient reaches it —
+    while the weights are the SCORES at those k (the bias chooses and
+    never weighs), renormalised, times `scale` (the routed scaling
+    factor).
+
     The choice is made once: `experts` carries the visit plan's name
-    ("moe_plan"), and the weights are read from the probabilities AT
-    those ids, so a layer recomputed under a policy that keeps the plan
+    ("moe_plan"), and the weights are read from the scores AT those
+    ids, so a layer recomputed under a policy that keeps the plan
     differentiates the router at the forward pass's choice — a second
-    top-k over recomputed probabilities may order near-ties otherwise,
-    and the kept plan's visits would meet another expert's weight."""
+    top-k over recomputed scores may order near-ties otherwise, and the
+    kept plan's visits would meet another expert's weight."""
     import jax
     import jax.numpy as jnp
     from jax.ad_checkpoint import checkpoint_name
@@ -266,9 +276,21 @@ def route_top_k(x, wr, top_k, renormalize=True):
     with jax.named_scope("router"):
         logits = jnp.dot(x, wr.astype(x.dtype),
                          preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
+        if scoring == "softmax":
+            probs = choose_by = jax.nn.softmax(logits, axis=-1)
+        elif scoring == "sigmoid":
+            from ..profiler import stat_add
+
+            stat_add("moe_sigmoid_router_total")
+            probs = choose_by = jax.nn.sigmoid(logits)
+            if bias is not None:
+                choose_by = probs + jax.lax.stop_gradient(
+                    bias.astype(jnp.float32))
+        else:
+            raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
         experts = checkpoint_name(
-            jax.lax.top_k(probs, top_k)[1].astype(jnp.int32), "moe_plan")
+            jax.lax.top_k(choose_by, top_k)[1].astype(jnp.int32),
+            "moe_plan")
         # compares and sums, forward and backward: no T*k-sized gather
         # or scatter-add
         chosen = experts[:, :, None] == jnp.arange(wr.shape[1],
@@ -276,7 +298,32 @@ def route_top_k(x, wr, top_k, renormalize=True):
         weights = jnp.sum(jnp.where(chosen, probs[:, None, :], 0), axis=-1)
         if renormalize:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        if scale != 1.0:
+            weights = weights * scale
     return experts, weights
+
+
+def router_load(experts, n_routed):
+    """(n_routed,) int32: the rows each of ALL the router's outputs was
+    chosen by, held here or not — what the selection bias's update
+    reads.  Compares and sums, as `_rows_per_expert`."""
+    import jax
+
+    with jax.named_scope("router"):
+        return jax.lax.stop_gradient(
+            _rows_per_expert(experts.reshape(-1), n_routed))
+
+
+def update_selection_bias(bias, load, rate):
+    """DeepSeek-V3's auxiliary-loss-free balancing, one step: an expert
+    that got fewer rows than the mean becomes likelier to be chosen, one
+    that got more less so — `bias + rate * sign(mean(load) - load)`,
+    `load` (..., n_routed) the counts `router_load` gives."""
+    import jax.numpy as jnp
+
+    load = load.astype(jnp.float32)
+    return bias + rate * jnp.sign(
+        jnp.mean(load, axis=-1, keepdims=True) - load).astype(bias.dtype)
 
 
 def _chunk_ffn(xs, eid, w, wg, wu, wd):
@@ -474,12 +521,15 @@ def default_chunk(visits, held_share):
 
 
 def routed_moe_local(params, x, top_k, held=None, ep_axis=None,
-                     renormalize=True, chunk=None, routing=None):
+                     renormalize=True, chunk=None, routing=None,
+                     scoring="softmax", scale=1.0):
     """The routed expert layer on LOCAL rows x (T, H) -> (out (T, H),
     stats, experts (T, k) the router chose).
 
     params: `wr` (H, n_routed) and the held experts' `wg`, `wu`
-    (count, H, F), `wd` (count, F, H).  `held = (first, count)` says
+    (count, H, F), `wd` (count, F, H); with `scoring` "sigmoid"
+    optionally `br` (n_routed,), the selection bias (`route_top_k`;
+    `scale`: the routed scaling factor).  `held = (first, count)` says
     which of the n_routed experts those are (default: all); the result
     is the part of the layer's output the held experts give, weighted
     by the w_i normalised over all k chosen.  With `ep_axis` (inside
@@ -503,7 +553,8 @@ def routed_moe_local(params, x, top_k, held=None, ep_axis=None,
     n_routed = params["wr"].shape[1]
     count = params["wg"].shape[0]
     experts, weights = routing if routing is not None else route_top_k(
-        x, params["wr"], top_k, renormalize)
+        x, params["wr"], top_k, renormalize, scoring, params.get("br"),
+        scale)
     wg, wu, wd = (params[k].astype(x.dtype) for k in ("wg", "wu", "wd"))
     if chunk is None:
         chunk = default_chunk(t * top_k, count / n_routed)

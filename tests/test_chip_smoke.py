@@ -57,6 +57,19 @@ class TestPhasesTiny:
         # off the chip attention takes the XLA path: no kernel, no tile
         assert out["flash_tiles_full_total"] == 0
 
+    def test_joyai_flash_step(self):
+        from paddle_tpu.models import joyai_flash
+
+        out = chip_smoke.joyai_flash_step(
+            joyai_flash.JoyAIFlashConfig.tiny(experts_held=(0, 4),
+                                              recompute=True),
+            batch=2, seq=16, steps=3, platform="cpu")
+        assert out["moe_sigmoid_router_total"] == 3
+        assert out["moe_bias_updates_total"] == 9
+        assert out["moe_router_rows_total"] == 3 * 3 * 32 * 2
+        # off the chip attention takes the XLA path: no kernel, no tile
+        assert out["flash_split_value_total"] == 0
+
     def test_failed_check_raises(self):
         ph = chip_smoke._Phase("x")
         ph.check(True, "fine")

@@ -409,6 +409,45 @@ def test_block_diffusion_mask_grouped_on_tpu():
                                             32 * calls)
 
 
+@pytest.mark.parametrize("heads", [4, 3])
+def test_latent_attention_heads_on_tpu(heads):
+    """Latent attention's head shape (q/k heads of 192 over v heads of
+    128, causal) through Mosaic, against `_xla_attention`: an even head
+    count takes the packed operands (a head pair on three lane blocks),
+    an odd one the merged ones; 1200 rows pad to (512, 512) tiles of
+    which the causal table skips the dead ones and masks the diagonal
+    ones."""
+    q, k = (_rand((2, 1200, heads, 192), s, jnp.bfloat16) for s in (70, 71))
+    v, w = (_rand((2, 1200, heads, 128), s, jnp.bfloat16) for s in (72, 73))
+    before = profiler.get_int_stats()
+
+    def out_and_grads(attention):
+        def f(q, k, v):
+            out, vjp = jax.vjp(
+                lambda q, k, v: attention(q, k, v, is_causal=True), q, k, v)
+            return out, vjp(w)
+        return jax.jit(f)(q, k, v)
+
+    out, got = out_and_grads(flash_attention)
+    ref, want = out_and_grads(_xla_attention)
+    assert out.shape == (2, 1200, heads, 128)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=2e-2, rtol=2e-2)
+    for a, b in zip(got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a / np.abs(b).max(),
+                                   b / np.abs(b).max(), atol=2e-2)
+    after = profiler.get_int_stats()
+    delta = lambda n: after.get(n, 0) - before.get(n, 0)
+    calls = delta("flash_split_value_total")
+    assert calls > 0
+    assert delta("flash_packed_layout_total") == (calls if heads == 4 else 0)
+    assert (delta("flash_tiles_full_total"),
+            delta("flash_tiles_live_total"),
+            delta("flash_tiles_total")) == (3 * calls, 6 * calls, 9 * calls)
+
+
 def test_no_kernel_gave_way():
     """Runs last: nothing above (and no other tpu-marked test before
     it) may have pushed a kernel onto its XLA path."""
