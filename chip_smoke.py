@@ -278,6 +278,15 @@ def _check_all_devices_hold(ph, arr, n, what):
 # phase 2: the functional BERT pretrain step with the flash kernels
 # ---------------------------------------------------------------------------
 
+def _check_pieces(ph, instances):
+    """The forward flash kernel of each of `instances` traced instances
+    walks its grid step's heads one at a time, more than one a step."""
+    pieces = ph.info["flash_fwd_pieces_total"]
+    ph.check(pieces > instances and pieces % instances == 0,
+             f"flash_fwd_pieces_total == {pieces}: the forward body walks "
+             f"{pieces // instances} heads a grid step, one at a time")
+
+
 def bert_base_step(cfg, batch=32, seq=512, n_masked=76, steps=3, *,
                    platform="tpu", mesh_shape=None):
     """`bert.build_pretrain_step(bf16=True)`: dropout 0.1, key-padding
@@ -301,10 +310,10 @@ def bert_base_step(cfg, batch=32, seq=512, n_masked=76, steps=3, *,
         kw = dict(mesh=mesh, dp_axis="dp", mp_axis="mp") if mesh else {}
         step, state = bert.build_pretrain_step(model, bf16=True, **kw)
         t0 = time.perf_counter()
-        packed0 = _stats().get("flash_packed_layout_total", 0)
+        s0 = _stats()
         lowered = step.lower(state, b, lr)
-        ph.info["flash_packed_layout_total"] = _stats().get(
-            "flash_packed_layout_total", 0) - packed0
+        for k in ("flash_packed_layout_total", "flash_fwd_pieces_total"):
+            ph.info[k] = _stats().get(k, 0) - s0.get(k, 0)
         compiled = lowered.compile()
         ph.compile_s += time.perf_counter() - t0
         losses = []
@@ -366,6 +375,7 @@ def bert_base_step(cfg, batch=32, seq=512, n_masked=76, steps=3, *,
                  f"flash_packed_layout_total == {layers}: tracing the "
                  f"step took the (B, S, H*D) operand layout {packed} "
                  "times, once a layer (no head transposes)")
+        _check_pieces(ph, layers)
     return ph.done()
 
 
@@ -564,7 +574,7 @@ def sdar_moe_step(cfg, batch=2, seq=1024, steps=3, *, platform="tpu"):
     t0 = time.perf_counter()
     compiled = step.lower(state, b, lr).compile()
     ph.compile_s = time.perf_counter() - t0
-    for k in plans + tiles:
+    for k in plans + tiles + ("flash_fwd_pieces_total",):
         ph.info[k] = _stats().get(k, 0) - s0.get(k, 0)
     losses, ces = [], []
     for _ in range(steps):
@@ -610,6 +620,11 @@ def sdar_moe_step(cfg, batch=2, seq=1024, steps=3, *, platform="tpu"):
                  f"{calls} masked flash instances: the backward kernels "
                  "run full tiles without the code mask, dead ones are "
                  "skipped")
+        group = cfg.num_attention_heads // cfg.num_key_value_heads
+        ph.check(ph.info["flash_fwd_pieces_total"] == group * calls,
+                 f"flash_fwd_pieces_total == {group} x {calls}: the "
+                 "forward body walks a key/value group's heads one at a "
+                 "time")
     return ph.done()
 
 
@@ -637,7 +652,8 @@ def joyai_flash_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
     lr = jnp.float32(1e-3)
     traced = ("flash_split_value_total", "flash_tiles_full_total",
               "flash_tiles_live_total", "flash_tiles_total",
-              "moe_sigmoid_router_total", "moe_plan_packed_total")
+              "moe_sigmoid_router_total", "moe_plan_packed_total",
+              "flash_fwd_pieces_total")
     ran = ("moe_router_rows_total", "moe_router_rows_max_total",
            "moe_bias_updates_total", "moe_rows_routed_total",
            "moe_rows_held_total", "moe_dropped_total")
@@ -702,6 +718,7 @@ def joyai_flash_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
             f"{attn} flash instances with v narrower than q/k; full / "
             f"live / all tiles == {n * (n - 1) // 2} / "
             f"{n * (n + 1) // 2} / {n * n} in each: dead tiles skipped")
+        _check_pieces(ph, attn)
     return ph.done()
 
 

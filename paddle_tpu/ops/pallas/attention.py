@@ -9,6 +9,19 @@ flash-attention recurrence:
     m_i = max(m_{i-1}, rowmax(S_i));  l_i = e^{m_{i-1}-m_i} l_{i-1} + rowsum(P_i)
     acc_i = e^{m_{i-1}-m_i} acc_{i-1} + P~_i V_i
 
+The forward body (`_flash_fwd_kernel`) runs it a head at a time: a grid
+step holds block_h heads' (block_q, block_k) score tiles, and each
+head's tile goes matmul -> mask -> max -> exp -> sum -> cast -> matmul
+before the next head's starts (`flash_fwd_pieces_total` counts the
+pieces a step), whatever the operand layout.  m and l are kept
+lane-replicated, (block_h, block_q, 128) float32 in scratch, so that
+`s - m`, `acc * alpha` and `acc / l` are whole-vreg operations with no
+lane broadcast of a (rows, 1) column and no one-lane store; scores,
+max, exp, sums and the accumulator are float32, P is cast only as the
+P V operand.  `lse` = m + log l leaves the kernel as the (B*H, Sq, 1)
+float32 column the backward kernels read (with `delta`, of the same
+shape), written once a q tile.
+
 Round-2 upgrades (VERDICT.md "weak" #3, ADVICE #1):
   * key-padding masks run IN-kernel: any mask that is constant across
     query positions and heads becomes an additive key bias (B, Sk)
@@ -248,10 +261,10 @@ def _keep_mask3(seed, bh0, q0, k0, block_h, block_q, block_k, dropout_p):
 
 # -- operand layouts ----------------------------------------------------------
 
-def _load_heads(ref, block_h, mask=False):
-    """A q/k/v-like block as (block_h, rows, lanes), one entry a head.
+def _load_head(ref, h, block_h, mask=False):
+    """Head `h` of a q/k/v-like block of block_h heads, (rows, lanes).
 
-    A merged (block_h, rows, d) block is that already.  A packed
+    A merged (block_h, rows, d) block holds it as entry h.  A packed
     (1, rows, block_h * d) block holds the heads side by side on the
     lane axis: where d is a multiple of 128 a head is an aligned static
     lane slice.  At d = 64 two heads share a 128-lane block and are
@@ -270,46 +283,48 @@ def _load_heads(ref, block_h, mask=False):
     head and 64 lanes of its sibling, zeroed with `mask` as above (192:
     3 lane blocks a pair, windows of 256)."""
     if ref.shape[0] == block_h:
-        return ref[...]
+        return ref[h]
     d = ref.shape[2] // block_h
     if d % 128 == 0:
-        return jnp.stack([ref[0, :, h * d:(h + 1) * d]
-                          for h in range(block_h)])
+        return ref[0, :, h * d:(h + 1) * d]
     if d != 64:
+        start = h // 2 * 2 * d + (h % 2) * (d - 64)
+        x = ref[0, :, start:start + d + 64]
         lane = lax.broadcasted_iota(jnp.int32, (1, d + 64), 1)
-        heads = []
-        for h in range(block_h):
-            start = h // 2 * 2 * d + (h % 2) * (d - 64)
-            x = ref[0, :, start:start + d + 64]
-            if mask:
-                x = jnp.where(lane >= 64 if h % 2 else lane < d, x, 0)
-            heads.append(x)
-        return jnp.stack(heads)
+        return jnp.where(lane >= 64 if h % 2 else lane < d, x, 0) \
+            if mask else x
+    x = ref[0, :, h // 2 * 128:(h // 2 + 1) * 128]
     lane = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
-    heads = []
-    for h in range(block_h):
-        x = ref[0, :, h // 2 * 128:(h // 2 + 1) * 128]
-        if mask:
-            x = jnp.where((lane >= 64) == bool(h % 2), x, 0)
-        heads.append(x)
-    return jnp.stack(heads)
+    return jnp.where((lane >= 64) == bool(h % 2), x, 0) if mask else x
+
+
+def _load_heads(ref, block_h, mask=False):
+    """A q/k/v-like block as (block_h, rows, lanes), one entry a head
+    (`_load_head`): what the backward kernels' head-batched products
+    take."""
+    if ref.shape[0] == block_h:
+        return ref[...]
+    return jnp.stack([_load_head(ref, h, block_h, mask)
+                      for h in range(block_h)])
 
 
 def _store_heads(ref, x):
-    """Inverse of _load_heads: x is (block_h, rows, lanes); of a head
-    pair's two results each head's own lanes are kept (the 128-lane
-    block the two windows share: its first 64 from the even head)."""
-    if ref.shape[0] == x.shape[0]:
-        ref[...] = x.astype(ref.dtype)
+    """Inverse of _load_heads: x is (block_h, rows, lanes), or the
+    block_h heads in a list; of a head pair's two results each head's
+    own lanes are kept (the 128-lane block the two windows share: its
+    first 64 from the even head)."""
+    if ref.shape[0] == len(x):
+        for h in range(len(x)):
+            ref[h] = x[h].astype(ref.dtype)
         return
-    d = ref.shape[2] // x.shape[0]
+    d = ref.shape[2] // len(x)
     if d % 128 == 0:
-        for h in range(x.shape[0]):
+        for h in range(len(x)):
             ref[0, :, h * d:(h + 1) * d] = x[h].astype(ref.dtype)
         return
     lane = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
     if d != 64:
-        for pair in range(x.shape[0] // 2):
+        for pair in range(len(x) // 2):
             base, even, odd = pair * 2 * d, x[2 * pair], x[2 * pair + 1]
             ref[0, :, base:base + d - 64] = even[:, :d - 64].astype(
                 ref.dtype)
@@ -318,7 +333,7 @@ def _store_heads(ref, x):
             ref[0, :, base + d + 64:base + 2 * d] = odd[:, 128:].astype(
                 ref.dtype)
         return
-    for pair in range(x.shape[0] // 2):
+    for pair in range(len(x) // 2):
         ref[0, :, pair * 128:(pair + 1) * 128] = jnp.where(
             lane < 64, x[2 * pair], x[2 * pair + 1]).astype(ref.dtype)
 
@@ -401,6 +416,22 @@ def _mask_scores(s, iq, ik, codes, full, *, block_h, block_q, block_k,
     if codes is not None:
         s = jnp.where(_code_mask(codes, reps), s, DEFAULT_MASK_VALUE)
     return s
+
+
+def _seen_pairs(iq, ik, codes, full, *, block_q, block_k, causal,
+                causal_offset):
+    """`_mask_scores`' masks as one (block_q, block_k) bool, the pairs
+    of tile (iq, ik) a row sees — the same for every head of a step,
+    which the forward kernel walks a head at a time — or None where
+    they hide nothing."""
+    if full and codes is None:
+        causal = False
+    seen = _causal_rows(iq, ik, block_q, block_k, 1, causal_offset)[0] \
+        if causal else None
+    if codes is not None and not full:
+        code = _code_mask(codes, 1)[0]
+        seen = code if seen is None else seen & code
+    return seen
 
 
 def _split_refs(refs, tabled, masked, n_in):
@@ -503,9 +534,27 @@ def _pallas_call(kernel, grid, in_specs, out_specs, out_shape,
 
 # -- Pallas forward kernel ----------------------------------------------------
 
+def _lanes(x, width):
+    """A lane-replicated (rows, 128) row statistic at `width` lanes:
+    whole vregs side by side (the last one cut where a merged head's
+    width is no multiple of 128), no broadcast."""
+    if width > 128:
+        x = jnp.concatenate([x] * -(-width // 128), axis=1)
+    return x[:, :width]
+
+
 def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
                       causal_offset, dropout_p, grouped=False,
                       tabled=False, masked=False, biased=True):
+    """One grid step = block_h heads' (block_q, block_k) score tiles,
+    walked a head at a time: a head's tile goes matmul -> mask -> max ->
+    exp -> sum -> cast -> matmul before the next head's starts, so no
+    float32 temporary spans the step's heads.  The row statistics m and
+    l live lane-replicated, (block_h, block_q, 128): where they meet
+    the scores (`_lanes`) `s - m`, `acc * alpha` and `acc / l` are
+    plain vreg operations, with no one-lane store and no lane broadcast
+    of a (rows, 1) column.  Only `lse` leaves as such a column, once a
+    q tile."""
     cls_ref, (seed_ref, q_ref, k_ref, v_ref, kbias_ref), codes, \
         (o_ref, lse_ref, m_scr, l_scr, acc_scr) = _split_refs(
             refs, tabled, masked, 5)
@@ -513,6 +562,7 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
+    kv_block_h = 1 if grouped else block_h  # heads in the k/v blocks
 
     @pl.when(ik == 0)
     def _init():
@@ -521,66 +571,55 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def _tile(full):
-        if grouped:
-            q = _load_rows(q_ref, block_h)   # (1, block_h * block_q, d)
-            k = _load_heads(k_ref, 1)        # (1, block_k, d)
-        else:
-            q = _load_heads(q_ref, block_h, mask=True)
-            k = _load_heads(k_ref, block_h)  # (block_h, block_k, d)
-        # batched over the head-block dim: one grid step feeds the MXU
-        # block_h (q, k) panels instead of one, amortizing the ~2us
-        # per-grid-step overhead that dominated the (BH, 1, 1) grid
-        # (profiled 0.9 ms/layer fwd vs a 0.13 ms compute floor)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # (bh, bq, bk)
-        if biased:
-            s = s + kbias_ref[...]  # additive key bias (1, 1, block_k)
-        s = _mask_scores(s, iq, ik, codes, full, block_h=block_h,
-                         block_q=block_q, block_k=block_k, causal=causal,
-                         causal_offset=causal_offset, grouped=grouped)
+        # the masks are the same for every head of the step
+        seen = _seen_pairs(iq, ik, codes, full, block_q=block_q,
+                           block_k=block_k, causal=causal,
+                           causal_offset=causal_offset)
+        for h in range(block_h):
+            kv = 0 if grouped else h
+            s = jax.lax.dot_general(
+                _load_head(q_ref, h, block_h, mask=True),
+                _load_head(k_ref, kv, kv_block_h),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (bq, bk)
+            if biased:
+                s = s + kbias_ref[0]     # additive key bias (1, block_k)
+            if seen is not None:
+                s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
+            m_prev = m_scr[h]                                # (bq, 128)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - _lanes(m_new, block_k))
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
+            m_scr[h] = m_new
+            if dropout_p > 0.0:
+                keep = _keep_mask3(seed_ref[0], b * block_h + h,
+                                   iq * block_q, ik * block_k, 1, block_q,
+                                   block_k, dropout_p)[0]
+                p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
+            pv = jax.lax.dot_general(
+                p.astype(v_ref.dtype),
+                _load_head(v_ref, kv, kv_block_h),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_scr[h] = acc_scr[h] * _lanes(alpha, pv.shape[1]) + pv
 
-        m_prev = m_scr[:]          # (block_h, block_q, 1)
-        l_prev = l_scr[:]
-        m_cur = jnp.max(s, axis=2, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                      # (block_h, bq, bk)
-        alpha = jnp.exp(m_prev - m_new)             # (block_h, bq, 1)
-        l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
-
-        if dropout_p > 0.0:
-            keep = _keep_mask3(seed_ref[0], b * block_h, iq * block_q,
-                               ik * block_k, block_h, block_q, block_k,
-                               dropout_p)
-            p_drop = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
-        else:
-            p_drop = p
-
-        m_scr[:] = m_new
-        l_scr[:] = l_new
-        pv = jax.lax.dot_general(
-            p_drop.astype(v_ref.dtype),
-            _load_heads(v_ref, 1 if grouped else block_h),
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-
-    # full tiles keep the select here: on the v5e this body is 8% slower
-    # without it (20.05 against 18.48 ms a call at the SDAR cell's shapes,
-    # PERF.md §6, PR 31), where the backward bodies are 1-3% faster
+    # full tiles keep the select here: a second copy of this body costs
+    # every forward instance 3 MB of the chip's memory (peak HBM 14.989
+    # against 14.986 GiB in the SDAR cell, 15.085 against 15.080 in the
+    # JoyAI cell, whose causal instances gain nothing by it; SDAR's run
+    # 5.5% faster a call: PERF.md §6, PR 33)
     _by_class(_tile, cls_ref, iq, ik, nk, unmask_full=False)
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        l = l_scr[:]
-        lse = m_scr[:] + jnp.log(l)
-        if grouped:
-            _store_rows(o_ref, acc_scr[:] / l)
-            for h in range(block_h):
-                lse_ref[h] = lse[0, h * block_q:(h + 1) * block_q]
-        else:
-            _store_heads(o_ref, acc_scr[:] / l)
-            lse_ref[...] = lse  # (block_h, block_q, 1)
+        out = []
+        for h in range(block_h):
+            l = l_scr[h]
+            out.append(acc_scr[h] / _lanes(l, acc_scr.shape[2]))
+            lse_ref[h] = (m_scr[h] + jnp.log(l))[:, :1]
+        _store_heads(o_ref, out)
 
 
 def _tiler(block_mask, is_causal, causal_offset):
@@ -635,8 +674,15 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
                    block_mask=None, biased=True):
     """q,k,v: merged (BH, S, D) or packed (B, S, H*D) — told apart by
     the leading dim, kbias carrying B; kbias: (B, 1, Sk) f32; seed:
-    (1,) i32 -> (out like q, lse (BH, Sq, 1)).  Shapes must be
-    pre-padded to block multiples (flash_attention() handles that).
+    (1,) i32 -> (out like q, lse (BH, Sq, 1) f32: a row's log-sum-exp
+    of its scaled, biased, masked scores, before dropout).  Shapes must
+    be pre-padded to block multiples (flash_attention() handles that).
+
+    One body for every layout: the step's block_h heads are walked one
+    at a time (`_load_head` reads a head's q/k/v from the block
+    whatever the layout; grouped heads read the group's one k/v tile),
+    the online softmax's m and l lane-replicated in (block_h, block_q,
+    128) scratch beside the (block_h, block_q, Dv lanes) accumulator.
 
     block_h batches consecutive batch-heads into one grid step; it must
     divide heads so a head block never spans two batch elements (the
@@ -689,16 +735,12 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
             (1, block_k, d), lambda n, i, j, *t: (
                 n // per_b, fetch(i, j, t) if fetch else j,
                 (n % per_b) * block_h // group))
-        rows = block_h * block_q
-        scratch = [pltpu.VMEM((1, rows, 1), jnp.float32),
-                   pltpu.VMEM((1, rows, 1), jnp.float32),
-                   pltpu.VMEM((1, rows, d), jnp.float32)]
     else:
         k_spec = _heads_spec(packed, heads, block_h, block_k, d, 2, fetch)
         v_spec = _heads_spec(packed, heads, block_h, block_k, dv, 2, fetch)
-        scratch = [pltpu.VMEM((block_h, block_q, 1), jnp.float32),
-                   pltpu.VMEM((block_h, block_q, 1), jnp.float32),
-                   pltpu.VMEM((block_h, block_q, v_lanes), jnp.float32)]
+    scratch = [pltpu.VMEM((block_h, block_q, 128), jnp.float32),
+               pltpu.VMEM((block_h, block_q, 128), jnp.float32),
+               pltpu.VMEM((block_h, block_q, v_lanes), jnp.float32)]
 
     out, lse = _pallas_call(
         kernel,
@@ -1165,7 +1207,8 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
     tile, k tile) pair a class: dead tiles are skipped, partial ones
     masked from index codes, and full ones (every pair live) run
     without the mask in the two backward kernels — the forward kernel
-    keeps it, being slower without on the v5e
+    keeps it, a second copy of its body costing memory the cells at
+    8k rows do not have
     (`flash_tiles_full_total` of `flash_tiles_live_total` of
     `flash_tiles_total`, per head, counted here at trace time with
     `flash_block_mask_total` instances); no dense mask exists.
@@ -1286,6 +1329,7 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
         stat_add("flash_tiles_total", cls.size)
     if dv != d:
         stat_add("flash_split_value_total")
+    stat_add("flash_fwd_pieces_total", block_h)
     out = _flash_attention(qm, km, vm, bias, seed_f, h, is_causal, scale,
                            float(dropout_p), interpret, sk - sq,
                            block_h, block_q, block_k, kv_heads, block_mask,

@@ -50,6 +50,11 @@ Int stats (get_int_stats):
 | flash_packed_layout_total     | flash-attention instances traced on the |
 |                               | projections' (B, S, H*D) layout (head   |
 |                               | pairs at D = 64): no head transposes    |
+| flash_fwd_pieces_total        | pieces the forward flash kernel of each |
+|                               | traced instance walks a grid step: the  |
+|                               | heads of the step, one at a time (1: a  |
+|                               | whole-tile step; 4 BERT s512 and JoyAI, |
+|                               | 6 BERT s128, 8 SDAR); at trace time     |
 | serving_decode_steps          | decode-step dispatches (autoregressive) |
 
 Per-tenant series (multi-tenant fleet, serving/registry.py): every

@@ -56,6 +56,7 @@ class TestPhasesTiny:
         assert out["moe_plan_two_operand_total"] == 0
         # off the chip attention takes the XLA path: no kernel, no tile
         assert out["flash_tiles_full_total"] == 0
+        assert out["flash_fwd_pieces_total"] == 0
 
     def test_joyai_flash_step(self):
         from paddle_tpu.models import joyai_flash
@@ -69,6 +70,7 @@ class TestPhasesTiny:
         assert out["moe_router_rows_total"] == 3 * 3 * 32 * 2
         # off the chip attention takes the XLA path: no kernel, no tile
         assert out["flash_split_value_total"] == 0
+        assert out["flash_fwd_pieces_total"] == 0
 
     def test_failed_check_raises(self):
         ph = chip_smoke._Phase("x")

@@ -225,7 +225,15 @@ def test_mosaic_accepts_the_latent_attention_instances(v5e):
     """The `joyai_llm_flash.ar_mtp_s8192` instances: 2 x 8192 rows, 32
     heads of 192 (q, k) over 128 (v), bfloat16, causal by tile class on
     (512, 512) tiles, no key bias — forward and both backward kernels,
-    at the head block flash_attention() takes there."""
+    at the head block flash_attention() takes there — and the generic
+    probe that lets the dispatcher reach them (`_flash_ok`: a merged
+    pair of heads 192 wide in q, k AND v, so an accumulator row is one
+    and a half vregs)."""
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16)
+    try:
+        assert A._flash_ok(q, q)
+    finally:
+        A._PROBE_CACHE.clear()
     assert A._probe_exact((64, 8192, 192), (64, 8192, 192), 32, True, 0.0,
                           jnp.bfloat16, 4, 512, 512, 0, packed=True,
                           kv_heads=32, biased=False, v_dim=128)

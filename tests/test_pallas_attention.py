@@ -184,10 +184,14 @@ class TestFlashDropout:
         np.testing.assert_array_equal(sub, keep[128:, 64:192])
 
 
-def _packed_count():
+def _stat(name):
     from paddle_tpu import profiler
 
-    return profiler.get_int_stats().get("flash_packed_layout_total", 0)
+    return profiler.get_int_stats().get(name, 0)
+
+
+def _packed_count():
+    return _stat("flash_packed_layout_total")
 
 
 class TestPackedLayout:
@@ -358,6 +362,132 @@ class TestPackedLayout:
         assert A._block_h_ladder(8, 64, 8) == [8, 4, 2]
         assert A._block_h_ladder(4, 128, 4) == [4, 2, 1]
         assert A._block_h_ladder(3, 128, 4) == [3, 1]
+
+
+def _pieces_count():
+    return _stat("flash_fwd_pieces_total")
+
+
+class TestForwardWalksHeads:
+    """The forward body walks a step's heads one at a time with the row
+    statistics lane-replicated: against a dense oracle (out, lse and
+    the three gradients through the backward kernels, which read that
+    lse), for every operand layout the one body serves and every mask
+    it composes, at 2 x 2 tiles with more than one head a step — more
+    than one piece a step, more than one online-softmax update a
+    row."""
+
+    LAYOUTS = {                 # h, hkv, d, dv, heads a step
+        "merged": (3, 3, 64, 64, 3),
+        "merged_192": (3, 3, 192, 192, 3),  # the probe's: 1.5 vregs a row
+        "packed_128": (4, 4, 128, 128, 4),
+        "packed_64_pairs": (4, 4, 64, 64, 4),
+        "packed_192_over_128_pairs": (4, 4, 192, 128, 4),
+        "grouped": (8, 2, 128, 128, 4),
+    }
+    S, VALID, P_DROP, SEED = 256, 200, 0.25, 13
+
+    def _oracle(self, kind, h, hkv):
+        s = self.S
+        seen = np.ones((s, s), bool)
+        if kind == "causal":
+            seen = np.tril(seen)
+        if kind == "block_mask":
+            seen = A.BlockDiffusionMask(s // 2, 4).dense()
+        bias = np.where(np.arange(s) < self.VALID, 0.0,
+                        A.DEFAULT_MASK_VALUE) if kind == "key_bias" \
+            else np.zeros(s)
+        keep = jnp.stack([A._keep_mask(jnp.int32(self.SEED), n, 0, 0, s, s,
+                                       self.P_DROP) for n in range(h)]) \
+            if kind == "dropout" else None
+
+        def logits(q, k):
+            k = jnp.repeat(k, h // hkv, axis=2)
+            x = jnp.einsum("bqhd,bkhd->bhqk", q, k) / q.shape[-1] ** 0.5
+            return jnp.where(seen, x + bias, A.DEFAULT_MASK_VALUE)
+
+        def out(q, k, v):
+            probs = jax.nn.softmax(logits(q, k), axis=-1)
+            if keep is not None:
+                probs = jnp.where(keep, probs / (1.0 - self.P_DROP), 0.0)
+            return jnp.einsum("bhqk,bkhd->bqhd", probs,
+                              jnp.repeat(v, h // hkv, axis=2))
+
+        lse = lambda q, k: jax.nn.logsumexp(logits(q, k), axis=-1)
+        return out, lse
+
+    @pytest.mark.parametrize("kind", ["plain", "causal", "block_mask",
+                                      "key_bias", "dropout"])
+    @pytest.mark.parametrize("layout", list(LAYOUTS))
+    def test_out_lse_and_grads(self, layout, kind, monkeypatch):
+        h, hkv, d, dv, block_h = self.LAYOUTS[layout]
+        if layout == "grouped" and kind == "dropout":
+            block_h = 8     # dropout repeats the kv heads in HBM first
+        rng = np.random.RandomState(len(layout) + len(kind))
+        mk = lambda n, w: jnp.asarray(rng.randn(1, self.S, n, w),
+                                      jnp.float32)
+        q, k, v = mk(h, d), mk(hkv, d), mk(hkv, dv)
+        kw = dict(
+            is_causal=kind == "causal",
+            block_mask=A.BlockDiffusionMask(self.S // 2, 4)
+            if kind == "block_mask" else None,
+            key_bias=jnp.where(jnp.arange(self.S) < self.VALID, 0.0,
+                               A.DEFAULT_MASK_VALUE)[None]
+            if kind == "key_bias" else None,
+            dropout_p=self.P_DROP if kind == "dropout" else 0.0,
+            dropout_seed=self.SEED)
+        forward, seen = A._flash_forward, {}
+
+        def spy(*a, **kws):
+            out, lse = forward(*a, **kws)
+            seen.update(lse=lse, block_h=kws["block_h"])
+            return out, lse
+
+        monkeypatch.setattr(A, "_flash_forward", spy)
+        flash = lambda q, k, v: A.flash_attention(
+            q, k, v, block_q=128, block_k=128, interpret=True, **kw)
+        before = _pieces_count()
+        out = flash(q, k, v)
+        assert seen["block_h"] == block_h > 1
+        assert _pieces_count() == before + block_h
+        oracle, oracle_lse = self._oracle(kind, h, hkv)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(oracle(q, k, v)),
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(seen["lse"]).reshape(h, self.S),
+            np.asarray(oracle_lse(q, k))[0], rtol=2e-5, atol=2e-5)
+        for a, b in zip(_grads(flash, q, k, v), _grads(oracle, q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-5)
+
+    @pytest.mark.parametrize("cell,b,s,h,hkv,d,dv,kw,pieces", [
+        ("bert_base.pretrain_s512", 32, 512, 12, 12, 64, 64,
+         dict(dropout_p=0.1, key_bias=True), 4),
+        ("bert_base.pretrain_s128", 128, 128, 12, 12, 64, 64,
+         dict(dropout_p=0.1, key_bias=True), 6),
+        ("sdar_30b_a3b.blockdiff_s4096", 4, 8192, 32, 4, 128, 128,
+         dict(block_mask=A.BlockDiffusionMask(4096, 4)), 8),
+        ("joyai_llm_flash.ar_mtp_s8192", 2, 8192, 32, 32, 192, 128,
+         dict(is_causal=True), 4),
+    ])
+    def test_pieces_a_step_at_the_cells_shapes(self, cell, b, s, h, hkv, d,
+                                               dv, kw, pieces):
+        """Trace only (`eval_shape`): `flash_fwd_pieces_total` gains
+        the heads a grid step walks, at the first rung of each cell's
+        head-block ladder (the rung the chip takes there)."""
+        kw = dict(kw)
+        if kw.pop("key_bias", False):
+            kw["key_bias"] = jax.ShapeDtypeStruct((b, s), jnp.float32)
+        x = lambda n, w: jax.ShapeDtypeStruct((b, s, n, w), jnp.bfloat16)
+        statics = {n: kw.pop(n) for n in ("dropout_p", "block_mask",
+                                          "is_causal") if n in kw}
+        before = _pieces_count()
+        jax.eval_shape(
+            lambda q, k, v, **dyn: A.flash_attention(
+                q, k, v, interpret=True, **statics, **dyn),
+            x(h, d), x(hkv, d), x(hkv, dv), **kw)
+        assert _pieces_count() == before + pieces, cell
 
 
 class TestMosaicAcceptsForV5e:

@@ -448,6 +448,35 @@ def test_latent_attention_heads_on_tpu(heads):
             delta("flash_tiles_total")) == (3 * calls, 6 * calls, 9 * calls)
 
 
+@pytest.mark.parametrize("cell,h,hkv,d,dv,kw,pieces", [
+    ("sdar_30b_a3b.blockdiff_s4096", 8, 1, 128, 128,
+     dict(mask=A.BlockDiffusionMask(4096, 4)), 8),
+    ("joyai_llm_flash.ar_mtp_s8192", 4, 4, 192, 128,
+     dict(is_causal=True), 4),
+])
+def test_forward_body_at_the_moe_cells_shapes(cell, h, hkv, d, dv, kw,
+                                              pieces):
+    """The forward kernel's grid step as the two MoE cells run it — the
+    cell's rows (8,192), tiles and heads a step (one key/value group of
+    8 heads on (256, 512) tiles under the block-diffusion mask; 4 heads
+    of 192 over 128 on (512, 512) causal tiles), one head block of it —
+    through Mosaic against `_xla_attention`; the body walked the step's
+    heads one at a time (`flash_fwd_pieces_total`)."""
+    q = _rand((1, 8192, h, d), 80, jnp.bfloat16)
+    k = _rand((1, 8192, hkv, d), 81, jnp.bfloat16)
+    v = _rand((1, 8192, hkv, dv), 82, jnp.bfloat16)
+    before = profiler.get_int_stats().get("flash_fwd_pieces_total", 0)
+    out = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, is_causal=kw.get("is_causal", False),
+        block_mask=kw.get("mask")))(q, k, v)
+    assert profiler.get_int_stats()["flash_fwd_pieces_total"] \
+        == before + pieces, cell
+    ref = jax.jit(lambda q, k, v: _xla_attention(q, k, v, **kw))(q, k, v)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
 def test_no_kernel_gave_way():
     """Runs last: nothing above (and no other tpu-marked test before
     it) may have pushed a kernel onto its XLA path."""
