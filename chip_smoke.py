@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
-Drives the five main paths once, in ONE process, through the entry
+Drives the six main paths once, in ONE process, through the entry
 points a user calls, at published widths, on seeded random weights:
 
   executor_resnet50   models/resnet.build_train_program -> fluid.Executor
@@ -12,6 +12,9 @@ points a user calls, at published widths, on seeded random weights:
   joyai_flash_step    models/joyai_flash.build_train_step (latent
                       attention on the split-width causal flash kernels,
                       the sigmoid router with its selection bias, MTP)
+  kimi_linear_step    models/kimi_linear.build_train_step (Kimi Delta
+                      Attention on the chunked scan kernels, position-
+                      free latent attention, the same expert layer)
 
     python chip_smoke.py            # one chip (what the driver runs)
     python chip_smoke.py --chips 4  # the four-chip host: data-parallel
@@ -86,7 +89,8 @@ def _stats():
 def _fallback_counts() -> dict:
     s = _stats()
     return {k: s.get(k, 0) for k in ("flash_fallback_total",
-                                     "serving_ragged_fallback_total")}
+                                     "serving_ragged_fallback_total",
+                                     "kda_fallback_total")}
 
 
 def _device_platforms(arr) -> set:
@@ -722,6 +726,95 @@ def joyai_flash_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
     return ph.done()
 
 
+def kimi_linear_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
+    """`kimi_linear.build_train_step` with per-layer recomputation: the
+    trace-time counters say that every KDA layer's scan took the
+    chunked path (forward, its recomputation and nothing a token at a
+    time) and walked seq / 64 chunks, and that the latent layer's flash
+    instance has v heads narrower than its q/k heads; the run-time
+    counters, that no held visit was dropped and that the selection
+    biases moved."""
+    import jax.numpy as jnp
+
+    import paddle_tpu
+    from paddle_tpu.models import kimi_linear
+
+    ph = _Phase("kimi_linear_step")
+    paddle_tpu.seed(SEED)
+    model = kimi_linear.KimiLinearForCausalLM(cfg)
+    kinds = [layer.kind for layer in model.model.layers]
+    kda, mla = kinds.count("kda"), kinds.count("mla")
+    sparse = sum(layer.sparse for layer in model.model.layers)
+    step, state = kimi_linear.build_train_step(model)
+    biases = kimi_linear.bias_names(state["params"])
+    b = kimi_linear.fake_batch(cfg, batch, seq, seed=SEED)
+    lr = jnp.float32(1e-3)
+    traced = ("kda_chunked_total", "kda_chunks_total", "kda_fallback_total",
+              "flash_split_value_total", "moe_sigmoid_router_total")
+    ran = ("moe_router_rows_total", "moe_bias_updates_total",
+           "moe_rows_routed_total", "moe_rows_held_total",
+           "moe_dropped_total")
+    s0 = _stats()
+    t0 = time.perf_counter()
+    compiled = step.lower(state, b, lr).compile()
+    ph.compile_s = time.perf_counter() - t0
+    for k in traced:
+        ph.info[k] = _stats().get(k, 0) - s0.get(k, 0)
+    losses = []
+    for _ in range(steps):
+        state, loss, aux = compiled(state, b, lr)
+        losses.append(float(loss))
+        kimi_linear.record_moe_stats(np.asarray(aux["moe_stats"]),
+                                     np.asarray(aux["moe_load"]),
+                                     bias_updates=len(biases))
+    s1 = _stats()
+    for k in ran:
+        ph.info[k] = s1.get(k, 0) - s0.get(k, 0)
+    ph.info["losses"] = [round(v, 4) for v in losses]
+    ph.check(all(math.isfinite(v) for v in losses),
+             f"{steps} losses finite")
+    ph.check(losses[-1] < losses[0], "loss falls on the repeated batch")
+    ph.check(abs(losses[0] - math.log(cfg.vocab_size)) < 1.0,
+             f"first loss {losses[0]:.3f} near ln(vocab) = "
+             f"{math.log(cfg.vocab_size):.3f}")
+    ph.check(ph.info["moe_rows_held_total"] > 0
+             and ph.info["moe_dropped_total"] == 0,
+             "held visits computed, moe_dropped_total did not move")
+    ph.check(ph.info["moe_router_rows_total"]
+             == ph.info["moe_rows_routed_total"]
+             == steps * sparse * batch * seq * cfg.num_experts_per_token,
+             "the routers' loads count every visit, held here or not")
+    ph.check(ph.info["moe_bias_updates_total"] == steps * sparse
+             and all(float(jnp.abs(state["params"][k]).max()) > 0
+                     for k in biases),
+             f"{sparse} selection biases moved a step, outside AdamW")
+    ph.check(_device_platforms(state["params"]["lm_head.weight"])
+             == {platform}, f"parameters sit on platform {platform!r}")
+    if platform == "tpu":
+        text = compiled.as_text()
+        ops = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
+                         r'op_name="([^"]*)"', text)
+        count = lambda fn: sum(fn in op for op in ops)
+        ph.check((count("_kda_forward"), count("_kda_backward"))
+                 == (2 * kda, kda),
+                 f"{2 * kda} kda_fwd calls (with the recomputed ones) and "
+                 f"{kda} kda_bwd calls for {kda} KDA layers")
+        chunks = -(-seq // 64)
+        ph.check((ph.info["kda_chunked_total"], ph.info["kda_chunks_total"],
+                  ph.info["kda_fallback_total"])
+                 == (kda, kda * chunks, 0),
+                 f"every scan instance chunked, {chunks} chunks each; "
+                 "kda_fallback_total did not move")
+        ph.check((count("_flash_forward"), count("_flash_backward"))
+                 == (2 * mla, 2 * mla)
+                 and ph.info["flash_split_value_total"] == mla,
+                 f"{mla} position-free latent layer(s) on the split-width "
+                 "causal flash kernels")
+        ph.check(_fallback_counts()["flash_fallback_total"] == 0,
+                 "flash_fallback_total == 0")
+    return ph.done()
+
+
 # ---------------------------------------------------------------------------
 
 def main(argv=None) -> int:
@@ -735,7 +828,7 @@ def main(argv=None) -> int:
     device, count = require_chip(args.chips)
 
     from paddle_tpu.fluid.compile_cache import enable_persistent_cache
-    from paddle_tpu.models import bert, joyai_flash, sdar_moe
+    from paddle_tpu.models import bert, joyai_flash, kimi_linear, sdar_moe
 
     print(f"chip_smoke: compile cache at {enable_persistent_cache()}")
     phases = []
@@ -755,6 +848,13 @@ def main(argv=None) -> int:
         # eighth of the vocabulary
         phases.append(joyai_flash_step(joyai_flash.JoyAIFlashConfig(
             num_hidden_layers=2, experts_held=(0, 16), vocab_size=16160,
+            recompute=True)))
+        # Kimi Linear's widths: layers 1-4 of the published 27 (three
+        # KDA layers, the first with the dense FFN, and the latent
+        # layer), one chip's 8 of the 256 experts and an eighth of the
+        # vocabulary
+        phases.append(kimi_linear_step(kimi_linear.KimiLinearConfig(
+            num_hidden_layers=4, experts_held=(0, 8), vocab_size=20480,
             recompute=True)))
     else:
         phases.append(executor_resnet50(4 * 128, data_parallel=4))

@@ -439,6 +439,18 @@ def build_train_step(model: JoyAIFlashForCausalLMWithMTP, weight_decay=0.0,
     (`parallel.moe.update_selection_bias`; scope `moe_bias_update`).
 
     `take_weights` as `sdar_moe.build_blockdiff_train_step`'s."""
+    return train_step_from_loss(
+        model, build_loss(model, bf16=bf16, probe=probe), weight_decay,
+        take_weights)
+
+
+def train_step_from_loss(model, loss_fn, weight_decay=0.0,
+                         take_weights=False, no_decay=()):
+    """`build_train_step` for any model of this family and its
+    `loss_fn(params, batch) -> (loss, aux)` (`aux["moe_load"]` the
+    (layers, n_routed) loads, in `bias_names`' order): the one AdamW +
+    selection-bias step the expert models with a sigmoid router share.
+    `no_decay`: name endings of matrices that take no weight decay."""
     import jax
     import jax.numpy as jnp
 
@@ -448,7 +460,6 @@ def build_train_step(model: JoyAIFlashForCausalLMWithMTP, weight_decay=0.0,
     params0 = {k: v if take_weights else jnp.array(v)
                for k, v in functional_state(model).items()}
     biases = bias_names(params0)
-    loss_fn = build_loss(model, bf16=bf16, probe=probe)
     gamma = model.config.bias_update_rate
     b1, b2, eps = 0.9, 0.999, 1e-8
 
@@ -471,7 +482,8 @@ def build_train_step(model: JoyAIFlashForCausalLMWithMTP, weight_decay=0.0,
                 v = b2 * state["v"][k] + (1 - b2) * jnp.square(g)
                 upd = (m / (1 - jnp.power(b1, tf))) / (
                     jnp.sqrt(v / (1 - jnp.power(b2, tf))) + eps)
-                if weight_decay and p.ndim > 1:     # not on norm scales
+                if weight_decay and p.ndim > 1 and not k.endswith(
+                        no_decay):                  # not on norm scales
                     upd = upd + weight_decay * p
                 new_p[k], new_m[k], new_v[k] = p - lr_s * upd, m, v
         with jax.named_scope("moe_bias_update"):

@@ -33,7 +33,8 @@ from .layer.pooling import (AdaptiveAvgPool2D, AdaptiveMaxPool2D, AvgPool1D,
 from .layer.rnn import (RNN, BiRNN, GRU, GRUCell, LSTM, LSTMCell,
                         RNNCellBase, SimpleRNN, SimpleRNNCell)
 from .layer.transformer import (GatedFFN, GroupedQueryAttention,
-                                LatentAttention, MultiHeadAttention, Transformer,
+                                KimiDeltaAttention, LatentAttention,
+                                ShortConvSiLU, MultiHeadAttention, Transformer,
                                 TransformerDecoder, TransformerDecoderLayer,
                                 TransformerEncoder, TransformerEncoderLayer)
 

@@ -771,6 +771,40 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     return trace_fn(f, ins)
 
 
+def kda_attention(q, k, v, g, beta, scale=None, name=None):
+    """The gated delta-rule recurrence with a per-channel decay (Kimi
+    Delta Attention): q, k, g (B, S, H, dk), v (B, S, H, dv), beta (B,
+    S, H) -> o (B, S, H, dv).  The chunked Pallas scan on a TPU at dk =
+    dv = 128, else a token at a time (ops/pallas/kda.py)."""
+    from ...ops.pallas import kda as _kda
+
+    return trace_fn(
+        lambda q, k, v, g, beta: _kda.kda_attention(q, k, v, g, beta, scale),
+        {"q": q, "k": k, "v": v, "g": g, "beta": beta})
+
+
+def short_conv1d(x, weight, activation=None, name=None):
+    """Depthwise causal convolution along the sequence: x (B, S, C),
+    weight (width, C) -> (B, S, C), y_t = sum_i weight[i] x_{t - width
+    + 1 + i}; `activation` "silu" or None, fused by XLA.  The backward
+    pass recomputes it from x (`jax.checkpoint`): it keeps no float32
+    intermediate of an elementwise pass."""
+    import jax
+
+    from .kda import short_conv
+
+    @jax.checkpoint
+    def f(x, w):
+        y = short_conv(x, w)
+        if activation == "silu":
+            y = jax.nn.silu(y.astype("float32")).astype(y.dtype)
+        elif activation is not None:
+            raise ValueError(f"activation {activation!r}")
+        return y
+
+    return trace_fn(f, {"x": x, "w": weight})
+
+
 def fused_feedforward(x, w1, b1, w2, b2, activation="gelu",
                       act_dropout=0.0, training=True, name=None):
     """The transformer's dense FFN as one call:

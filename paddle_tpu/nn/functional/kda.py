@@ -1,0 +1,214 @@
+"""The XLA half of Kimi Delta Attention (arXiv:2510.26692): the gated
+delta-rule recurrence with a per-channel decay, a token at a time (the
+fallback and the tests' oracle), and the chunk-local quantities of its
+chunked form, which `ops/pallas/kda.py` walks along the sequence.
+
+The recurrence, for one head (S in R^{dk x dv}, float32, S_0 = 0):
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = scale * S_t^T q_t
+
+With u_t = beta_t (v_t - (Diag(exp(g_t)) S_{t-1})^T k_t) it reads
+S_t = Diag(exp(g_t)) S_{t-1} + k_t u_t^T.  Inside a chunk of C = 64
+tokens, with G_i the gate cumulated from the chunk's first token and
+S_0 the state entering the chunk:
+
+    A_ij = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)      (j < i, else 0)
+    T    = (I + A)^{-1}
+    W    = T Diag(beta) (K . exp(G))        U0 = T Diag(beta) V
+    U    = U0 - W S_0
+    o    = Qg S_0 + Aqk U       Qg = scale q . exp(G)
+                                Aqk_ij = scale sum_c q_ic k_jc exp(G_ic - G_jc)  (j <= i)
+    S_C  = Diag(d) S_0 + Kg^T U             Kg = k . exp(G_C - G),  d = exp(G_C)
+
+W, U0, Qg, Kg, Aqk and d need no state: `chunk_local` makes them for
+every chunk at once, as batched float32 matmuls; the three lines that
+need S_0 are the kernels'.
+
+Every exponent is a difference G_i - G_j with i >= j, or G_i alone: at
+most 0.  exp(G_i - G_j) sits INSIDE the contraction over channels; a
+factorised matmul (q . exp(G_i - G_ref)) (k . exp(G_ref - G_j))^T
+keeps both exponents at most 0 only while G_ref lies between the two,
+so the chunk is cut into sub-blocks of 16 rows: a sub-block of rows
+against every EARLIER sub-block takes G_ref at its own first row, and
+the four diagonal sub-blocks are formed from explicit pairwise
+differences.  1 / exp(G) is never formed.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+CHUNK = 64
+SUB = 16
+_HI = jax.lax.Precision.HIGHEST
+# chunks a `lax.map` iteration of `chunk_local` handles: bounds the
+# pairwise (16, 16, dk) temporaries of the diagonal sub-blocks
+_SEGMENT_CHUNKS = 32
+
+
+def recurrent(q, k, v, g, beta, scale):
+    """The recurrence itself, a token at a time, in float32.  q, k, g
+    (B, S, H, dk), v (B, S, H, dv), beta (B, S, H) -> o (B, S, H, dv)
+    float32."""
+    f32 = lambda a: jnp.moveaxis(a.astype(jnp.float32), 1, 0)
+    b, _, h, dk = q.shape
+
+    def step(state, x):
+        q_t, k_t, v_t, g_t, b_t = x
+        state = state * jnp.exp(g_t)[..., None]
+        u = b_t[..., None] * (v_t - jnp.einsum(
+            "bhk,bhkv->bhv", k_t, state, precision=_HI))
+        state = state + k_t[..., None] * u[..., None, :]
+        return state, scale * jnp.einsum("bhk,bhkv->bhv", q_t, state,
+                                         precision=_HI)
+
+    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32),
+                        tuple(f32(a) for a in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1)
+
+
+def _substitute(a):
+    """(I + a)^{-1} for strictly lower-triangular a (..., n, n), by
+    forward substitution a row at a time: backward stable, where the
+    finite Neumann product is not once keys repeat."""
+    n = a.shape[-1]
+    t = jnp.broadcast_to(jnp.eye(n, dtype=a.dtype), a.shape)
+    for i in range(1, n):
+        t = t.at[..., i, :].add(-jnp.einsum("...j,...jc->...c",
+                                            a[..., i, :], t, precision=_HI))
+    return t
+
+
+def _merge(ta, tb, a21):
+    """The lower-left block of [[La, 0], [a21, Lb]]^{-1}."""
+    mm = functools.partial(jnp.matmul, precision=_HI)
+    return -mm(mm(tb, a21), ta)
+
+
+def _stack2(ta, tb, tba):
+    z = jnp.zeros_like(tba)
+    return jnp.concatenate([jnp.concatenate([ta, z], -1),
+                            jnp.concatenate([tba, tb], -1)], -2)
+
+
+@jax.custom_vjp
+def inv_unit_lower(a):
+    """(I + a)^{-1} for strictly lower-triangular a (..., 64, 64):
+    the four diagonal 16-blocks by substitution, merged twice."""
+    n = a.shape[-1] // SUB
+    a4 = a.reshape(a.shape[:-2] + (n, SUB, n, SUB))
+    t = _substitute(jnp.stack([a4[..., i, :, i, :] for i in range(n)], -3))
+    blocks = [t[..., i, :, :] for i in range(n)]
+    size = SUB
+    while len(blocks) > 1:
+        blocks = [_stack2(ta, tb, _merge(
+            ta, tb, a[..., (2 * i + 1) * size:(2 * i + 2) * size,
+                      2 * i * size:(2 * i + 1) * size]))
+            for i, (ta, tb) in enumerate(zip(blocks[::2], blocks[1::2]))]
+        size *= 2
+    return blocks[0]
+
+
+def _inv_fwd(a):
+    t = inv_unit_lower(a)
+    return t, t
+
+
+def _inv_bwd(t, dt):
+    tt = jnp.swapaxes(t, -1, -2)
+    mm = functools.partial(jnp.matmul, precision=_HI)
+    return (-mm(mm(tt, dt), tt),)
+
+
+inv_unit_lower.defvjp(_inv_fwd, _inv_bwd)
+
+
+def _local(q, k, v, g, beta, scale):
+    """`chunk_local` for (B, N, C, H, d) operands in float32 -> W, U0,
+    Qg, Kg (B, N, C, H, d), Aqk (B, N, H, C, C), d (B, N, H, dk)."""
+    b, n, c, h, dk = q.shape
+    nb = c // SUB
+    gc = jnp.cumsum(g, axis=2)
+    sub = lambda a: a.reshape((b, n, nb, SUB) + a.shape[3:])
+    g4, k4 = sub(gc), sub(k)
+    ref = g4[:, :, :, :1]                               # G at a sub-block's head
+    e_in = jnp.exp(g4 - ref)
+    # keys of EARLIER sub-blocks against each sub-block's head: the
+    # later ones (exponent > 0) are masked out below, clamp them here
+    k_ref = k[:, :, None] * jnp.exp(jnp.minimum(
+        ref - gc[:, :, None], 0.0))                     # (B, N, nb, C, H, dk)
+    off = lambda x: jnp.einsum("bnIihc,bnIjhc->bnhIij", sub(x) * e_in,
+                               k_ref, precision=_HI)
+    earlier = (jnp.arange(c)[None, None, :] // SUB
+               < jnp.arange(nb)[:, None, None])         # (nb, 1, C)
+    # the diagonal sub-blocks, from explicit pairwise differences
+    rows, cols = jnp.arange(SUB)[:, None], jnp.arange(SUB)[None, :]
+    seen = (rows >= cols)[..., None, None]              # (i, j, 1, 1)
+    pair = jnp.exp(jnp.where(seen, g4[:, :, :, :, None]
+                             - g4[:, :, :, None, :], 0.0))
+    pair = jnp.where(seen, pair * k4[:, :, :, None, :], 0.0)
+    diag = lambda x: jnp.einsum(
+        "bnIijh->bnhIij", jnp.sum(sub(x)[:, :, :, :, None] * pair, axis=-1))
+    eye = jnp.eye(nb, dtype=q.dtype)
+
+    def whole(x):                                       # -> (B, N, H, C, C)
+        full = jnp.where(earlier, off(x), 0.0).reshape(b, n, h, c, c)
+        return full + jnp.einsum("bnhIij,IJ->bnhIiJj", diag(x),
+                                 eye).reshape(b, n, h, c, c)
+
+    beta_h = jnp.moveaxis(beta, 2, 3)[..., None]        # (B, N, H, C, 1)
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)
+    a = jnp.where(strict, whole(k) * beta_h, 0.0)
+    t = inv_unit_lower(a)
+    e_g = jnp.exp(gc)
+    bk = beta[..., None] * k * e_g
+    bv = beta[..., None] * v
+    w = jnp.einsum("bnhij,bnjhc->bnihc", t, bk, precision=_HI)
+    u0 = jnp.einsum("bnhij,bnjhc->bnihc", t, bv, precision=_HI)
+    last = gc[:, :, -1]                                 # (B, N, H, dk)
+    return (w, u0, scale * q * e_g, k * jnp.exp(last[:, :, None] - gc),
+            scale * whole(q), jnp.exp(last))
+
+
+def largest_divisor(n, at_most):
+    return max(s for s in range(1, min(n, at_most) + 1) if n % s == 0)
+
+
+def chunk_local(q, k, v, g, beta, scale):
+    """The quantities of the chunked form that need no state.  q, k, g
+    (B, S, H, dk), v (B, S, H, dv), beta (B, S, H), S a multiple of 64
+    -> W, Qg, Kg (B, S, H * dk), U0 (B, S, H * dv), Aqk (B, S / 64, H,
+    64, 64), d (B, S / 64, 1, H * dk), all float32.  A `lax.map` over
+    segments of at most 32 chunks, each recomputed in the backward
+    pass, bounds what lives at once."""
+    b, s, h, dk = q.shape
+    n = s // CHUNK
+    seg = largest_divisor(n, _SEGMENT_CHUNKS)
+    cut = lambda a: jnp.moveaxis(a.astype(jnp.float32).reshape(
+        (b, n // seg, seg, CHUNK) + a.shape[2:]), 1, 0)
+    outs = jax.lax.map(
+        jax.checkpoint(lambda x: _local(*x, scale)),
+        tuple(cut(a) for a in (q, k, v, g, beta)))
+    w, u0, qg, kg, aqk, d = (jnp.moveaxis(a, 0, 1) for a in outs)
+    rows = lambda a: a.reshape(b, s, -1)
+    return (rows(w), rows(u0), rows(qg), rows(kg),
+            aqk.reshape(b, n, h, CHUNK, CHUNK), d.reshape(b, n, 1, h * dk))
+
+
+def short_conv(x, taps):
+    """Depthwise causal convolution along the sequence as shifted
+    multiply-adds: x (B, S, D), taps (width, D) -> y_t = sum_i
+    taps[i] x_{t - (width - 1) + i} (tap width - 1 on the token
+    itself), zeros before the sequence.  Float32 sum, x's dtype out; no
+    `conv` op and no transpose to channels-first."""
+    width = taps.shape[0]
+    xf = x.astype(jnp.float32)
+    padded = jnp.pad(xf, ((0, 0), (width - 1, 0), (0, 0)))
+    s = x.shape[1]
+    y = sum(padded[:, i:i + s] * taps[i].astype(jnp.float32)
+            for i in range(width))
+    return y.astype(x.dtype)

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...fluid.dygraph.tracer import trace_fn, trace_op
+from ...fluid.initializer import ConstantInitializer, UniformInitializer
 from .. import functional as F
 from .activation import GELU, ReLU
 from .common import Dropout, Linear
@@ -167,8 +168,13 @@ class LatentAttention(Layer):
         out = concat_h softmax(q_h k_h^T / sqrt(nope + rope)) v_h  W_o
 
     `rope_interleave`: rotary pairs are (2i, 2i + 1)
-    (F.rotary_embedding).  The absorbed decode form and its latent
-    cache row are not built.
+    (F.rotary_embedding).  `q_lora_rank=None`: no query latent, q = x
+    W_q directly (sublayer `q_proj`; no `q_a_proj`, `q_a_layernorm`,
+    `q_b_proj`).  `use_rope=False`: neither part is rotated (a
+    position-free layer beside layers that carry position; the
+    "rope" part is then one more shared key head part) and `positions`
+    is not read.  The absorbed decode form and its latent cache row
+    are not built.
 
     forward(x (B, S, E), positions (B, S) | (S,), is_causal=True) ->
     (B, S, E); the flash kernels take the (nope + rope)-wide q/k heads
@@ -178,17 +184,23 @@ class LatentAttention(Layer):
     def __init__(self, embed_dim, num_heads, q_lora_rank, kv_lora_rank,
                  qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
                  rope_theta=10000.0, rope_interleave=True, epsilon=1e-6,
-                 weight_attr=None):
+                 weight_attr=None, use_rope=True):
         super().__init__()
         self.num_heads = num_heads
         self.kv_lora_rank = kv_lora_rank
+        self.use_rope = use_rope
         self.nope, self.rope, self.v_dim = (qk_nope_head_dim,
                                             qk_rope_head_dim, v_head_dim)
         self.rope_theta, self.rope_interleave = rope_theta, rope_interleave
         q_out = num_heads * (qk_nope_head_dim + qk_rope_head_dim)
-        self.q_a_proj = Linear(embed_dim, q_lora_rank, weight_attr, False)
-        self.q_a_layernorm = RMSNorm(q_lora_rank, epsilon)
-        self.q_b_proj = Linear(q_lora_rank, q_out, weight_attr, False)
+        if q_lora_rank is None:
+            self.q_proj = Linear(embed_dim, q_out, weight_attr, False)
+        else:
+            self.q_a_proj = Linear(embed_dim, q_lora_rank, weight_attr,
+                                   False)
+            self.q_a_layernorm = RMSNorm(q_lora_rank, epsilon)
+            self.q_b_proj = Linear(q_lora_rank, q_out, weight_attr, False)
+        self.q_lora_rank = q_lora_rank
         self.kv_a_proj_with_mqa = Linear(
             embed_dim, kv_lora_rank + qk_rope_head_dim, weight_attr, False)
         self.kv_a_layernorm = RMSNorm(kv_lora_rank, epsilon)
@@ -201,7 +213,10 @@ class LatentAttention(Layer):
     def forward(self, x, positions, is_causal=True):
         h, nope, rope, rank = (self.num_heads, self.nope, self.rope,
                                self.kv_lora_rank)
-        q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
+        if self.q_lora_rank is None:
+            q = self.q_proj(x)
+        else:
+            q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
         kv_a = self.kv_a_proj_with_mqa(x)
         c_kv, k_r = trace_fn(
             lambda a: (a[..., :rank], a[..., None, rank:]), {"a": kv_a},
@@ -211,8 +226,10 @@ class LatentAttention(Layer):
             lambda q: tuple(jnp.split(
                 q.reshape(q.shape[:2] + (h, nope + rope)), [nope], axis=-1)),
             {"q": q}, multi_out=True)
-        q_r, k_r = F.rotary_embedding(q_r, k_r, positions, self.rope_theta,
-                                      interleaved=self.rope_interleave)
+        if self.use_rope:
+            q_r, k_r = F.rotary_embedding(
+                q_r, k_r, positions, self.rope_theta,
+                interleaved=self.rope_interleave)
 
         def assemble(q_nope, q_r, kv, k_r):
             kv = kv.reshape(kv.shape[:2] + (h, nope + self.v_dim))
@@ -228,6 +245,135 @@ class LatentAttention(Layer):
         out = trace_fn(
             lambda o: o.reshape(o.shape[0], o.shape[1], -1), {"o": out})
         return self.o_proj(out)
+
+
+class ShortConvSiLU(Layer):
+    """SiLU of a depthwise causal convolution of `width` taps along the
+    sequence, no bias: x (B, S, C) -> (B, S, C) (F.short_conv1d: shifted
+    multiply-adds on the (B, S, C) layout).  `weight` (width, C), tap
+    `width - 1` on the token itself; drawn from U(-width^-1/2,
+    width^-1/2), a depthwise Conv1d's usual default."""
+
+    def __init__(self, channels, width=4):
+        super().__init__()
+        bound = width ** -0.5
+        self.weight = self.create_parameter(
+            shape=[width, channels],
+            default_initializer=UniformInitializer(-bound, bound))
+
+    def forward(self, x):
+        return F.short_conv1d(x, self.weight, activation="silu")
+
+
+class _KDACore(Layer):
+    """The scan of Kimi Delta Attention, a layer of its own so that all
+    of it — the chunk-local XLA part and the kernels — runs under the
+    scope `kda_core`."""
+
+    def forward(self, q, k, v, g, beta):
+        return F.kda_attention(q, k, v, g, beta)
+
+
+class _GatedHeadNorm(Layer):
+    """RMSNorm over each head's channels with a learned scale, times a
+    sigmoid gate: (o (B, S, H, D), gate (B, S, H * D)) -> (B, S, H * D)."""
+
+    def __init__(self, head_dim, epsilon):
+        super().__init__()
+        self._epsilon = epsilon
+        self.weight = self.create_parameter(
+            shape=[head_dim], default_initializer=ConstantInitializer(1.0))
+
+    def forward(self, o, gate):
+        @jax.checkpoint     # elementwise: recomputed from o and gate
+        def f(o, gate, w):
+            y = o.astype(jnp.float32)
+            y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                                  + self._epsilon) * w.astype(jnp.float32)
+            y = y.reshape(gate.shape) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))
+            return y.astype(o.dtype)
+
+        return trace_fn(f, {"o": o, "gate": gate, "w": self.weight})
+
+
+class KimiDeltaAttention(Layer):
+    """Kimi Delta Attention (Kimi Linear, arXiv:2510.26692): a gated
+    delta-rule linear attention whose decay is a vector per head and
+    token.  For token t and head h (dk = dv = `head_dim`):
+
+        q', k', v = SiLU(Conv(x W_q)), SiLU(Conv(x W_k)), SiLU(Conv(x W_v))
+        q, k   = q' / |q'|, k' / |k'|          per head (rsqrt(sum + 1e-6))
+        g_t    = -exp(A_log[h]) softplus((x W_fa) W_fb + dt_bias)   <= 0
+        beta_t = sigmoid(x w_b[h])
+        S_t    = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+        o_t    = head_dim^-1/2 S_t^T q_t
+        out    = [RMSNorm_head(o_t) * sigmoid((x W_ga) W_gb)] W_o
+
+    The convolutions are depthwise and causal (`conv_width` taps);
+    W_fa, W_ga project to `head_dim`, W_fb, W_gb back to H * head_dim;
+    no biases but `dt_bias`.  `A_log` (H,) starts at log U(1, 16),
+    `dt_bias` (H * head_dim,) at the inverse softplus of a log-uniform
+    step in [1e-3, 1e-1] (the Mamba-2 / Gated DeltaNet convention);
+    both stay float32.  The recurrence runs as the chunked scan of
+    ops/pallas/kda.py (scope `kda_core`).
+
+    forward(x (B, S, E)) -> (B, S, E); causal by construction, and the
+    layer carries position itself: it takes none."""
+
+    def __init__(self, embed_dim, num_heads, head_dim=128, conv_width=4,
+                 epsilon=1e-5, weight_attr=None):
+        super().__init__()
+        self.num_heads, self.head_dim = num_heads, head_dim
+        width = num_heads * head_dim
+        lin = lambda i, o: Linear(i, o, weight_attr, False)
+        self.q_proj, self.k_proj, self.v_proj = (
+            lin(embed_dim, width) for _ in range(3))
+        self.q_conv1d, self.k_conv1d, self.v_conv1d = (
+            ShortConvSiLU(width, conv_width) for _ in range(3))
+        self.f_a_proj, self.f_b_proj = lin(embed_dim, head_dim), lin(
+            head_dim, width)
+        self.b_proj = lin(embed_dim, num_heads)
+        self.g_a_proj, self.g_b_proj = lin(embed_dim, head_dim), lin(
+            head_dim, width)
+        self.A_log = self.create_parameter(
+            shape=[num_heads], default_initializer=UniformInitializer(1, 16))
+        self.A_log._value = jnp.log(self.A_log._value)
+        self.dt_bias = self.create_parameter(
+            shape=[width], default_initializer=UniformInitializer(
+                np.log(1e-3), np.log(1e-1)))
+        dt = jnp.exp(self.dt_bias._value)
+        self.dt_bias._value = dt + jnp.log(-jnp.expm1(-dt))
+        self.kda_core = _KDACore()
+        self.o_norm = _GatedHeadNorm(head_dim, epsilon)
+        self.o_proj = lin(width, embed_dim)
+
+    def forward(self, x):
+        h, d = self.num_heads, self.head_dim
+        q = self.q_conv1d(self.q_proj(x))
+        k = self.k_conv1d(self.k_proj(x))
+        v = self.v_conv1d(self.v_proj(x))
+        f = self.f_b_proj(self.f_a_proj(x))
+
+        @jax.checkpoint     # elementwise: recomputed from its operands
+        def prepare(q, k, v, f, b, a_log, dt_bias):
+            heads = lambda a: a.reshape(a.shape[:2] + (h, d))
+
+            def unit(a):
+                y = heads(a).astype(jnp.float32)
+                return (y * jax.lax.rsqrt(jnp.sum(
+                    jnp.square(y), -1, keepdims=True) + 1e-6)).astype(a.dtype)
+
+            g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
+                heads(f.astype(jnp.float32) + dt_bias.astype(jnp.float32)))
+            return (unit(q), unit(k), heads(v), g,
+                    jax.nn.sigmoid(b.astype(jnp.float32)))
+
+        q, k, v, g, beta = trace_fn(prepare, {
+            "q": q, "k": k, "v": v, "f": f, "b": self.b_proj(x),
+            "a_log": self.A_log, "dt_bias": self.dt_bias}, multi_out=True)
+        o = self.kda_core(q, k, v, g, beta)
+        return self.o_proj(self.o_norm(o, self.g_b_proj(self.g_a_proj(x))))
 
 
 class GatedFFN(Layer):

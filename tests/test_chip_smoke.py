@@ -72,6 +72,21 @@ class TestPhasesTiny:
         assert out["flash_split_value_total"] == 0
         assert out["flash_fwd_pieces_total"] == 0
 
+    def test_kimi_linear_step(self):
+        from paddle_tpu.models import kimi_linear
+
+        out = chip_smoke.kimi_linear_step(
+            kimi_linear.KimiLinearConfig.tiny(experts_held=(0, 4),
+                                              recompute=True),
+            batch=2, seq=16, steps=3, platform="cpu")
+        assert out["moe_sigmoid_router_total"] == 3
+        assert out["moe_bias_updates_total"] == 9
+        assert out["moe_router_rows_total"] == 3 * 3 * 32 * 2
+        # off the chip the scan runs a token at a time, uncounted, and
+        # attention takes the XLA path: no kernel
+        assert out["kda_chunked_total"] == out["kda_fallback_total"] == 0
+        assert out["flash_split_value_total"] == 0
+
     def test_failed_check_raises(self):
         ph = chip_smoke._Phase("x")
         ph.check(True, "fine")

@@ -590,6 +590,30 @@ class TestMosaicAcceptsForV5e:
                               block_mask=A.BlockDiffusionMask(512, 32))
 
 
+    def test_kda_scan_kernels(self, v5e):
+        """The Kimi cell's scan (ops/pallas/kda.py): `kda_fwd` and
+        `kda_bwd` on head-major (64, 128) float32 blocks, a group of 8
+        heads of 128, the state in VMEM over the chunk axis, matmuls
+        at full float32 precision — forward and the hand-written
+        backward, with the chunk-local XLA part around them, compiled
+        whole for a v5e."""
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from paddle_tpu.ops.pallas import _common
+        from paddle_tpu.ops.pallas.kda import kda_attention
+
+        put = lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=_common._COMPILE_TARGET)
+        args = (put((1, 1024, 8, 128), jnp.bfloat16),) * 3 + (
+            put((1, 1024, 8, 128), jnp.float32),
+            put((1, 1024, 8), jnp.float32))
+        loss = lambda *a: jnp.sum(kda_attention(*a).astype(jnp.float32))
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *args).compile().as_text()
+        for kernel in ("kda_fwd", "kda_bwd"):
+            assert kernel in text
+
+
 def test_flash_per_shard_matches_unsharded():
     """`sharded_attention_scope`'s kernel path: flash attention under
     shard_map over (batch, heads) equals the unsharded kernel — the

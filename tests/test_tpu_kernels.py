@@ -477,12 +477,55 @@ def test_forward_body_at_the_moe_cells_shapes(cell, h, hkv, d, dv, kw,
                                atol=2e-2, rtol=2e-2)
 
 
+def test_kda_scan_against_the_recurrence_on_tpu():
+    """The chunked gated delta-rule scan (ops/pallas/kda.py: `kda_fwd`,
+    `kda_bwd` through Mosaic, the chunk-local part as XLA's float32
+    matmuls) at the Kimi cell's head shape — 128-wide heads, bfloat16
+    q / k / v, float32 gate — against the recurrence a token at a time
+    on the same chip, outputs and all five operands' gradients; a
+    length that is no multiple of 64, decays down to -1 a token."""
+    from paddle_tpu.nn.functional import kda as X
+    from paddle_tpu.ops.pallas.kda import kda_attention
+
+    rng = np.random.RandomState(90)
+    b, s, h, d = 1, 1000, 4, 128
+    unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
+    q, k = (jnp.asarray(unit(rng.randn(b, s, h, d)), jnp.bfloat16)
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(b, s, h, d), jnp.bfloat16)
+    g = jnp.asarray(-rng.uniform(0.001, 1.0, (b, s, h, d)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.05, 0.95, (b, s, h)), jnp.float32)
+    w = _rand((b, s, h, d), 91)
+    before = profiler.get_int_stats()
+
+    def out_and_grads(scan):
+        def f(*a):
+            out, vjp = jax.vjp(lambda *a: scan(*a).astype(jnp.float32), *a)
+            return out, vjp(w)
+        return jax.jit(f)(q, k, v, g, beta)
+
+    out, got = out_and_grads(kda_attention)
+    ref, want = out_and_grads(
+        lambda *a: X.recurrent(*a, d ** -0.5))
+    after = profiler.get_int_stats()
+    assert after.get("kda_chunked_total", 0) \
+        == before.get("kda_chunked_total", 0) + 1
+    assert after.get("kda_chunks_total", 0) \
+        == before.get("kda_chunks_total", 0) + 16
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-2, rtol=2e-2)
+    for a, b_ in zip(got, want):
+        a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
+        assert np.linalg.norm(a - b_) <= 2e-2 * np.linalg.norm(b_)
+
+
 def test_no_kernel_gave_way():
     """Runs last: nothing above (and no other tpu-marked test before
     it) may have pushed a kernel onto its XLA path."""
     stats = profiler.get_int_stats()
     assert stats.get("flash_fallback_total", 0) == 0
     assert stats.get("serving_ragged_fallback_total", 0) == 0
+    assert stats.get("kda_fallback_total", 0) == 0
 
 
 def test_packed_layout_engaged():
