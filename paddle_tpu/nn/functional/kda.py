@@ -1,7 +1,10 @@
-"""The XLA half of Kimi Delta Attention (arXiv:2510.26692): the gated
+"""Kimi Delta Attention (arXiv:2510.26692) in plain XLA: the gated
 delta-rule recurrence with a per-channel decay, a token at a time (the
-fallback and the tests' oracle), and the chunk-local quantities of its
-chunked form, which `ops/pallas/kda.py` walks along the sequence.
+fallback for shapes the kernels refuse, and the tests' oracle), and
+the float32 statement of its chunked form (`_local`: the oracle of
+what `ops/pallas/kda.py` makes in VMEM).  No cell runs this file's
+chunked form: since PR 35 both halves of it, chunk-local and
+chunk-sequential, are inside the kernels `kda_fwd` / `kda_bwd`.
 
 The recurrence, for one head (S in R^{dk x dv}, float32, S_0 = 0):
 
@@ -21,9 +24,9 @@ S_0 the state entering the chunk:
                                 Aqk_ij = scale sum_c q_ic k_jc exp(G_ic - G_jc)  (j <= i)
     S_C  = Diag(d) S_0 + Kg^T U             Kg = k . exp(G_C - G),  d = exp(G_C)
 
-W, U0, Qg, Kg, Aqk and d need no state: `chunk_local` makes them for
-every chunk at once, as batched float32 matmuls; the three lines that
-need S_0 are the kernels'.
+W, U0, Qg, Kg, Aqk and d need no state: `_local` makes them for
+every chunk at once, as batched float32 matmuls (the kernels make them
+a chunk and head at a time, beside the three lines that need S_0).
 
 Every exponent is a difference G_i - G_j with i >= j, or G_i alone: at
 most 0.  exp(G_i - G_j) sits INSIDE the contraction over channels; a
@@ -45,9 +48,6 @@ import jax.numpy as jnp
 CHUNK = 64
 SUB = 16
 _HI = jax.lax.Precision.HIGHEST
-# chunks a `lax.map` iteration of `chunk_local` handles: bounds the
-# pairwise (16, 16, dk) temporaries of the diagonal sub-blocks
-_SEGMENT_CHUNKS = 32
 
 
 def recurrent(q, k, v, g, beta, scale):
@@ -128,8 +128,11 @@ inv_unit_lower.defvjp(_inv_fwd, _inv_bwd)
 
 
 def _local(q, k, v, g, beta, scale):
-    """`chunk_local` for (B, N, C, H, d) operands in float32 -> W, U0,
-    Qg, Kg (B, N, C, H, d), Aqk (B, N, H, C, C), d (B, N, H, dk)."""
+    """The quantities of the chunked form that need no state, every
+    chunk at once: (B, N, C, H, d) operands in float32 (C = 64) -> W,
+    U0, Qg, Kg (B, N, C, H, d), Aqk (B, N, H, C, C), d (B, N, H, dk).
+    The pairwise (16, 16, dk) temporaries make it a test-size
+    function."""
     b, n, c, h, dk = q.shape
     nb = c // SUB
     gc = jnp.cumsum(g, axis=2)
@@ -172,31 +175,6 @@ def _local(q, k, v, g, beta, scale):
     last = gc[:, :, -1]                                 # (B, N, H, dk)
     return (w, u0, scale * q * e_g, k * jnp.exp(last[:, :, None] - gc),
             scale * whole(q), jnp.exp(last))
-
-
-def largest_divisor(n, at_most):
-    return max(s for s in range(1, min(n, at_most) + 1) if n % s == 0)
-
-
-def chunk_local(q, k, v, g, beta, scale):
-    """The quantities of the chunked form that need no state.  q, k, g
-    (B, S, H, dk), v (B, S, H, dv), beta (B, S, H), S a multiple of 64
-    -> W, Qg, Kg (B, S, H * dk), U0 (B, S, H * dv), Aqk (B, S / 64, H,
-    64, 64), d (B, S / 64, 1, H * dk), all float32.  A `lax.map` over
-    segments of at most 32 chunks, each recomputed in the backward
-    pass, bounds what lives at once."""
-    b, s, h, dk = q.shape
-    n = s // CHUNK
-    seg = largest_divisor(n, _SEGMENT_CHUNKS)
-    cut = lambda a: jnp.moveaxis(a.astype(jnp.float32).reshape(
-        (b, n // seg, seg, CHUNK) + a.shape[2:]), 1, 0)
-    outs = jax.lax.map(
-        jax.checkpoint(lambda x: _local(*x, scale)),
-        tuple(cut(a) for a in (q, k, v, g, beta)))
-    w, u0, qg, kg, aqk, d = (jnp.moveaxis(a, 0, 1) for a in outs)
-    rows = lambda a: a.reshape(b, s, -1)
-    return (rows(w), rows(u0), rows(qg), rows(kg),
-            aqk.reshape(b, n, h, CHUNK, CHUNK), d.reshape(b, n, 1, h * dk))
 
 
 def short_conv(x, taps):

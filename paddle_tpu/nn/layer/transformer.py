@@ -267,8 +267,8 @@ class ShortConvSiLU(Layer):
 
 class _KDACore(Layer):
     """The scan of Kimi Delta Attention, a layer of its own so that all
-    of it — the chunk-local XLA part and the kernels — runs under the
-    scope `kda_core`."""
+    of it — the two kernels and the reshapes at their edge — runs under
+    the scope `kda_core`."""
 
     def forward(self, q, k, v, g, beta):
         return F.kda_attention(q, k, v, g, beta)

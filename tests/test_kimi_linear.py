@@ -1,6 +1,7 @@
 """Kimi Linear: the chunked gated delta-rule scan (interpret-mode
-kernels and their XLA part) against the recurrence a token at a time,
-the model against the plain reference (benchmark/reference/
+kernels: their chunk-local half against its float32 statement, the
+whole and its hand-written backward) against the recurrence a token
+at a time, the model against the plain reference (benchmark/reference/
 kimi_linear.py — the one the benchmark's `correct` uses), the share
 test that ties a chip's share to the whole layer, latent attention
 without a query latent and without rotation, and the counters."""
@@ -47,47 +48,166 @@ def _rel(a, b):
 
 # -- the scan against the recurrence -----------------------------------------
 
-@functools.lru_cache(maxsize=None)
-def _scan_and_recurrence(s, g_min, head_group):
-    """Outputs and all five operands' gradients of the chunked scan
-    (interpret-mode kernels) and of the recurrence, B 2, H 2, float32."""
-    args = _operands(2, s, 2, 128, seed=s, g_min=g_min)
-    w = jnp.asarray(np.random.default_rng(9).normal(size=(2, s, 2, 128)),
+def _out_and_grads(scan, args, seed=9):
+    w = jnp.asarray(np.random.default_rng(seed).normal(size=args[2].shape),
                     jnp.float32)
-    mp = pytest.MonkeyPatch()
-    mp.setattr(K, "_HEAD_GROUP", head_group)
-    try:
-        def both(scan):
-            out, vjp = jax.vjp(scan, *args)
-            return out, vjp(w)
+    out, vjp = jax.vjp(scan, *args)
+    return out, vjp(w)
 
-        got = both(lambda *a: K.kda_attention(*a, interpret=True))
-    finally:
-        mp.undo()
-    return got, both(lambda *a: X.recurrent(*a, SCALE))
+
+@functools.lru_cache(maxsize=None)
+def _scan_and_recurrence(s, g_min, heads, batch):
+    """Outputs and all five operands' gradients of the chunked scan
+    (interpret-mode kernels) and of the recurrence, float32."""
+    args = _operands(batch, s, heads, 128, seed=s, g_min=g_min)
+    return (_out_and_grads(lambda *a: K.kda_attention(*a, interpret=True),
+                           args),
+            _out_and_grads(lambda *a: X.recurrent(*a, SCALE), args))
 
 
 # S = 64, 192, a length that is no multiple of 64; strong decay (g down
-# to -5 a token: -320 cumulated in a chunk); a head at a time
-CASES = [(64, -0.5, 8), (192, -0.5, 8), (100, -0.5, 8), (128, -5.0, 8),
-         (128, -0.5, 1)]
+# to -5 a token: -320 cumulated in a chunk); one head, an odd count (a
+# head a grid step), 8 (two a grid step); batch 1 and 2
+CASES = [(64, -0.5, 2, 2), (192, -0.5, 8, 1), (100, -0.5, 3, 2),
+         (128, -5.0, 2, 2), (128, -0.5, 1, 2)]
 
 
-@pytest.mark.parametrize("s,g_min,head_group", CASES)
-def test_chunked_scan_output_matches_recurrence(s, g_min, head_group):
-    (out, _), (ref, _) = _scan_and_recurrence(s, g_min, head_group)
-    assert out.shape == ref.shape == (2, s, 2, 128)
+@pytest.mark.parametrize("s,g_min,heads,batch", CASES)
+def test_chunked_scan_output_matches_recurrence(s, g_min, heads, batch):
+    (out, _), (ref, _) = _scan_and_recurrence(s, g_min, heads, batch)
+    assert out.shape == ref.shape == (batch, s, heads, 128)
     assert bool(jnp.isfinite(out).all())
     assert _rel(out, ref) < 1e-5
 
 
 @pytest.mark.parametrize("operand", range(5), ids=OPERANDS)
-@pytest.mark.parametrize("s,g_min,head_group", CASES)
-def test_chunked_scan_gradient_matches_recurrence(s, g_min, head_group,
+@pytest.mark.parametrize("s,g_min,heads,batch", CASES)
+def test_chunked_scan_gradient_matches_recurrence(s, g_min, heads, batch,
                                                   operand):
-    (_, got), (_, want) = _scan_and_recurrence(s, g_min, head_group)
+    (_, got), (_, want) = _scan_and_recurrence(s, g_min, heads, batch)
     assert bool(jnp.isfinite(got[operand]).all())
     assert _rel(got[operand], want[operand]) < 2e-5
+
+
+# -- the kernels' chunk-local half against its float32 statement -------------
+
+LOCAL = ("W", "U0", "Qg", "Kg", "Aqk", "d")
+
+
+def _kernel_local(q, k, v, g, beta):
+    """W, U0, Qg, Kg, Aqk, d of every chunk and head as the kernels'
+    body makes them in VMEM (ops/pallas/kda.py: `_chunk_matmuls`,
+    `_chunk_local`), through a thin interpret-mode harness, in
+    `X._local`'s layouts."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, h, d = q.shape
+    n = s // X.CHUNK
+
+    def body(q_ref, k_ref, v_ref, g_ref, beta_ref, w_ref, u0_ref, qg_ref,
+             kg_ref, aqk_ref, d_ref, g_scr, k_scr):
+        x = K._chunk_local(K._chunk_matmuls(
+            0, 1, q_ref, k_ref, v_ref, g_ref, beta_ref,
+            jnp.zeros((d, d), jnp.float32), g_scr, k_scr, SCALE))
+        w_ref[0] = K._dot(x.t, x.bk, K._NN)
+        u0_ref[0] = x.u                 # T (bv - bk S) at S = 0
+        qg_ref[0], kg_ref[0] = x.qg, x.kg
+        aqk_ref[0, 0, 0], d_ref[0, 0, 0] = x.aqk, x.d
+
+    rows, beta_rows, _, _ = K._specs(n, h, 1, False)
+    at = lambda shape: pl.BlockSpec((1, 1, 1) + shape,
+                                    lambda b, i, c: (b, c, i, 0, 0))
+    flat = lambda a: a.reshape(b, s, -1)
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    w, u0, qg, kg, aqk, dd = pl.pallas_call(
+        body, grid=(b, h, n), in_specs=[rows] * 4 + [beta_rows],
+        out_specs=[rows] * 4 + [at((X.CHUNK, X.CHUNK)), at((1, d))],
+        out_shape=[f32(b, s, h * d)] * 4 + [f32(b, n, h, X.CHUNK, X.CHUNK),
+                                            f32(b, n, h, 1, d)],
+        scratch_shapes=[pltpu.VMEM((X.CHUNK, d), jnp.float32)] * 2,
+        interpret=True)(flat(q), flat(k), flat(v), flat(g), beta)
+    cut = lambda a: a.reshape(b, n, X.CHUNK, h, d)
+    return cut(w), cut(u0), cut(qg), cut(kg), aqk, dd[:, :, :, 0]
+
+
+def _cut(args):
+    b, s = args[0].shape[:2]
+    return tuple(a.reshape((b, s // X.CHUNK, X.CHUNK) + a.shape[2:])
+                 for a in args)
+
+
+@functools.lru_cache(maxsize=None)
+def _local_both(g_min):
+    args = _operands(2, 128, 3, 128, seed=7, g_min=g_min)
+    return _kernel_local(*args), X._local(*_cut(args), SCALE)
+
+
+@pytest.mark.parametrize("g_min", [-0.5, -5.0])
+@pytest.mark.parametrize("quantity", range(6), ids=LOCAL)
+def test_kernel_chunk_local_matches_its_float32_statement(quantity, g_min):
+    got, want = _local_both(g_min)
+    assert got[quantity].shape == want[quantity].shape
+    assert bool(jnp.isfinite(got[quantity]).all())
+    # (at -5 a token d underflows to 0 in both)
+    assert float(jnp.linalg.norm(got[quantity] - want[quantity])) \
+        <= 1e-5 * float(jnp.linalg.norm(want[quantity]))
+
+
+def _chunked_oracle(q, k, v, g, beta):
+    """The chunked form in XLA: `X._local` and the three lines that need
+    the state, a `lax.scan` over chunks — autodiff of it is `jax.vjp(
+    _local)` composed with the lines the backward kernel transposes."""
+    b, s, h, d = q.shape
+    local = X._local(*_cut((q, k, v, g, beta)), SCALE)
+    ein = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+
+    def chunk(state, x):                            # state (B, H, dk, dv)
+        w, u0, qg, kg, aqk, dd = x
+        u = u0 - ein("bchk,bhkv->bchv", w, state)
+        o = ein("bchk,bhkv->bchv", qg, state) + ein("bhij,bjhv->bihv", aqk, u)
+        return dd[..., None] * state + ein("bchk,bchv->bhkv", kg, u), o
+
+    _, o = jax.lax.scan(chunk, jnp.zeros((b, h, d, d), jnp.float32),
+                        tuple(jnp.moveaxis(a, 1, 0) for a in local))
+    return jnp.moveaxis(o, 0, 1).reshape(b, s, h, d)
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_and_oracle():
+    args = _operands(1, 192, 2, 128, seed=11, g_min=-1.0)
+    return (_out_and_grads(lambda *a: K.kda_attention(*a, interpret=True),
+                           args), _out_and_grads(_chunked_oracle, args))
+
+
+@pytest.mark.parametrize("operand", range(5), ids=OPERANDS)
+def test_hand_written_backward_matches_autodiff_of_the_chunked_form(operand):
+    """dq, dk, dv, dg, dbeta of `kda_bwd` (three chunks: dS is carried)
+    against `jax.vjp` through `_local` and the walk's lines."""
+    (out, got), (ref, want) = _kernel_and_oracle()
+    assert _rel(out, ref) < 1e-5
+    assert _rel(got[operand], want[operand]) < 2e-5
+
+
+@functools.lru_cache(maxsize=None)
+def _repeated_keys():
+    """Two keys a head, each repeated over the whole sequence, beta ->
+    1, hardly any decay: I + A is then as far from the identity as it
+    gets, and a finite Neumann product of A is wrong."""
+    q, k, v, g, beta = _operands(1, 128, 2, 128, seed=13, g_min=-0.01)
+    k = jnp.broadcast_to(k[:, :2], (1, 64, 2, 2, 128)).reshape(1, 128, 2, 128)
+    args = (q, k, v, g, jnp.full_like(beta, 0.999))
+    return (_out_and_grads(lambda *a: K.kda_attention(*a, interpret=True),
+                           args),
+            _out_and_grads(lambda *a: X.recurrent(*a, SCALE), args))
+
+
+@pytest.mark.parametrize("what", range(6), ids=("out",) + OPERANDS)
+def test_repeated_keys_with_beta_near_one(what):
+    (out, got), (ref, want) = _repeated_keys()
+    a, b = ((out,) + got)[what], ((ref,) + want)[what]
+    assert bool(jnp.isfinite(a).all())
+    assert _rel(a, b) < 1e-4
 
 
 def test_inverse_of_unit_lower_triangular():
@@ -107,13 +227,15 @@ def test_inverse_of_unit_lower_triangular():
 
 
 def test_exponents_never_positive_under_strong_decay():
-    """The chunk-local quantities stay finite and bounded at -5 a token
-    (-320 cumulated): no exponent above 0 is ever formed."""
-    q, k, v, g, beta = _operands(1, 128, 2, 128, seed=3, g_min=-5.0)
-    local = X.chunk_local(q, k, v, g, beta, SCALE)
+    """The kernels' chunk-local quantities stay finite and bounded at -5
+    a token (-320 cumulated): no exponent above 0 is ever formed."""
+    local = _kernel_local(*_operands(1, 128, 2, 128, seed=3, g_min=-5.0))
     assert all(bool(jnp.isfinite(a).all()) for a in local)
     # |Aqk_ij| <= scale |q_i| |k_j|, the decay a contraction
     assert float(jnp.abs(local[4]).max()) <= SCALE * 1.0001
+    # Qg, Kg and d are decays of q and k: never above them
+    assert float(jnp.abs(local[3]).max()) <= 1.0001
+    assert float(local[5].max()) <= 1.0
 
 
 def test_short_conv_is_causal_and_depthwise():

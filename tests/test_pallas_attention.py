@@ -590,28 +590,31 @@ class TestMosaicAcceptsForV5e:
                               block_mask=A.BlockDiffusionMask(512, 32))
 
 
-    def test_kda_scan_kernels(self, v5e):
-        """The Kimi cell's scan (ops/pallas/kda.py): `kda_fwd` and
-        `kda_bwd` on head-major (64, 128) float32 blocks, a group of 8
-        heads of 128, the state in VMEM over the chunk axis, matmuls
-        at full float32 precision — forward and the hand-written
-        backward, with the chunk-local XLA part around them, compiled
-        whole for a v5e."""
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
+    @pytest.mark.parametrize("tokens,heads", [(16384, 32), (256, 3)])
+    def test_kda_scan_kernels(self, v5e, tokens, heads):
+        """The Kimi cell's scan (ops/pallas/kda.py) at the cell's shape:
+        one `kda_fwd` and one `kda_bwd` call with all 32 heads of 128
+        over 16,384 tokens — (64, 128) blocks of the projections' own
+        layout, the chunk-local half (cumulated gate, scores, the
+        inverse by substitution and merges) and the hand-written
+        backward in VMEM, two heads a grid step, the state in VMEM over
+        the chunk axis, matmuls at full float32 precision — compiled
+        whole for a v5e; and an odd head count (a head a grid step)."""
         from paddle_tpu.ops.pallas import _common
         from paddle_tpu.ops.pallas.kda import kda_attention
 
         put = lambda shape, dtype: jax.ShapeDtypeStruct(
             shape, dtype, sharding=_common._COMPILE_TARGET)
-        args = (put((1, 1024, 8, 128), jnp.bfloat16),) * 3 + (
-            put((1, 1024, 8, 128), jnp.float32),
-            put((1, 1024, 8), jnp.float32))
+        args = (put((1, tokens, heads, 128), jnp.bfloat16),) * 3 + (
+            put((1, tokens, heads, 128), jnp.float32),
+            put((1, tokens, heads), jnp.float32))
         loss = lambda *a: jnp.sum(kda_attention(*a).astype(jnp.float32))
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
             *args).compile().as_text()
-        for kernel in ("kda_fwd", "kda_bwd"):
-            assert kernel in text
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert sum("_kda_forward" in c for c in calls) == 1
+        assert sum("_kda_backward" in c for c in calls) == 1
 
 
 def test_flash_per_shard_matches_unsharded():

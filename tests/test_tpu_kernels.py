@@ -477,46 +477,81 @@ def test_forward_body_at_the_moe_cells_shapes(cell, h, hkv, d, dv, kw,
                                atol=2e-2, rtol=2e-2)
 
 
-def test_kda_scan_against_the_recurrence_on_tpu():
-    """The chunked gated delta-rule scan (ops/pallas/kda.py: `kda_fwd`,
-    `kda_bwd` through Mosaic, the chunk-local part as XLA's float32
-    matmuls) at the Kimi cell's head shape — 128-wide heads, bfloat16
-    q / k / v, float32 gate — against the recurrence a token at a time
-    on the same chip, outputs and all five operands' gradients; a
-    length that is no multiple of 64, decays down to -1 a token."""
-    from paddle_tpu.nn.functional import kda as X
-    from paddle_tpu.ops.pallas.kda import kda_attention
-
-    rng = np.random.RandomState(90)
-    b, s, h, d = 1, 1000, 4, 128
+def _kda_operands(b, s, h, d, seed):
+    rng = np.random.RandomState(seed)
     unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
     q, k = (jnp.asarray(unit(rng.randn(b, s, h, d)), jnp.bfloat16)
             for _ in range(2))
     v = jnp.asarray(rng.randn(b, s, h, d), jnp.bfloat16)
     g = jnp.asarray(-rng.uniform(0.001, 1.0, (b, s, h, d)), jnp.float32)
     beta = jnp.asarray(rng.uniform(0.05, 0.95, (b, s, h)), jnp.float32)
-    w = _rand((b, s, h, d), 91)
+    return q, k, v, g, beta
+
+
+def _kda_out_and_grads(scan, args, w):
+    def f(*a):
+        out, vjp = jax.vjp(lambda *a: scan(*a).astype(jnp.float32), *a)
+        return out, vjp(w)
+    return jax.jit(f)(*args)
+
+
+def _assert_kda_close(got, want):
+    (out, grads), (ref, ref_grads) = got, want
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-2, rtol=2e-2)
+    for a, b_ in zip(grads, ref_grads):
+        a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
+        assert np.linalg.norm(a - b_) <= 2e-2 * np.linalg.norm(b_)
+
+
+@pytest.mark.parametrize("heads", [4, 3])
+def test_kda_scan_against_the_recurrence_on_tpu(heads):
+    """The chunked gated delta-rule scan (ops/pallas/kda.py: `kda_fwd`,
+    `kda_bwd` through Mosaic, the chunk-local half made in VMEM inside
+    them, the backward written by hand) at the Kimi cell's head shape —
+    128-wide heads, bfloat16 q / k / v, float32 gate — against the
+    recurrence a token at a time on the same chip, outputs and all
+    five operands' gradients; a length that is no multiple of 64,
+    decays down to -1 a token; two heads a grid step and (3 heads) one."""
+    from paddle_tpu.nn.functional import kda as X
+    from paddle_tpu.ops.pallas.kda import kda_attention
+
+    b, s, d = 1, 1000, 128
+    args = _kda_operands(b, s, heads, d, 90)
+    w = _rand((b, s, heads, d), 91)
     before = profiler.get_int_stats()
-
-    def out_and_grads(scan):
-        def f(*a):
-            out, vjp = jax.vjp(lambda *a: scan(*a).astype(jnp.float32), *a)
-            return out, vjp(w)
-        return jax.jit(f)(q, k, v, g, beta)
-
-    out, got = out_and_grads(kda_attention)
-    ref, want = out_and_grads(
-        lambda *a: X.recurrent(*a, d ** -0.5))
+    got = _kda_out_and_grads(kda_attention, args, w)
     after = profiler.get_int_stats()
     assert after.get("kda_chunked_total", 0) \
         == before.get("kda_chunked_total", 0) + 1
     assert after.get("kda_chunks_total", 0) \
         == before.get("kda_chunks_total", 0) + 16
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-2, rtol=2e-2)
-    for a, b_ in zip(got, want):
-        a, b_ = np.asarray(a, np.float32), np.asarray(b_, np.float32)
-        assert np.linalg.norm(a - b_) <= 2e-2 * np.linalg.norm(b_)
+    _assert_kda_close(got, _kda_out_and_grads(
+        lambda *a: X.recurrent(*a, d ** -0.5), args, w))
+
+
+def test_kda_scan_at_the_cell_shape_on_tpu():
+    """One call of each kernel with all 32 heads at 16,384 tokens (the
+    Kimi cell's instance: 8,192 chunk-heads a call, nothing of a chunk
+    through HBM but the operands and the entering states): the first
+    1,024 tokens' outputs, and every gradient under a cotangent that is
+    zero beyond them, equal the recurrence's on that prefix — and the
+    gradients beyond it are exactly zero."""
+    from paddle_tpu.nn.functional import kda as X
+    from paddle_tpu.ops.pallas.kda import kda_attention
+
+    b, s, h, d, n = 1, 16384, 32, 128, 1024
+    args = _kda_operands(b, s, h, d, 92)
+    w = _rand((b, s, h, d), 93).at[:, n:].set(0.0)
+    out, grads = _kda_out_and_grads(kda_attention, args, w)
+    assert bool(jnp.isfinite(out).all())
+    for grad in grads:
+        assert float(jnp.abs(grad[:, n:].astype(jnp.float32)).max()) == 0.0
+    prefix = tuple(a[:, :n] for a in args)
+    _assert_kda_close((out[:, :n], tuple(a[:, :n] for a in grads)),
+                      _kda_out_and_grads(
+                          lambda *a: X.recurrent(*a, d ** -0.5), prefix,
+                          w[:, :n]))
 
 
 def test_no_kernel_gave_way():
