@@ -817,6 +817,19 @@ def kimi_linear_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
 
 # ---------------------------------------------------------------------------
 
+def _print_startup_phases() -> None:
+    """Where this process's start-up went so far, by the program's own
+    phases (docs/observability.md "Start-up"): seconds a name, nested
+    phases not taken out of their parents."""
+    from paddle_tpu import profiler
+
+    totals = {name: round(s, 2)
+              for name, s in profiler.phase_totals().items()
+              if "/" not in name}
+    print(f"chip_smoke: start-up phases through the first phase, s: "
+          f"{totals}")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
@@ -836,6 +849,7 @@ def main(argv=None) -> int:
         # fp32 Program at the batch of the one on-chip record: the v5e
         # compiler places it in 9.3 of 16 GB (0.3 arguments + 9.0 temp)
         phases.append(executor_resnet50(128))
+        _print_startup_phases()
         phases.append(bert_base_step(bert.BertConfig.base()))
         phases.append(generation_engine())
         # SDAR-30B-A3B's widths, two layers, one chip's 16 of the 128
@@ -858,6 +872,7 @@ def main(argv=None) -> int:
             recompute=True)))
     else:
         phases.append(executor_resnet50(4 * 128, data_parallel=4))
+        _print_startup_phases()
         phases.append(bert_base_step(bert.BertConfig.base(),
                                      mesh_shape=(2, 2)))
     fallbacks = _fallback_counts()

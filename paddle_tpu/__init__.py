@@ -18,35 +18,72 @@ Architecture (vs. the reference, SURVEY.md §7):
 
 from __future__ import annotations
 
+import time as _time
+
+# the phase `setup.import` runs from here to this file's last line
+# (`profiler.add_phase` there: no stage can open before `profiler` is
+# imported); `_imported` closes the child phase of a subpackage's line
+_IMPORT_MARKS = [("", _time.perf_counter())]
+
+
+def _imported(name):
+    _IMPORT_MARKS.append((name, _time.perf_counter()))
+
+
 __version__ = "0.1.0"
 
 from . import fluid
+_imported("fluid")
 from . import ops
+_imported("ops")
 from . import nn
+_imported("nn")
 from . import optimizer
+_imported("optimizer")
 from . import tensor
+_imported("tensor")
 from . import jit
+_imported("jit")
 from . import models
+_imported("models")
 from . import amp
+_imported("amp")
 from . import io
+_imported("io")
 from . import metric
+_imported("metric")
 from . import hapi
+_imported("hapi")
 from .hapi import Model, summary
 from .framework_io import load, save
+_imported("framework_io")
 from . import distribution
+_imported("distribution")
 from . import vision
+_imported("vision")
 from . import text
+_imported("text")
 from . import dataset
+_imported("dataset")
 from . import inference
+_imported("inference")
 from . import transforms
+_imported("transforms")
 from . import profiler
+_imported("profiler")
 from . import obs
+_imported("obs")
 from . import ckpt
+_imported("ckpt")
 from . import utils
+_imported("utils")
 from . import reader
+_imported("reader")
 from .batch import batch
 from . import static
+_imported("static")
 from . import onnx
+_imported("onnx")
 from .fluid.flags import get_flags, set_flags
 from .nn.layer.layers import Layer  # 2.0 alias: paddle.nn.Layer
 from .tensor import (to_tensor, zeros, ones, full, zeros_like, ones_like,
@@ -130,3 +167,20 @@ def disable_static(place=None):
 
     enable_dygraph(place)
 from . import incubate  # noqa: E402,F401
+_imported("incubate")
+
+
+def _record_import_phases():
+    """`setup.import` and a child `setup.import/<subpackage>` for each
+    marked line (a child runs from the mark before it: lines between
+    two marks import what is loaded already); timer `import_ms`."""
+    end = _time.perf_counter()
+    start = _IMPORT_MARKS[0][1]
+    for (_, t0), (name, t1) in zip(_IMPORT_MARKS, _IMPORT_MARKS[1:]):
+        profiler.add_phase("setup.import/" + name, t0, t1 - t0,
+                           parent="setup.import")
+    profiler.add_phase("setup.import", start, end - start)
+    profiler.time_add("import_ms", (end - start) * 1e3)
+
+
+_record_import_phases()

@@ -729,16 +729,17 @@ def maybe_verify_program(program, feed_names=None, fetch_names=None,
     ERROR findings ('on'), warns and continues ('warn'), or is a no-op
     ('off').  Never runs on a cache hit — callers sit behind the
     compile cache — and books its wall time on the `verify_ms`
-    profiler timer so the hot path stays provably free."""
+    profiler timer (and the start-up phase `setup.verify`) so the hot
+    path stays provably free."""
     from ..fluid.flags import flag
 
     mode = str(flag("verify_program", "on")).lower()
     if mode in ("off", "0", "false", "no"):
         return
     from ..obs import span as obs_span
-    from ..profiler import stat_add, timed
+    from ..profiler import stage, stat_add
 
-    with obs_span("verifier.run"), timed("verify_ms"):
+    with obs_span("verifier.run"), stage("setup.verify", "verify_ms"):
         findings = verify_program(program, feed=feed_names,
                                   fetch_list=fetch_names, scope=scope,
                                   donated=donated, tiers=(ERROR,))

@@ -35,7 +35,8 @@ this module; behavior is then byte-identical to the pre-cache compiler.
 Profiler surface: `aot_cache_hits` / `aot_cache_misses` /
 `aot_cache_signature_drift` / `aot_cache_stores` / `aot_cache_errors` /
 `aot_cache_store_unsupported` counters and `aot_cache_load_ms` /
-`aot_cache_store_ms` timers — the cold-start win is provable from
+`aot_cache_store_ms` timers (a load is also the start-up phase
+`setup.cache_load`) — the cold-start win is provable from
 counters alone (bench.py --mode fleet; tools/ci.sh fleet smoke).
 """
 
@@ -185,7 +186,7 @@ def try_load(stable: str, label: str = "",
     corrupted entry is a counted miss — never a crash."""
     if not enabled() or not stable:
         return None, None
-    from ..profiler import stat_add, timed
+    from ..profiler import stage, stat_add
 
     root = cache_dir()
     vol = volatile_signature(mesh_token)
@@ -206,7 +207,7 @@ def try_load(stable: str, label: str = "",
         stat_add("aot_cache_misses")
         return None, None
     try:
-        with timed("aot_cache_load_ms"):
+        with stage("setup.cache_load", "aot_cache_load_ms"):
             with open(os.path.join(path, "meta.json")) as f:
                 meta = json.load(f)
             if meta.get("volatile") != vol:

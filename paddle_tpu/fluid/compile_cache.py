@@ -9,6 +9,10 @@ bounded both; this module is the single implementation) so the serving
 engine's bucket cache is literally the same machinery, not a third
 copy.
 
+Also here (ISSUE 36): the listener that turns JAX's own compile events
+into start-up phases, installed where this module is imported
+(`install_phase_listener`).
+
 Thread safety: the serving engine hits its cache from the dispatch loop
 AND the off-path compiler thread, so every operation takes the lock.
 The training executor is single-threaded per instance; the lock is
@@ -20,6 +24,7 @@ from __future__ import annotations
 import collections
 import os
 import threading
+import time
 from typing import Any, Callable, Iterator, Optional, Tuple
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -61,6 +66,88 @@ def enable_persistent_cache() -> str:
     # should find those too
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     return jax_dir
+
+
+# JAX's own events -> phases of the profiler's phase log (ISSUE 36).
+# They also see the steps that never pass the Executor (`jax.jit(...)
+# .lower().compile()`), and every process pays trace and lower whatever
+# the caches hold.  Each carries `time.time()` stamps and `fun_name`.
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_JAX_PHASES = {_TRACE: "setup.trace", _LOWER: "setup.lower",
+               _BACKEND_COMPILE: "setup.backend_compile"}
+_LISTENING = [False]
+# per thread: `.open`, how many trace and lower events are under way
+# (every `jnp` function is a jit of its own, so tracing or lowering a
+# model fires tens of thousands of trace events inside the one that
+# matters), and `.retrieved`, the compile under way was a cache hit
+_JAX = threading.local()
+
+
+def _on_jax_start(event, start_time, **_):
+    if event == _TRACE or event == _LOWER:
+        _JAX.open = getattr(_JAX, "open", 0) + 1
+
+
+def _on_jax_time_span(event, start_time, end_time, fun_name="", **_):
+    phase = _JAX_PHASES.get(event)
+    if phase is None:
+        return
+    if event != _BACKEND_COMPILE:
+        _JAX.open = max(getattr(_JAX, "open", 0) - 1, 0)
+        if _JAX.open:
+            return      # inside another trace or lower, which covers it
+    from ..profiler import add_phase, stat_add
+
+    if phase == "setup.trace":
+        stat_add("jax_traces_total")
+    elif event == _BACKEND_COMPILE:
+        # served from the persistent cache: the retrieval event fired
+        # inside this interval, and nothing was compiled
+        if getattr(_JAX, "retrieved", False):
+            _JAX.retrieved = False
+            phase = "setup.cache_load"
+        else:
+            stat_add("backend_compiles_total")
+    # JAX stamps time.time(); the phase log is on perf_counter
+    to_perf = time.perf_counter() - time.time()
+    add_phase(phase, start_time + to_perf, end_time - start_time,
+              attrs={"fun_name": fun_name})
+
+
+def _on_jax_duration(event, duration_secs, **_):
+    if event != _CACHE_RETRIEVAL:
+        return
+    from ..profiler import add_phase
+
+    # a duration only, reported as the retrieval ends
+    _JAX.retrieved = True
+    add_phase("setup.cache_load", time.perf_counter() - duration_secs,
+              duration_secs)
+
+
+def install_phase_listener() -> None:
+    """Listen to JAX's trace, lower, backend-compile and cache-retrieval
+    events and keep each as a `setup.*` phase (`profiler.get_phases()`):
+    `setup.trace`, `setup.lower`, `setup.backend_compile` — or
+    `setup.cache_load` where the persistent cache served it —, of
+    traces and lowerings the outermost only; counters
+    `jax_traces_total` and `backend_compiles_total`.  Called once,
+    where this module is imported; calling it again does nothing.  For
+    any other event a listener is one dictionary lookup."""
+    if _LISTENING[0]:
+        return
+    import jax
+
+    jax.monitoring.register_scalar_listener(_on_jax_start)
+    jax.monitoring.register_event_time_span_listener(_on_jax_time_span)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    _LISTENING[0] = True
+
+
+install_phase_listener()
 
 
 class CompileCache:

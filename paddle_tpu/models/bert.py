@@ -273,6 +273,7 @@ def build_pretrain_step(model: BertForPretraining,
     import jax.numpy as jnp
 
     from ..jit import functional_call, functional_state
+    from ..profiler import stage
 
     if use_ring_attention and model.bert.config.attention_probs_dropout_prob:
         raise ValueError(
@@ -288,8 +289,12 @@ def build_pretrain_step(model: BertForPretraining,
     criterion = BertPretrainingCriterion(model.bert.config.vocab_size)
     # copy: the jitted step donates state buffers; the model's live
     # weights must not alias them
-    params0 = {k: jnp.array(v)
-               for k, v in functional_state(model).items()}
+    with stage("setup.state_build", "state_build_ms"):
+        params0 = {k: jnp.array(v)
+                   for k, v in functional_state(model).items()}
+        moments = lambda: {k: jnp.zeros_like(v) for k, v in params0.items()}
+        state = {"params": params0, "m": moments(), "v": moments(),
+                 "t": jnp.int32(0)}
 
     def loss_fn(params, batch, key):
         from ..fluid.dygraph.tracer import rng_key_scope
@@ -396,10 +401,6 @@ def build_pretrain_step(model: BertForPretraining,
                 new_v[k] = v
         return ({"params": new_p, "m": new_m, "v": new_v, "t": t},
                 loss)
-
-    zeros_like = lambda d: {k: jnp.zeros_like(v) for k, v in d.items()}
-    state = {"params": params0, "m": zeros_like(params0),
-             "v": zeros_like(params0), "t": jnp.int32(0)}
 
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec as P

@@ -456,10 +456,17 @@ def train_step_from_loss(model, loss_fn, weight_decay=0.0,
 
     from ..jit import functional_state
     from ..parallel.moe import update_selection_bias
+    from ..profiler import stage
 
-    params0 = {k: v if take_weights else jnp.array(v)
-               for k, v in functional_state(model).items()}
-    biases = bias_names(params0)
+    # master weights (copies unless `take_weights`) and AdamW's moments
+    with stage("setup.state_build", "state_build_ms"):
+        params0 = {k: v if take_weights else jnp.array(v)
+                   for k, v in functional_state(model).items()}
+        biases = bias_names(params0)
+        moments = lambda: {k: jnp.zeros_like(v)
+                           for k, v in params0.items() if k not in biases}
+        state = {"params": params0, "m": moments(), "v": moments(),
+                 "t": jnp.int32(0)}
     gamma = model.config.bias_update_rate
     b1, b2, eps = 0.9, 0.999, 1e-8
 
@@ -493,8 +500,4 @@ def train_step_from_loss(model, loss_fn, weight_decay=0.0,
         return ({"params": new_p, "m": new_m, "v": new_v, "t": t},
                 loss, aux)
 
-    trained = {k: v for k, v in params0.items() if k not in biases}
-    zeros_like = lambda d: {k: jnp.zeros_like(v) for k, v in d.items()}
-    state = {"params": params0, "m": zeros_like(trained),
-             "v": zeros_like(trained), "t": jnp.int32(0)}
     return jax.jit(step, donate_argnums=(0,)), state

@@ -389,9 +389,15 @@ def build_blockdiff_train_step(model: SdarMoeForBlockDiffusion,
     import jax.numpy as jnp
 
     from ..jit import functional_state
+    from ..profiler import stage
 
-    params0 = {k: v if take_weights else jnp.array(v)
-               for k, v in functional_state(model).items()}
+    # master weights (copies unless `take_weights`) and AdamW's moments
+    with stage("setup.state_build", "state_build_ms"):
+        params0 = {k: v if take_weights else jnp.array(v)
+                   for k, v in functional_state(model).items()}
+        moments = lambda: {k: jnp.zeros_like(v) for k, v in params0.items()}
+        state = {"params": params0, "m": moments(), "v": moments(),
+                 "t": jnp.int32(0)}
     loss_fn = build_blockdiff_loss(model, bf16=bf16, probe=probe)
     b1, b2, eps = 0.9, 0.999, 1e-8
 
@@ -418,7 +424,4 @@ def build_blockdiff_train_step(model: SdarMoeForBlockDiffusion,
         return ({"params": new_p, "m": new_m, "v": new_v, "t": t},
                 loss, aux)
 
-    zeros_like = lambda d: {k: jnp.zeros_like(v) for k, v in d.items()}
-    state = {"params": params0, "m": zeros_like(params0),
-             "v": zeros_like(params0), "t": jnp.int32(0)}
     return jax.jit(step, donate_argnums=(0,)), state

@@ -24,6 +24,7 @@ from ...fluid import core, unique_name
 from ...fluid.dygraph.varbase import Tensor
 from ...fluid.initializer import ConstantInitializer, XavierInitializer
 from ...fluid.param_attr import ParamAttr
+from ...profiler import stage, stat_add
 
 
 class Parameter(Tensor):
@@ -96,13 +97,19 @@ class Layer:
             init = ConstantInitializer(0.0) if is_bias else XavierInitializer()
         shape = [int(s) for s in shape]
         np_dt = core.np_dtype(dtype)
-        value = init.eager_value(shape, np.dtype(np_dt).name)
         name = attr.name or unique_name.generate(
             f"{self._full_name}.{'b' if is_bias else 'w'}")
-        return Parameter(
-            value, name=name, trainable=attr.trainable,
-            optimize_attr={"learning_rate": attr.learning_rate},
-            regularizer=attr.regularizer, need_clip=attr.need_clip)
+        # draw, cast and the copy to the device (`Parameter` makes the
+        # jax array): one start-up phase a leaf
+        with stage("setup.param_init", "param_init_ms"):
+            value = init.eager_value(shape, np.dtype(np_dt).name)
+            param = Parameter(
+                value, name=name, trainable=attr.trainable,
+                optimize_attr={"learning_rate": attr.learning_rate},
+                regularizer=attr.regularizer, need_clip=attr.need_clip)
+        stat_add("param_init_total", value.size)
+        stat_add("param_init_bytes_total", value.nbytes)
+        return param
 
     def create_variable(self, name=None, persistable=False, dtype=None):
         value = np.zeros([1], dtype=core.np_dtype(dtype or self._dtype))

@@ -46,6 +46,29 @@ def probe_struct(shape, dtype) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(shape, dtype, sharding=_COMPILE_TARGET)
 
 
+def kernel_trace(kernel: str):
+    """Decorator for a kernel's entry point (and the rules of its
+    `custom_vjp`, which a differentiated program calls later): the
+    time inside it while a program is being traced — some operand is
+    a tracer — is the start-up phase `setup.kernel_trace` (timer
+    `kernel_trace_ms`, attribute `kernel`): building specs and tables,
+    the compile probes, the kernel body's trace.  Called on concrete
+    arrays it adds one scan of the operands."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def entry(*args, **kwargs):
+            if not any(isinstance(a, jax.core.Tracer)
+                       for a in jax.tree_util.tree_leaves((args, kwargs))):
+                return fn(*args, **kwargs)
+            from ...profiler import stage
+
+            with stage("setup.kernel_trace", "kernel_trace_ms",
+                       {"kernel": kernel}):
+                return fn(*args, **kwargs)
+        return entry
+    return wrap
+
+
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)
 

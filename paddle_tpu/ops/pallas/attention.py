@@ -70,7 +70,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import on_tpu, probe_struct, round_up
+from ._common import kernel_trace, on_tpu, probe_struct, round_up
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -1085,6 +1085,7 @@ def _flash_attention(q, k, v, kbias, seed_f, heads, is_causal, scale,
     return out
 
 
+@kernel_trace("flash_attention")
 def _flash_fwd_rule(q, k, v, kbias, seed_f, heads, is_causal, scale,
                     dropout_p, interpret, causal_offset, block_h,
                     block_q, block_k, kv_heads, block_mask, biased):
@@ -1099,6 +1100,7 @@ def _flash_fwd_rule(q, k, v, kbias, seed_f, heads, is_causal, scale,
     return out, (q, k, v, kbias, seed, out, lse)
 
 
+@kernel_trace("flash_attention")
 def _flash_bwd_rule(heads, is_causal, scale, dropout_p, interpret,
                     causal_offset, block_h, block_q, block_k, kv_heads,
                     block_mask, biased, res, g):
@@ -1167,6 +1169,7 @@ def _block_h_ladder(heads, lane_d=None, max_h=8):
             and B <= max_h]
 
 
+@kernel_trace("flash_attention")
 def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
                     dropout_p=0.0, dropout_seed=None, block_q=None,
                     block_k=None, interpret=False, block_mask=None):

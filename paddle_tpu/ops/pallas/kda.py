@@ -477,6 +477,7 @@ def _kda_chunked(q, k, v, g, beta, scale, interpret):
     return _chunked_fwd(q, k, v, g, beta, scale, interpret)[0]
 
 
+@_common.kernel_trace("kda_attention")
 def _chunked_fwd(q, k, v, g, beta, scale, interpret):
     b, s = q.shape[:2]
     flat = tuple(a.reshape(b, s, -1) for a in (q, k, v, g))
@@ -484,6 +485,7 @@ def _chunked_fwd(q, k, v, g, beta, scale, interpret):
     return o.reshape(v.shape), (q, k, v, g, beta, states)
 
 
+@_common.kernel_trace("kda_attention")
 def _chunked_bwd(scale, interpret, res, do):
     q, k, v, g, beta, states = res
     b, s = q.shape[:2]
@@ -496,6 +498,7 @@ def _chunked_bwd(scale, interpret, res, do):
 _kda_chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
+@_common.kernel_trace("kda_attention")
 def kda_attention(q, k, v, g, beta, scale=None, interpret=False):
     """o_t = scale S_t^T q_t of the gated delta-rule recurrence
     (nn/functional/kda.py).  q, k (B, S, H, dk) — the caller has

@@ -28,6 +28,7 @@ import contextlib
 import threading
 import time
 from collections import defaultdict
+from typing import NamedTuple, Optional
 
 _STATE = threading.local()
 _ENABLED = [False]
@@ -289,30 +290,125 @@ def timed(name: str):
 # the program's stages in a profiler trace are called `pt.<stage>`
 ANNOTATION_PREFIX = "pt."
 
+# ---------------------------------------------------------------------------
+# The phase log (ISSUE 36): a stage whose name starts with `setup.` is a
+# phase of the process's own set-up (import, weights, trace, lower,
+# compile or cache load, ...).  Set-up has hundreds of events, not one a
+# step, so these are ALWAYS kept, tracer and profiler session on or off:
+# a bounded in-memory list on `time.perf_counter`, read by
+# `get_phases()` (docs/observability.md "Start-up"; the benchmark's
+# `setup.*` metrics partition `setup_s` with it).
+# ---------------------------------------------------------------------------
+
+PHASE_PREFIX = "setup."
+PHASE_CAPACITY = 16384
+
+
+class Phase(NamedTuple):
+    """One finished phase.  `parent` is the name of the `setup.*` stage
+    that was open on the recording thread (None at top level): what the
+    site knew, not an interval test — a phase JAX reports when it ends
+    (`add_phase`) may cover stages that closed before it."""
+    name: str
+    start_s: float              # time.perf_counter()
+    dur_s: float
+    parent: Optional[str]
+    attrs: Optional[dict] = None
+
+
+_PHASES: list = []
+_PHASES_LOCK = threading.Lock()
+
+
+def _open_phases() -> list:
+    """This thread's stack of open `setup.*` stage names."""
+    stack = getattr(_STATE, "phases", None)
+    if stack is None:
+        stack = _STATE.phases = []
+    return stack
+
+
+def add_phase(name: str, start_s: float, dur_s: float,
+              parent: Optional[str] = None,
+              attrs: Optional[dict] = None) -> None:
+    """Record a phase retroactively (`start_s` on `time.perf_counter`),
+    for a site that learns of it when it ends.  `parent` defaults to
+    the innermost `setup.*` stage open on this thread.  Beyond
+    `PHASE_CAPACITY` records the phase is dropped and counted in
+    `setup_phases_dropped_total`."""
+    if parent is None:
+        stack = _open_phases()
+        parent = stack[-1] if stack else None
+    with _PHASES_LOCK:
+        if len(_PHASES) < PHASE_CAPACITY:
+            _PHASES.append(Phase(name, start_s, dur_s, parent, attrs))
+            return
+    stat_add("setup_phases_dropped_total")
+
+
+def get_phases() -> list:
+    """The phases recorded so far, in the order they ENDED."""
+    with _PHASES_LOCK:
+        return list(_PHASES)
+
+
+def reset_phases() -> None:
+    with _PHASES_LOCK:
+        _PHASES.clear()
+
+
+def phase_totals() -> dict:
+    """`{name: seconds}`: per name the length of the union of its
+    intervals (a jit inside a jit is two `setup.trace` phases over the
+    same seconds), children not taken out — a one-line summary for a
+    log; the benchmark's `lib/setup_phases.py` does the partition."""
+    by_name = defaultdict(list)
+    for p in get_phases():
+        by_name[p.name].append((p.start_s, p.start_s + p.dur_s))
+    out = {}
+    for name, spans in by_name.items():
+        total, reach = 0.0, float("-inf")
+        for s, e in sorted(spans):
+            if e > reach:
+                total += e - max(s, reach)
+                reach = e
+        out[name] = total
+    return out
+
 
 class stage:
-    """The one instrument of a per-step site (the Executor's feed
-    copies, dispatch and host materialisation): the with-block is a
-    `jax.profiler.TraceAnnotation` called `pt.<name>`, so that whoever
-    opened a profiler trace (`jax.profiler.start_trace`, the benchmark,
-    `obs.profile_window`) finds the stage in it on the device's clock;
-    on exit its wall time goes onto the millisecond timer `timer`
-    (`host_feed_ms` / `dispatch_ms` / `sync_ms`) and, when span tracing
-    is on, into the obs trace as a span called `name`.  Outside a
-    profiler session the annotation is a flag test."""
+    """The one instrument of a site that does a named piece of work:
+    the with-block is a `jax.profiler.TraceAnnotation` called
+    `pt.<name>`, so that whoever opened a profiler trace
+    (`jax.profiler.start_trace`, the benchmark, `obs.profile_window`)
+    finds the stage in it on the device's clock; on exit its wall time
+    goes onto the millisecond timer `timer` and, when span tracing is
+    on, into the obs trace as a span called `name` (with `attrs`).
+    Outside a profiler session the annotation is a flag test.
 
-    __slots__ = ("name", "timer", "_annotation", "_t0")
+    Per-step sites (the Executor's feed copies, dispatch and host
+    materialisation: `host_feed_ms` / `dispatch_ms` / `sync_ms`) pay
+    that and no more.  A stage called `setup.<phase>` is also kept in
+    the phase log, always (see `add_phase`)."""
 
-    def __init__(self, name: str, timer: str | None = None):
+    __slots__ = ("name", "timer", "attrs", "_phase", "_annotation", "_t0")
+
+    def __init__(self, name: str, timer: str | None = None,
+                 attrs: dict | None = None):
         self.name = name
         self.timer = timer
+        self.attrs = attrs
+        self._phase = name.startswith(PHASE_PREFIX)
 
     def __enter__(self):
         import jax
 
         self._annotation = jax.profiler.TraceAnnotation(
-            ANNOTATION_PREFIX + self.name)
+            ANNOTATION_PREFIX + self.name, **self.attrs) if self.attrs \
+            else jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + self.name)
         self._annotation.__enter__()
+        if self._phase:
+            _open_phases().append(self.name)
         self._t0 = time.perf_counter()
         return self
 
@@ -321,7 +417,15 @@ class stage:
         self._annotation.__exit__(*exc)
         if self.timer is not None:
             time_add(self.timer, dt * 1e3)
-        _tracing().TRACER.add_span(self.name, self._t0, dt)
+        if self._phase:
+            # closes correctly when the body raised: this stage's entry
+            # is the innermost one left, whatever leaked above it
+            stack = _open_phases()
+            while stack and stack.pop() != self.name:
+                pass
+            add_phase(self.name, self._t0, dt, attrs=self.attrs)
+        _tracing().TRACER.add_span(self.name, self._t0, dt,
+                                   attrs=self.attrs)
         return False
 
 

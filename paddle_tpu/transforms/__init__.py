@@ -323,16 +323,18 @@ def maybe_transform_program(program, feed_names=None, fetch_names=None,
     Returns the transformed clone (or the original program untouched
     when every pass is disabled).  Never runs on a cache hit — callers
     sit behind the compile cache — and books its wall time on the
-    `transform_ms` profiler timer plus per-pass
+    `transform_ms` profiler timer (and the start-up phase
+    `setup.transform`) plus per-pass
     `transform_<pass>_rewrites` counters so tests can assert the hot
     path pays zero transform time."""
     enabled = [n for n, on in enabled_passes().items() if on]
     if not enabled:
         return program
     from ..obs import span as obs_span
-    from ..profiler import stat_add, timed
+    from ..profiler import stage, stat_add
 
-    with obs_span("transforms.apply"), timed("transform_ms"):
+    with obs_span("transforms.apply"), \
+            stage("setup.transform", "transform_ms"):
         out, stats = apply_transforms(program, feed_names=feed_names,
                                       fetch_names=fetch_names,
                                       scope=scope, passes=enabled)

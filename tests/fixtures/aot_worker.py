@@ -41,6 +41,7 @@ def main(out_path: str) -> None:
             "out": np.asarray(out).tolist(),
             "compile_ms": t.get("compile_ms", 0.0),
             "aot_cache_load_ms": t.get("aot_cache_load_ms", 0.0),
+            "phase_totals": profiler.phase_totals(),
             "stats": {k: v for k, v in s.items()
                       if k.startswith("aot_cache")},
         }, f)

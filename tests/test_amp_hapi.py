@@ -213,7 +213,14 @@ class TestHapiModel:
                      epochs=5, verbose=0, callbacks=[es])
         assert len(hist) < 5
 
-    def test_classification_with_metric(self):
+    def test_classification_with_metric(self, monkeypatch):
+        # the layer's initial weights come from a process-wide draw
+        # counter: pin it, or the outcome depends on which tests ran
+        # before in this worker (7 of 40 counters end under 0.6)
+        from paddle_tpu.fluid import initializer
+
+        monkeypatch.setattr(initializer, "_eager_seed", [2023, 0])
+
         class Cls(paddle.io.Dataset):
             def __init__(self):
                 rng = np.random.RandomState(0)

@@ -217,6 +217,17 @@ class TestColdStartAcceptance:
         assert warm["stats"].get("aot_cache_misses", 0) == 0
         assert warm["aot_cache_load_ms"] > 0.0
 
+    def test_warm_load_is_a_cache_load_phase(self, cold_and_warm):
+        """The load site is also the start-up phase `setup.cache_load`
+        (profiler.get_phases); the cold run compiled instead."""
+        cold, warm = (cold_and_warm[k]["phase_totals"]
+                      for k in ("cold", "warm"))
+        assert "setup.cache_load" not in cold
+        assert cold["setup.backend_compile"] > 0
+        assert warm["setup.cache_load"] == pytest.approx(
+            cold_and_warm["warm"]["aot_cache_load_ms"] / 1e3, rel=0.05)
+        assert warm["setup.cache_load"] < cold["setup.backend_compile"]
+
     def test_warm_compile_ms_below_cold(self, cold_and_warm):
         cold, warm = cold_and_warm["cold"], cold_and_warm["warm"]
         # warm first-dispatch must be decisively cheaper than the cold
