@@ -90,7 +90,8 @@ def _fallback_counts() -> dict:
     s = _stats()
     return {k: s.get(k, 0) for k in ("flash_fallback_total",
                                      "serving_ragged_fallback_total",
-                                     "kda_fallback_total")}
+                                     "kda_fallback_total",
+                                     "kda_edge_fallback_total")}
 
 
 def _device_platforms(arr) -> set:
@@ -730,7 +731,9 @@ def kimi_linear_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
     """`kimi_linear.build_train_step` with per-layer recomputation: the
     trace-time counters say that every KDA layer's scan took the
     chunked path (forward, its recomputation and nothing a token at a
-    time) and walked seq / 64 chunks, and that the latent layer's flash
+    time) and walked seq / 64 chunks, that the elementwise work before
+    and after each scan ran as the two fused passes of
+    ops/pallas/kda_edge.py, and that the latent layer's flash
     instance has v heads narrower than its q/k heads; the run-time
     counters, that no held visit was dropped and that the selection
     biases moved."""
@@ -750,6 +753,7 @@ def kimi_linear_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
     b = kimi_linear.fake_batch(cfg, batch, seq, seed=SEED)
     lr = jnp.float32(1e-3)
     traced = ("kda_chunked_total", "kda_chunks_total", "kda_fallback_total",
+              "kda_edge_fused_total", "kda_edge_fallback_total",
               "flash_split_value_total", "moe_sigmoid_router_total")
     ran = ("moe_router_rows_total", "moe_bias_updates_total",
            "moe_rows_routed_total", "moe_rows_held_total",
@@ -805,6 +809,14 @@ def kimi_linear_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
                  == (kda, kda * chunks, 0),
                  f"every scan instance chunked, {chunks} chunks each; "
                  "kda_fallback_total did not move")
+        ph.check((count("_pre_forward"), count("_pre_backward"),
+                  count("_post_forward"), count("_post_backward"))
+                 == (2 * kda, kda, 2 * kda, kda)
+                 and (ph.info["kda_edge_fused_total"],
+                      ph.info["kda_edge_fallback_total"]) == (2 * kda, 0),
+                 f"each KDA layer's work before and after its scan is one "
+                 f"kernel a pass: kda_edge_fused_total == {2 * kda}, "
+                 "kda_edge_fallback_total did not move")
         ph.check((count("_flash_forward"), count("_flash_backward"))
                  == (2 * mla, 2 * mla)
                  and ph.info["flash_split_value_total"] == mla,

@@ -783,12 +783,51 @@ def kda_attention(q, k, v, g, beta, scale=None, name=None):
         {"q": q, "k": k, "v": v, "g": g, "beta": beta})
 
 
+def kda_pre(q_raw, k_raw, v_raw, f, q_taps, k_taps, v_taps, dt_bias, a_log,
+            name=None):
+    """Kimi Delta Attention's work between its projections and its
+    scan: q = unit(SiLU(conv(q_raw))), k likewise, v = SiLU(conv(
+    v_raw)), g = -exp(a_log)[h] softplus(f + dt_bias).  q_raw, k_raw,
+    v_raw, f (B, S, H * d), taps (width, H * d), dt_bias (H * d,),
+    a_log (H,) -> q, k, v (B, S, H, d), g the same in float32: what
+    `kda_attention` takes.  One Pallas pass each way on a TPU at d =
+    128 and width = 4, else XLA (ops/pallas/kda_edge.py)."""
+    from ...ops.pallas import kda_edge
+
+    def pre(**operands):
+        heads = operands["a_log"].shape[0]
+        return tuple(a.reshape(a.shape[:2] + (heads, -1))
+                     for a in kda_edge.kda_pre(**operands))
+
+    return trace_fn(pre, {
+        "q_raw": q_raw, "k_raw": k_raw, "v_raw": v_raw, "f": f,
+        "q_taps": q_taps, "k_taps": k_taps, "v_taps": v_taps,
+        "dt_bias": dt_bias, "a_log": a_log}, multi_out=True)
+
+
+def kda_post(o, gate, weight, epsilon=1e-5, name=None):
+    """Kimi Delta Attention's work between its scan and its output
+    projection: RMSNorm over each head's channels times `weight` (d,)
+    times sigmoid(gate).  o (B, S, H, d), gate (B, S, H * d) -> (B, S,
+    H * d).  One Pallas pass each way on a TPU at d = 128, else XLA
+    (ops/pallas/kda_edge.py)."""
+    from ...ops.pallas import kda_edge
+
+    return trace_fn(
+        lambda o, gate, weight: kda_edge.kda_post(
+            o.reshape(gate.shape), gate, weight, epsilon),
+        {"o": o, "gate": gate, "weight": weight})
+
+
 def short_conv1d(x, weight, activation=None, name=None):
     """Depthwise causal convolution along the sequence: x (B, S, C),
     weight (width, C) -> (B, S, C), y_t = sum_i weight[i] x_{t - width
     + 1 + i}; `activation` "silu" or None, fused by XLA.  The backward
     pass recomputes it from x (`jax.checkpoint`): it keeps no float32
-    intermediate of an elementwise pass."""
+    intermediate of an elementwise pass.  Since PR 37 no model's step
+    runs it: `kda_pre` holds Kimi Delta Attention's three convolutions;
+    this is the convolution alone, for other layers and as the tests'
+    oracle."""
     import jax
 
     from .kda import short_conv

@@ -4,7 +4,10 @@ fallback for shapes the kernels refuse, and the tests' oracle), and
 the float32 statement of its chunked form (`_local`: the oracle of
 what `ops/pallas/kda.py` makes in VMEM).  No cell runs this file's
 chunked form: since PR 35 both halves of it, chunk-local and
-chunk-sequential, are inside the kernels `kda_fwd` / `kda_bwd`.
+chunk-sequential, are inside the kernels `kda_fwd` / `kda_bwd`.  Also
+the layer's elementwise work around the scan in plain XLA (`edge_pre`,
+`edge_post`, `short_conv`): the oracle of `ops/pallas/kda_edge.py`'s
+two passes and the path for what those kernels refuse.
 
 The recurrence, for one head (S in R^{dk x dv}, float32, S_0 = 0):
 
@@ -190,3 +193,47 @@ def short_conv(x, taps):
     y = sum(padded[:, i:i + s] * taps[i].astype(jnp.float32)
             for i in range(width))
     return y.astype(x.dtype)
+
+
+def edge_pre(q_raw, k_raw, v_raw, f, q_taps, k_taps, v_taps, dt_bias, a_log):
+    """The layer's work before the scan, in float32 with one rounding
+    at the end: from the four projections (B, S, H * d) and the
+    parameters (taps (width, H * d), dt_bias (H * d,), a_log (H,))
+
+        q = unit(SiLU(conv(q_raw))), k likewise, v = SiLU(conv(v_raw))
+        g = -exp(a_log)[h] softplus(f + dt_bias)
+
+    with unit(y) = y rsqrt(sum over the head's channels of y^2 + 1e-6)
+    -> q, k, v (B, S, H * d) in the operands' dtype, g the same in
+    float32."""
+    f32 = jnp.float32
+    heads = a_log.shape[0]
+    per_head = lambda a: a.reshape(a.shape[:2] + (heads, -1))
+
+    def conv_silu(x, taps):
+        return jax.nn.silu(short_conv(x.astype(f32), taps))
+
+    def unit(y):
+        y = per_head(y)
+        return (y * jax.lax.rsqrt(jnp.sum(jnp.square(y), -1, keepdims=True)
+                                  + 1e-6)).reshape(q_raw.shape)
+
+    g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
+        per_head(f.astype(f32) + dt_bias.astype(f32)))
+    return (unit(conv_silu(q_raw, q_taps)).astype(q_raw.dtype),
+            unit(conv_silu(k_raw, k_taps)).astype(k_raw.dtype),
+            conv_silu(v_raw, v_taps).astype(v_raw.dtype),
+            g.reshape(f.shape))
+
+
+def edge_post(o, gate, weight, epsilon):
+    """The layer's work after the scan: RMSNorm over each head's
+    channels times the learned scale `weight` (d,) times sigmoid(gate),
+    in float32 with one rounding at the end.  o, gate (B, S, H * d) ->
+    (B, S, H * d) in o's dtype."""
+    f32 = jnp.float32
+    y = o.astype(f32).reshape(o.shape[:2] + (-1, weight.shape[0]))
+    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                          + epsilon) * weight.astype(f32)
+    return (y.reshape(o.shape) * jax.nn.sigmoid(gate.astype(f32))).astype(
+        o.dtype)

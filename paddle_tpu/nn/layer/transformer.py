@@ -250,9 +250,12 @@ class LatentAttention(Layer):
 class ShortConvSiLU(Layer):
     """SiLU of a depthwise causal convolution of `width` taps along the
     sequence, no bias: x (B, S, C) -> (B, S, C) (F.short_conv1d: shifted
-    multiply-adds on the (B, S, C) layout).  `weight` (width, C), tap
-    `width - 1` on the token itself; drawn from U(-width^-1/2,
-    width^-1/2), a depthwise Conv1d's usual default."""
+    multiply-adds on the (B, S, C) layout, in XLA).  `weight` (width,
+    C), tap `width - 1` on the token itself; drawn from U(-width^-1/2,
+    width^-1/2), a depthwise Conv1d's usual default.  In
+    `KimiDeltaAttention` it holds the taps only: the layer hands them
+    to `F.kda_pre`, which runs the three convolutions with what follows
+    them in one pass; `forward` is the convolution on its own."""
 
     def __init__(self, channels, width=4):
         super().__init__()
@@ -276,7 +279,9 @@ class _KDACore(Layer):
 
 class _GatedHeadNorm(Layer):
     """RMSNorm over each head's channels with a learned scale, times a
-    sigmoid gate: (o (B, S, H, D), gate (B, S, H * D)) -> (B, S, H * D)."""
+    sigmoid gate: (o (B, S, H, D), gate (B, S, H * D)) -> (B, S, H * D)
+    (F.kda_post).  `KimiDeltaAttention` reads `weight` and makes that
+    call itself, under its scope `kda_post`."""
 
     def __init__(self, head_dim, epsilon):
         super().__init__()
@@ -285,16 +290,7 @@ class _GatedHeadNorm(Layer):
             shape=[head_dim], default_initializer=ConstantInitializer(1.0))
 
     def forward(self, o, gate):
-        @jax.checkpoint     # elementwise: recomputed from o and gate
-        def f(o, gate, w):
-            y = o.astype(jnp.float32)
-            y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
-                                  + self._epsilon) * w.astype(jnp.float32)
-            y = y.reshape(gate.shape) * jax.nn.sigmoid(
-                gate.astype(jnp.float32))
-            return y.astype(o.dtype)
-
-        return trace_fn(f, {"o": o, "gate": gate, "w": self.weight})
+        return F.kda_post(o, gate, self.weight, self._epsilon)
 
 
 class KimiDeltaAttention(Layer):
@@ -316,7 +312,10 @@ class KimiDeltaAttention(Layer):
     `dt_bias` (H * head_dim,) at the inverse softplus of a log-uniform
     step in [1e-3, 1e-1] (the Mamba-2 / Gated DeltaNet convention);
     both stay float32.  The recurrence runs as the chunked scan of
-    ops/pallas/kda.py (scope `kda_core`).
+    ops/pallas/kda.py (scope `kda_core`); all between the projections
+    and the scan (the convolutions, SiLU, the unit norms, g) is one
+    pass, and all between the scan and `o_proj` another
+    (ops/pallas/kda_edge.py; scopes `kda_pre`, `kda_post`).
 
     forward(x (B, S, E)) -> (B, S, E); causal by construction, and the
     layer carries position itself: it takes none."""
@@ -349,31 +348,19 @@ class KimiDeltaAttention(Layer):
         self.o_proj = lin(width, embed_dim)
 
     def forward(self, x):
-        h, d = self.num_heads, self.head_dim
-        q = self.q_conv1d(self.q_proj(x))
-        k = self.k_conv1d(self.k_proj(x))
-        v = self.v_conv1d(self.v_proj(x))
-        f = self.f_b_proj(self.f_a_proj(x))
-
-        @jax.checkpoint     # elementwise: recomputed from its operands
-        def prepare(q, k, v, f, b, a_log, dt_bias):
-            heads = lambda a: a.reshape(a.shape[:2] + (h, d))
-
-            def unit(a):
-                y = heads(a).astype(jnp.float32)
-                return (y * jax.lax.rsqrt(jnp.sum(
-                    jnp.square(y), -1, keepdims=True) + 1e-6)).astype(a.dtype)
-
-            g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(
-                heads(f.astype(jnp.float32) + dt_bias.astype(jnp.float32)))
-            return (unit(q), unit(k), heads(v), g,
-                    jax.nn.sigmoid(b.astype(jnp.float32)))
-
-        q, k, v, g, beta = trace_fn(prepare, {
-            "q": q, "k": k, "v": v, "f": f, "b": self.b_proj(x),
-            "a_log": self.A_log, "dt_bias": self.dt_bias}, multi_out=True)
+        raw = (self.q_proj(x), self.k_proj(x), self.v_proj(x),
+               self.f_b_proj(self.f_a_proj(x)))
+        with jax.named_scope("kda_pre"):
+            q, k, v, g = F.kda_pre(
+                *raw, self.q_conv1d.weight, self.k_conv1d.weight,
+                self.v_conv1d.weight, self.dt_bias, self.A_log)
+        beta = trace_fn(lambda b: jax.nn.sigmoid(b.astype(jnp.float32)),
+                        {"b": self.b_proj(x)})
         o = self.kda_core(q, k, v, g, beta)
-        return self.o_proj(self.o_norm(o, self.g_b_proj(self.g_a_proj(x))))
+        gate = self.g_b_proj(self.g_a_proj(x))
+        with jax.named_scope("kda_post"):
+            o = F.kda_post(o, gate, self.o_norm.weight, self.o_norm._epsilon)
+        return self.o_proj(o)
 
 
 class GatedFFN(Layer):

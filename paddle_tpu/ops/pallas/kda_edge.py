@@ -1,0 +1,473 @@
+"""Kimi Delta Attention's elementwise work around its scan, as ONE
+pass over HBM each way: what `nn.KimiDeltaAttention` does between its
+projections and `kda_attention` (`kda_pre`), and between the scan and
+the output projection (`kda_post`), each a `jax.custom_vjp` over two
+Pallas kernels on the projections' own (B, S, H * 128) layout.
+`nn/functional/kda.py` states both passes in plain XLA (`edge_pre`,
+`edge_post`: the tests' oracle and the path for what the kernels
+refuse).
+
+    kda_pre_fwd   q = unit(SiLU(conv(q_raw))), k likewise,
+                  v = SiLU(conv(v_raw)),
+                  g = -exp(A_log)[h] softplus(f + dt_bias)
+    kda_pre_bwd   recomputes those from the same raw operands and
+                  pulls dq, dk, dv, dg back to dq_raw, dk_raw, dv_raw,
+                  df and the parameters' cotangents
+    kda_post_fwd  y = RMSNorm_head(o) w sigmoid(gate)
+    kda_post_bwd  do, dgate, dw from o, gate and dy
+
+A grid step is one (batch, block of heads, tile of `ROW_TILE` rows)
+and walks the tile `_STEP` rows and a head at a time in a loop whose
+body is a few vregs an operand: nothing of a tile but its operands and
+results is in HBM, and every intermediate is float32 in VMEM with one
+rounding at the store.
+
+The convolution's taps reach 3 rows back, so a body is also handed
+the 16-row block before its tile (a second operand of the same array;
+zeros before the sequence) and lays [those rows; the tile] into a
+float32 scratch that the loop reads at the four row offsets.  The
+pull-back of the convolution reaches 3 rows AHEAD (dx_u = sum_i w_i
+dc_{u + 3 - i}, dc the cotangent of the convolution's sum): the
+backward call walks the tiles from the last, its sequence axis
+`"arbitrary"`, and keeps the first 8 rows of dc of the tile after in
+VMEM.  The parameters arrive as one (16, width) float32 block (rows 0-3,
+4-7, 8-11 the taps of q, k, v; 12 dt_bias; 13 A_log by lane) and their
+cotangents leave as the same rows' sums, still split over the 8
+sublanes, in a block that stays in VMEM over the sequence axis; XLA
+adds the sublanes (and, for A_log, a head's lanes) up.
+
+The kernels take heads of 128 channels and 4 taps.  Anything else, and
+everything off the TPU that does not ask for `interpret`, runs the XLA
+statement.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ...nn.functional import kda as _xla
+from . import _common
+from .attention import _compiler_params
+from .kda import _F32, HEAD_DIM, _lanes
+
+ROW_TILE = 256          # rows a grid step
+TAPS = 4
+_STEP = 32              # rows a loop step
+_HALO = 16              # rows handed of the tile before: a bfloat16 tile
+_EDGE = 8               # of which a body keeps these: a float32 tile
+_SUB = 8                # sublanes: a parameter's cotangent is 8 partial rows
+_DT, _A, _PARAM_ROWS = 3 * TAPS, 3 * TAPS + 1, 16
+
+
+def _sigmoid(x):
+    return 0.5 + 0.5 * jnp.tanh(0.5 * x)
+
+
+def _lane_sum(x):
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _shifted(x, rows):
+    """x's rows moved down by `rows` (up if negative), around the end."""
+    return pltpu.roll(x, rows % x.shape[0], 0) if rows else x
+
+
+def _walk(tile, step, descending=False):
+    """step(first row of `_STEP` rows) over a tile's rows, in a loop."""
+    n = tile // _STEP
+
+    def body(i, carry):
+        step(pl.multiple_of((n - 1 - i if descending else i) * _STEP, _STEP))
+        return carry
+
+    jax.lax.fori_loop(0, n, body, 0)
+
+
+def _fill(scr, x_ref, halo_ref, first):
+    """scr (8 + tile, w) <- [the 8 rows before the tile, zeros before
+    the sequence; the tile], float32."""
+    before = halo_ref[0].astype(_F32)[_HALO - _EDGE:]
+    scr[:_EDGE] = jnp.where(first, 0.0, before)
+    scr[_EDGE:] = x_ref[0].astype(_F32)
+
+
+def _conv(scr, p_ref, operand, r0, lanes):
+    """The convolution's sum for rows r0.. of the tile in `scr`, and
+    the rows it read: x_{t-3}, x_{t-2}, x_{t-1}, x_t."""
+    rows = scr[pl.ds(r0, _EDGE + _STEP), lanes]
+    xs = [_shifted(rows, TAPS - 1 - i)[_EDGE:] for i in range(TAPS)]
+    taps = [p_ref[operand * TAPS + i:operand * TAPS + i + 1, lanes]
+            for i in range(TAPS)]
+    return sum(w * x for w, x in zip(taps, xs)), xs, taps
+
+
+def _gate(f_ref, p_ref, at, lanes):
+    """-exp(A_log) (1, 128), softplus(f + dt_bias) and its derivative
+    sigmoid(f + dt_bias), (rows, 128)."""
+    z = f_ref[0, at, lanes].astype(_F32) + p_ref[_DT:_DT + 1, lanes]
+    return (-jnp.exp(p_ref[_A:_A + 1, lanes]),
+            jnp.maximum(z, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(z))),
+            _sigmoid(z))
+
+
+def _add_rows(d_ref, row, lanes, x):
+    """d_ref's partial sums of parameter row `row` += x's rows, 8 by 8."""
+    at = slice(row * _SUB, (row + 1) * _SUB)
+    d_ref[0, at, lanes] += sum(x[m:m + _SUB] for m in range(0, _STEP, _SUB))
+
+
+# -- before the scan ---------------------------------------------------------
+
+def _pre_fwd_kernel(q_ref, k_ref, v_ref, f_ref, qh_ref, kh_ref, vh_ref, p_ref,
+                    qo_ref, ko_ref, vo_ref, g_ref, *scratch, heads, tile):
+    first = pl.program_id(2) == 0
+    operands = list(zip(scratch, (q_ref, k_ref, v_ref),
+                        (qh_ref, kh_ref, vh_ref), (qo_ref, ko_ref, vo_ref)))
+    for scr, x_ref, halo_ref, _ in operands:
+        _fill(scr, x_ref, halo_ref, first)
+
+    def step(r0):
+        at = pl.ds(r0, _STEP)
+        for h in range(heads):
+            lanes = _lanes(h)
+            for n, (scr, _, _, o_ref) in enumerate(operands):
+                c = _conv(scr, p_ref, n, r0, lanes)[0]
+                y = c * _sigmoid(c)
+                if n < 2:           # q, k: unit length a head
+                    y = y * jax.lax.rsqrt(_lane_sum(y * y) + 1e-6)
+                o_ref[0, at, lanes] = y.astype(o_ref.dtype)
+            a, softplus, _ = _gate(f_ref, p_ref, at, lanes)
+            g_ref[0, at, lanes] = a * softplus
+
+    _walk(tile, step)
+
+
+def _pre_bwd_kernel(q_ref, k_ref, v_ref, f_ref, qh_ref, kh_ref, vh_ref, p_ref,
+                    dq_ref, dk_ref, dv_ref, dg_ref,
+                    dqr_ref, dkr_ref, dvr_ref, df_ref, dp_ref,
+                    *scratch, heads, tile):
+    i, n = pl.program_id(2), pl.num_programs(2)
+    x_scr, dc_scr = scratch[:3], scratch[3:]
+    operands = list(zip(x_scr, dc_scr, (q_ref, k_ref, v_ref),
+                        (qh_ref, kh_ref, vh_ref), (dq_ref, dk_ref, dv_ref),
+                        (dqr_ref, dkr_ref, dvr_ref)))
+    for scr, _, x_ref, halo_ref, _, _ in operands:
+        _fill(scr, x_ref, halo_ref, i == n - 1)     # tiles from the last
+
+    @pl.when(i == 0)
+    def _init():
+        dp_ref[...] = jnp.zeros_like(dp_ref)
+        for dc in dc_scr:           # nothing follows the sequence
+            dc[tile:] = jnp.zeros((_EDGE, dc.shape[1]), _F32)
+
+    def step(r0):
+        at = pl.ds(r0, _STEP)
+        for h in range(heads):
+            lanes = _lanes(h)
+            for m, (scr, dc, _, _, d_ref, dx_ref) in enumerate(operands):
+                c, xs, taps = _conv(scr, p_ref, m, r0, lanes)
+                s = _sigmoid(c)
+                y = c * s
+                d = d_ref[0, at, lanes].astype(_F32)
+                if m < 2:           # through u = y r, r = rsqrt(|y|^2 + eps)
+                    r = jax.lax.rsqrt(_lane_sum(y * y) + 1e-6)
+                    u = y * r
+                    d = r * (d - u * _lane_sum(d * u))
+                d = d * (s + y * (1.0 - s))             # SiLU'
+                dc[at, lanes] = d
+                for t in range(TAPS):
+                    _add_rows(dp_ref, m * TAPS + t, lanes, d * xs[t])
+                # row u's x met tap t in row u + 3 - t's sum
+                ahead = dc[pl.ds(r0, _STEP + _EDGE), lanes]
+                dx = sum(taps[t] * _shifted(ahead, t + 1 - TAPS)[:_STEP]
+                         for t in range(TAPS))
+                dx_ref[0, at, lanes] = dx.astype(dx_ref.dtype)
+            a, softplus, slope = _gate(f_ref, p_ref, at, lanes)
+            dg = dg_ref[0, at, lanes] * a
+            _add_rows(dp_ref, _A, lanes, dg * softplus)   # dg g: g' = g
+            dz = dg * slope
+            _add_rows(dp_ref, _DT, lanes, dz)
+            df_ref[0, at, lanes] = dz.astype(df_ref.dtype)
+
+    _walk(tile, step, descending=True)
+    for dc in dc_scr:               # for the tile before
+        dc[tile:] = dc[:_EDGE]
+
+
+def _blocks(shape, tile):
+    """(heads a grid step — 4, 2 or 1 —, their lanes, the grid) for a
+    (B, S, H * 128) row operand."""
+    b, s, w = shape
+    heads = next(n for n in (4, 2, 1) if w // HEAD_DIM % n == 0)
+    return heads, heads * HEAD_DIM, (b, w // (heads * HEAD_DIM), s // tile)
+
+
+def _like(a):
+    return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+
+def _row_specs(tile, width, tiles, reverse):
+    """Over the grid (batch, block of heads, tile): a (B, S, W) row
+    operand's tile, the 16 rows before it, and the parameter block's
+    and its cotangent's columns; `reverse` walks the tiles from the
+    last."""
+    at = (lambda i: tiles - 1 - i) if reverse else (lambda i: i)
+    rows = pl.BlockSpec((1, tile, width), lambda b, j, i: (b, at(i), j))
+    halo = pl.BlockSpec(
+        (1, _HALO, width),
+        lambda b, j, i: (b, jnp.maximum(at(i) * (tile // _HALO) - 1, 0), j))
+    params = pl.BlockSpec((_PARAM_ROWS, width), lambda b, j, i: (0, j))
+    dparams = pl.BlockSpec((1, _PARAM_ROWS * _SUB, width),
+                           lambda b, j, i: (b, 0, j))
+    return rows, halo, params, dparams
+
+
+def _scratch(tile, width, n):
+    return [pltpu.VMEM((tile + _EDGE, width), _F32)] * n
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _pre_forward(q, k, v, f, params, tile=ROW_TILE, interpret=False):
+    """q, k, v, f (B, S, H * 128) raw, params (16, H * 128) float32 ->
+    q, k, v in the operands' dtype, g float32."""
+    heads, width, grid = _blocks(q.shape, tile)
+    rows, halo, param_rows, _ = _row_specs(tile, width, grid[2], False)
+    return pl.pallas_call(
+        functools.partial(_pre_fwd_kernel, heads=heads, tile=tile),
+        grid=grid,
+        in_specs=[rows] * 4 + [halo] * 3 + [param_rows],
+        out_specs=[rows] * 4,
+        out_shape=[_like(q), _like(k), _like(v),
+                   jax.ShapeDtypeStruct(f.shape, _F32)],
+        scratch_shapes=_scratch(tile, width, 3),
+        compiler_params=_compiler_params(("parallel",) * 3),
+        interpret=interpret, name="kda_pre_fwd",
+    )(q, k, v, f, q, k, v, params)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _pre_backward(q, k, v, f, params, dq, dk, dv, dg, tile=ROW_TILE,
+                  interpret=False):
+    """-> dq_raw, dk_raw, dv_raw, df in the operands' dtypes and the
+    parameter block's cotangent (16, H * 128) float32."""
+    b, _, w = q.shape
+    heads, width, grid = _blocks(q.shape, tile)
+    rows, halo, param_rows, dparam_rows = _row_specs(tile, width, grid[2],
+                                                     True)
+    *grads, dparams = pl.pallas_call(
+        functools.partial(_pre_bwd_kernel, heads=heads, tile=tile),
+        grid=grid,
+        in_specs=[rows] * 4 + [halo] * 3 + [param_rows] + [rows] * 4,
+        out_specs=[rows] * 4 + [dparam_rows],
+        out_shape=[_like(q), _like(k), _like(v), _like(f),
+                   jax.ShapeDtypeStruct((b, _PARAM_ROWS * _SUB, w), _F32)],
+        scratch_shapes=_scratch(tile, width, 6),
+        compiler_params=_compiler_params(), interpret=interpret,
+        name="kda_pre_bwd",
+    )(q, k, v, f, q, k, v, params, dq, dk, dv, dg)
+    return (*grads, dparams.reshape(b, _PARAM_ROWS, _SUB, w).sum((0, 2)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _pre(q, k, v, f, params, tile, interpret):
+    return _pre_fwd(q, k, v, f, params, tile, interpret)[0]
+
+
+@_common.kernel_trace("kda_edge")
+def _pre_fwd(q, k, v, f, params, tile, interpret):
+    out = _pre_forward(q, k, v, f, params, tile=tile, interpret=interpret)
+    return tuple(out), (q, k, v, f, params)
+
+
+@_common.kernel_trace("kda_edge")
+def _pre_bwd(tile, interpret, res, cotangents):
+    return _pre_backward(*res, *cotangents, tile=tile, interpret=interpret)
+
+
+_pre.defvjp(_pre_fwd, _pre_bwd)
+
+
+# -- after the scan ----------------------------------------------------------
+
+def _post_fwd_kernel(o_ref, gate_ref, w_ref, y_ref, *, heads, tile, epsilon):
+    def step(r0):
+        at = pl.ds(r0, _STEP)
+        for h in range(heads):
+            lanes = _lanes(h)
+            o = o_ref[0, at, lanes].astype(_F32)
+            r = jax.lax.rsqrt(_lane_sum(o * o) * (1.0 / HEAD_DIM) + epsilon)
+            gate = _sigmoid(gate_ref[0, at, lanes].astype(_F32))
+            y_ref[0, at, lanes] = (o * r * w_ref[0:1, lanes] * gate).astype(
+                y_ref.dtype)
+
+    _walk(tile, step)
+
+
+def _post_bwd_kernel(o_ref, gate_ref, w_ref, dy_ref, do_ref, dgate_ref,
+                     dw_ref, *, heads, tile, epsilon):
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    def step(r0):
+        at = pl.ds(r0, _STEP)
+        for h in range(heads):
+            lanes = _lanes(h)
+            o = o_ref[0, at, lanes].astype(_F32)
+            r = jax.lax.rsqrt(_lane_sum(o * o) * (1.0 / HEAD_DIM) + epsilon)
+            u = o * r
+            gate = _sigmoid(gate_ref[0, at, lanes].astype(_F32))
+            d = dy_ref[0, at, lanes].astype(_F32) * gate    # of u w
+            _add_rows(dw_ref, 0, lanes, d * u)
+            d = d * w_ref[0:1, lanes]                       # of u
+            du = d * u
+            dgate_ref[0, at, lanes] = (du * (1.0 - gate)).astype(
+                dgate_ref.dtype)
+            do_ref[0, at, lanes] = (
+                r * (d - u * (_lane_sum(du) * (1.0 / HEAD_DIM)))).astype(
+                    do_ref.dtype)
+
+    _walk(tile, step)
+
+
+def _post_specs(tile, width):
+    rows = pl.BlockSpec((1, tile, width), lambda b, j, i: (b, i, j))
+    weight = pl.BlockSpec((1, width), lambda b, j, i: (0, j))
+    dweight = pl.BlockSpec((1, _SUB, width), lambda b, j, i: (b, 0, j))
+    return rows, weight, dweight
+
+
+@functools.partial(jax.jit, static_argnames=("epsilon", "tile", "interpret"))
+def _post_forward(o, gate, weight, epsilon, tile=ROW_TILE, interpret=False):
+    """o, gate (B, S, H * 128), weight (1, H * 128) float32 -> y in o's
+    dtype."""
+    heads, width, grid = _blocks(o.shape, tile)
+    rows, weight_rows, _ = _post_specs(tile, width)
+    return pl.pallas_call(
+        functools.partial(_post_fwd_kernel, heads=heads, tile=tile,
+                          epsilon=epsilon),
+        grid=grid,
+        in_specs=[rows, rows, weight_rows], out_specs=rows,
+        out_shape=_like(o),
+        compiler_params=_compiler_params(("parallel",) * 3),
+        interpret=interpret, name="kda_post_fwd",
+    )(o, gate, weight)
+
+
+@functools.partial(jax.jit, static_argnames=("epsilon", "tile", "interpret"))
+def _post_backward(o, gate, weight, dy, epsilon, tile=ROW_TILE,
+                   interpret=False):
+    """-> do, dgate in the operands' dtypes, dweight (1, H * 128)
+    float32."""
+    heads, width, grid = _blocks(o.shape, tile)
+    rows, weight_rows, dweight_rows = _post_specs(tile, width)
+    do, dgate, dweight = pl.pallas_call(
+        functools.partial(_post_bwd_kernel, heads=heads, tile=tile,
+                          epsilon=epsilon),
+        grid=grid,
+        in_specs=[rows, rows, weight_rows, rows],
+        out_specs=[rows, rows, dweight_rows],
+        out_shape=[_like(o), _like(gate),
+                   jax.ShapeDtypeStruct((o.shape[0], _SUB, o.shape[2]),
+                                        _F32)],
+        compiler_params=_compiler_params(), interpret=interpret,
+        name="kda_post_bwd",
+    )(o, gate, weight, dy)
+    return do, dgate, dweight.sum((0, 1))[None]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _post(o, gate, weight, epsilon, tile, interpret):
+    return _post_fwd(o, gate, weight, epsilon, tile, interpret)[0]
+
+
+@_common.kernel_trace("kda_edge")
+def _post_fwd(o, gate, weight, epsilon, tile, interpret):
+    y = _post_forward(o, gate, weight, epsilon=epsilon, tile=tile,
+                      interpret=interpret)
+    return y, (o, gate, weight)
+
+
+@_common.kernel_trace("kda_edge")
+def _post_bwd(epsilon, tile, interpret, res, dy):
+    return _post_backward(*res, dy, epsilon=epsilon, tile=tile,
+                          interpret=interpret)
+
+
+_post.defvjp(_post_fwd, _post_bwd)
+
+
+# -- the two entry points ----------------------------------------------------
+
+def _fused(head_dim, interpret, refused=False):
+    """Whether an instance takes the kernels, counted where traced:
+    `kda_edge_fused_total` += 1 if so, `kda_edge_fallback_total` += 1
+    where the kernels refused the shape (not the platform: the XLA
+    path off the TPU is uncounted, as `kda_attention`'s is)."""
+    from ...profiler import stat_add
+
+    kernels = interpret or _common.on_tpu()
+    fused = kernels and head_dim == HEAD_DIM and not refused
+    if kernels:
+        stat_add("kda_edge_fused_total" if fused
+                 else "kda_edge_fallback_total")
+    return fused
+
+
+def _padded(tile, *rows):
+    """Row operands (B, S, W) with S padded to a multiple of the tile."""
+    pad = -rows[0].shape[1] % tile
+    return tuple(jnp.pad(a, ((0, 0), (0, pad), (0, 0))) if pad else a
+                 for a in rows)
+
+
+@_common.kernel_trace("kda_edge")
+def kda_pre(q_raw, k_raw, v_raw, f, q_taps, k_taps, v_taps, dt_bias, a_log,
+            interpret=False, tile=ROW_TILE):
+    """The scan's operands from the layer's projections: q_raw, k_raw,
+    v_raw, f (B, S, H * d); the three convolutions' taps (width, H *
+    d), dt_bias (H * d,), a_log (H,) -> q, k, v (B, S, H * d) in the
+    operands' dtype, g (B, S, H * d) float32 <= 0
+    (nn/functional/kda.py: `edge_pre` states them).
+
+    d = 128 and width = 4 on a TPU (or under `interpret`): one kernel,
+    `kda_pre_fwd`, and `kda_pre_bwd` behind it; a length that is no
+    multiple of the row tile is padded with zero rows, which no earlier
+    row reads.  Otherwise the XLA statement.  `tile` is there for the
+    tests, which cross tile boundaries at a few dozen rows."""
+    heads = a_log.shape[0]
+    if not _fused(q_raw.shape[-1] // heads, interpret,
+                  refused=q_taps.shape[0] != TAPS):
+        return jax.checkpoint(_xla.edge_pre)(
+            q_raw, k_raw, v_raw, f, q_taps, k_taps, v_taps, dt_bias, a_log)
+    width = q_raw.shape[-1]
+    params = jnp.concatenate(
+        [t.astype(_F32) for t in (q_taps, k_taps, v_taps)]
+        + [dt_bias.astype(_F32)[None],
+           jnp.repeat(a_log.astype(_F32), HEAD_DIM)[None],
+           jnp.zeros((_PARAM_ROWS - _A - 1, width), _F32)])
+    s = q_raw.shape[1]
+    out = _pre(*_padded(tile, q_raw, k_raw, v_raw, f), params, tile,
+               bool(interpret))
+    return tuple(a[:, :s] for a in out)
+
+
+@_common.kernel_trace("kda_edge")
+def kda_post(o, gate, weight, epsilon, interpret=False, tile=ROW_TILE):
+    """RMSNorm over each head's d channels times `weight` (d,) times
+    sigmoid(gate): o, gate (B, S, H * d) -> (B, S, H * d) in o's dtype
+    (nn/functional/kda.py: `edge_post`).  d = 128 on a TPU (or under
+    `interpret`): `kda_post_fwd` / `kda_post_bwd`; otherwise the XLA
+    statement."""
+    if not _fused(weight.shape[0], interpret):
+        return jax.checkpoint(_xla.edge_post, static_argnums=(3,))(
+            o, gate, weight, epsilon)
+    s = o.shape[1]
+    lanes = jnp.tile(weight.astype(_F32), o.shape[-1] // HEAD_DIM)[None]
+    return _post(*_padded(tile, o, gate), lanes, float(epsilon), tile,
+                 bool(interpret))[:, :s]

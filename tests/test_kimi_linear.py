@@ -4,11 +4,14 @@ whole and its hand-written backward) against the recurrence a token
 at a time, the model against the plain reference (benchmark/reference/
 kimi_linear.py — the one the benchmark's `correct` uses), the share
 test that ties a chip's share to the whole layer, latent attention
-without a query latent and without rotation, and the counters."""
+without a query latent and without rotation, the layer's two
+elementwise passes around the scan (ops/pallas/kda_edge.py, interpret
+mode) against their XLA statement, and the counters."""
 
 import dataclasses
 import functools
 import os
+import re
 import sys
 
 import jax
@@ -22,6 +25,7 @@ from paddle_tpu.jit import functional_call, functional_state
 from paddle_tpu.models import kimi_linear as M
 from paddle_tpu.nn.functional import kda as X
 from paddle_tpu.ops.pallas import kda as K
+from paddle_tpu.ops.pallas import kda_edge as E
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -278,6 +282,146 @@ def test_counters_count_what_was_traced():
             _delta(before, "kda_fallback_total")) == (0, 0)
 
 
+# -- the layer's edge: one pass before the scan, one after -------------------
+
+PRE_IN = ("q_raw", "k_raw", "v_raw", "f", "q_conv1d", "k_conv1d",
+          "v_conv1d", "dt_bias", "A_log")
+PRE_OUT = ("q", "k", "v", "g")
+POST_IN = ("o", "gate", "o_norm")
+# (batch, tokens, heads, row tile): three tiles with four heads a grid
+# step's worth of one; a length that is no multiple of the tile, two
+# heads; three heads (a head a grid step), a tile of two loop steps
+EDGE_CASES = [(1, 96, 1, 32), (2, 100, 2, 32), (1, 64, 3, 64)]
+
+
+def _edge_operands(b, s, h, seed=0):
+    rng = np.random.default_rng(seed)
+    w = h * 128
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    pre = (draw(b, s, w), draw(b, s, w), draw(b, s, w), draw(b, s, w) - 2.0,
+           draw(4, w) / 2, draw(4, w) / 2, draw(4, w) / 2, draw(w) - 3.0,
+           jnp.log(jnp.asarray(rng.uniform(1, 16, h), jnp.float32)))
+    return pre, (draw(b, s, w), draw(b, s, w), draw(128))
+
+
+@functools.lru_cache(maxsize=None)
+def _pre_both(b, s, h, tile):
+    """(outputs, the nine cotangents) of `kda_pre` through the kernels
+    and of its XLA statement, float32."""
+    pre, _ = _edge_operands(b, s, h)
+    w = tuple(jnp.asarray(np.random.default_rng(5 + i).normal(
+        size=pre[0].shape), jnp.float32) for i in range(4))
+    both = []
+    for fn in (functools.partial(E.kda_pre, interpret=True, tile=tile),
+               X.edge_pre):
+        out, vjp = jax.vjp(fn, *pre)
+        both.append((out, vjp(w)))
+    return both
+
+
+@functools.lru_cache(maxsize=None)
+def _post_both(b, s, h, tile):
+    _, post = _edge_operands(b, s, h)
+    w = jnp.asarray(np.random.default_rng(9).normal(size=post[0].shape),
+                    jnp.float32)
+    both = []
+    for fn in (lambda *a: E.kda_post(*a, 1e-5, interpret=True, tile=tile),
+               lambda *a: X.edge_post(*a, 1e-5)):
+        out, vjp = jax.vjp(fn, *post)
+        both.append((out,) + vjp(w))
+    return both
+
+
+@pytest.mark.parametrize("out", range(4), ids=PRE_OUT)
+@pytest.mark.parametrize("case", EDGE_CASES)
+def test_fused_pre_output_matches_its_xla_statement(case, out):
+    (got, _), (want, _) = _pre_both(*case)
+    assert got[out].shape == want[out].shape
+    assert got[out].dtype == want[out].dtype
+    assert _rel(got[out], want[out]) < 1e-6
+
+
+@pytest.mark.parametrize("operand", range(9), ids=PRE_IN)
+@pytest.mark.parametrize("case", EDGE_CASES)
+def test_fused_pre_cotangent_matches_its_xla_statement(case, operand):
+    """The hand-written backward: the four raw operands, the three tap
+    matrices, dt_bias and A_log (its lanes summed to heads)."""
+    (_, got), (_, want) = _pre_both(*case)
+    assert got[operand].shape == want[operand].shape
+    assert _rel(got[operand], want[operand]) < 1e-5
+
+
+@pytest.mark.parametrize("what", range(4), ids=("out",) + POST_IN)
+@pytest.mark.parametrize("case", EDGE_CASES)
+def test_fused_post_matches_its_xla_statement(case, what):
+    got, want = _post_both(*case)
+    assert got[what].shape == want[what].shape
+    assert _rel(got[what], want[what]) < 1e-5
+
+
+@pytest.mark.parametrize("operand", range(3), ids=PRE_IN[:3])
+def test_fused_pre_backward_reaches_into_the_tile_before(operand):
+    """A cotangent that is non-zero only in the first rows of ONE tile
+    (the third of four): the convolution's pull-back reaches 3 rows
+    ahead, so the raw operand's gradient lands in the last 3 rows of
+    the tile BEFORE (carried there in VMEM by the backward walk), and
+    nowhere earlier."""
+    tile, first = 32, 64
+    pre, _ = _edge_operands(1, 128, 2, seed=3)
+    w = [jnp.zeros_like(pre[0]) for _ in range(4)]
+    w[operand] = w[operand].at[:, first:first + 2].set(1.0)
+    grads = []
+    for fn in (functools.partial(E.kda_pre, interpret=True, tile=tile),
+               X.edge_pre):
+        grads.append(jax.vjp(fn, *pre)[1](tuple(w))[operand])
+    got, want = grads
+    assert _rel(got, want) < 1e-5
+    before = np.abs(np.asarray(got[0, first - 3:first]))
+    assert before.min(axis=-1).max() > 0        # rows 61..63 of tile 1
+    assert float(jnp.abs(got[0, :first - 3]).max()) == 0.0
+    assert float(jnp.abs(got[0, first + 2:]).max()) == 0.0
+
+
+@pytest.mark.parametrize("token", [32, 45])
+def test_fused_pre_forward_is_causal(token):
+    """A change at token t (a tile's first row; a row inside one)
+    leaves every earlier row of q, k, v, g as it was, bit for bit, and
+    moves row t."""
+    pre, _ = _edge_operands(1, 96, 1, seed=4)
+    moved = tuple(a.at[:, token].add(1.0) for a in pre[:4]) + pre[4:]
+    run = functools.partial(E.kda_pre, interpret=True, tile=32)
+    for a, b in zip(run(*pre), run(*moved)):
+        assert bool((a[:, :token] == b[:, :token]).all())
+        assert float(jnp.abs(a[:, token] - b[:, token]).max()) > 0
+
+
+def test_edge_counters_and_the_width_the_kernels_refuse():
+    pre, post = _edge_operands(1, 40, 2)
+    narrow_pre = tuple(a[..., :128] for a in pre[:8]) + (pre[8],)
+    narrow_post = (post[0][..., :128], post[1][..., :128], post[2][:64])
+    before = profiler.get_int_stats()
+    jax.jit(functools.partial(E.kda_pre, interpret=True)).lower(*pre)
+    jax.jit(lambda *a: E.kda_post(*a, 1e-5, interpret=True)).lower(*post)
+    assert (_delta(before, "kda_edge_fused_total"),
+            _delta(before, "kda_edge_fallback_total")) == (2, 0)
+    # heads of 64 channels: the XLA statement, counted as refused
+    before = profiler.get_int_stats()
+    got = E.kda_pre(*narrow_pre, interpret=True)
+    y = E.kda_post(*narrow_post, 1e-5, interpret=True)
+    assert (_delta(before, "kda_edge_fused_total"),
+            _delta(before, "kda_edge_fallback_total")) == (0, 2)
+    for a, b in zip(got, X.edge_pre(*narrow_pre)):
+        assert _rel(a, b) < 1e-6
+    assert _rel(y, X.edge_post(*narrow_post, 1e-5)) < 1e-6
+    # off the TPU and not asked to interpret: the XLA path, uncounted
+    before = profiler.get_int_stats()
+    E.kda_pre(*narrow_pre)
+    E.kda_pre(*pre)
+    E.kda_post(*post, 1e-5)
+    assert (_delta(before, "kda_edge_fused_total"),
+            _delta(before, "kda_edge_fallback_total")) == (0, 0)
+
+
 # -- the model against the reference -----------------------------------------
 
 def _params(model, bias=0.0, seed=0):
@@ -519,6 +663,22 @@ def test_kda_layer_scopes_and_parameters():
         "q_proj", "k_proj", "v_proj", "q_conv1d", "k_conv1d", "v_conv1d",
         "f_a_proj", "f_b_proj", "b_proj", "g_a_proj", "g_b_proj", "kda_core",
         "o_norm", "o_proj"]
+    assert sorted(n for n, _ in layer.named_parameters()) == sorted(
+        [f"{n}.weight" for n in (
+            "q_proj", "k_proj", "v_proj", "q_conv1d", "k_conv1d", "v_conv1d",
+            "f_a_proj", "f_b_proj", "b_proj", "g_a_proj", "g_b_proj",
+            "o_norm", "o_proj")] + ["A_log", "dt_bias"])
+    # the two passes around the scan run under scopes of their own,
+    # forward and backward, outside every projection's
+    state = functional_state(layer)
+    loss = lambda p, x: jnp.sum(functional_call(layer, p, x)[0])
+    text = jax.jit(jax.grad(loss)).lower(
+        state, jnp.ones((1, 8, 32))).compile().as_text()
+    scopes = set(re.findall(r"kimideltaattention\)*/(\w+)", text))
+    assert {"kda_pre", "kda_core", "kda_post", "q_proj", "f_b_proj",
+            "b_proj", "g_b_proj", "o_proj"} <= scopes
+    assert not scopes & {"q_conv1d", "k_conv1d", "v_conv1d", "o_norm"}
+    assert not re.search(r"kda_(pre|post)/\w+_proj", text)
     a = np.exp(np.asarray(layer.A_log._value))
     assert a.min() >= 1 and a.max() <= 16
     dt = np.log1p(np.exp(np.asarray(layer.dt_bias._value)))

@@ -616,6 +616,43 @@ class TestMosaicAcceptsForV5e:
         assert sum("_kda_forward" in c for c in calls) == 1
         assert sum("_kda_backward" in c for c in calls) == 1
 
+    @pytest.mark.parametrize("tokens,heads", [(16384, 32), (512, 3)])
+    def test_kda_edge_kernels(self, v5e, tokens, heads):
+        """The Kimi cell's two elementwise passes around the scan
+        (ops/pallas/kda_edge.py) at the cell's shape, 1 x 16,384 x 32
+        heads of 128: `kda_pre_fwd`, `kda_pre_bwd`, `kda_post_fwd`,
+        `kda_post_bwd` — (256, 512) blocks of the projections' own
+        layout walked 32 rows at a time, the rows before a tile as a
+        second operand, row shifts as sublane rotations — compiled for a
+        v5e; and an odd head count (a head a grid step).  None of the
+        four calls is named as the scan's or the flash kernels' are."""
+        from paddle_tpu.ops.pallas import _common, kda_edge
+
+        put = lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=_common._COMPILE_TARGET)
+        width = heads * 128
+        rows = put((1, tokens, width), jnp.bfloat16)
+        taps = put((4, width), jnp.bfloat16)
+        pre = (rows,) * 4 + (taps,) * 3 + (put((width,), jnp.float32),
+                                           put((heads,), jnp.float32))
+        post = (rows, rows, put((128,), jnp.bfloat16))
+
+        def loss(pre, post):
+            q, k, v, g = kda_edge.kda_pre(*pre)
+            y = kda_edge.kda_post(*post, 1e-5)
+            return sum(jnp.sum(a.astype(jnp.float32)) for a in (q, k, v, g, y))
+
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            pre, post).compile().as_text()
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        for fn in ("_pre_forward", "_pre_backward", "_post_forward",
+                   "_post_backward"):
+            assert sum(fn in c for c in calls) == 1, fn
+        assert not any(fn in c for c in calls for fn in (
+            "_kda_forward", "_kda_backward", "_flash_forward",
+            "_flash_backward"))
+
 
 def test_flash_per_shard_matches_unsharded():
     """`sharded_attention_scope`'s kernel path: flash attention under

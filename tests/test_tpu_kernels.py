@@ -554,6 +554,63 @@ def test_kda_scan_at_the_cell_shape_on_tpu():
                           w[:, :n]))
 
 
+def _edge_operands_tpu(s, heads, seed):
+    rng = np.random.RandomState(seed)
+    w = heads * 128
+    rows = lambda shift=0.0: jnp.asarray(rng.randn(1, s, w) + shift,
+                                         jnp.bfloat16)
+    pre = (rows(), rows(), rows(), rows(-2.0),
+           *(jnp.asarray(rng.uniform(-0.5, 0.5, (4, w)), jnp.bfloat16)
+             for _ in range(3)),
+           jnp.asarray(rng.randn(w) - 3.0, jnp.float32),
+           jnp.asarray(np.log(rng.uniform(1, 16, heads)), jnp.float32))
+    post = (rows(), rows(), jnp.asarray(1 + 0.1 * rng.randn(128),
+                                        jnp.bfloat16))
+    cot = tuple(rows() for _ in range(3)) + (
+        jnp.asarray(rng.randn(1, s, w), jnp.float32), rows())
+    return pre, post, cot
+
+
+@pytest.mark.parametrize("tokens,heads", [(16384, 32), (1000, 3)])
+def test_kda_edge_passes_against_their_xla_statement_on_tpu(tokens, heads):
+    """The two elementwise passes around the scan (ops/pallas/
+    kda_edge.py: `kda_pre_fwd` / `kda_pre_bwd`, `kda_post_fwd` /
+    `kda_post_bwd` through Mosaic) at the Kimi cell's shape — 1 x
+    16,384 x 32 heads of 128, bfloat16 operands, float32 g — and at a
+    length that is no multiple of the row tile with a head a grid step,
+    against their XLA statement (nn/functional/kda.py) on the same
+    chip: the five outputs and all twelve cotangents; and the two
+    counters."""
+    from paddle_tpu.nn.functional import kda as X
+    from paddle_tpu.ops.pallas import kda_edge as E
+
+    pre, post, cot = _edge_operands_tpu(tokens, heads, 94)
+
+    def both(pre_fn, post_fn):
+        def f(pre, post):
+            out, vjp = jax.vjp(lambda pre, post: (
+                *pre_fn(*pre), post_fn(*post, 1e-5)), pre, post)
+            return out, vjp(cot)
+        return jax.jit(f)(pre, post)
+
+    before = profiler.get_int_stats()
+    out, (d_pre, d_post) = both(E.kda_pre, E.kda_post)
+    after = profiler.get_int_stats()
+    assert after.get("kda_edge_fused_total", 0) \
+        == before.get("kda_edge_fused_total", 0) + 2
+    assert after.get("kda_edge_fallback_total", 0) \
+        == before.get("kda_edge_fallback_total", 0)
+    ref, (r_pre, r_post) = both(X.edge_pre, X.edge_post)
+    f32 = lambda a: np.asarray(a, np.float32)
+    for a, b_ in zip(out, ref):
+        assert a.dtype == b_.dtype
+        np.testing.assert_allclose(f32(a), f32(b_), atol=2e-2, rtol=2e-2)
+    for a, b_ in zip(d_pre + d_post, r_pre + r_post):
+        assert a.dtype == b_.dtype and a.shape == b_.shape
+        assert np.linalg.norm(f32(a) - f32(b_)) <= 2e-2 * np.linalg.norm(
+            f32(b_))
+
+
 def test_no_kernel_gave_way():
     """Runs last: nothing above (and no other tpu-marked test before
     it) may have pushed a kernel onto its XLA path."""
@@ -561,6 +618,8 @@ def test_no_kernel_gave_way():
     assert stats.get("flash_fallback_total", 0) == 0
     assert stats.get("serving_ragged_fallback_total", 0) == 0
     assert stats.get("kda_fallback_total", 0) == 0
+    assert stats.get("kda_edge_fallback_total", 0) == 0
+    assert stats.get("kda_edge_fused_total", 0) > 0
 
 
 def test_packed_layout_engaged():
