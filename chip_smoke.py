@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
-Drives the six main paths once, in ONE process, through the entry
+Drives the seven main paths once, in ONE process, through the entry
 points a user calls, at published widths, on seeded random weights:
 
   executor_resnet50   models/resnet.build_train_program -> fluid.Executor
@@ -15,6 +15,10 @@ points a user calls, at published widths, on seeded random weights:
   kimi_linear_step    models/kimi_linear.build_train_step (Kimi Delta
                       Attention on the chunked scan kernels, position-
                       free latent attention, the same expert layer)
+  laguna_step         models/laguna.build_train_step (window and full
+                      causal attention mixed on the flash kernels, a
+                      window as a band their grids walk, 64 and 48 heads
+                      over 8, both rotations, the softmax router)
 
     python chip_smoke.py            # one chip (what the driver runs)
     python chip_smoke.py --chips 4  # the four-chip host: data-parallel
@@ -827,6 +831,93 @@ def kimi_linear_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
     return ph.done()
 
 
+def laguna_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
+    """`laguna.build_train_step` with per-layer recomputation: the
+    trace-time counters say that every window layer's flash instance
+    carries its window and that its grid walks the band (the forward
+    grid's steps all but the first q tiles' on a live tile), that the
+    full layers rotate half a head by YaRN's frequencies, and the
+    executable, how many flash calls of each kind it holds; the
+    run-time counters, that no held visit was dropped."""
+    import jax.numpy as jnp
+
+    import paddle_tpu
+    from paddle_tpu.models import laguna
+
+    ph = _Phase("laguna_step")
+    paddle_tpu.seed(SEED)
+    model = laguna.LagunaForCausalLM(cfg)
+    window = sum(cfg.window(i) is not None
+                 for i in range(cfg.num_hidden_layers))
+    full = cfg.num_hidden_layers - window
+    sparse = sum(layer.sparse for layer in model.model.layers)
+    step, state = laguna.build_train_step(model)
+    b = laguna.fake_batch(cfg, batch, seq, seed=SEED)
+    lr = jnp.float32(1e-3)
+    traced = ("flash_window_total", "flash_window_grid_steps_total",
+              "flash_window_tiles_live_total", "rope_yarn_total",
+              "rope_partial_total")
+    ran = ("moe_rows_routed_total", "moe_rows_held_total",
+           "moe_expert_rows_max_total", "moe_dropped_total")
+    s0 = _stats()
+    t0 = time.perf_counter()
+    compiled = step.lower(state, b, lr).compile()
+    ph.compile_s = time.perf_counter() - t0
+    for k in traced:
+        ph.info[k] = _stats().get(k, 0) - s0.get(k, 0)
+    losses = []
+    for _ in range(steps):
+        state, loss, aux = compiled(state, b, lr)
+        losses.append(float(loss))
+        laguna.record_moe_stats(np.asarray(aux["moe_stats"]))
+    s1 = _stats()
+    for k in ran:
+        ph.info[k] = s1.get(k, 0) - s0.get(k, 0)
+    ph.info["losses"] = [round(v, 4) for v in losses]
+    ph.check(all(math.isfinite(v) for v in losses),
+             f"{steps} losses finite")
+    ph.check(losses[-1] < losses[0], "loss falls on the repeated batch")
+    ph.check(abs(losses[0] - math.log(cfg.vocab_size)) < 1.0,
+             f"first loss {losses[0]:.3f} near ln(vocab) = "
+             f"{math.log(cfg.vocab_size):.3f}")
+    ph.check(ph.info["moe_rows_held_total"] > 0
+             and ph.info["moe_dropped_total"] == 0,
+             "held visits computed, moe_dropped_total did not move")
+    ph.check(ph.info["moe_rows_routed_total"]
+             == steps * sparse * batch * seq * cfg.num_experts_per_tok,
+             "the routers' count vectors count every visit")
+    ph.check((ph.info["rope_yarn_total"], ph.info["rope_partial_total"])
+             == (full, full),
+             f"{full} full layers rotate part of the head by YaRN's "
+             "frequencies")
+    ph.check(_device_platforms(state["params"]["lm_head.weight"])
+             == {platform}, f"parameters sit on platform {platform!r}")
+    if platform == "tpu":
+        text = compiled.as_text()
+        ops = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*'
+                         r'op_name="([^"]*)"', text)
+        count = lambda fn: sum(fn in op for op in ops)
+        layers = cfg.num_hidden_layers
+        ph.check((count("_flash_forward"), count("_flash_backward"))
+                 == (2 * layers, 2 * layers),
+                 f"{2 * layers} flash_fwd calls (with the recomputed ones) "
+                 f"and {2 * layers} backward calls for {layers} layers")
+        # (256, 256) tiles: a band of window / 256 + 1 tiles a q tile, the
+        # first q tiles' bands shorter by 1, 2, ... tiles
+        tiles, band = seq // 256, cfg.sliding_window // 256 + 1
+        ph.check((ph.info["flash_window_total"],
+                  ph.info["flash_window_grid_steps_total"],
+                  ph.info["flash_window_tiles_live_total"])
+                 == (window, window * tiles * band,
+                     window * (tiles * band - band * (band - 1) // 2)),
+                 f"{window} window instances; their forward grids walk the "
+                 f"band: {tiles} q tiles x {band} steps a head, all live but "
+                 "the first q tiles' shorter bands")
+        ph.check(_fallback_counts()["flash_fallback_total"] == 0,
+                 "flash_fallback_total == 0")
+    return ph.done()
+
+
 # ---------------------------------------------------------------------------
 
 def _print_startup_phases() -> None:
@@ -853,7 +944,8 @@ def main(argv=None) -> int:
     device, count = require_chip(args.chips)
 
     from paddle_tpu.fluid.compile_cache import enable_persistent_cache
-    from paddle_tpu.models import bert, joyai_flash, kimi_linear, sdar_moe
+    from paddle_tpu.models import (bert, joyai_flash, kimi_linear, laguna,
+                                   sdar_moe)
 
     print(f"chip_smoke: compile cache at {enable_persistent_cache()}")
     phases = []
@@ -881,6 +973,13 @@ def main(argv=None) -> int:
         # vocabulary
         phases.append(kimi_linear_step(kimi_linear.KimiLinearConfig(
             num_hidden_layers=4, experts_held=(0, 8), vocab_size=20480,
+            recompute=True)))
+        # Laguna-XS.2's widths: layers 0-4 of the published 40 (full +
+        # dense, three window layers, a full one, the four with their
+        # expert layers), one chip's 16 of the 256 experts and an eighth
+        # of the vocabulary
+        phases.append(laguna_step(laguna.LagunaConfig(
+            num_hidden_layers=5, experts_held=(0, 16), vocab_size=12544,
             recompute=True)))
     else:
         phases.append(executor_resnet50(4 * 128, data_parallel=4))

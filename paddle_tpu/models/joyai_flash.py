@@ -467,7 +467,6 @@ def train_step_from_loss(model, loss_fn, weight_decay=0.0,
                            for k, v in params0.items() if k not in biases}
         state = {"params": params0, "m": moments(), "v": moments(),
                  "t": jnp.int32(0)}
-    gamma = model.config.bias_update_rate
     b1, b2, eps = 0.9, 0.999, 1e-8
 
     def step(state, batch, lr_s):
@@ -496,7 +495,8 @@ def train_step_from_loss(model, loss_fn, weight_decay=0.0,
         with jax.named_scope("moe_bias_update"):
             for i, k in enumerate(biases):
                 new_p[k] = update_selection_bias(
-                    fixed[k], aux["moe_load"][i], gamma)
+                    fixed[k], aux["moe_load"][i],
+                    model.config.bias_update_rate)
         return ({"params": new_p, "m": new_m, "v": new_v, "t": t},
                 loss, aux)
 

@@ -310,7 +310,8 @@ def rms_norm(x, weight=None, epsilon=1e-6, name=None):
 
 
 def rotary_embedding(q, k, positions, theta=10000.0, interleaved=False,
-                     name=None):
+                     name=None, rotary_dim=None, inv_freq=None,
+                     amplitude=1.0):
     """Rotary position embedding (Su et al. 2021) over the whole head
     width, rotate-half convention: q, k (batch, seq, heads, head_dim),
     positions (batch, seq) or (seq,) integers -> (q, k) rotated, with
@@ -322,29 +323,84 @@ def rotary_embedding(q, k, positions, theta=10000.0, interleaved=False,
     first (evens, then odds) and stay so: q and k come out under the
     same fixed permutation, so every q · k is the interleaved
     rotation's (the released modeling code of those models does the
-    same)."""
+    same).
+
+    `rotary_dim` (a `partial_rotary_factor` times the head width): the
+    first `rotary_dim` lanes alone are rotated, rotate-half within
+    them, and the others pass through untouched.  `inv_freq`
+    (rotary_dim / 2,) float32, host values: the pairs' inverse
+    frequencies where they are not theta's powers (`yarn_inv_freq`).
+    `amplitude`: cos and sin both times it, on the rotated lanes only
+    (YaRN's attention factor)."""
     import jax.numpy as jnp
 
+    plain = rotary_dim is None and inv_freq is None and amplitude == 1.0
+    if rotary_dim is not None and rotary_dim < q.shape[-1]:
+        from ...profiler import stat_add
+
+        stat_add("rope_partial_total")
+
     def f(q, k, pos):
-        d = q.shape[-1]
-        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        d = q.shape[-1] if rotary_dim is None else rotary_dim
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d) \
+            if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
         ang = pos.astype(jnp.float32)[..., None] * inv
         if ang.ndim == 2:
             ang = ang[None]
         cos = jnp.cos(ang)[:, :, None, :]
         sin = jnp.sin(ang)[:, :, None, :]
+        if amplitude != 1.0:
+            cos, sin = cos * amplitude, sin * amplitude
 
         def rot(x):
             xf = x.astype(jnp.float32)
+            if not plain:
+                xf, rest = xf[..., :d], x[..., d:]
             x1, x2 = (xf[..., 0::2], xf[..., 1::2]) if interleaved \
                 else jnp.split(xf, 2, axis=-1)
-            return jnp.concatenate(
+            out = jnp.concatenate(
                 [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                 axis=-1).astype(x.dtype)
+            return out if plain else jnp.concatenate([out, rest], axis=-1)
 
         return rot(q), rot(k)
 
     return trace_fn(f, {"q": q, "k": k, "pos": positions}, multi_out=True)
+
+
+def yarn_inv_freq(dim, base, factor, original_max, beta_fast=32.0,
+                  beta_slow=1.0):
+    """The inverse frequencies of YaRN (Peng et al. 2023,
+    arXiv:2309.00071) for `dim` rotated lanes, (dim / 2,) float32 on
+    the host, as the `transformers` library computes `rope_type: yarn`:
+    with f_p = base^(-2p / dim) and c(r) = dim ln(original_max / (2 pi
+    r)) / (2 ln base) — the pair that turns r times over the original
+    context —, low = floor(c(beta_fast)), high = ceil(c(beta_slow))
+    (clipped to [0, dim - 1]) and ramp_p = clip((p - low) / (high -
+    low), 0, 1),
+
+        inv_p = f_p ((1 - ramp_p) + ramp_p / factor):
+
+    pairs under `low` keep their frequency (they turn often inside the
+    original context: extrapolated), pairs over `high` are slowed by
+    `factor` (interpolated), those between blend.  The amplitude that
+    goes with it (`0.1 ln factor + 1` unless the config states one) is
+    the caller's `amplitude`."""
+    import math
+
+    def turns(r):
+        return dim * math.log(original_max / (r * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    p = np.arange(dim // 2, dtype=np.float32)
+    freq = 1.0 / np.float32(base) ** (2 * p / np.float32(dim))
+    ramp = np.clip((p - low) / (high - low), 0, 1).astype(np.float32)
+    return (freq * ((1 - ramp) + ramp / np.float32(factor))).astype(
+        np.float32)
 
 
 def instance_norm(x, running_mean=None, running_var=None, weight=None,
@@ -739,11 +795,13 @@ def unfold(x, kernel_sizes, strides=1, paddings=0, dilations=1, name=None):
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True, name=None):
+                                 training=True, name=None, window=None):
     """Fused attention.  On TPU this routes to the Pallas flash-attention
     kernel (paddle_tpu/ops/pallas/flash_attention.py) when shapes allow;
     otherwise an XLA-fused reference path.  Inputs are (batch, seq, heads,
-    head_dim) like the reference's 2.x API."""
+    head_dim) like the reference's 2.x API.  `window` (with
+    `is_causal`, self-attention): a position attends to the `window`
+    latest positions up to its own."""
     from ...fluid.dygraph.tracer import _next_func_key, _tracer
     from ...ops.pallas import attention as _attn
 
@@ -766,7 +824,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     def f(q, k, v, mask=None):
         return _attn.scaled_dot_product_attention(
             q, k, v, mask=mask, is_causal=is_causal, dropout_p=p,
-            dropout_key=dkey)
+            dropout_key=dkey, window=window)
 
     return trace_fn(f, ins)
 

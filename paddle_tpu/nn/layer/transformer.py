@@ -152,6 +152,107 @@ class GroupedQueryAttention(Layer):
         return self.out_proj(out)
 
 
+class _Float32Linear(Linear):
+    """x W with the result left in float32 whatever x's dtype (no
+    bias): a narrow projection whose output feeds a transcendental."""
+
+    def forward(self, x):
+        return trace_fn(
+            lambda x, w: jnp.dot(x, w.astype(x.dtype),
+                                 preferred_element_type=jnp.float32),
+            {"x": x, "w": self.weight})
+
+
+class GatedWindowAttention(Layer):
+    """Causal self-attention with grouped key/value heads, an optional
+    sliding window, rotary positions on all or part of the head, and a
+    sigmoid gate per head on the attention output before the output
+    projection (Qiu et al. 2025, arXiv:2505.06708: a head-wise gate
+    after the scaled dot-product attention) — the attention block of a
+    decoder whose layers are windowed or full, each kind with a head
+    count and a rotation of its own.  No biases.
+
+        q, k, v = x W_q, x W_k, x W_v     -> H, Hkv, Hkv heads of `head_dim`
+        g       = sigmoid(x W_g)          -> H, float32
+        o_j     = softmax(RoPE(q_j) RoPE(k_{j // (H / Hkv)})^T
+                          / sqrt(head_dim) + mask) v_{j // (H / Hkv)}
+        out     = concat_j(g_j o_j) W_o
+
+    `window`: row i sees the keys i - window < j <= i (None: every key
+    j <= i).  `rope`: one entry of a config's `rope_parameters` —
+    `rope_theta`, `partial_rotary_factor` (the first factor * head_dim
+    lanes are rotated, rotate-half within them) and `rope_type`
+    "default" or "yarn" (then `factor`,
+    `original_max_position_embeddings`, `beta_fast`, `beta_slow`,
+    `attention_factor`: `F.yarn_inv_freq`, the amplitude on cos and
+    sin).  `gate=False`: no `g_proj`.
+
+    forward(x (B, S, E), positions (B, S) | (S,)) -> (B, S, E); the
+    flash kernels read the Hkv heads as they are, a window as a band
+    their grids walk (ops/pallas/attention.py).  Scopes beside the
+    sublayers': `rope`, `gate`."""
+
+    def __init__(self, embed_dim, num_heads, num_kv_heads, head_dim,
+                 window=None, rope=None, gate=True, weight_attr=None):
+        super().__init__()
+        assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim, self.window = head_dim, window
+        rope = dict(rope or {})
+        kind = rope.get("rope_type", "default")
+        self._theta = float(rope.get("rope_theta", 10000.0))
+        self._rotary_dim = int(head_dim
+                               * rope.get("partial_rotary_factor", 1.0))
+        self._inv_freq, self._amplitude = None, 1.0
+        if kind == "yarn":
+            factor = rope["factor"]
+            self._inv_freq = F.yarn_inv_freq(
+                self._rotary_dim, self._theta, factor,
+                rope["original_max_position_embeddings"],
+                rope.get("beta_fast", 32.0), rope.get("beta_slow", 1.0))
+            self._amplitude = float(rope.get("attention_factor")
+                                    or 0.1 * np.log(factor) + 1.0)
+        elif kind != "default":
+            raise NotImplementedError(f"rope_type {kind!r}: default or yarn")
+        lin = lambda i, o: Linear(i, o, weight_attr, False)
+        self.q_proj = lin(embed_dim, num_heads * head_dim)
+        self.k_proj = lin(embed_dim, num_kv_heads * head_dim)
+        self.v_proj = lin(embed_dim, num_kv_heads * head_dim)
+        self.g_proj = _Float32Linear(embed_dim, num_heads, weight_attr,
+                                     False) if gate else None
+        self.o_proj = lin(num_heads * head_dim, embed_dim)
+
+    def forward(self, x, positions):
+        d = self.head_dim
+        split = lambda y, n: trace_fn(
+            lambda y: y.reshape(y.shape[0], y.shape[1], n, d), {"y": y})
+        q = split(self.q_proj(x), self.num_heads)
+        k = split(self.k_proj(x), self.num_kv_heads)
+        v = split(self.v_proj(x), self.num_kv_heads)
+        with jax.named_scope("rope"):
+            if self._inv_freq is not None:
+                from ...profiler import stat_add
+
+                stat_add("rope_yarn_total")
+            partial = self._rotary_dim != d
+            q, k = F.rotary_embedding(
+                q, k, positions, self._theta,
+                rotary_dim=self._rotary_dim if partial else None,
+                inv_freq=self._inv_freq, amplitude=self._amplitude)
+        out = F.scaled_dot_product_attention(
+            q, k, v, is_causal=True, training=self.training,
+            window=self.window)
+        if self.g_proj is not None:
+            g = self.g_proj(x)
+            with jax.named_scope("gate"):
+                out = trace_fn(
+                    lambda o, g: (o.astype(jnp.float32) * jax.nn.sigmoid(
+                        g)[..., None]).astype(o.dtype), {"o": out, "g": g})
+        out = trace_fn(
+            lambda o: o.reshape(o.shape[0], o.shape[1], -1), {"o": out})
+        return self.o_proj(out)
+
+
 class LatentAttention(Layer):
     """Multi-head latent attention (DeepSeek-V2 §2.1; the V3 family's
     attention block) in its expanded, training form: queries and

@@ -191,14 +191,80 @@ class _CausalTiles:
         return _with_fetch_tables(cls)
 
 
+@dataclasses.dataclass(frozen=True)
+class _WindowTiles:
+    """The tile classes of a causal sliding window over self-attention
+    — row i sees column j iff i - window < j <= i: `window` keys, the
+    row's own among them — in closed form, as `_CausalTiles`: a tile
+    the band misses is dead, one inside it full, one that crosses
+    either edge partial (masked by the index compare, which has the
+    lower bound too).  The kernels do not walk this table: their grids
+    are the band's own length along the inner axis (`_band_k`,
+    `_band_q`); it is what the counters and the tests read."""
+    window: int
+
+    @functools.lru_cache(maxsize=None)
+    def tiles(self, n_rows: int, n_cols: int, block_q: int, block_k: int):
+        r0 = np.arange(n_rows // block_q)[:, None] * block_q
+        c0 = np.arange(n_cols // block_k)[None, :] * block_k
+        some = (c0 <= r0 + block_q - 1) & (c0 + block_k - 1 > r0 - self.window)
+        every = (c0 + block_k - 1 <= r0) \
+            & (c0 > r0 + block_q - 1 - self.window)
+        return _with_fetch_tables(some.astype(np.int32) + every)
+
+
+def _band_k(iq, block_q, block_k, window, nk, xp=jnp):
+    """(first, last) k tile that q tile `iq` of a window's band sees:
+    columns iq * block_q - window + 1 ... iq * block_q + block_q - 1.
+    Host integers with `xp=np`, a grid step's scalars without."""
+    lo = xp.maximum(iq * block_q - window + 1, 0) // block_k
+    hi = xp.minimum((iq * block_q + block_q - 1) // block_k, nk - 1)
+    return lo, hi
+
+
+def _band_q(ik, block_q, block_k, window, nq, xp=jnp):
+    """(first, last) q tile that sees k tile `ik`: rows ik * block_k ...
+    ik * block_k + block_k + window - 2."""
+    lo = (ik * block_k) // block_q
+    hi = xp.minimum((ik * block_k + block_k + window - 2) // block_q, nq - 1)
+    return lo, hi
+
+
+def _band_lengths(window, nq, nk, block_q, block_k):
+    """Grid steps along the inner axis of the (q tile, k tile) and the
+    (k tile, q tile) grids of a window: the most tiles any outer tile's
+    band holds (`window / block + 1`, or one more where the tiles'
+    edges do not meet the band's)."""
+    lo, hi = _band_k(np.arange(nq), block_q, block_k, window, nk, np)
+    lo_q, hi_q = _band_q(np.arange(nk), block_q, block_k, window, nq, np)
+    return int((hi - lo + 1).max()), int((hi_q - lo_q + 1).max())
+
+
+def _band_step(outer, step, outer_is_q, block_q, block_k, window, tiles):
+    """Where step `step` of the band of tile `outer` stands — `outer` a
+    q tile and the band its k tiles (`outer_is_q`), or a k tile and the
+    band its q tiles; `tiles` = (q tiles, k tiles): `(inner tile, (live,
+    full))`.  A step past the band's last tile is dead (the first q
+    tiles' bands and the last k tiles' are shorter than the grid); a
+    tile wholly inside the band is full."""
+    lo, hi = _band_k(outer, block_q, block_k, window, tiles[1]) \
+        if outer_is_q else _band_q(outer, block_q, block_k, window, tiles[0])
+    inner = lo + step
+    iq, ik = (outer, inner) if outer_is_q else (inner, outer)
+    full = (ik * block_k + block_k - 1 <= iq * block_q) \
+        & (ik * block_k > iq * block_q + block_q - 1 - window)
+    return inner, (inner <= hi, full)
+
+
 # -- XLA reference path -------------------------------------------------------
 
 def _xla_attention(q, k, v, mask=None, is_causal=False, scale=None,
-                   dropout_p=0.0, dropout_key=None):
+                   dropout_p=0.0, dropout_key=None, window=None):
     """(B, S, H, D) attention in plain XLA; used off-TPU, for masks the
     kernel cannot express, and as the numerical oracle in tests.  Fewer
     key/value heads than query heads (grouped-query attention) are
-    repeated here; a `BlockDiffusionMask` becomes its dense form."""
+    repeated here; a `BlockDiffusionMask` becomes its dense form, a
+    `window` (with `is_causal`) a dense band."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     if k.shape[2] != q.shape[2]:
@@ -214,6 +280,9 @@ def _xla_attention(q, k, v, mask=None, is_causal=False, scale=None,
     if is_causal:
         sq, sk = logits.shape[-2], logits.shape[-1]
         causal = jnp.tril(jnp.ones((sq, sk), jnp.bool_), k=sk - sq)
+        if window is not None:
+            causal &= ~jnp.tril(jnp.ones((sq, sk), jnp.bool_),
+                                k=sk - sq - window)
         logits = jnp.where(causal, logits, DEFAULT_MASK_VALUE)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     if dropout_p > 0.0:
@@ -379,17 +448,22 @@ def _code_mask(codes, reps):
             | (c_eq[0] == _tile_rows(r_eq[0], reps)))[None]
 
 
-def _causal_rows(iq, ik, block_q, block_k, reps, causal_offset):
-    """The causal mask of a grouped tile, (1, reps * block_q, block_k)."""
+def _causal_rows(iq, ik, block_q, block_k, reps, causal_offset,
+                 window=None):
+    """The causal mask of a grouped tile, (1, reps * block_q, block_k);
+    with `window`, the band's: the lower bound too."""
     q_idx = _tile_rows(iq * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, 1), 0), reps)
     k_idx = ik * block_k + lax.broadcasted_iota(
         jnp.int32, (1, block_k), 1)
-    return (q_idx + causal_offset >= k_idx)[None]
+    seen = q_idx + causal_offset >= k_idx
+    if window is not None:
+        seen &= k_idx > q_idx + causal_offset - window
+    return seen[None]
 
 
 def _mask_scores(s, iq, ik, codes, full, *, block_h, block_q, block_k,
-                 causal, causal_offset, grouped):
+                 causal, causal_offset, grouped, window=None):
     """The score tile `s` with what the step's masks hide set to
     DEFAULT_MASK_VALUE: causal (query i attends keys <= i +
     causal_offset, offset = sk - sq, matching the XLA path's
@@ -403,9 +477,10 @@ def _mask_scores(s, iq, ik, codes, full, *, block_h, block_q, block_k,
         causal = False
     if full:
         codes = None
-    if causal and grouped:
+    if causal and (grouped or window is not None):
         s = jnp.where(_causal_rows(iq, ik, block_q, block_k, reps,
-                                   causal_offset), s, DEFAULT_MASK_VALUE)
+                                   causal_offset, window), s,
+                      DEFAULT_MASK_VALUE)
     elif causal:
         q_idx = iq * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_h, block_q, block_k), 1)
@@ -419,15 +494,15 @@ def _mask_scores(s, iq, ik, codes, full, *, block_h, block_q, block_k,
 
 
 def _seen_pairs(iq, ik, codes, full, *, block_q, block_k, causal,
-                causal_offset):
+                causal_offset, window=None):
     """`_mask_scores`' masks as one (block_q, block_k) bool, the pairs
     of tile (iq, ik) a row sees — the same for every head of a step,
     which the forward kernel walks a head at a time — or None where
     they hide nothing."""
     if full and codes is None:
         causal = False
-    seen = _causal_rows(iq, ik, block_q, block_k, 1, causal_offset)[0] \
-        if causal else None
+    seen = _causal_rows(iq, ik, block_q, block_k, 1, causal_offset,
+                        window)[0] if causal else None
     if codes is not None and not full:
         code = _code_mask(codes, 1)[0]
         seen = code if seen is None else seen & code
@@ -449,14 +524,23 @@ def _split_refs(refs, tabled, masked, n_in):
     return cls, ins, codes, rest
 
 
-def _by_class(tile, cls_ref, iq, ik, nk, unmask_full=True):
+def _by_class(tile, cls_ref, iq, ik, nk, unmask_full=True, band=None):
     """Run the tile body `tile(full)` with the masking that can change
-    the tile: all of it where there is no table (`cls_ref` None); else
+    the tile: by `band` = (live, full) where the grid walks a window's
+    band (`_band_step`); all of it where there is no table (`cls_ref`
+    None); else
     by the class of tile (iq, ik) in the (nq, nk) table of
     `BlockDiffusionMask.tiles` / `_CausalTiles.tiles` — a dead tile is
     skipped, not computed and masked; a partial one gets the table's
     mask (`full` False); a full one runs the same body without it
     (`unmask_full` False: with it, as a partial one)."""
+    if band is not None:
+        live, full = band
+        pl.when(live & ~full if unmask_full else live)(
+            functools.partial(tile, False))
+        if unmask_full:
+            pl.when(live & full)(functools.partial(tile, True))
+        return
     if cls_ref is None:
         tile(False)
         return
@@ -471,6 +555,8 @@ def _by_class(tile, cls_ref, iq, ik, nk, unmask_full=True):
 # their temporaries: 17.5 MB in flash_bwd_dkv, over the compiler's
 # default scoped-VMEM budget of 16 MiB, well inside the v5e's 128.
 _PACKED_VMEM_LIMIT = 32 * 1024 * 1024
+# tile edge of an instance with a window, unless the caller names one
+_WINDOW_BLOCK = 256
 _PACKED_MAX_SCORES = 4 * 512 * 512
 # a grouped step's q, g and f32 accumulators span the whole group
 _GROUPED_VMEM_LIMIT = 48 * 1024 * 1024
@@ -532,6 +618,27 @@ def _pallas_call(kernel, grid, in_specs, out_specs, out_shape,
     return functools.partial(call, *tables)
 
 
+def _band_fetch_k(window, block_q, block_k, sk):
+    """The k tile to have in VMEM at step j of q tile i's band, as an
+    index-map function `(i, j, tables)`: the band's first plus j, held
+    at the band's last on the steps past it (a dead step moves
+    nothing)."""
+    def fetch(i, j, _):
+        lo, hi = _band_k(i, block_q, block_k, window, sk // block_k)
+        return jnp.minimum(lo + j, hi)
+    return fetch
+
+
+def _band_fetch_q(window, block_q, block_k, sq, steps):
+    """`_band_fetch_k`'s twin for the (k tile, q tile) grid, whose last
+    axis runs over (head block of a group, band step): `steps` band
+    steps a head block."""
+    def fetch(i, j, _):
+        lo, hi = _band_q(i, block_q, block_k, window, sq // block_q)
+        return jnp.minimum(lo + j % steps, hi)
+    return fetch
+
+
 # -- Pallas forward kernel ----------------------------------------------------
 
 def _lanes(x, width):
@@ -545,7 +652,8 @@ def _lanes(x, width):
 
 def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
                       causal_offset, dropout_p, grouped=False,
-                      tabled=False, masked=False, biased=True):
+                      tabled=False, masked=False, biased=True,
+                      window=None, tiles=None):
     """One grid step = block_h heads' (block_q, block_k) score tiles,
     walked a head at a time: a head's tile goes matmul -> mask -> max ->
     exp -> sum -> cast -> matmul before the next head's starts, so no
@@ -554,7 +662,8 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
     the scores (`_lanes`) `s - m`, `acc * alpha` and `acc / l` are
     plain vreg operations, with no one-lane store and no lane broadcast
     of a (rows, 1) column.  Only `lse` leaves as such a column, once a
-    q tile."""
+    q tile.  `window` (with `tiles` = (q tiles, k tiles)): the last grid
+    axis walks the band of the q tile, not the k tiles (`_band_k`)."""
     cls_ref, (seed_ref, q_ref, k_ref, v_ref, kbias_ref), codes, \
         (o_ref, lse_ref, m_scr, l_scr, acc_scr) = _split_refs(
             refs, tabled, masked, 5)
@@ -563,8 +672,12 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
     kv_block_h = 1 if grouped else block_h  # heads in the k/v blocks
+    step, band = ik, None
+    if window is not None:
+        ik, band = _band_step(iq, step, True, block_q, block_k, window,
+                              tiles)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -574,7 +687,7 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
         # the masks are the same for every head of the step
         seen = _seen_pairs(iq, ik, codes, full, block_q=block_q,
                            block_k=block_k, causal=causal,
-                           causal_offset=causal_offset)
+                           causal_offset=causal_offset, window=window)
         for h in range(block_h):
             kv = 0 if grouped else h
             s = jax.lax.dot_general(
@@ -610,9 +723,9 @@ def _flash_fwd_kernel(*refs, scale, block_h, block_q, block_k, causal,
     # against 14.986 GiB in the SDAR cell, 15.085 against 15.080 in the
     # JoyAI cell, whose causal instances gain nothing by it; SDAR's run
     # 5.5% faster a call: PERF.md §6, PR 33)
-    _by_class(_tile, cls_ref, iq, ik, nk, unmask_full=False)
+    _by_class(_tile, cls_ref, iq, ik, nk, unmask_full=False, band=band)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         out = []
         for h in range(block_h):
@@ -667,11 +780,11 @@ def _mask_operands(tiler, block_mask, sq, sk, block_q, block_k, order):
 @functools.partial(jax.jit, static_argnames=(
     "heads", "is_causal", "scale", "dropout_p", "block_h", "block_q",
     "block_k", "interpret", "causal_offset", "kv_heads", "block_mask",
-    "biased"))
+    "biased", "window"))
 def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
                    dropout_p=0.0, block_h=1, block_q=128, block_k=128,
                    interpret=False, causal_offset=None, kv_heads=None,
-                   block_mask=None, biased=True):
+                   block_mask=None, biased=True, window=None):
     """q,k,v: merged (BH, S, D) or packed (B, S, H*D) — told apart by
     the leading dim, kbias carrying B; kbias: (B, 1, Sk) f32; seed:
     (1,) i32 -> (out like q, lse (BH, Sq, 1) f32: a row's log-sum-exp
@@ -698,6 +811,11 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
     not class dead; those are skipped, their k/v blocks not fetched.
     biased=False (the caller's promise that kbias is all zeros): the
     kernels are built without the `s + kbias` line.
+    window (causal self-attention: row i sees the `window` keys i -
+    window < j <= i): the k axis of the grid is as long as the band of
+    a q tile (`_band_lengths`), a step's k tile the band's first plus
+    the step's index (`_band_k`, in the index maps and in the kernel
+    alike); no table.
 
     Row-vector operands are laid out with a unit SUBLANE dim ((B, 1, Sk)
     bias blocks (1, 1, block_k); (BH, Sq, 1) lse blocks (block_h,
@@ -716,7 +834,8 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
 
     if causal_offset is None:
         causal_offset = sk - sq
-    tiler = _tiler(block_mask, is_causal, causal_offset)
+    tiler = None if window is not None else _tiler(
+        block_mask, is_causal, causal_offset)
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, block_h=block_h, block_q=block_q,
         block_k=block_k, causal=is_causal, causal_offset=causal_offset,
@@ -724,6 +843,13 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
         masked=masked, biased=biased)
     tables, codes, code_specs, fetch = _mask_operands(
         tiler, block_mask, sq, sk, block_q, block_k, "qk")
+    kb_tile = lambda i, j, t: j
+    if window is not None:
+        assert is_causal and not masked and causal_offset == 0, window
+        kernel = functools.partial(kernel, window=window, tiles=grid[1:])
+        grid = grid[:2] + (_band_lengths(window, *grid[1:], block_q,
+                                         block_k)[0],)
+        kb_tile = fetch = _band_fetch_k(window, block_q, block_k, sk)
     q_spec = _heads_spec(packed, heads, block_h, block_q, d, 1)
     o_spec = _heads_spec(packed, heads, block_h, block_q, dv, 1)
     if grouped:
@@ -750,7 +876,7 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
             q_spec, k_spec, v_spec,
             pl.BlockSpec((1, 1, block_k),
                          lambda b, iq, ik, *t, h=heads, bh_=block_h:
-                         ((b * bh_) // h, 0, ik)),
+                         ((b * bh_) // h, 0, kb_tile(iq, ik, t))),
         ] + code_specs,
         out_specs=[
             o_spec,
@@ -782,7 +908,7 @@ def _flash_forward(q, k, v, kbias, seed, heads, is_causal=False, scale=None,
 def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
                           causal_offset, dropout_p, grouped=False,
                           tabled=False, masked=False, biased=True,
-                          q_tiles=None):
+                          q_tiles=None, window=None, tiles=None):
     cls_ref, (seed_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref,
                kbias_ref), codes, (dk_ref, dv_ref, dk_scr, dv_scr) = \
         _split_refs(refs, tabled, masked, 8)
@@ -797,6 +923,10 @@ def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
     first = iq == 0
     if grouped:
         iq = iq % q_tiles
+    band = None
+    if window is not None:      # the last axis walks the k tile's band
+        iq, band = _band_step(ik, iq, False, block_q, block_k, window,
+                              tiles)
 
     @pl.when(first)
     def _init():
@@ -824,7 +954,8 @@ def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
             s = s + kbias_ref[...]
         s = _mask_scores(s, iq, ik, codes, full, block_h=block_h,
                          block_q=block_q, block_k=block_k, causal=causal,
-                         causal_offset=causal_offset, grouped=grouped)
+                         causal_offset=causal_offset, grouped=grouped,
+                         window=window)
         p = jnp.exp(s - lse)      # softmax probs, (block_h, bq, bk)
 
         if dropout_p > 0.0:
@@ -854,7 +985,7 @@ def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
             ds.astype(q.dtype), q, (((1,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
-    _by_class(_tile, cls_ref, iq, ik, nk)
+    _by_class(_tile, cls_ref, iq, ik, nk, band=band)
 
     @pl.when(last)
     def _finalize():
@@ -864,7 +995,8 @@ def _flash_bwd_dkv_kernel(*refs, scale, block_h, block_q, block_k, causal,
 
 def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
                          causal_offset, dropout_p, grouped=False,
-                         tabled=False, masked=False, biased=True):
+                         tabled=False, masked=False, biased=True,
+                         window=None, tiles=None):
     cls_ref, (seed_ref, q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref,
                kbias_ref), codes, (dq_ref, dq_scr) = _split_refs(
         refs, tabled, masked, 8)
@@ -872,8 +1004,12 @@ def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
     iq = pl.program_id(1)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
+    step, band = ik, None
+    if window is not None:
+        ik, band = _band_step(iq, step, True, block_q, block_k, window,
+                              tiles)
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -898,7 +1034,8 @@ def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
             s = s + kbias_ref[...]
         s = _mask_scores(s, iq, ik, codes, full, block_h=block_h,
                          block_q=block_q, block_k=block_k, causal=causal,
-                         causal_offset=causal_offset, grouped=grouped)
+                         causal_offset=causal_offset, grouped=grouped,
+                         window=window)
         p = jnp.exp(s - lse)
 
         dp_drop = jax.lax.dot_general(
@@ -916,9 +1053,9 @@ def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
             ds.astype(k.dtype), k, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
-    _by_class(_tile, cls_ref, iq, ik, nk)
+    _by_class(_tile, cls_ref, iq, ik, nk, band=band)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         if grouped:
             _store_rows(dq_ref, dq_scr[:])
@@ -929,12 +1066,12 @@ def _flash_bwd_dq_kernel(*refs, scale, block_h, block_q, block_k, causal,
 @functools.partial(jax.jit, static_argnames=(
     "heads", "is_causal", "scale", "dropout_p", "block_h", "block_q",
     "block_k", "interpret", "causal_offset", "kv_heads", "block_mask",
-    "biased"))
+    "biased", "window"))
 def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
                     is_causal=False, scale=None, dropout_p=0.0,
                     block_h=1, block_q=128, block_k=128, interpret=False,
                     causal_offset=None, kv_heads=None, block_mask=None,
-                    biased=True):
+                    biased=True, window=None):
     bh, sq, sk, d, packed, lanes = _layout(q, k, kbias, heads)
     dv, v_lanes = _value_width(v, packed, kv_heads or heads)
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -955,7 +1092,8 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
         delta = jnp.sum(go, axis=-1, keepdims=True)  # (BH, Sq, 1)
     if causal_offset is None:
         causal_offset = sk - sq
-    tiler = _tiler(block_mask, is_causal, causal_offset)
+    tiler = None if window is not None else _tiler(
+        block_mask, is_causal, causal_offset)
     tabled = tiler is not None
     kw = dict(scale=scale, block_h=block_h, block_q=block_q,
               block_k=block_k, causal=is_causal,
@@ -966,6 +1104,15 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
         tiler, block_mask, sq, sk, block_q, block_k, "qk")
     t_kq, _, specs_kq, fetch_q = _mask_operands(
         tiler, block_mask, sq, sk, block_q, block_k, "kq")
+    # steps of the inner axis: every tile, or a window's band
+    k_steps, q_steps = nk, nq
+    kb_tile = lambda i, j, t: j
+    if window is not None:
+        assert is_causal and not masked and causal_offset == 0, window
+        kw.update(window=window, tiles=(nq, nk))
+        k_steps, q_steps = _band_lengths(window, nq, nk, block_q, block_k)
+        kb_tile = fetch_k = _band_fetch_k(window, block_q, block_k, sk)
+        fetch_q = _band_fetch_q(window, block_q, block_k, sq, q_steps)
 
     q_spec = _heads_spec(packed, heads, block_h, block_q, d, 1)
     g_spec = _heads_spec(packed, heads, block_h, block_q, dv, 1)
@@ -973,8 +1120,10 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
                             lambda b, i, j, *t: (b, i, 0))
     kb_spec = pl.BlockSpec((1, 1, block_k),
                            lambda b, i, j, *t, h=heads, bh_=block_h:
-                           ((b * bh_) // h, 0, j))
+                           ((b * bh_) // h, 0, kb_tile(i, j, t)))
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    # whether the inner axis's tile goes through fetch_k / fetch_q
+    redirect = fetch_k is not None
     if grouped:
         assert packed and d % 128 == 0 and dv == d and dropout_p == 0.0
         group = heads // kv_heads
@@ -983,20 +1132,20 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
         rows = block_h * block_q
         k_spec = v_spec = pl.BlockSpec(
             (1, block_k, d), lambda n, i, j, *t: (
-                n // per_b, fetch_k(i, j, t) if tabled else j,
+                n // per_b, fetch_k(i, j, t) if redirect else j,
                 (n % per_b) * block_h // group))
         # the dkv grid: (batch x kv head, k tile, head block x q tile)
-        dkv_grid = (bh // group, nk, per_g * nq)
-        q_tile = (lambda i, j, t: fetch_q(i, j, t)) if tabled \
+        dkv_grid = (bh // group, nk, per_g * q_steps)
+        q_tile = (lambda i, j, t: fetch_q(i, j, t)) if redirect \
             else (lambda i, j, t: j % nq)
         q_spec_t = g_spec_t = pl.BlockSpec(
             (1, block_q, block_h * d), lambda n, i, j, *t: (
                 n // kv_heads, q_tile(i, j, t),
-                (n % kv_heads) * per_g + j // nq))
+                (n % kv_heads) * per_g + j // q_steps))
         row_spec_t = pl.BlockSpec(
             (block_h, block_q, 1), lambda n, i, j, *t: (
-                (n // kv_heads) * per_b + (n % kv_heads) * per_g + j // nq,
-                q_tile(i, j, t), 0))
+                (n // kv_heads) * per_b + (n % kv_heads) * per_g
+                + j // q_steps, q_tile(i, j, t), 0))
         k_spec_t = v_spec_t = pl.BlockSpec(
             (1, block_k, d),
             lambda n, i, j, *t: (n // kv_heads, i, n % kv_heads))
@@ -1012,14 +1161,14 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
                              fetch_k)
         # dkv grid iterates (bh, ik, iq): swap index maps for q-side
         # inputs
-        dkv_grid = (bh // block_h, nk, nq)
+        dkv_grid = (bh // block_h, nk, q_steps)
         q_spec_t = _heads_spec(packed, heads, block_h, block_q, d, 2,
                                fetch_q)
         g_spec_t = _heads_spec(packed, heads, block_h, block_q, dv, 2,
                                fetch_q)
         row_spec_t = pl.BlockSpec(
             (block_h, block_q, 1), lambda b, i, j, *t: (
-                b, fetch_q(i, j, t) if tabled else j, 0))
+                b, fetch_q(i, j, t) if redirect else j, 0))
         k_spec_t = _heads_spec(packed, heads, block_h, block_k, d, 1)
         v_spec_t = _heads_spec(packed, heads, block_h, block_k, dv, 1)
         kb_spec_t = pl.BlockSpec((1, 1, block_k),
@@ -1033,7 +1182,7 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
     params = _compiler_params(vmem_limit=vmem)
 
     dk, dv_out = _pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, q_tiles=nq, **kw),
+        functools.partial(_flash_bwd_dkv_kernel, q_tiles=q_steps, **kw),
         grid=dkv_grid,
         in_specs=[smem, q_spec_t, g_spec_t, row_spec_t, row_spec_t,
                   k_spec_t, v_spec_t, kb_spec_t] + specs_kq,
@@ -1049,7 +1198,7 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
 
     dq = _pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **kw),
-        grid=(bh // block_h, nq, nk),
+        grid=(bh // block_h, nq, k_steps),
         in_specs=[smem, q_spec, g_spec, row_spec, row_spec,
                   k_spec, v_spec, kb_spec] + specs_qk,
         out_specs=q_spec,
@@ -1066,10 +1215,11 @@ def _flash_backward(q, k, v, kbias, seed, out, lse, g, heads,
 # -- custom VJP over the kernels ----------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=tuple(range(5, 17)))
+                   nondiff_argnums=tuple(range(5, 18)))
 def _flash_attention(q, k, v, kbias, seed_f, heads, is_causal, scale,
                      dropout_p, interpret, causal_offset, block_h,
-                     block_q, block_k, kv_heads, block_mask, biased):
+                     block_q, block_k, kv_heads, block_mask, biased,
+                     window=None):
     """seed_f: (1,) float32 — a bitcast int32 dropout seed (float so the
     custom_vjp machinery sees only inexact primals).  causal_offset is
     the ORIGINAL sk - sq (pre-padding): the shim pads seq lengths, so it
@@ -1081,14 +1231,15 @@ def _flash_attention(q, k, v, kbias, seed_f, heads, is_causal, scale,
                             causal_offset=causal_offset, block_h=block_h,
                             block_q=block_q, block_k=block_k,
                             kv_heads=kv_heads, block_mask=block_mask,
-                            biased=biased)
+                            biased=biased, window=window)
     return out
 
 
 @kernel_trace("flash_attention")
 def _flash_fwd_rule(q, k, v, kbias, seed_f, heads, is_causal, scale,
                     dropout_p, interpret, causal_offset, block_h,
-                    block_q, block_k, kv_heads, block_mask, biased):
+                    block_q, block_k, kv_heads, block_mask, biased,
+                    window=None):
     seed = lax.bitcast_convert_type(seed_f, jnp.int32)
     out, lse = _flash_forward(q, k, v, kbias, seed, heads,
                               is_causal=is_causal, scale=scale,
@@ -1096,21 +1247,22 @@ def _flash_fwd_rule(q, k, v, kbias, seed_f, heads, is_causal, scale,
                               causal_offset=causal_offset,
                               block_h=block_h, block_q=block_q,
                               block_k=block_k, kv_heads=kv_heads,
-                              block_mask=block_mask, biased=biased)
+                              block_mask=block_mask, biased=biased,
+                              window=window)
     return out, (q, k, v, kbias, seed, out, lse)
 
 
 @kernel_trace("flash_attention")
 def _flash_bwd_rule(heads, is_causal, scale, dropout_p, interpret,
                     causal_offset, block_h, block_q, block_k, kv_heads,
-                    block_mask, biased, res, g):
+                    block_mask, biased, window, res, g):
     q, k, v, kbias, seed, out, lse = res
     dq, dk, dv = _flash_backward(
         q, k, v, kbias, seed, out, lse, g, heads, is_causal=is_causal,
         scale=scale, dropout_p=dropout_p, interpret=interpret,
         causal_offset=causal_offset, block_h=block_h, block_q=block_q,
         block_k=block_k, kv_heads=kv_heads, block_mask=block_mask,
-        biased=biased)
+        biased=biased, window=window)
     # key-bias grads are not needed (masks are constants); seed is rng
     return dq, dk, dv, jnp.zeros_like(kbias), jnp.zeros_like(
         lse, shape=(1,))
@@ -1172,7 +1324,8 @@ def _block_h_ladder(heads, lane_d=None, max_h=8):
 @kernel_trace("flash_attention")
 def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
                     dropout_p=0.0, dropout_seed=None, block_q=None,
-                    block_k=None, interpret=False, block_mask=None):
+                    block_k=None, interpret=False, block_mask=None,
+                    window=None):
     """(B, S, H, D) flash attention via the Pallas kernels.
 
     key_bias: optional (B, Sk) float32 additive bias applied to every
@@ -1215,9 +1368,35 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
     (`flash_tiles_full_total` of `flash_tiles_live_total` of
     `flash_tiles_total`, per head, counted here at trace time with
     `flash_block_mask_total` instances); no dense mask exists.
+
+    window (with `is_causal`, self-attention): row i sees the `window`
+    keys i - window < j <= i.  The kernels' grids walk the band: the k
+    axis of the forward and dq kernels, the q axis of dkv, is as long
+    as the band of one tile (`window / block + 1` tiles, or + 2), the
+    tile of a step the band's first plus the step's index, so that the
+    grid grows with rows x window and not rows^2; (`_WINDOW_BLOCK`,
+    `_WINDOW_BLOCK`) tiles unless the caller names others.  Counted at
+    trace time: `flash_window_total` instances,
+    `flash_window_grid_steps_total` forward grid steps a head and
+    `flash_window_tiles_live_total` of them on a live tile (the first
+    q tiles' bands are shorter than the grid) beside the `flash_tiles_*`
+    of the whole rectangle.  `window >= rows` is the plain causal
+    instance.
     """
     b, sq, h, d = q.shape
     sk, dv = k.shape[1], v.shape[3]
+    if window is not None:
+        if not is_causal or sq != sk or block_mask is not None \
+                or window < 1:
+            raise ValueError(
+                "a window is for causal self-attention without a block "
+                f"mask: is_causal {is_causal}, q {sq} and k {sk} rows, "
+                f"window {window}")
+        if window >= sq:
+            window = None
+        else:
+            block_q = block_q or min(_WINDOW_BLOCK, round_up(sq, 128))
+            block_k = block_k or min(_WINDOW_BLOCK, round_up(sk, 128))
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     kv_heads = k.shape[2]
     packed = _packs(h, d) and _packs(h, dv)
@@ -1301,7 +1480,7 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
                                 final_rung=(cand == ladder[-1]),
                                 packed=packed, kv_heads=kv_heads,
                                 block_mask=block_mask, biased=biased,
-                                v_dim=dv_p):
+                                v_dim=dv_p, window=window):
                     block_h = cand
                     break
         if block_h is None:
@@ -1318,11 +1497,13 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
                 if dropout_p > 0.0 else None
             return _xla_attention(q, k, v, mask=mask,
                                   is_causal=is_causal, scale=scale,
-                                  dropout_p=dropout_p, dropout_key=dk)
+                                  dropout_p=dropout_p, dropout_key=dk,
+                                  window=window)
 
     from ...profiler import stat_add
 
-    tiler = _tiler(block_mask, is_causal, sk - sq)
+    tiler = _WindowTiles(window) if window is not None else _tiler(
+        block_mask, is_causal, sk - sq)
     if tiler is not None:
         cls = tiler.tiles(sq_p, sk_p, block_q, block_k)[0]
         if block_mask is not None:
@@ -1330,13 +1511,19 @@ def flash_attention(q, k, v, key_bias=None, is_causal=False, scale=None,
         stat_add("flash_tiles_full_total", int((cls == 2).sum()))
         stat_add("flash_tiles_live_total", int((cls != 0).sum()))
         stat_add("flash_tiles_total", cls.size)
+        if window is not None:
+            stat_add("flash_window_total")
+            stat_add("flash_window_grid_steps_total", cls.shape[0]
+                     * _band_lengths(window, *cls.shape, block_q,
+                                     block_k)[0])
+            stat_add("flash_window_tiles_live_total", int((cls != 0).sum()))
     if dv != d:
         stat_add("flash_split_value_total")
     stat_add("flash_fwd_pieces_total", block_h)
     out = _flash_attention(qm, km, vm, bias, seed_f, h, is_causal, scale,
                            float(dropout_p), interpret, sk - sq,
                            block_h, block_q, block_k, kv_heads, block_mask,
-                           biased)
+                           biased, window)
     if packed:
         stat_add("flash_packed_layout_total")
         return out[:, :sq].reshape(b, sq, h, dv)
@@ -1350,7 +1537,7 @@ _EXACT_PROBE_CACHE = {}
 def _probe_exact(q_shape, k_shape, heads, is_causal, dropout_p, dtype,
                  block_h, block_q, block_k, causal_offset,
                  final_rung=True, packed=False, kv_heads=None,
-                 block_mask=None, biased=True, v_dim=None):
+                 block_mask=None, biased=True, v_dim=None, window=None):
     """Compile (never run) the exact kernel instances flash_attention is
     about to stage, once per configuration.  q_shape / k_shape are the
     padded (B*H, S, D) whichever the operand layout; `packed` probes
@@ -1361,7 +1548,8 @@ def _probe_exact(q_shape, k_shape, heads, is_causal, dropout_p, dtype,
     refusal is routine and stays silent and uncounted."""
     key = (q_shape, k_shape, heads, is_causal, dropout_p,
            jnp.dtype(dtype).name, block_h, block_q, block_k,
-           causal_offset, packed, kv_heads, block_mask, biased, v_dim)
+           causal_offset, packed, kv_heads, block_mask, biased, v_dim,
+           window)
     if key not in _EXACT_PROBE_CACHE:
         def compile_probe():
             bh, sq, d = q_shape
@@ -1378,7 +1566,7 @@ def _probe_exact(q_shape, k_shape, heads, is_causal, dropout_p, dtype,
             kw = dict(is_causal=is_causal, dropout_p=dropout_p,
                       block_h=block_h, block_q=block_q, block_k=block_k,
                       causal_offset=causal_offset, kv_heads=kv_heads,
-                      block_mask=block_mask, biased=biased)
+                      block_mask=block_mask, biased=biased, window=window)
             _flash_forward.lower(x, kk, vv, kb, seed, heads,
                                  **kw).compile()
             lse = probe_struct((bh, sq, 1), jnp.float32)
@@ -1559,7 +1747,8 @@ def sharded_attention_scope(mesh, batch_axis="dp", head_axis=None):
 
 
 def _flash_per_shard(spec, q, k, v, key_bias, is_causal, scale,
-                     dropout_p, seed, interpret=False, block_mask=None):
+                     dropout_p, seed, interpret=False, block_mask=None,
+                     window=None):
     """flash_attention under shard_map over `spec` = (mesh, batch_axis,
     head_axis); q/k/v (B, S, H, D) global, key_bias (B, Sk) or None."""
     from jax.sharding import PartitionSpec as P
@@ -1581,7 +1770,7 @@ def _flash_per_shard(spec, q, k, v, key_bias, is_causal, scale,
         return flash_attention(q, k, v, key_bias=kb, is_causal=is_causal,
                                scale=scale, dropout_p=dropout_p,
                                dropout_seed=seed, interpret=interpret,
-                               block_mask=block_mask)
+                               block_mask=block_mask, window=window)
 
     return jax.shard_map(
         local, mesh=mesh,
@@ -1822,19 +2011,22 @@ def paged_attention(q, k_pages, v_pages, page_rows, lengths, scale=None,
 
 def scaled_dot_product_attention(q, k, v, mask=None, is_causal=False,
                                  scale=None, dropout_p=0.0,
-                                 dropout_key=None):
+                                 dropout_key=None, window=None):
     """Dispatcher: ring attention inside ring_attention_scope (sequence
     parallel), Pallas flash kernel on TPU (key-padding masks, a
     `BlockDiffusionMask`, grouped key/value heads and attention dropout
     run in-kernel), XLA path otherwise (arbitrary dense masks, tiny
     shapes, non-TPU backends).
-    q/k/v: (batch, seq, heads, head_dim); k/v may hold fewer heads."""
+    q/k/v: (batch, seq, heads, head_dim); k/v may hold fewer heads.
+    `window` (with `is_causal`): a sliding window of that many keys,
+    in-kernel too (`flash_attention`)."""
     block_mask = mask if isinstance(mask, BlockDiffusionMask) else None
-    if block_mask is not None and (
+    if (block_mask is not None or window is not None) and (
             getattr(_ULYSSES_CTX, "mesh", None) is not None
             or getattr(_RING_CTX, "mesh", None) is not None):
-        raise ValueError("a BlockDiffusionMask cannot be routed through "
-                         "the ring / all-to-all sequence-parallel paths")
+        raise ValueError("a BlockDiffusionMask or a window cannot be "
+                         "routed through the ring / all-to-all "
+                         "sequence-parallel paths")
     uly_mesh = getattr(_ULYSSES_CTX, "mesh", None)
     if uly_mesh is not None:
         if dropout_p != 0.0:
@@ -1879,11 +2071,12 @@ def scaled_dot_product_attention(q, k, v, mask=None, is_causal=False,
             if spec is not None:
                 return _flash_per_shard(spec, q, k, v, key_bias,
                                         is_causal, scale, dropout_p,
-                                        seed, block_mask=block_mask)
+                                        seed, block_mask=block_mask,
+                                        window=window)
             return flash_attention(
                 q, k, v, key_bias=key_bias, is_causal=is_causal,
                 scale=scale, dropout_p=dropout_p, dropout_seed=seed,
-                block_mask=block_mask)
+                block_mask=block_mask, window=window)
     return _xla_attention(q, k, v, mask=mask, is_causal=is_causal,
                           scale=scale, dropout_p=dropout_p,
-                          dropout_key=dropout_key)
+                          dropout_key=dropout_key, window=window)

@@ -87,6 +87,21 @@ class TestPhasesTiny:
         assert out["kda_chunked_total"] == out["kda_fallback_total"] == 0
         assert out["flash_split_value_total"] == 0
 
+    def test_laguna_step(self):
+        from paddle_tpu.models import laguna
+
+        out = chip_smoke.laguna_step(
+            laguna.LagunaConfig.tiny(experts_held=(0, 4), recompute=True),
+            batch=2, seq=16, steps=3, platform="cpu")
+        # two full layers rotate half a head by YaRN's frequencies
+        assert (out["rope_yarn_total"], out["rope_partial_total"]) == (2, 2)
+        assert out["moe_rows_routed_total"] == 3 * 4 * 32 * 2
+        assert out["moe_dropped_total"] == 0
+        # off the chip attention takes the XLA path with a dense band:
+        # no kernel, no window instance counted
+        assert out["flash_window_total"] == 0
+        assert out["flash_window_grid_steps_total"] == 0
+
     def test_failed_check_raises(self):
         ph = chip_smoke._Phase("x")
         ph.check(True, "fine")

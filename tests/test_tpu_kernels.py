@@ -611,6 +611,127 @@ def test_kda_edge_passes_against_their_xla_statement_on_tpu(tokens, heads):
             f32(b_))
 
 
+def _attend_and_grads(attend, q, k, v, w):
+    def f(q, k, v, w):      # w an operand: a closed-over one is a constant
+        out, vjp = jax.vjp(lambda *a: attend(*a).astype(jnp.float32),
+                           q, k, v)
+        return out, vjp(w)
+    return jax.jit(f)(q, k, v, w)
+
+
+def _assert_attention_close(got, want, what):
+    (out, grads), (ref, ref_grads) = got, want
+    f32 = lambda a: np.asarray(a, np.float32)
+    np.testing.assert_allclose(f32(out), f32(ref), atol=2e-2, rtol=2e-2,
+                               err_msg=what)
+    for a, b_, name in zip(grads, ref_grads, "qkv"):
+        assert np.linalg.norm(f32(a) - f32(b_)) <= 2e-2 * np.linalg.norm(
+            f32(b_)), (what, name)
+
+
+def test_window_flash_at_the_cell_shape_on_tpu():
+    """The three kernels with a window of 512 at the Laguna cell's
+    instance — 1 x 16,384 rows, 64 query heads in groups of 8 over 8
+    key/value heads of 128, grids that walk the band — through Mosaic
+    against `_xla_attention` with a dense band.  The oracle cannot hold
+    16,384^2 scores a head, and need not: rows 7,000 ... 8,535 (no tile's
+    edge) see keys 6,489 ... 8,535 alone, so their outputs, and every
+    gradient under a cotangent that is zero outside them, equal the
+    oracle's on that segment (a causal offset of 511 rows: its band is
+    the same band) — and the gradients outside it are exactly zero."""
+    s, h, hkv, d, window = 16384, 64, 8, 128, 512
+    lo, hi = 7000, 8536
+    first = lo - window + 1
+    q = _rand((1, s, h, d), 100, jnp.bfloat16)
+    k = _rand((1, s, hkv, d), 101, jnp.bfloat16)
+    v = _rand((1, s, hkv, d), 102, jnp.bfloat16)
+    rows = (jnp.arange(s) >= lo) & (jnp.arange(s) < hi)
+    w = jnp.where(rows[None, :, None, None], _rand((1, s, h, d), 103), 0.0)
+    names = ("flash_window_total", "flash_window_grid_steps_total",
+             "flash_window_tiles_live_total")
+    before = {n: profiler.get_int_stats().get(n, 0) for n in names}
+    out, grads = _attend_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, is_causal=True,
+                                        window=window), q, k, v, w)
+    delta = {n: profiler.get_int_stats().get(n, 0) - before[n]
+             for n in names}
+    # (256, 256) tiles: 64 q tiles x a band of 3, the first two shorter
+    assert delta == {"flash_window_total": 1,
+                     "flash_window_grid_steps_total": 192,
+                     "flash_window_tiles_live_total": 189}
+    assert bool(jnp.isfinite(out).all())
+    dq, dk, dv = grads
+    zero = lambda a: float(jnp.abs(a.astype(jnp.float32)).max()) == 0.0
+    assert zero(dq[:, :lo]) and zero(dq[:, hi:])
+    for g in (dk, dv):
+        assert zero(g[:, :first]) and zero(g[:, hi:])
+    _assert_attention_close(
+        (out[:, lo:hi], (dq[:, lo:hi], dk[:, first:hi], dv[:, first:hi])),
+        _attend_and_grads(
+            lambda q, k, v: _xla_attention(q, k, v, is_causal=True,
+                                           window=window),
+            q[:, lo:hi], k[:, first:hi], v[:, first:hi], w[:, lo:hi]),
+        "window 512, groups of 8")
+
+
+def test_grouped_causal_flash_in_groups_of_six_on_tpu():
+    """The full layers' instance of the Laguna cell — 48 query heads in
+    groups of 6 over 8 key/value heads, a rung of the head-block ladder
+    no other configuration takes — at 16,384 rows through Mosaic: the
+    first 1,024 rows' outputs, and every gradient under a cotangent that
+    is zero beyond them, equal `_xla_attention`'s on that prefix."""
+    s, h, hkv, d, n = 16384, 48, 8, 128, 1024
+    q = _rand((1, s, h, d), 110, jnp.bfloat16)
+    k = _rand((1, s, hkv, d), 111, jnp.bfloat16)
+    v = _rand((1, s, hkv, d), 112, jnp.bfloat16)
+    w = _rand((1, s, h, d), 113).at[:, n:].set(0.0)
+    before = profiler.get_int_stats().get("flash_fwd_pieces_total", 0)
+    out, grads = _attend_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, is_causal=True),
+        q, k, v, w)
+    # the whole group a grid step: 6 heads walked one at a time
+    assert profiler.get_int_stats()["flash_fwd_pieces_total"] == before + 6
+    assert bool(jnp.isfinite(out).all())
+    for g in grads:
+        assert float(jnp.abs(g[:, n:].astype(jnp.float32)).max()) == 0.0
+    _assert_attention_close(
+        (out[:, :n], tuple(g[:, :n] for g in grads)),
+        _attend_and_grads(
+            lambda q, k, v: _xla_attention(q, k, v, is_causal=True),
+            q[:, :n], k[:, :n], v[:, :n], w[:, :n]),
+        "causal, groups of 6")
+
+
+@pytest.mark.parametrize("rows,window", [(3000, 200), (1024, 512),
+                                         (2048, 2047)])
+def test_window_flash_small_shapes_on_tpu(rows, window):
+    """Windows under, at twice and far over the tile's edge, rows that
+    are no multiple of it, with key padding beside the band."""
+    h, hkv, d = 16, 2, 128
+    q = _rand((2, rows, h, d), 120, jnp.bfloat16)
+    k = _rand((2, rows, hkv, d), 121, jnp.bfloat16)
+    v = _rand((2, rows, hkv, d), 122, jnp.bfloat16)
+    w = _rand((2, rows, h, d), 123)
+    lens = np.array([rows, rows - 300])
+    keep = jnp.asarray(np.arange(rows)[None, :] < lens[:, None])
+    w = jnp.where(keep[:, :, None, None], w, 0.0)
+    bias = jnp.where(keep, 0.0, A.DEFAULT_MASK_VALUE)
+    got = _attend_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, key_bias=bias,
+                                        is_causal=True, window=window),
+        q, k, v, w)
+    want = _attend_and_grads(
+        lambda q, k, v: _xla_attention(q, k, v,
+                                       mask=keep[:, None, None, :],
+                                       is_causal=True, window=window),
+        q, k, v, w)
+    real = keep[:, :, None, None]
+    _assert_attention_close(
+        (jnp.where(real, got[0], 0.0), got[1]),
+        (jnp.where(real, want[0], 0.0), want[1]),
+        f"rows {rows}, window {window}")
+
+
 def test_no_kernel_gave_way():
     """Runs last: nothing above (and no other tpu-marked test before
     it) may have pushed a kernel onto its XLA path."""
@@ -620,6 +741,7 @@ def test_no_kernel_gave_way():
     assert stats.get("kda_fallback_total", 0) == 0
     assert stats.get("kda_edge_fallback_total", 0) == 0
     assert stats.get("kda_edge_fused_total", 0) > 0
+    assert stats.get("flash_window_total", 0) > 0
 
 
 def test_packed_layout_engaged():
