@@ -95,7 +95,8 @@ def _fallback_counts() -> dict:
     return {k: s.get(k, 0) for k in ("flash_fallback_total",
                                      "serving_ragged_fallback_total",
                                      "kda_fallback_total",
-                                     "kda_edge_fallback_total")}
+                                     "kda_edge_fallback_total",
+                                     "attn_edge_fallback_total")}
 
 
 def _device_platforms(arr) -> set:
@@ -836,9 +837,11 @@ def laguna_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
     trace-time counters say that every window layer's flash instance
     carries its window and that its grid walks the band (the forward
     grid's steps all but the first q tiles' on a live tile), that the
-    full layers rotate half a head by YaRN's frequencies, and the
-    executable, how many flash calls of each kind it holds; the
-    run-time counters, that no held visit was dropped."""
+    full layers rotate half a head by YaRN's frequencies, that every
+    layer's rotation and gate took its Pallas pass
+    (ops/pallas/attn_edge.py), and the executable, how many flash calls
+    of each kind it holds; the run-time counters, that no held visit
+    was dropped."""
     import jax.numpy as jnp
 
     import paddle_tpu
@@ -856,7 +859,8 @@ def laguna_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
     lr = jnp.float32(1e-3)
     traced = ("flash_window_total", "flash_window_grid_steps_total",
               "flash_window_tiles_live_total", "rope_yarn_total",
-              "rope_partial_total")
+              "rope_partial_total", "attn_edge_fused_total",
+              "attn_edge_fallback_total")
     ran = ("moe_rows_routed_total", "moe_rows_held_total",
            "moe_expert_rows_max_total", "moe_dropped_total")
     s0 = _stats()
@@ -913,6 +917,11 @@ def laguna_step(cfg, batch=1, seq=2048, steps=3, *, platform="tpu"):
                  f"{window} window instances; their forward grids walk the "
                  f"band: {tiles} q tiles x {band} steps a head, all live but "
                  "the first q tiles' shorter bands")
+        ph.check((ph.info["attn_edge_fused_total"],
+                  ph.info["attn_edge_fallback_total"]) == (2 * layers, 0),
+                 "every layer's rotation and gate one Pallas pass each: "
+                 f"attn_edge_fused_total == {2 * layers}, "
+                 "attn_edge_fallback_total did not move")
         ph.check(_fallback_counts()["flash_fallback_total"] == 0,
                  "flash_fallback_total == 0")
     return ph.done()

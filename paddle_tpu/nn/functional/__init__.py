@@ -403,6 +403,33 @@ def yarn_inv_freq(dim, base, factor, original_max, beta_fast=32.0,
         np.float32)
 
 
+def head_rotary_embedding(q, k, positions, theta=10000.0, interleaved=False,
+                          name=None, rotary_dim=None, inv_freq=None,
+                          amplitude=1.0):
+    """`rotary_embedding`, its arguments and its results, as one Pallas
+    pass over q and one over k each way where the heads are 128 wide
+    and the pairs rotate-half on a TPU, else `rotary_embedding` itself
+    (ops/pallas/attn_edge.py: `rope`)."""
+    from ...ops.pallas import attn_edge
+
+    return trace_fn(
+        lambda q, k, pos: attn_edge.rope(
+            q, k, pos, theta, interleaved, rotary_dim=rotary_dim,
+            inv_freq=inv_freq, amplitude=amplitude),
+        {"q": q, "k": k, "pos": positions}, multi_out=True)
+
+
+def head_gate(o, g, name=None):
+    """A sigmoid gate per head on an attention output: o (B, S, H, d)
+    times sigmoid(g) (B, S, H) over each head's channels, in float32
+    with one rounding at the end.  One Pallas pass each way on a TPU at
+    d = 128 (the sigmoid stays XLA's), else XLA
+    (ops/pallas/attn_edge.py)."""
+    from ...ops.pallas import attn_edge
+
+    return trace_fn(attn_edge.head_gate, {"o": o, "g": g})
+
+
 def instance_norm(x, running_mean=None, running_var=None, weight=None,
                   bias=None, use_input_stats=True, momentum=0.9, eps=1e-5,
                   data_format="NCHW", name=None):

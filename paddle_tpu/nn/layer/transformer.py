@@ -190,7 +190,10 @@ class GatedWindowAttention(Layer):
     forward(x (B, S, E), positions (B, S) | (S,)) -> (B, S, E); the
     flash kernels read the Hkv heads as they are, a window as a band
     their grids walk (ops/pallas/attention.py).  Scopes beside the
-    sublayers': `rope`, `gate`."""
+    sublayers': `rope`, `gate` — at `head_dim` 128 on a TPU each one
+    Pallas pass over HBM each way (ops/pallas/attn_edge.py:
+    `rope_fwd` / `rope_bwd`, `head_gate_fwd` / `head_gate_bwd`), else
+    `F.rotary_embedding` and the gate's XLA statement."""
 
     def __init__(self, embed_dim, num_heads, num_kv_heads, head_dim,
                  window=None, rope=None, gate=True, weight_attr=None):
@@ -235,7 +238,7 @@ class GatedWindowAttention(Layer):
 
                 stat_add("rope_yarn_total")
             partial = self._rotary_dim != d
-            q, k = F.rotary_embedding(
+            q, k = F.head_rotary_embedding(
                 q, k, positions, self._theta,
                 rotary_dim=self._rotary_dim if partial else None,
                 inv_freq=self._inv_freq, amplitude=self._amplitude)
@@ -245,9 +248,7 @@ class GatedWindowAttention(Layer):
         if self.g_proj is not None:
             g = self.g_proj(x)
             with jax.named_scope("gate"):
-                out = trace_fn(
-                    lambda o, g: (o.astype(jnp.float32) * jax.nn.sigmoid(
-                        g)[..., None]).astype(o.dtype), {"o": out, "g": g})
+                out = F.head_gate(out, g)
         out = trace_fn(
             lambda o: o.reshape(o.shape[0], o.shape[1], -1), {"o": out})
         return self.o_proj(out)

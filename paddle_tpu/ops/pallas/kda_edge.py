@@ -77,12 +77,12 @@ def _shifted(x, rows):
     return pltpu.roll(x, rows % x.shape[0], 0) if rows else x
 
 
-def _walk(tile, step, descending=False):
-    """step(first row of `_STEP` rows) over a tile's rows, in a loop."""
-    n = tile // _STEP
+def _walk(tile, step, descending=False, rows=_STEP):
+    """step(first row of `rows` rows) over a tile's rows, in a loop."""
+    n = tile // rows
 
     def body(i, carry):
-        step(pl.multiple_of((n - 1 - i if descending else i) * _STEP, _STEP))
+        step(pl.multiple_of((n - 1 - i if descending else i) * rows, rows))
         return carry
 
     jax.lax.fori_loop(0, n, body, 0)

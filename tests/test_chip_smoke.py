@@ -101,6 +101,9 @@ class TestPhasesTiny:
         # no kernel, no window instance counted
         assert out["flash_window_total"] == 0
         assert out["flash_window_grid_steps_total"] == 0
+        # nor does the rotation or the gate take (or refuse) its pass
+        assert (out["attn_edge_fused_total"],
+                out["attn_edge_fallback_total"]) == (0, 0)
 
     def test_failed_check_raises(self):
         ph = chip_smoke._Phase("x")

@@ -2,8 +2,10 @@
 laguna.py — the one the benchmark's `correct` uses) on seeded weights,
 the three published lists honoured layer by layer, the share test that
 ties a chip's share to the whole layer, the attention layer's gate,
-window and two rotations against a softmax written out, the train step
-and the counters."""
+window and two rotations against a softmax written out, the two
+elementwise passes around the flash kernels (ops/pallas/attn_edge.py,
+interpret mode) against their XLA statements, the train step and the
+counters."""
 
 import dataclasses
 import os
@@ -18,6 +20,9 @@ import paddle_tpu
 from paddle_tpu import nn, profiler
 from paddle_tpu.jit import functional_call, functional_state
 from paddle_tpu.models import laguna as M
+from paddle_tpu.nn import functional as F
+from paddle_tpu.nn.functional import attn_edge as X
+from paddle_tpu.ops.pallas import attn_edge as E
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -333,11 +338,113 @@ def test_gate_logits_stay_float32_under_bfloat16():
     assert layer.g_proj(x)._value.dtype == jnp.float32
 
 
-# -- the train step and the counters ------------------------------------------
+# -- the passes around the flash kernels ---------------------------------------
 
 def _stat(name):
     return profiler.get_int_stats().get(name, 0)
 
+
+def _ulps(a, b):
+    """The largest distance between two bfloat16 arrays in units in the
+    last place of the larger of each pair — where a sum of products
+    cancels, in float32's at the operands' size (XLA's CPU fusions
+    contract a multiply and an add into one rounding; the kernels'
+    statements, interpreted, do not)."""
+    a, b = (np.asarray(x, np.float32) for x in (a, b))
+    size = np.maximum(np.maximum(np.abs(a), np.abs(b)), 2.0 ** -12)
+    return float((np.abs(a - b) / 2.0 ** (np.floor(np.log2(size)) - 7)).max())
+
+
+_ROTATIONS = {
+    "plain": dict(theta=1e4),
+    "yarn_half": dict(theta=5e5, rotary_dim=64, amplitude=1.4159,
+                      inv_freq=F.yarn_inv_freq(64, 5e5, 64.0, 4096)),
+}
+
+
+def _xla_rope(q, k, pos, **kw):
+    return tuple(t._value for t in F.rotary_embedding(q, k, pos, **kw))
+
+
+@pytest.mark.parametrize("rows", [64, 72], ids=["tiles", "ragged"])
+@pytest.mark.parametrize("heads", [64, 48])
+@pytest.mark.parametrize("rotation", list(_ROTATIONS))
+def test_edge_kernels_match_the_xla_statement(rotation, heads, rows,
+                                              monkeypatch):
+    """`rope` and `head_gate` through the kernels (interpret mode, the
+    row tile set to 32 so that a pass crosses tiles and, at 72 rows,
+    pads one) at the cell's head counts over 8 kv heads of 128: values and
+    pull-backs of bfloat16 operands within one unit in the last place of
+    `F.rotary_embedding` / the gate's statement, the gate logits'
+    float32 gradient to 1e-5."""
+    monkeypatch.setattr(E, "ROW_TILE", 32)
+    kw = _ROTATIONS[rotation]
+    rng = np.random.default_rng(heads + rows)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    q, k = draw(1, rows, heads, 128), draw(1, rows, 8, 128)
+    dq, dk = draw(*q.shape), draw(*k.shape)
+    pos = np.arange(rows, dtype=np.int32) * 37      # angles that wrap
+    got, pull = jax.vjp(lambda q, k: E.rope(
+        q, k, pos, interpret=True, **kw), q, k)
+    want, pull_xla = jax.vjp(lambda q, k: _xla_rope(q, k, pos, **kw), q, k)
+    for a, b in zip(got + pull((dq, dk)), want + pull_xla((dq, dk))):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert _ulps(a, b) <= 1
+    g = jnp.asarray(rng.normal(size=(1, rows, heads)) * 3, jnp.float32)
+    y, pull = jax.vjp(lambda o, g: E.head_gate(o, g, interpret=True), q, g)
+    y_xla, pull_xla = jax.vjp(X.head_gate, q, g)
+    (do, dg), (do_xla, dg_xla) = pull(dq), pull_xla(dq)
+    assert _ulps(y, y_xla) <= 1 and _ulps(do, do_xla) <= 1
+    assert dg.dtype == jnp.float32
+    assert _rel(dg, dg_xla) < 1e-5
+
+
+def _edge_counts(before):
+    return tuple(_stat(n) - before[n] for n in (
+        "attn_edge_fused_total", "attn_edge_fallback_total"))
+
+
+def test_edge_counters_and_what_the_kernels_refuse():
+    rng = np.random.default_rng(3)
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    q, k, g = draw(1, 32, 4, 128), draw(1, 32, 2, 128), draw(1, 32, 4)
+    pos = np.arange(32, dtype=np.int32)
+    now = lambda: {n: _stat(n) for n in (
+        "attn_edge_fused_total", "attn_edge_fallback_total",
+        "rope_partial_total")}
+    # a layer of 128-wide heads: one rotation, one gate
+    before = now()
+    jax.jit(lambda q, k: E.rope(q, k, pos, rotary_dim=64,
+                                interpret=True)).lower(q, k)
+    jax.jit(lambda o, g: E.head_gate(o, g, interpret=True)).lower(q, g)
+    assert _edge_counts(before) == (2, 0)
+    # the partial rotation is counted on this path as F.rotary_embedding
+    # counts it on its own
+    assert _stat("rope_partial_total") - before["rope_partial_total"] == 1
+    # 64-wide heads, and interleaved pairs at 128: the XLA statement,
+    # counted as refused, bit for bit
+    narrow = lambda a: a.reshape(a.shape[:2] + (-1, 64))
+    before = now()
+    got = E.rope(narrow(q), narrow(k), pos, interpret=True)
+    y = E.head_gate(narrow(q), jnp.tile(g, 2), interpret=True)
+    assert _edge_counts(before) == (0, 2)
+    for a, b in zip(got, _xla_rope(narrow(q), narrow(k), pos)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(
+        np.asarray(y), np.asarray(X.head_gate(narrow(q), jnp.tile(g, 2))))
+    before = now()
+    got = E.rope(q, k, pos, interleaved=True, interpret=True)
+    assert _edge_counts(before) == (0, 1)
+    for a, b in zip(got, _xla_rope(q, k, pos, interleaved=True)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # off the TPU and not asked to interpret: the XLA path, uncounted
+    before = now()
+    E.rope(q, k, pos)
+    E.head_gate(q, g)
+    assert _edge_counts(before) == (0, 0)
+
+
+# -- the train step and the counters ------------------------------------------
 
 def test_bf16_step_trains_and_counts():
     paddle_tpu.seed(1)

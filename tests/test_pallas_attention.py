@@ -654,6 +654,45 @@ class TestMosaicAcceptsForV5e:
             "_flash_backward"))
 
 
+    @pytest.mark.parametrize("tokens,heads,rotary_dim", [
+        (16384, 64, 128), (16384, 48, 64), (512, 3, 64)])
+    def test_attn_edge_kernels(self, v5e, tokens, heads, rotary_dim):
+        """The Laguna cell's two elementwise passes around its flash
+        kernels (ops/pallas/attn_edge.py) at the cell's shapes, 1 x
+        16,384 x 64 heads of 128 rotated whole and x 48 rotated on 64 of
+        128 lanes, over 8 kv heads: `rope_fwd`, `rope_bwd` (q's call and
+        k's), `head_gate_fwd`, `head_gate_bwd` — (256, 1024) blocks of
+        the projections' own layout, the rotation's halves swapped by
+        lane rotations, the gate's per-head broadcast and lane sums as
+        matmuls with a 0/1 matrix — compiled for a v5e; and an odd head
+        count (a head a grid step).  None of the calls is named as the
+        flash kernels are: the benchmark tells those by `_flash_`."""
+        from paddle_tpu.ops.pallas import _common, attn_edge
+
+        put = lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=_common._COMPILE_TARGET)
+        kv = 8 if heads % 8 == 0 else 1
+        q = put((1, tokens, heads, 128), jnp.bfloat16)
+        k = put((1, tokens, kv, 128), jnp.bfloat16)
+        g = put((1, tokens, heads), jnp.float32)
+        pos = np.arange(tokens, dtype=np.int32)
+
+        def loss(q, k, g):
+            qr, kr = attn_edge.rope(q, k, pos, 5e5, rotary_dim=rotary_dim)
+            y = attn_edge.head_gate(qr, g)
+            return sum(jnp.sum(a.astype(jnp.float32)) for a in (kr, y))
+
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            q, k, g).compile().as_text()
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        for name, n in (("rope_fwd", 2), ("rope_bwd", 2),
+                        ("head_gate_fwd", 1), ("head_gate_bwd", 1)):
+            assert sum(f"/{name}/" in c for c in calls) == n, name
+        assert len(calls) == 6
+        assert not any("_flash_" in c or "/flash_" in c for c in calls)
+
+
 def test_flash_per_shard_matches_unsharded():
     """`sharded_attention_scope`'s kernel path: flash attention under
     shard_map over (batch, heads) equals the unsharded kernel — the

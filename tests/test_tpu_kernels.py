@@ -732,6 +732,59 @@ def test_window_flash_small_shapes_on_tpu(rows, window):
         f"rows {rows}, window {window}")
 
 
+@pytest.mark.parametrize("tokens,heads,rotation", [
+    (16384, 64, "plain"), (16384, 48, "yarn_half"), (1000, 3, "yarn_half")])
+def test_attn_edge_passes_against_their_xla_statement_on_tpu(tokens, heads,
+                                                             rotation):
+    """The two elementwise passes around the flash kernels (ops/pallas/
+    attn_edge.py: `rope_fwd` / `rope_bwd`, `head_gate_fwd` /
+    `head_gate_bwd` through Mosaic) at the Laguna cell's two instances —
+    1 x 16,384 x 64 heads of 128 with the plain whole-head rotation, 48
+    with YaRN's on 64 of the 128 lanes, over 8 kv heads — and at a length
+    that is no multiple of the row tile with a head a grid step, against
+    `F.rotary_embedding` and the gate's XLA statement on the same chip:
+    values, pull-backs, and the two counters."""
+    from paddle_tpu.nn import functional as F
+    from paddle_tpu.nn.functional import attn_edge as X
+    from paddle_tpu.ops.pallas import attn_edge as E
+
+    kw = dict(theta=1e4) if rotation == "plain" else dict(
+        theta=5e5, rotary_dim=64, amplitude=1.4159,
+        inv_freq=F.yarn_inv_freq(64, 5e5, 64.0, 4096))
+    kv = 8 if heads % 8 == 0 else 1
+    q = _rand((1, tokens, heads, 128), 130, jnp.bfloat16)
+    k = _rand((1, tokens, kv, 128), 131, jnp.bfloat16)
+    g = 3 * _rand((1, tokens, heads), 132)
+    cot = (_rand(q.shape, 133, jnp.bfloat16), _rand(k.shape, 134,
+                                                    jnp.bfloat16),
+           _rand(q.shape, 135, jnp.bfloat16))
+    pos = np.arange(tokens, dtype=np.int32)
+
+    def both(rope, gate):
+        def f(q, k, g, cot):
+            out, vjp = jax.vjp(lambda q, k, g: (
+                *rope(q, k, pos, **kw), gate(q, g)), q, k, g)
+            return out, vjp(cot)
+        return jax.jit(f)(q, k, g, cot)
+
+    names = ("attn_edge_fused_total", "attn_edge_fallback_total")
+    before = [profiler.get_int_stats().get(n, 0) for n in names]
+    got = both(E.rope, E.head_gate)
+    after = [profiler.get_int_stats().get(n, 0) for n in names]
+    assert [a - b for a, b in zip(after, before)] == [2, 0]
+    want = both(lambda *a, **kw: tuple(
+        t._value for t in F.rotary_embedding(*a, **kw)), X.head_gate)
+    f32 = lambda a: np.asarray(a, np.float32)
+    for a, b_ in zip(jax.tree_util.tree_leaves(got),
+                     jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b_.dtype and a.shape == b_.shape
+        # one rounding to bfloat16 on either side; the gate logits'
+        # float32 gradient sums 128 lanes in another order
+        np.testing.assert_allclose(f32(a), f32(b_), atol=2e-2, rtol=2e-2)
+        assert np.linalg.norm(f32(a) - f32(b_)) <= 4e-3 * np.linalg.norm(
+            f32(b_))
+
+
 def test_no_kernel_gave_way():
     """Runs last: nothing above (and no other tpu-marked test before
     it) may have pushed a kernel onto its XLA path."""
@@ -741,6 +794,8 @@ def test_no_kernel_gave_way():
     assert stats.get("kda_fallback_total", 0) == 0
     assert stats.get("kda_edge_fallback_total", 0) == 0
     assert stats.get("kda_edge_fused_total", 0) > 0
+    assert stats.get("attn_edge_fallback_total", 0) == 0
+    assert stats.get("attn_edge_fused_total", 0) > 0
     assert stats.get("flash_window_total", 0) > 0
 
 
