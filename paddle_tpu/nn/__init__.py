@@ -32,8 +32,8 @@ from .layer.pooling import (AdaptiveAvgPool2D, AdaptiveMaxPool2D, AvgPool1D,
                             AvgPool2D, MaxPool1D, MaxPool2D)
 from .layer.rnn import (RNN, BiRNN, GRU, GRUCell, LSTM, LSTMCell,
                         RNNCellBase, SimpleRNN, SimpleRNNCell)
-from .layer.transformer import (GatedFFN, GatedWindowAttention,
-                                GroupedQueryAttention,
+from .layer.transformer import (GatedDeltaNet, GatedFFN,
+                                GatedWindowAttention, GroupedQueryAttention,
                                 KimiDeltaAttention, LatentAttention,
                                 ShortConvSiLU, MultiHeadAttention, Transformer,
                                 TransformerDecoder, TransformerDecoderLayer,

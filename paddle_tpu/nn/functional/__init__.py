@@ -288,10 +288,11 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5,
     return outs["Y"][0]
 
 
-def rms_norm(x, weight=None, epsilon=1e-6, name=None):
+def rms_norm(x, weight=None, epsilon=1e-6, name=None, zero_centred=False):
     """x * rsqrt(mean(x^2, -1) + epsilon) * weight over the last axis
     (Zhang & Sennrich 2019), computed in float32 and returned in x's
-    dtype."""
+    dtype.  `zero_centred`: the scale is (1 + weight), a weight that
+    starts at 0 (the Qwen3-Next family's norms)."""
     import jax
     import jax.numpy as jnp
 
@@ -300,7 +301,8 @@ def rms_norm(x, weight=None, epsilon=1e-6, name=None):
         y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
                               + epsilon)
         if w is not None:
-            y = y * w.astype(jnp.float32)
+            w = w.astype(jnp.float32)
+            y = y * (1.0 + w if zero_centred else w)
         return y.astype(x.dtype)
 
     ins = {"x": x}
@@ -857,10 +859,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
 
 def kda_attention(q, k, v, g, beta, scale=None, name=None):
-    """The gated delta-rule recurrence with a per-channel decay (Kimi
-    Delta Attention): q, k, g (B, S, H, dk), v (B, S, H, dv), beta (B,
-    S, H) -> o (B, S, H, dv).  The chunked Pallas scan on a TPU at dk =
-    dv = 128, else a token at a time (ops/pallas/kda.py)."""
+    """The gated delta-rule recurrence: q, k (B, S, Hk, dk), v (B, S,
+    Hv, dv), beta (B, S, Hv) -> o (B, S, Hv, dv); the decay g a channel
+    (B, S, Hv, dk) (Kimi Delta Attention) or a head (B, S, Hv) (Gated
+    DeltaNet), value head h reading key head h // (Hv / Hk).  The
+    chunked Pallas scan on a TPU at dk = dv = 128, else a token at a
+    time (ops/pallas/kda.py)."""
     from ...ops.pallas import kda as _kda
 
     return trace_fn(
@@ -890,18 +894,39 @@ def kda_pre(q_raw, k_raw, v_raw, f, q_taps, k_taps, v_taps, dt_bias, a_log,
         "dt_bias": dt_bias, "a_log": a_log}, multi_out=True)
 
 
-def kda_post(o, gate, weight, epsilon=1e-5, name=None):
+def kda_post(o, gate, weight, epsilon=1e-5, name=None, activation="sigmoid"):
     """Kimi Delta Attention's work between its scan and its output
     projection: RMSNorm over each head's channels times `weight` (d,)
-    times sigmoid(gate).  o (B, S, H, d), gate (B, S, H * d) -> (B, S,
-    H * d).  One Pallas pass each way on a TPU at d = 128, else XLA
+    times sigmoid(gate) — or SiLU(gate), `activation="silu"`, Gated
+    DeltaNet's.  o (B, S, H, d), gate (B, S, H * d) -> (B, S, H * d).
+    One Pallas pass each way on a TPU at d = 128, else XLA
     (ops/pallas/kda_edge.py)."""
     from ...ops.pallas import kda_edge
 
     return trace_fn(
         lambda o, gate, weight: kda_edge.kda_post(
-            o.reshape(gate.shape), gate, weight, epsilon),
+            o.reshape(gate.shape), gate, weight, epsilon,
+            activation=activation),
         {"o": o, "gate": gate, "weight": weight})
+
+
+def gdn_pre(qkv, ba, taps, dt_bias, a_log, key_heads, name=None):
+    """Gated DeltaNet's work between its projections and its scan
+    (nn/functional/kda.py: `gdn_pre` states it): qkv (B, S, 2 Hk d + Hv
+    d), ba (B, S, 2 Hv), taps (width, 2 Hk d + Hv d), dt_bias, a_log
+    (Hv,) -> q, k (B, S, Hk, d), v (B, S, Hv, d), g, beta (B, S, Hv)
+    float32: what `kda_attention` takes.  XLA, recomputed in the
+    backward pass (`jax.checkpoint`): it keeps no float32 intermediate."""
+    import jax
+
+    from .kda import gdn_pre as pre
+
+    run = jax.checkpoint(lambda *operands: pre(*operands, key_heads))
+    return trace_fn(
+        lambda qkv, ba, taps, dt_bias, a_log: run(qkv, ba, taps, dt_bias,
+                                                  a_log),
+        {"qkv": qkv, "ba": ba, "taps": taps, "dt_bias": dt_bias,
+         "a_log": a_log}, multi_out=True)
 
 
 def short_conv1d(x, weight, activation=None, name=None):

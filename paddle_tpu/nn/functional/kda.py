@@ -7,7 +7,8 @@ chunked form: since PR 35 both halves of it, chunk-local and
 chunk-sequential, are inside the kernels `kda_fwd` / `kda_bwd`.  Also
 the layer's elementwise work around the scan in plain XLA (`edge_pre`,
 `edge_post`, `short_conv`): the oracle of `ops/pallas/kda_edge.py`'s
-two passes and the path for what those kernels refuse.
+two passes and the path for what those kernels refuse; and Gated
+DeltaNet's work before its scan (`gdn_pre`), which runs as XLA.
 
 The recurrence, for one head (S in R^{dk x dv}, float32, S_0 = 0):
 
@@ -226,14 +227,45 @@ def edge_pre(q_raw, k_raw, v_raw, f, q_taps, k_taps, v_taps, dt_bias, a_log):
             g.reshape(f.shape))
 
 
-def edge_post(o, gate, weight, epsilon):
-    """The layer's work after the scan: RMSNorm over each head's
-    channels times the learned scale `weight` (d,) times sigmoid(gate),
-    in float32 with one rounding at the end.  o, gate (B, S, H * d) ->
-    (B, S, H * d) in o's dtype."""
+def gdn_pre(qkv, ba, taps, dt_bias, a_log, key_heads):
+    """Gated DeltaNet's work between its projections and its scan, in
+    float32 with one rounding of q, k, v at the end: from qkv = [q~ | k~
+    | v~] (B, S, 2 Hk d + Hv d), ba = [b | a] (B, S, 2 Hv), the taps
+    (width, 2 Hk d + Hv d), dt_bias and a_log (Hv,)
+
+        [q', k', v] = SiLU(conv([q~ | k~ | v~]))
+        q = unit(q'), k = unit(k')            per key head
+        beta = sigmoid(b)
+        g = -exp(a_log) softplus(a + dt_bias)  one scalar a value head
+
+    -> q, k (B, S, Hk, d), v (B, S, Hv, dv) in qkv's dtype, g, beta (B,
+    S, Hv) float32 — what `kda_attention` takes as a decay a head."""
     f32 = jnp.float32
+    heads = a_log.shape[0]
+    b, s, _ = qkv.shape
+    y = jax.nn.silu(short_conv(qkv.astype(f32), taps))
+    d = y.shape[-1] // (2 * key_heads + heads)          # 2 Hk d + Hv d
+    q, k, v = jnp.split(y, [key_heads * d, 2 * key_heads * d], axis=-1)
+    unit = lambda a: (lambda a: a * jax.lax.rsqrt(
+        jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6))(
+            a.reshape(b, s, key_heads, d))
+    ba = ba.astype(f32)
+    beta = jax.nn.sigmoid(ba[..., :heads])
+    g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+        ba[..., heads:] + dt_bias.astype(f32))
+    return (unit(q).astype(qkv.dtype), unit(k).astype(qkv.dtype),
+            v.reshape(b, s, heads, -1).astype(qkv.dtype), g, beta)
+
+
+def edge_post(o, gate, weight, epsilon, activation="sigmoid"):
+    """The layer's work after the scan: RMSNorm over each head's
+    channels times the learned scale `weight` (d,) times
+    `activation`(gate) — "sigmoid" (Kimi Delta Attention) or "silu"
+    (Gated DeltaNet) —, in float32 with one rounding at the end.  o,
+    gate (B, S, H * d) -> (B, S, H * d) in o's dtype."""
+    f32 = jnp.float32
+    act = jax.nn.silu if activation == "silu" else jax.nn.sigmoid
     y = o.astype(f32).reshape(o.shape[:2] + (-1, weight.shape[0]))
     y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
                           + epsilon) * weight.astype(f32)
-    return (y.reshape(o.shape) * jax.nn.sigmoid(gate.astype(f32))).astype(
-        o.dtype)
+    return (y.reshape(o.shape) * act(gate.astype(f32))).astype(o.dtype)

@@ -324,8 +324,11 @@ class RoutedMoE(Layer):
     the `load` this layer then returns.  `n_shared_experts` > 0: one
     `GatedFFN` of width `n_shared_experts * d_ff` (sublayer
     `shared_experts`) that every row passes, added to the routed
-    output.  `n_group`, `topk_group`: 1, no group limit on the choice
-    (group-limited top-k is not built: anything else raises).
+    output.  `shared_gate`: that output times sigmoid(x w_sg) first,
+    w_sg (d_model, 1) (sublayer `shared_expert_gate`, float32 logits;
+    the Qwen3-Next family's).  `n_group`, `topk_group`: 1, no group
+    limit on the choice (group-limited top-k is not built: anything
+    else raises).
 
     forward(x (..., H)) -> (out (..., H), stats, experts[, load]):
     stats is the layer's (count + 2,) int32 count vector (rows per held
@@ -336,7 +339,8 @@ class RoutedMoE(Layer):
     def __init__(self, d_model, d_ff, num_experts, top_k, held=None,
                  norm_topk_prob=True, weight_attr=None, scoring="softmax",
                  routed_scaling_factor=1.0, selection_bias=False,
-                 n_shared_experts=0, n_group=1, topk_group=1):
+                 n_shared_experts=0, n_group=1, topk_group=1,
+                 shared_gate=False):
         super().__init__()
         if n_group != 1 or topk_group != 1:
             raise NotImplementedError(
@@ -365,11 +369,17 @@ class RoutedMoE(Layer):
 
             self.register_buffer("e_score_correction_bias",
                                  jnp.zeros((num_experts,), jnp.float32))
+        if shared_gate and not n_shared_experts:
+            raise ValueError("a shared-expert gate without a shared expert")
+        self._shared_gate = shared_gate
         if n_shared_experts:
-            from .transformer import GatedFFN
+            from .transformer import GatedFFN, _Float32Linear
 
             self.shared_experts = GatedFFN(
                 d_model, n_shared_experts * d_ff, "silu", weight_attr)
+            if shared_gate:
+                self.shared_expert_gate = _Float32Linear(
+                    d_model, 1, weight_attr, False)
 
     def forward(self, x):
         from ...fluid.dygraph.tracer import trace_fn
@@ -395,6 +405,15 @@ class RoutedMoE(Layer):
         if biased:
             ins["br"] = self.e_score_correction_bias
         out, *rest = trace_fn(f, ins, multi_out=True)
-        if self._shared:
+        if self._shared_gate:
+            import jax
+            import jax.numpy as jnp
+
+            out = out + trace_fn(
+                lambda y, g: (y.astype(jnp.float32) * jax.nn.sigmoid(g)
+                              ).astype(y.dtype),
+                {"y": self.shared_experts(x),
+                 "g": self.shared_expert_gate(x)})
+        elif self._shared:
             out = out + self.shared_experts(x)
         return (out, *rest)

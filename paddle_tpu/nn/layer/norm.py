@@ -107,18 +107,24 @@ class LayerNorm(Layer):
 
 class RMSNorm(Layer):
     """Root-mean-square norm over the last axis with a learned scale
-    and no bias (F.rms_norm)."""
+    and no bias (F.rms_norm).  `zero_centred`: the scale is (1 +
+    weight) and the weight starts at 0."""
 
     def __init__(self, hidden_size, epsilon=1e-6, weight_attr=None,
-                 name=None):
+                 name=None, zero_centred=False):
         super().__init__()
         self._hidden_size = hidden_size
         self._epsilon = epsilon
+        self._zero_centred = zero_centred
         self.weight = self.create_parameter(
             shape=[hidden_size], attr=weight_attr,
-            default_initializer=ConstantInitializer(1.0))
+            default_initializer=ConstantInitializer(
+                0.0 if zero_centred else 1.0))
 
     def forward(self, input):
+        if self._zero_centred:
+            return F.rms_norm(input, self.weight, self._epsilon,
+                              zero_centred=True)
         return F.rms_norm(input, self.weight, self._epsilon)
 
     def extra_repr(self):

@@ -185,7 +185,17 @@ class GatedWindowAttention(Layer):
     "default" or "yarn" (then `factor`,
     `original_max_position_embeddings`, `beta_fast`, `beta_slow`,
     `attention_factor`: `F.yarn_inv_freq`, the amplitude on cos and
-    sin).  `gate=False`: no `g_proj`.
+    sin).
+
+    `gate`: "head" (or True) the gate above, one sigmoid a head;
+    "element" a gate as wide as the head taken from the query
+    projection (Qwen3-Next: W_q gives each head [query | gate], 2 x
+    `head_dim`, and out = concat_j(o_j * sigmoid(gate_j)) W_o, the
+    sigmoid and the multiply float32); None (or False) no gate.
+    `qk_norm`: an RMSNorm of every query and key head over `head_dim`
+    before the rotation (sublayers `q_norm`, `k_norm`, scope
+    `qk_norm`), `epsilon` its own, zero-centred — a scale (1 + w), w
+    from 0 — where `norm_offset`.
 
     forward(x (B, S, E), positions (B, S) | (S,)) -> (B, S, E); the
     flash kernels read the Hkv heads as they are, a window as a band
@@ -193,14 +203,20 @@ class GatedWindowAttention(Layer):
     sublayers': `rope`, `gate` — at `head_dim` 128 on a TPU each one
     Pallas pass over HBM each way (ops/pallas/attn_edge.py:
     `rope_fwd` / `rope_bwd`, `head_gate_fwd` / `head_gate_bwd`), else
-    `F.rotary_embedding` and the gate's XLA statement."""
+    `F.rotary_embedding` and the gate's XLA statement; the element-wise
+    gate is always XLA's."""
 
     def __init__(self, embed_dim, num_heads, num_kv_heads, head_dim,
-                 window=None, rope=None, gate=True, weight_attr=None):
+                 window=None, rope=None, gate=True, weight_attr=None,
+                 qk_norm=False, norm_offset=False, epsilon=1e-6):
         super().__init__()
         assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
+        gate = {True: "head", False: None}.get(gate, gate)
+        if gate not in ("head", "element", None):
+            raise ValueError(f"gate {gate!r}: 'head', 'element' or None")
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.head_dim, self.window = head_dim, window
+        self._gate = gate
         rope = dict(rope or {})
         kind = rope.get("rope_type", "default")
         self._theta = float(rope.get("rope_theta", 10000.0))
@@ -218,20 +234,35 @@ class GatedWindowAttention(Layer):
         elif kind != "default":
             raise NotImplementedError(f"rope_type {kind!r}: default or yarn")
         lin = lambda i, o: Linear(i, o, weight_attr, False)
-        self.q_proj = lin(embed_dim, num_heads * head_dim)
+        self.q_proj = lin(embed_dim, num_heads * head_dim
+                          * (2 if gate == "element" else 1))
         self.k_proj = lin(embed_dim, num_kv_heads * head_dim)
         self.v_proj = lin(embed_dim, num_kv_heads * head_dim)
         self.g_proj = _Float32Linear(embed_dim, num_heads, weight_attr,
-                                     False) if gate else None
+                                     False) if gate == "head" else None
         self.o_proj = lin(num_heads * head_dim, embed_dim)
+        self._qk_norm = qk_norm
+        if qk_norm:
+            self.q_norm, self.k_norm = (
+                RMSNorm(head_dim, epsilon, zero_centred=norm_offset)
+                for _ in range(2))
 
     def forward(self, x, positions):
         d = self.head_dim
         split = lambda y, n: trace_fn(
             lambda y: y.reshape(y.shape[0], y.shape[1], n, d), {"y": y})
-        q = split(self.q_proj(x), self.num_heads)
+        if self._gate == "element":
+            q, gate = trace_fn(
+                lambda y: tuple(jnp.split(y.reshape(
+                    y.shape[:2] + (self.num_heads, 2 * d)), 2, axis=-1)),
+                {"y": self.q_proj(x)}, multi_out=True)
+        else:
+            q = split(self.q_proj(x), self.num_heads)
         k = split(self.k_proj(x), self.num_kv_heads)
         v = split(self.v_proj(x), self.num_kv_heads)
+        if self._qk_norm:
+            with jax.named_scope("qk_norm"):
+                q, k = self.q_norm(q), self.k_norm(k)
         with jax.named_scope("rope"):
             if self._inv_freq is not None:
                 from ...profiler import stat_add
@@ -249,6 +280,12 @@ class GatedWindowAttention(Layer):
             g = self.g_proj(x)
             with jax.named_scope("gate"):
                 out = F.head_gate(out, g)
+        elif self._gate == "element":
+            with jax.named_scope("gate"):
+                out = trace_fn(
+                    lambda o, g: (o.astype(jnp.float32) * jax.nn.sigmoid(
+                        g.astype(jnp.float32))).astype(o.dtype),
+                    {"o": out, "g": gate})
         out = trace_fn(
             lambda o: o.reshape(o.shape[0], o.shape[1], -1), {"o": out})
         return self.o_proj(out)
@@ -371,9 +408,10 @@ class ShortConvSiLU(Layer):
 
 
 class _KDACore(Layer):
-    """The scan of Kimi Delta Attention, a layer of its own so that all
-    of it — the two kernels and the reshapes at their edge — runs under
-    the scope `kda_core`."""
+    """The gated delta-rule scan, a layer of its own so that all of it —
+    the two kernels and the reshapes at their edge — runs under the
+    scope its parent names it by: `kda_core` in Kimi Delta Attention,
+    `gdn_core` in Gated DeltaNet."""
 
     def forward(self, q, k, v, g, beta):
         return F.kda_attention(q, k, v, g, beta)
@@ -463,6 +501,73 @@ class KimiDeltaAttention(Layer):
         with jax.named_scope("kda_post"):
             o = F.kda_post(o, gate, self.o_norm.weight, self.o_norm._epsilon)
         return self.o_proj(o)
+
+
+class GatedDeltaNet(Layer):
+    """Gated DeltaNet (Yang et al., arXiv:2412.06464) as the Qwen3-Next
+    family lays it out: a gated delta-rule linear attention whose decay
+    is ONE scalar a value head and token, with `num_v_heads` value heads
+    over `num_k_heads` query/key heads (value head h reads key head h //
+    (Hv / Hk)).  For token t and value head h (dk, dv the head widths):
+
+        [q~ | k~ | v~ | z] = x W_qkvz          Hk dk, Hk dk, Hv dv, Hv dv
+        [b | a]            = x W_ba            Hv, Hv
+        q', k', v = SiLU(Conv([q~ | k~ | v~])) depthwise, causal, no bias
+        q, k   = q' / |q'|, k' / |k'|          per key head (rsqrt(sum + 1e-6))
+        beta_t = sigmoid(b_t[h])
+        g_t    = -exp(A_log[h]) softplus(a_t[h] + dt_bias[h])      <= 0
+        S_t    = exp(g_t) (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t v_t^T
+        o_t    = dk^-1/2 S_t^T q_t
+        out    = [RMSNorm_head(o_t) w * SiLU(z_t)] W_o
+
+    `A_log` (Hv,) starts at log U(0, 16) and `dt_bias` (Hv,) at 1 (the
+    released modeling code's), both float32; the head norm's `w` at 1;
+    the taps (width, 2 Hk dk + Hv dv) as `ShortConvSiLU`'s.  The work
+    before the scan is one XLA statement (`F.gdn_pre`, scope `gdn_pre`),
+    the scan the chunked kernels of ops/pallas/kda.py with the decay a
+    head and the heads grouped (scope `gdn_core`), the gated head norm
+    the `kda_post` pass with SiLU (scope `gdn_post`).
+
+    forward(x (B, S, E)) -> (B, S, E); causal by construction, and the
+    layer carries position itself: it takes none."""
+
+    def __init__(self, embed_dim, num_k_heads, num_v_heads, head_k_dim=128,
+                 head_v_dim=128, conv_width=4, epsilon=1e-6,
+                 weight_attr=None):
+        super().__init__()
+        if num_v_heads % num_k_heads:
+            raise ValueError(f"{num_v_heads} value heads over {num_k_heads} "
+                             "query/key heads")
+        self.num_k_heads, self.num_v_heads = num_k_heads, num_v_heads
+        key, value = num_k_heads * head_k_dim, num_v_heads * head_v_dim
+        self._split = 2 * key + value
+        lin = lambda i, o: Linear(i, o, weight_attr, False)
+        self.in_proj_qkvz = lin(embed_dim, 2 * key + 2 * value)
+        self.in_proj_ba = lin(embed_dim, 2 * num_v_heads)
+        self.conv1d = ShortConvSiLU(2 * key + value, conv_width)
+        self.A_log = self.create_parameter(
+            shape=[num_v_heads], default_initializer=UniformInitializer(0, 16))
+        self.A_log._value = jnp.log(self.A_log._value)
+        self.dt_bias = self.create_parameter(
+            shape=[num_v_heads], default_initializer=ConstantInitializer(1.0))
+        self.gdn_core = _KDACore()
+        self.norm = _GatedHeadNorm(head_v_dim, epsilon)
+        self.out_proj = lin(value, embed_dim)
+
+    def forward(self, x):
+        qkv, z = trace_fn(
+            lambda y: (y[..., :self._split], y[..., self._split:]),
+            {"y": self.in_proj_qkvz(x)}, multi_out=True)
+        ba = self.in_proj_ba(x)
+        with jax.named_scope("gdn_pre"):
+            q, k, v, g, beta = F.gdn_pre(qkv, ba, self.conv1d.weight,
+                                         self.dt_bias, self.A_log,
+                                         self.num_k_heads)
+        o = self.gdn_core(q, k, v, g, beta)
+        with jax.named_scope("gdn_post"):
+            o = F.kda_post(o, z, self.norm.weight, self.norm._epsilon,
+                           activation="silu")
+        return self.out_proj(o)
 
 
 class GatedFFN(Layer):

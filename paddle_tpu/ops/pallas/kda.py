@@ -48,10 +48,30 @@ Anything else runs the recurrence a token at a time (`lax.scan`,
 `kda_fallback_total`).  Off the TPU the kernels run under
 `interpret=True` where asked (the CPU tests) and the fallback
 otherwise.
+
+The same two kernels take the Gated DeltaNet form of the recurrence
+(Yang et al., arXiv:2412.06464) as it is, in the instances `gdn_fwd` /
+`gdn_bwd`:
+
+  * a decay that is ONE scalar a head and token, g (B, S, Hv): it
+    arrives as the chunk's (64, Hv) block beside beta's, a head's
+    column is broadcast to its 128 lanes in VMEM, and the backward
+    sums its lanes' dg (one more matmul with a ones row, as dbeta) and
+    writes (B, N, Hv, 1, 64) rows that XLA lays out as (B, S, Hv);
+  * twice as many value heads as query/key heads, value head h reading
+    key head h // 2: a grid step's two value heads are one key head's
+    pair, q and k are fetched once a step by the index map, and the
+    backward sums the pair's dq and dk in the step and writes them
+    once.  Any other ratio has q and k repeated in HBM first
+    (`kda_group_repeat_total`).
+
+A per-channel decay over as many key as value heads is the program it
+was: the forms are chosen by the operands' shapes at trace time.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import types
 
@@ -74,6 +94,11 @@ _F32 = jnp.float32
 def _dot(a, b, dims):
     return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
                                preferred_element_type=_F32)
+
+
+_Form = collections.namedtuple("_Form", "group head_decay")
+_Form.__doc__ = """What an instance's operands hold: `group` value heads a
+key head (1 or 2), `head_decay` whether g is one scalar a head."""
 
 
 _NN = ((1,), (0,))      # a b
@@ -228,20 +253,33 @@ def _lanes(h):
     return slice(h * HEAD_DIM, (h + 1) * HEAD_DIM)
 
 
+def _lane_row(x):
+    """A (64, 128) block's lane sums as a lane-dense (1, 64) row: the
+    exact float32 matmul with a ones row that dbeta's rows use."""
+    return _dot(jnp.ones((8, HEAD_DIM), _F32), x, _NT)[:1]
+
+
 def _chunk_matmuls(h, heads, q_ref, k_ref, v_ref, g_ref, beta_ref, st, g_scr,
-                   k_scr, scale):
+                   k_scr, scale, form=_Form(1, False)):
     """The first half of a head's chunk: the cumulated gate, what is
     elementwise in it, and the matmuls that wait for nothing else —
     the scores against earlier sub-blocks and [bk; Qg] S for the state
     entering, S^T = `st` (dv, dk).  A grid step issues these for all
     its heads before any head's second half (`_chunk_local`): matmuls
     keep their program order, so the second head's are then not behind
-    the first head's lane reductions."""
-    q, k, v = (r[0, :, _lanes(h)].astype(_F32)
-               for r in (q_ref, k_ref, v_ref))
-    beta = _head_column(beta_ref, pl.program_id(1) * heads + h)
+    the first head's lane reductions.  `form.group` value heads read
+    one key head; `form.head_decay`: g is a (64, heads) block, a head's
+    column broadcast to its lanes."""
+    q, k = (r[0, :, _lanes(h // form.group)].astype(_F32)
+            for r in (q_ref, k_ref))
+    v = v_ref[0, :, _lanes(h)].astype(_F32)
+    head = pl.program_id(1) * heads + h
+    beta = _head_column(beta_ref, head)
     row, col, rel = _positions()
-    gc = _dot((col <= row).astype(_F32), g_ref[0, :, _lanes(h)], _NN)
+    lower = (col <= row).astype(_F32)
+    g = (jnp.broadcast_to(_head_column(g_ref, head), (CHUNK, HEAD_DIM))
+         if form.head_decay else g_ref[0, :, _lanes(h)])
+    gc = _dot(lower, g, _NN)
     g_scr[...] = gc
     k_scr[...] = k
     e_g = jnp.exp(gc)
@@ -271,7 +309,7 @@ def _chunk_local(x):
 
 
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref,
-                    s_scr, g_scr, k_scr, *, scale, heads):
+                    s_scr, g_scr, k_scr, *, scale, heads, form):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         s_scr[...] = jnp.zeros_like(s_scr)
@@ -279,7 +317,7 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref,
     # the step's heads are independent: one's matmuls fill the waits of
     # another's lane reductions
     xs = [_chunk_matmuls(h, heads, q_ref, k_ref, v_ref, g_ref, beta_ref,
-                         s_scr[h], g_scr.at[h], k_scr.at[h], scale)
+                         s_scr[h], g_scr.at[h], k_scr.at[h], scale, form)
           for h in range(heads)]
     for h, x in enumerate([_chunk_local(x) for x in xs]):
         st_ref[0, 0, h] = x.st                      # S^T entering: (dv, dk)
@@ -290,7 +328,7 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref,
 
 def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
                     dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
-                    ds_scr, g_scr, k_scr, col_scr, *, scale, heads):
+                    ds_scr, g_scr, k_scr, col_scr, *, scale, heads, form):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         ds_scr[...] = jnp.zeros_like(ds_scr)
@@ -298,7 +336,8 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
     xs = []
     for h in range(heads):
         x = _chunk_matmuls(h, heads, q_ref, k_ref, v_ref, g_ref, beta_ref,
-                           st_ref[0, 0, h], g_scr.at[h], k_scr.at[h], scale)
+                           st_ref[0, 0, h], g_scr.at[h], k_scr.at[h], scale,
+                           form)
         # the walk's lines that wait for neither scores nor T: from do,
         # the state entering and dS^T leaving (dv, dk)
         x.ds, x.do = ds_scr[h], do_ref[0, :, _lanes(h)].astype(_F32)
@@ -307,7 +346,14 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
     for h, x in enumerate([_chunk_local(x) for x in xs]):
         _bwd_walk(h, x, dv_ref, dbeta_ref, ds_scr.at[h])
     for h, x in enumerate(xs):
-        _bwd_scores(h, x, dq_ref, dk_ref, dg_ref, col_scr.at[h])
+        _bwd_scores(h, x, dq_ref, dk_ref, dg_ref, col_scr.at[h], form)
+    if form.group > 1:      # a key head's value heads: dq, dk summed, once
+        for j in range(heads // form.group):
+            pair = xs[j * form.group:(j + 1) * form.group]
+            for ref, name in ((dq_ref, "dq"), (dk_ref, "dk")):
+                ref[0, :, _lanes(j)] = functools.reduce(
+                    jnp.add, [getattr(x, name) for x in pair]).astype(
+                        ref.dtype)
 
 
 def _bwd_walk(h, x, dv_ref, dbeta_ref, ds_scr):
@@ -342,9 +388,11 @@ def _bwd_walk(h, x, dv_ref, dbeta_ref, ds_scr):
     x.d_qg, x.d_bk, x.d_kg, x.d_last = d_qg, d_bk, d_kg, d_last
 
 
-def _bwd_scores(h, x, dq_ref, dk_ref, dg_ref, col_scr):
+def _bwd_scores(h, x, dq_ref, dk_ref, dg_ref, col_scr, form):
     """The last third: the scores' cotangents back to q, k and G, and
-    G's to g."""
+    G's to g.  Grouped: the head's dq and dk stay on `x`, float32, for
+    the caller to sum over the key head's value heads; a decay a head:
+    dg leaves as the row of its lane sums."""
     scale, g_scr, k_scr = x.scale, x.g_scr, x.k_scr
     q, k, gc, e_g, beta = x.q, x.k, x.gc, x.e_g, x.beta
     d_mk, d_mq, d_qg, d_bk, d_kg = x.d_mk, x.d_mq, x.d_qg, x.d_bk, x.d_kg
@@ -384,19 +432,31 @@ def _bwd_scores(h, x, dq_ref, dk_ref, dg_ref, col_scr):
     row_k, row_q = _key_rows(key_row, (row_k, row_q),
                              (d_mk, d_mq, q, k, gc, rel))
     key = key + col_scr[...]
-    dq_ref[0, :, _lanes(h)] = (scale * e_g * d_qg + row_q).astype(
-        dq_ref.dtype)
-    dk_ref[0, :, _lanes(h)] = (beta * e_g * d_bk + d_kg * x.e_out + row_k
-                               + key).astype(dk_ref.dtype)
+    dq = scale * e_g * d_qg + row_q
+    if form.group == 1:
+        dq_ref[0, :, _lanes(h)] = dq.astype(dq_ref.dtype)
+    dk = beta * e_g * d_bk + d_kg * x.e_out + row_k + key
+    if form.group == 1:
+        dk_ref[0, :, _lanes(h)] = dk.astype(dk_ref.dtype)
+    else:
+        x.dq, x.dk = dq, dk
     # G's cotangent: a row of G gains where it decays its own q / k and
     # loses where it is the key's; then the cumulation's transpose
     d_gc = (d_bk * x.bk + d_qg * x.qg - d_kg * x.kg + q * row_q
             + k * (row_k - key))
-    dg_ref[0, :, _lanes(h)] = _dot((row <= col).astype(_F32), d_gc,
-                                   _NN) + x.d_last
+    dg = _dot((row <= col).astype(_F32), d_gc, _NN) + x.d_last
+    if form.head_decay:
+        dg_ref[0, 0, h] = _lane_row(dg)
+    else:
+        dg_ref[0, :, _lanes(h)] = dg
 
 
 # -- the two calls -----------------------------------------------------------
+
+def _form(q, v, g, beta) -> _Form:
+    """The form of flat (B, S, W) operands, from their widths."""
+    return _Form(v.shape[-1] // q.shape[-1], g.shape[-1] == beta.shape[-1])
+
 
 def _specs(n, all_heads, heads, reverse):
     """Block specs over the grid (batch, group of `heads` heads, chunk)
@@ -414,6 +474,19 @@ def _specs(n, all_heads, heads, reverse):
     return rows, beta, state, dbeta
 
 
+def _form_specs(n, heads, reverse, form, rows, beta, dbeta):
+    """(q / k blocks, g blocks, dg blocks) of `form`: a grouped step's
+    key heads are `heads / group` lane tiles at the step's index; a
+    decay a head comes and goes as beta does."""
+    at = (lambda c: n - 1 - c) if reverse else (lambda c: c)
+    keys = rows if form.group == 1 else pl.BlockSpec(
+        (1, CHUNK, heads // form.group * HEAD_DIM),
+        lambda b, i, c: (b, at(c), i))
+    if form.head_decay:
+        return keys, beta, dbeta
+    return keys, rows, rows
+
+
 def _heads_a_step(all_heads):
     """Heads a grid step: two where the count is even — independent
     chains for the scheduler to interleave —, else one."""
@@ -424,18 +497,18 @@ def _scratch(heads, rows):
     return pltpu.VMEM((heads, rows, HEAD_DIM), _F32)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _kda_forward(q, k, v, g, beta, scale, interpret=False):
-    """q, k, v (B, S, H * 128), g the same in float32, beta (B, S, H)
-    -> (o (B, S, H * 128) in v's dtype, the transposed state entering
-    every chunk (B, N, H, 128, 128) float32)."""
+def _forward_call(q, k, v, g, beta, scale, interpret, name):
     (b, s, _), all_heads = q.shape, beta.shape[-1]
     n, heads = s // CHUNK, _heads_a_step(all_heads)
-    rows, beta_rows, state, _ = _specs(n, all_heads, heads, False)
+    form = _form(q, v, g, beta)
+    rows, beta_rows, state, dbeta_rows = _specs(n, all_heads, heads, False)
+    keys, gates, _ = _form_specs(n, heads, False, form, rows, beta_rows,
+                                 dbeta_rows)
     return pl.pallas_call(
-        functools.partial(_kda_fwd_kernel, scale=scale, heads=heads),
+        functools.partial(_kda_fwd_kernel, scale=scale, heads=heads,
+                          form=form),
         grid=(b, all_heads // heads, n),
-        in_specs=[rows, rows, rows, rows, beta_rows],
+        in_specs=[keys, keys, rows, gates, beta_rows],
         out_specs=[rows, state],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((b, n, all_heads, HEAD_DIM, HEAD_DIM),
@@ -443,33 +516,73 @@ def _kda_forward(q, k, v, g, beta, scale, interpret=False):
         scratch_shapes=[_scratch(heads, HEAD_DIM), _scratch(heads, CHUNK),
                         _scratch(heads, CHUNK)],
         compiler_params=_compiler_params(), interpret=interpret,
-        name="kda_fwd",
+        name=name,
     )(q, k, v, g, beta)
+
+
+def _backward_call(q, k, v, g, beta, states, do, scale, interpret, name):
+    (b, s, _), all_heads = q.shape, beta.shape[-1]
+    n, heads = s // CHUNK, _heads_a_step(all_heads)
+    form = _form(q, v, g, beta)
+    rows, beta_rows, state, dbeta_rows = _specs(n, all_heads, heads, True)
+    keys, gates, dgates = _form_specs(n, heads, True, form, rows, beta_rows,
+                                      dbeta_rows)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    row_outs = (b, n, all_heads, 1, CHUNK)
+    dq, dk, dv, dg, dbeta = pl.pallas_call(
+        functools.partial(_kda_bwd_kernel, scale=scale, heads=heads,
+                          form=form),
+        grid=(b, all_heads // heads, n),
+        in_specs=[keys, keys, rows, gates, beta_rows, state, rows],
+        out_specs=[keys, keys, rows, dgates, dbeta_rows],
+        out_shape=[like(q), like(k), like(v),
+                   jax.ShapeDtypeStruct(row_outs, _F32)
+                   if form.head_decay else like(g),
+                   jax.ShapeDtypeStruct(row_outs, _F32)],
+        scratch_shapes=[_scratch(heads, HEAD_DIM)]
+        + [_scratch(heads, CHUNK)] * 3,
+        compiler_params=_compiler_params(), interpret=interpret,
+        name=name,
+    )(q, k, v, g, beta, states, do)
+    # a chunk and head's dbeta (and a decay a head's dg) leaves the
+    # kernel as a lane-dense row
+    as_heads = lambda r: jnp.swapaxes(r[:, :, :, 0], 2, 3).reshape(
+        b, s, all_heads)
+    if form.head_decay:
+        dg = as_heads(dg)
+    return dq, dk, dv, dg, as_heads(dbeta)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _kda_forward(q, k, v, g, beta, scale, interpret=False):
+    """q, k, v (B, S, H * 128), g the same in float32, beta (B, S, H)
+    -> (o (B, S, H * 128) in v's dtype, the transposed state entering
+    every chunk (B, N, H, 128, 128) float32)."""
+    return _forward_call(q, k, v, g, beta, scale, interpret, "kda_fwd")
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _kda_backward(q, k, v, g, beta, states, do, scale, interpret=False):
     """-> dq, dk, dv (B, S, H * 128) in the operands' dtypes, dg the
     same in float32, dbeta (B, S, H) float32; `do` (B, S, H * 128)."""
-    (b, s, _), all_heads = q.shape, beta.shape[-1]
-    n, heads = s // CHUNK, _heads_a_step(all_heads)
-    rows, beta_rows, state, dbeta_rows = _specs(n, all_heads, heads, True)
-    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
-    dq, dk, dv, dg, dbeta = pl.pallas_call(
-        functools.partial(_kda_bwd_kernel, scale=scale, heads=heads),
-        grid=(b, all_heads // heads, n),
-        in_specs=[rows, rows, rows, rows, beta_rows, state, rows],
-        out_specs=[rows, rows, rows, rows, dbeta_rows],
-        out_shape=[like(q), like(k), like(v), like(g),
-                   jax.ShapeDtypeStruct((b, n, all_heads, 1, CHUNK), _F32)],
-        scratch_shapes=[_scratch(heads, HEAD_DIM)]
-        + [_scratch(heads, CHUNK)] * 3,
-        compiler_params=_compiler_params(), interpret=interpret,
-        name="kda_bwd",
-    )(q, k, v, g, beta, states, do)
-    # a chunk and head's dbeta leaves the kernel as a lane-dense row
-    return dq, dk, dv, dg, jnp.swapaxes(dbeta[:, :, :, 0], 2, 3).reshape(
-        b, s, all_heads)
+    return _backward_call(q, k, v, g, beta, states, do, scale, interpret,
+                          "kda_bwd")
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _gdn_forward(q, k, v, g, beta, scale, interpret=False):
+    """The Gated DeltaNet instances: q, k (B, S, Hk * 128), v (B, S, Hv
+    * 128), g (B, S, Hv) or (B, S, Hv * 128) float32, beta (B, S, Hv),
+    Hv = Hk or 2 Hk -> as `_kda_forward`."""
+    return _forward_call(q, k, v, g, beta, scale, interpret, "gdn_fwd")
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _gdn_backward(q, k, v, g, beta, states, do, scale, interpret=False):
+    """-> dq, dk, dv in the operands' shapes and dtypes, dg in g's
+    shape, float32, dbeta (B, S, Hv) float32."""
+    return _backward_call(q, k, v, g, beta, states, do, scale, interpret,
+                          "gdn_bwd")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -477,11 +590,20 @@ def _kda_chunked(q, k, v, g, beta, scale, interpret):
     return _chunked_fwd(q, k, v, g, beta, scale, interpret)[0]
 
 
+def _calls(q, v, g, beta):
+    """The Kimi Delta Attention instances' jitted calls, or the Gated
+    DeltaNet instances' (a decay a head, or grouped heads)."""
+    if g.ndim == beta.ndim or v.shape[2] != q.shape[2]:
+        return _gdn_forward, _gdn_backward
+    return _kda_forward, _kda_backward
+
+
 @_common.kernel_trace("kda_attention")
 def _chunked_fwd(q, k, v, g, beta, scale, interpret):
     b, s = q.shape[:2]
     flat = tuple(a.reshape(b, s, -1) for a in (q, k, v, g))
-    o, states = _kda_forward(*flat, beta, scale=scale, interpret=interpret)
+    forward = _calls(q, v, g, beta)[0]
+    o, states = forward(*flat, beta, scale=scale, interpret=interpret)
     return o.reshape(v.shape), (q, k, v, g, beta, states)
 
 
@@ -490,9 +612,11 @@ def _chunked_bwd(scale, interpret, res, do):
     q, k, v, g, beta, states = res
     b, s = q.shape[:2]
     flat = tuple(a.reshape(b, s, -1) for a in (q, k, v, g))
-    *grads, dbeta = _kda_backward(*flat, beta, states, do.reshape(b, s, -1),
-                                  scale=scale, interpret=interpret)
-    return tuple(a.reshape(q.shape) for a in grads) + (dbeta,)
+    backward = _calls(q, v, g, beta)[1]
+    *grads, dbeta = backward(*flat, beta, states, do.reshape(b, s, -1),
+                             scale=scale, interpret=interpret)
+    return tuple(a.reshape(x.shape) for a, x in zip(grads, (q, k, v, g))) \
+        + (dbeta,)
 
 
 _kda_chunked.defvjp(_chunked_fwd, _chunked_bwd)
@@ -501,27 +625,47 @@ _kda_chunked.defvjp(_chunked_fwd, _chunked_bwd)
 @_common.kernel_trace("kda_attention")
 def kda_attention(q, k, v, g, beta, scale=None, interpret=False):
     """o_t = scale S_t^T q_t of the gated delta-rule recurrence
-    (nn/functional/kda.py).  q, k (B, S, H, dk) — the caller has
-    normalised them —, v (B, S, H, dv), g (B, S, H, dk) <= 0 the log
-    decay, beta (B, S, H) in (0, 1) -> o (B, S, H, dv) in v's dtype.
+    (nn/functional/kda.py).  q, k (B, S, Hk, dk) — the caller has
+    normalised them —, v (B, S, Hv, dv), g <= 0 the log decay, (B, S,
+    Hv, dk) a channel (Kimi Delta Attention) or (B, S, Hv) a head
+    (Gated DeltaNet), beta (B, S, Hv) in (0, 1) -> o (B, S, Hv, dv) in
+    v's dtype.  Value head h reads key head h // (Hv / Hk).
 
     dk = dv = 128 on a TPU (or under `interpret`): the chunked scan,
     `kda_chunked_total` += 1 and `kda_chunks_total` += the chunks it
     walks; a length that is no multiple of 64 is padded with rows of g
-    = 0, beta = 0, k = 0, which leave the state as it is.  Otherwise
-    the recurrence a token at a time: `kda_fallback_total` += 1 where
-    the kernels refused the shape, uncounted off the TPU (as the flash
-    kernels' XLA path is).  Counted where traced."""
+    = 0, beta = 0, k = 0, which leave the state as it is.  A decay a
+    head: `kda_head_decay_total` += 1; Hv = 2 Hk: `kda_grouped_heads_total`
+    += 1; any other ratio: q and k repeated to Hv heads in HBM first,
+    `kda_group_repeat_total` += 1.  Otherwise the recurrence a token at
+    a time: `kda_fallback_total` += 1 where the kernels refused the
+    shape, uncounted off the TPU (as the flash kernels' XLA path is).
+    Counted where traced."""
     from ...profiler import stat_add
 
     dk, dv = q.shape[-1], v.shape[-1]
     scale = float(dk ** -0.5 if scale is None else scale)
     g = g.astype(_F32)
+    group = v.shape[2] // q.shape[2]
+    if group * q.shape[2] != v.shape[2]:
+        raise ValueError(f"{v.shape[2]} value heads over {q.shape[2]} "
+                         "query/key heads: no whole group")
     kernels = interpret or _common.on_tpu()
     if not (kernels and dk == dv == HEAD_DIM):
         if kernels:     # refused by shape, not by platform
             stat_add("kda_fallback_total")
+        if g.ndim == 3:
+            g = jnp.broadcast_to(g[..., None], g.shape + (dk,))
+        if group > 1:
+            q, k = (jnp.repeat(a, group, axis=2) for a in (q, k))
         return recurrent(q, k, v, g, beta, scale).astype(v.dtype)
+    if g.ndim == 3:
+        stat_add("kda_head_decay_total")
+    if group == 2:
+        stat_add("kda_grouped_heads_total")
+    elif group > 2:
+        stat_add("kda_group_repeat_total")
+        q, k = (jnp.repeat(a, group, axis=2) for a in (q, k))
     s = q.shape[1]
     pad = -s % CHUNK
     if pad:

@@ -15,6 +15,8 @@ refuse).
                   df and the parameters' cotangents
     kda_post_fwd  y = RMSNorm_head(o) w sigmoid(gate)
     kda_post_bwd  do, dgate, dw from o, gate and dy
+    gdn_post_fwd, the same two with SiLU(gate) for sigmoid(gate):
+    gdn_post_bwd  Gated DeltaNet's gated head norm (`activation="silu"`)
 
 A grid step is one (batch, block of heads, tile of `ROW_TILE` rows)
 and walks the tile `_STEP` rows and a head at a time in a loop whose
@@ -294,14 +296,22 @@ _pre.defvjp(_pre_fwd, _pre_bwd)
 
 # -- after the scan ----------------------------------------------------------
 
-def _post_fwd_kernel(o_ref, gate_ref, w_ref, y_ref, *, heads, tile, epsilon):
+def _activation(z, activation):
+    """(the gate's activation of z, z's sigmoid)."""
+    s = _sigmoid(z)
+    return (z * s if activation == "silu" else s), s
+
+
+def _post_fwd_kernel(o_ref, gate_ref, w_ref, y_ref, *, heads, tile, epsilon,
+                     activation):
     def step(r0):
         at = pl.ds(r0, _STEP)
         for h in range(heads):
             lanes = _lanes(h)
             o = o_ref[0, at, lanes].astype(_F32)
             r = jax.lax.rsqrt(_lane_sum(o * o) * (1.0 / HEAD_DIM) + epsilon)
-            gate = _sigmoid(gate_ref[0, at, lanes].astype(_F32))
+            gate = _activation(gate_ref[0, at, lanes].astype(_F32),
+                               activation)[0]
             y_ref[0, at, lanes] = (o * r * w_ref[0:1, lanes] * gate).astype(
                 y_ref.dtype)
 
@@ -309,7 +319,7 @@ def _post_fwd_kernel(o_ref, gate_ref, w_ref, y_ref, *, heads, tile, epsilon):
 
 
 def _post_bwd_kernel(o_ref, gate_ref, w_ref, dy_ref, do_ref, dgate_ref,
-                     dw_ref, *, heads, tile, epsilon):
+                     dw_ref, *, heads, tile, epsilon, activation):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         dw_ref[...] = jnp.zeros_like(dw_ref)
@@ -321,13 +331,18 @@ def _post_bwd_kernel(o_ref, gate_ref, w_ref, dy_ref, do_ref, dgate_ref,
             o = o_ref[0, at, lanes].astype(_F32)
             r = jax.lax.rsqrt(_lane_sum(o * o) * (1.0 / HEAD_DIM) + epsilon)
             u = o * r
-            gate = _sigmoid(gate_ref[0, at, lanes].astype(_F32))
-            d = dy_ref[0, at, lanes].astype(_F32) * gate    # of u w
+            z = gate_ref[0, at, lanes].astype(_F32)
+            gate, s = _activation(z, activation)
+            dy = dy_ref[0, at, lanes].astype(_F32)
+            d = dy * gate                                   # of u w
             _add_rows(dw_ref, 0, lanes, d * u)
             d = d * w_ref[0:1, lanes]                       # of u
             du = d * u
-            dgate_ref[0, at, lanes] = (du * (1.0 - gate)).astype(
-                dgate_ref.dtype)
+            if activation == "silu":    # SiLU' = s (1 + z (1 - s))
+                dz = dy * w_ref[0:1, lanes] * u * (s * (1.0 + z * (1.0 - s)))
+            else:                       # sigmoid' = s (1 - s)
+                dz = du * (1.0 - gate)
+            dgate_ref[0, at, lanes] = dz.astype(dgate_ref.dtype)
             do_ref[0, at, lanes] = (
                 r * (d - u * (_lane_sum(du) * (1.0 / HEAD_DIM)))).astype(
                     do_ref.dtype)
@@ -342,33 +357,39 @@ def _post_specs(tile, width):
     return rows, weight, dweight
 
 
-@functools.partial(jax.jit, static_argnames=("epsilon", "tile", "interpret"))
-def _post_forward(o, gate, weight, epsilon, tile=ROW_TILE, interpret=False):
+_POST_NAME = {"sigmoid": "kda_post", "silu": "gdn_post"}
+
+
+@functools.partial(jax.jit, static_argnames=("epsilon", "tile", "interpret",
+                                             "activation"))
+def _post_forward(o, gate, weight, epsilon, tile=ROW_TILE, interpret=False,
+                  activation="sigmoid"):
     """o, gate (B, S, H * 128), weight (1, H * 128) float32 -> y in o's
     dtype."""
     heads, width, grid = _blocks(o.shape, tile)
     rows, weight_rows, _ = _post_specs(tile, width)
     return pl.pallas_call(
         functools.partial(_post_fwd_kernel, heads=heads, tile=tile,
-                          epsilon=epsilon),
+                          epsilon=epsilon, activation=activation),
         grid=grid,
         in_specs=[rows, rows, weight_rows], out_specs=rows,
         out_shape=_like(o),
         compiler_params=_compiler_params(("parallel",) * 3),
-        interpret=interpret, name="kda_post_fwd",
+        interpret=interpret, name=_POST_NAME[activation] + "_fwd",
     )(o, gate, weight)
 
 
-@functools.partial(jax.jit, static_argnames=("epsilon", "tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("epsilon", "tile", "interpret",
+                                             "activation"))
 def _post_backward(o, gate, weight, dy, epsilon, tile=ROW_TILE,
-                   interpret=False):
+                   interpret=False, activation="sigmoid"):
     """-> do, dgate in the operands' dtypes, dweight (1, H * 128)
     float32."""
     heads, width, grid = _blocks(o.shape, tile)
     rows, weight_rows, dweight_rows = _post_specs(tile, width)
     do, dgate, dweight = pl.pallas_call(
         functools.partial(_post_bwd_kernel, heads=heads, tile=tile,
-                          epsilon=epsilon),
+                          epsilon=epsilon, activation=activation),
         grid=grid,
         in_specs=[rows, rows, weight_rows, rows],
         out_specs=[rows, rows, dweight_rows],
@@ -376,27 +397,28 @@ def _post_backward(o, gate, weight, dy, epsilon, tile=ROW_TILE,
                    jax.ShapeDtypeStruct((o.shape[0], _SUB, o.shape[2]),
                                         _F32)],
         compiler_params=_compiler_params(), interpret=interpret,
-        name="kda_post_bwd",
+        name=_POST_NAME[activation] + "_bwd",
     )(o, gate, weight, dy)
     return do, dgate, dweight.sum((0, 1))[None]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _post(o, gate, weight, epsilon, tile, interpret):
-    return _post_fwd(o, gate, weight, epsilon, tile, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _post(o, gate, weight, epsilon, tile, interpret, activation):
+    return _post_fwd(o, gate, weight, epsilon, tile, interpret,
+                     activation)[0]
 
 
 @_common.kernel_trace("kda_edge")
-def _post_fwd(o, gate, weight, epsilon, tile, interpret):
+def _post_fwd(o, gate, weight, epsilon, tile, interpret, activation):
     y = _post_forward(o, gate, weight, epsilon=epsilon, tile=tile,
-                      interpret=interpret)
+                      interpret=interpret, activation=activation)
     return y, (o, gate, weight)
 
 
 @_common.kernel_trace("kda_edge")
-def _post_bwd(epsilon, tile, interpret, res, dy):
+def _post_bwd(epsilon, tile, interpret, activation, res, dy):
     return _post_backward(*res, dy, epsilon=epsilon, tile=tile,
-                          interpret=interpret)
+                          interpret=interpret, activation=activation)
 
 
 _post.defvjp(_post_fwd, _post_bwd)
@@ -458,16 +480,23 @@ def kda_pre(q_raw, k_raw, v_raw, f, q_taps, k_taps, v_taps, dt_bias, a_log,
 
 
 @_common.kernel_trace("kda_edge")
-def kda_post(o, gate, weight, epsilon, interpret=False, tile=ROW_TILE):
+def kda_post(o, gate, weight, epsilon, interpret=False, tile=ROW_TILE,
+             activation="sigmoid"):
     """RMSNorm over each head's d channels times `weight` (d,) times
-    sigmoid(gate): o, gate (B, S, H * d) -> (B, S, H * d) in o's dtype
-    (nn/functional/kda.py: `edge_post`).  d = 128 on a TPU (or under
-    `interpret`): `kda_post_fwd` / `kda_post_bwd`; otherwise the XLA
+    `activation`(gate), "sigmoid" or "silu": o, gate (B, S, H * d) -> (B,
+    S, H * d) in o's dtype (nn/functional/kda.py: `edge_post`).  d = 128
+    on a TPU (or under `interpret`): `kda_post_fwd` / `kda_post_bwd`
+    (`gdn_post_fwd` / `gdn_post_bwd` for SiLU); otherwise the XLA
     statement."""
+    if activation not in _POST_NAME:
+        raise ValueError(f"activation {activation!r}: sigmoid or silu")
     if not _fused(weight.shape[0], interpret):
+        if activation != "sigmoid":
+            return jax.checkpoint(_xla.edge_post, static_argnums=(3, 4))(
+                o, gate, weight, epsilon, activation)
         return jax.checkpoint(_xla.edge_post, static_argnums=(3,))(
             o, gate, weight, epsilon)
     s = o.shape[1]
     lanes = jnp.tile(weight.astype(_F32), o.shape[-1] // HEAD_DIM)[None]
     return _post(*_padded(tile, o, gate), lanes, float(epsilon), tile,
-                 bool(interpret))[:, :s]
+                 bool(interpret), activation)[:, :s]

@@ -554,6 +554,83 @@ def test_kda_scan_at_the_cell_shape_on_tpu():
                           w[:, :n]))
 
 
+@pytest.mark.parametrize("tokens,key_heads", [(16384, 16), (1000, 1)])
+def test_gdn_forms_against_the_per_channel_kernels_on_tpu(tokens, key_heads):
+    """The scan's Gated DeltaNet instances (`gdn_fwd` / `gdn_bwd`: a
+    decay a value head, two value heads over each key head) at the
+    Qwen3-Next cell's shape — 1 x 16,384, 16 key heads over 32 value
+    heads of 128, decays down to -20 a token — and at a length that is
+    no multiple of 64, against the Kimi instances (`kda_fwd` /
+    `kda_bwd`) fed the decay broadcast to the lanes and q and k repeated
+    to the value heads: the same float32 arithmetic a value head, so
+    the outputs and dv, dbeta agree to float32 rounding and dq, dk, dg
+    (sums over a key head's pair and a head's lanes) nearly so; and the
+    first 1,000 tokens against the recurrence."""
+    from paddle_tpu.nn.functional import kda as X
+    from paddle_tpu.ops.pallas.kda import kda_attention
+
+    b, d, hk = 1, 128, key_heads
+    hv = 2 * hk
+    rng = np.random.RandomState(95)
+    unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
+    q, k = (jnp.asarray(unit(rng.randn(b, tokens, hk, d)), jnp.bfloat16)
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(b, tokens, hv, d), jnp.bfloat16)
+    g = jnp.asarray(-rng.uniform(0.0, 20.0, (b, tokens, hv)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.05, 0.95, (b, tokens, hv)), jnp.float32)
+    args = (q, k, v, g, beta)
+    w = _rand((b, tokens, hv, d), 96)
+
+    def expand(q, k, v, g, beta):
+        return (jnp.repeat(q, 2, 2), jnp.repeat(k, 2, 2), v,
+                jnp.broadcast_to(g[..., None], g.shape + (d,)), beta)
+
+    before = profiler.get_int_stats()
+    out, grads = _kda_out_and_grads(kda_attention, args, w)
+    after = profiler.get_int_stats()
+    for name in ("kda_head_decay_total", "kda_grouped_heads_total"):
+        assert after.get(name, 0) == before.get(name, 0) + 1
+    assert after.get("kda_group_repeat_total", 0) \
+        == before.get("kda_group_repeat_total", 0)
+    alike, alike_grads = _kda_out_and_grads(
+        lambda *a: kda_attention(*expand(*a)), args, w)
+    f32 = lambda a: np.asarray(a, np.float32)
+    rel = lambda a, b_: np.linalg.norm(f32(a) - f32(b_)) / np.linalg.norm(
+        f32(b_))
+    assert rel(out, alike) < 1e-6
+    for i, (a, b_) in enumerate(zip(grads, alike_grads)):
+        assert a.dtype == args[i].dtype and a.shape == args[i].shape
+        assert rel(a, b_) < (1e-2 if a.dtype == jnp.bfloat16 else 1e-4), i
+    n = min(tokens, 1000)
+    prefix = tuple(a[:, :n] for a in args)
+    _assert_kda_close(_kda_out_and_grads(kda_attention, prefix, w[:, :n]),
+                      _kda_out_and_grads(
+                          lambda *a: X.recurrent(*expand(*a), d ** -0.5),
+                          prefix, w[:, :n]))
+
+
+def test_silu_gated_head_norm_against_its_xla_statement_on_tpu():
+    """`gdn_post_fwd` / `gdn_post_bwd` (the gated head norm with SiLU)
+    at the Qwen3-Next cell's shape — 1 x 16,384 x 32 heads of 128,
+    bfloat16 — against the XLA statement on the same chip."""
+    from paddle_tpu.nn.functional import kda as X
+    from paddle_tpu.ops.pallas import kda_edge as E
+
+    _, post, cot = _edge_operands_tpu(16384, 32, 97)
+
+    def run(fn):
+        return jax.jit(lambda *a: jax.vjp(fn, *a)[1](cot[-1]) + (fn(*a),))(
+            *post)
+
+    got = run(lambda o, g, w: E.kda_post(o, g, w, 1e-6, activation="silu"))
+    want = run(lambda o, g, w: X.edge_post(o, g, w, 1e-6, "silu"))
+    f32 = lambda a: np.asarray(a, np.float32)
+    for a, b_ in zip(got, want):
+        assert a.dtype == b_.dtype and a.shape == b_.shape
+        assert np.linalg.norm(f32(a) - f32(b_)) <= 2e-2 * np.linalg.norm(
+            f32(b_))
+
+
 def _edge_operands_tpu(s, heads, seed):
     rng = np.random.RandomState(seed)
     w = heads * 128
