@@ -910,22 +910,24 @@ def kda_post(o, gate, weight, epsilon=1e-5, name=None, activation="sigmoid"):
         {"o": o, "gate": gate, "weight": weight})
 
 
-def gdn_pre(qkv, ba, taps, dt_bias, a_log, key_heads, name=None):
+def gdn_pre(qkvz, ba, taps, dt_bias, a_log, key_heads, name=None):
     """Gated DeltaNet's work between its projections and its scan
-    (nn/functional/kda.py: `gdn_pre` states it): qkv (B, S, 2 Hk d + Hv
-    d), ba (B, S, 2 Hv), taps (width, 2 Hk d + Hv d), dt_bias, a_log
-    (Hv,) -> q, k (B, S, Hk, d), v (B, S, Hv, d), g, beta (B, S, Hv)
-    float32: what `kda_attention` takes.  XLA, recomputed in the
-    backward pass (`jax.checkpoint`): it keeps no float32 intermediate."""
-    import jax
+    (nn/functional/kda.py: `gdn_pre` states it) from the projection's
+    whole output qkvz = [q~ | k~ | v~ | z] (B, S, 2 Hk d + Hv d + rest),
+    ba (B, S, 2 Hv), taps (width, 2 Hk d + Hv d), dt_bias, a_log (Hv,)
+    -> q, k (B, S, Hk, d), v (B, S, Hv, d), g, beta (B, S, Hv) float32
+    — what `kda_attention` takes — and z, the lanes past the taps'.  On
+    a TPU at d = 128 and width = 4, q, k, v are one Pallas pass each way
+    that reads q~, k~, v~ in place (`gdn_pre_fwd` / `gdn_pre_bwd`,
+    ops/pallas/kda_edge.py); else the XLA statement, recomputed in the
+    backward pass (`jax.checkpoint`).  g and beta are XLA's on either
+    path."""
+    from ...ops.pallas import kda_edge
 
-    from .kda import gdn_pre as pre
-
-    run = jax.checkpoint(lambda *operands: pre(*operands, key_heads))
     return trace_fn(
-        lambda qkv, ba, taps, dt_bias, a_log: run(qkv, ba, taps, dt_bias,
-                                                  a_log),
-        {"qkv": qkv, "ba": ba, "taps": taps, "dt_bias": dt_bias,
+        lambda qkvz, ba, taps, dt_bias, a_log: kda_edge.gdn_pre(
+            qkvz, ba, taps, dt_bias, a_log, key_heads),
+        {"qkvz": qkvz, "ba": ba, "taps": taps, "dt_bias": dt_bias,
          "a_log": a_log}, multi_out=True)
 
 

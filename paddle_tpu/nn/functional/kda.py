@@ -8,7 +8,9 @@ chunk-sequential, are inside the kernels `kda_fwd` / `kda_bwd`.  Also
 the layer's elementwise work around the scan in plain XLA (`edge_pre`,
 `edge_post`, `short_conv`): the oracle of `ops/pallas/kda_edge.py`'s
 two passes and the path for what those kernels refuse; and Gated
-DeltaNet's work before its scan (`gdn_pre`), which runs as XLA.
+DeltaNet's work before its scan (`gdn_pre`): the oracle of
+`kda_edge.gdn_pre` and its fallback, whose β and g (`gdn_gate`) run as
+XLA on either path.
 
 The recurrence, for one head (S in R^{dk x dv}, float32, S_0 = 0):
 
@@ -249,12 +251,22 @@ def gdn_pre(qkv, ba, taps, dt_bias, a_log, key_heads):
     unit = lambda a: (lambda a: a * jax.lax.rsqrt(
         jnp.sum(jnp.square(a), -1, keepdims=True) + 1e-6))(
             a.reshape(b, s, key_heads, d))
+    g, beta = gdn_gate(ba, dt_bias, a_log)
+    return (unit(q).astype(qkv.dtype), unit(k).astype(qkv.dtype),
+            v.reshape(b, s, heads, -1).astype(qkv.dtype), g, beta)
+
+
+def gdn_gate(ba, dt_bias, a_log):
+    """`gdn_pre`'s two scalars a value head and token: ba = [b | a] (B,
+    S, 2 Hv), dt_bias and a_log (Hv,) -> g = -exp(a_log) softplus(a +
+    dt_bias), beta = sigmoid(b), (B, S, Hv) float32 each."""
+    f32 = jnp.float32
+    heads = a_log.shape[0]
     ba = ba.astype(f32)
     beta = jax.nn.sigmoid(ba[..., :heads])
     g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
         ba[..., heads:] + dt_bias.astype(f32))
-    return (unit(q).astype(qkv.dtype), unit(k).astype(qkv.dtype),
-            v.reshape(b, s, heads, -1).astype(qkv.dtype), g, beta)
+    return g, beta
 
 
 def edge_post(o, gate, weight, epsilon, activation="sigmoid"):

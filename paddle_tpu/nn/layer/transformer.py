@@ -523,10 +523,13 @@ class GatedDeltaNet(Layer):
     `A_log` (Hv,) starts at log U(0, 16) and `dt_bias` (Hv,) at 1 (the
     released modeling code's), both float32; the head norm's `w` at 1;
     the taps (width, 2 Hk dk + Hv dv) as `ShortConvSiLU`'s.  The work
-    before the scan is one XLA statement (`F.gdn_pre`, scope `gdn_pre`),
-    the scan the chunked kernels of ops/pallas/kda.py with the decay a
-    head and the heads grouped (scope `gdn_core`), the gated head norm
-    the `kda_post` pass with SiLU (scope `gdn_post`).
+    before the scan (`F.gdn_pre`, scope `gdn_pre`) is one Pallas pass
+    each way over q~, k~, v~, read in place from `in_proj_qkvz`'s output
+    (`gdn_pre_fwd` / `gdn_pre_bwd`), beside β and g in XLA; the scan the
+    chunked kernels of ops/pallas/kda.py with the decay a head and the
+    heads grouped (scope `gdn_core`), the gated head norm the
+    `kda_post` pass with SiLU (scope `gdn_post`).  Off the TPU, all of
+    `gdn_pre` is the XLA statement.
 
     forward(x (B, S, E)) -> (B, S, E); causal by construction, and the
     layer carries position itself: it takes none."""
@@ -540,7 +543,6 @@ class GatedDeltaNet(Layer):
                              "query/key heads")
         self.num_k_heads, self.num_v_heads = num_k_heads, num_v_heads
         key, value = num_k_heads * head_k_dim, num_v_heads * head_v_dim
-        self._split = 2 * key + value
         lin = lambda i, o: Linear(i, o, weight_attr, False)
         self.in_proj_qkvz = lin(embed_dim, 2 * key + 2 * value)
         self.in_proj_ba = lin(embed_dim, 2 * num_v_heads)
@@ -555,14 +557,12 @@ class GatedDeltaNet(Layer):
         self.out_proj = lin(value, embed_dim)
 
     def forward(self, x):
-        qkv, z = trace_fn(
-            lambda y: (y[..., :self._split], y[..., self._split:]),
-            {"y": self.in_proj_qkvz(x)}, multi_out=True)
+        qkvz = self.in_proj_qkvz(x)
         ba = self.in_proj_ba(x)
         with jax.named_scope("gdn_pre"):
-            q, k, v, g, beta = F.gdn_pre(qkv, ba, self.conv1d.weight,
-                                         self.dt_bias, self.A_log,
-                                         self.num_k_heads)
+            q, k, v, g, beta, z = F.gdn_pre(qkvz, ba, self.conv1d.weight,
+                                            self.dt_bias, self.A_log,
+                                            self.num_k_heads)
         o = self.gdn_core(q, k, v, g, beta)
         with jax.named_scope("gdn_post"):
             o = F.kda_post(o, z, self.norm.weight, self.norm._epsilon,

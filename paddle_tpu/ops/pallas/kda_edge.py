@@ -1,11 +1,12 @@
-"""Kimi Delta Attention's elementwise work around its scan, as ONE
-pass over HBM each way: what `nn.KimiDeltaAttention` does between its
-projections and `kda_attention` (`kda_pre`), and between the scan and
-the output projection (`kda_post`), each a `jax.custom_vjp` over two
-Pallas kernels on the projections' own (B, S, H * 128) layout.
-`nn/functional/kda.py` states both passes in plain XLA (`edge_pre`,
-`edge_post`: the tests' oracle and the path for what the kernels
-refuse).
+"""Kimi Delta Attention's and Gated DeltaNet's elementwise work around
+their scan, as ONE pass over HBM each way: what `nn.KimiDeltaAttention`
+does between its projections and `kda_attention` (`kda_pre`), and
+between the scan and the output projection (`kda_post`); what
+`nn.GatedDeltaNet` does between its projection and the scan
+(`gdn_pre`) — each a `jax.custom_vjp` over two Pallas kernels on the
+projections' own (B, S, H * 128) layout.  `nn/functional/kda.py`
+states the passes in plain XLA (`edge_pre`, `edge_post`, `gdn_pre`:
+the tests' oracle and the path for what the kernels refuse).
 
     kda_pre_fwd   q = unit(SiLU(conv(q_raw))), k likewise,
                   v = SiLU(conv(v_raw)),
@@ -17,6 +18,16 @@ refuse).
     kda_post_bwd  do, dgate, dw from o, gate and dy
     gdn_post_fwd, the same two with SiLU(gate) for sigmoid(gate):
     gdn_post_bwd  Gated DeltaNet's gated head norm (`activation="silu"`)
+    gdn_pre_fwd   q = unit(SiLU(conv(q~))), k likewise, v = SiLU(conv(v~)),
+                  q~, k~, v~ read in place from the projection's output
+                  [q~ | k~ | v~ | z] by three lane-block specs over it:
+                  a block of key heads' q~ and k~ and their value heads'
+                  v~ (Hv / Hk times as wide) a grid step
+    gdn_pre_bwd   the same pull-back, over every lane block of the
+                  projection a grid step: it writes the projection's
+                  cotangent [dq~ | dk~ | dv~ | dz] whole, dz copied in,
+                  so that no concatenate follows it; beta and g stay in
+                  XLA (`gdn_gate`: 2 MB at the cell's shape)
 
 A grid step is one (batch, block of heads, tile of `ROW_TILE` rows)
 and walks the tile `_STEP` rows and a head at a time in a loop whose
@@ -123,6 +134,42 @@ def _add_rows(d_ref, row, lanes, x):
     d_ref[0, at, lanes] += sum(x[m:m + _SUB] for m in range(0, _STEP, _SUB))
 
 
+def _silu_conv(scr, p_ref, operand, o_ref, r0, lanes, unit):
+    """o_ref's rows r0.. on `lanes` <- SiLU(conv) of the tile in `scr`,
+    of unit length over the lanes if `unit`."""
+    c = _conv(scr, p_ref, operand, r0, lanes)[0]
+    y = c * _sigmoid(c)
+    if unit:
+        y = y * jax.lax.rsqrt(_lane_sum(y * y) + 1e-6)
+    o_ref[0, pl.ds(r0, _STEP), lanes] = y.astype(o_ref.dtype)
+
+
+def _pull_back(scr, dc, p_ref, operand, d_ref, dx_ref, dp_ref, r0, lanes,
+               unit):
+    """`_silu_conv`'s pull-back for rows r0.. on `lanes`: d_ref's
+    cotangent through the unit norm (if `unit`) and SiLU to the
+    convolution's sum, kept in `dc`; dx_ref <- its transpose, which
+    reads `dc` 3 rows ahead; the taps' cotangents into dp_ref."""
+    at = pl.ds(r0, _STEP)
+    c, xs, taps = _conv(scr, p_ref, operand, r0, lanes)
+    s = _sigmoid(c)
+    y = c * s
+    d = d_ref[0, at, lanes].astype(_F32)
+    if unit:                # through u = y r, r = rsqrt(|y|^2 + eps)
+        r = jax.lax.rsqrt(_lane_sum(y * y) + 1e-6)
+        u = y * r
+        d = r * (d - u * _lane_sum(d * u))
+    d = d * (s + y * (1.0 - s))             # SiLU'
+    dc[at, lanes] = d
+    for t in range(TAPS):
+        _add_rows(dp_ref, operand * TAPS + t, lanes, d * xs[t])
+    # row u's x met tap t in row u + 3 - t's sum
+    ahead = dc[pl.ds(r0, _STEP + _EDGE), lanes]
+    dx = sum(taps[t] * _shifted(ahead, t + 1 - TAPS)[:_STEP]
+             for t in range(TAPS))
+    dx_ref[0, at, lanes] = dx.astype(dx_ref.dtype)
+
+
 # -- before the scan ---------------------------------------------------------
 
 def _pre_fwd_kernel(q_ref, k_ref, v_ref, f_ref, qh_ref, kh_ref, vh_ref, p_ref,
@@ -138,11 +185,7 @@ def _pre_fwd_kernel(q_ref, k_ref, v_ref, f_ref, qh_ref, kh_ref, vh_ref, p_ref,
         for h in range(heads):
             lanes = _lanes(h)
             for n, (scr, _, _, o_ref) in enumerate(operands):
-                c = _conv(scr, p_ref, n, r0, lanes)[0]
-                y = c * _sigmoid(c)
-                if n < 2:           # q, k: unit length a head
-                    y = y * jax.lax.rsqrt(_lane_sum(y * y) + 1e-6)
-                o_ref[0, at, lanes] = y.astype(o_ref.dtype)
+                _silu_conv(scr, p_ref, n, o_ref, r0, lanes, unit=n < 2)
             a, softplus, _ = _gate(f_ref, p_ref, at, lanes)
             g_ref[0, at, lanes] = a * softplus
 
@@ -172,23 +215,8 @@ def _pre_bwd_kernel(q_ref, k_ref, v_ref, f_ref, qh_ref, kh_ref, vh_ref, p_ref,
         for h in range(heads):
             lanes = _lanes(h)
             for m, (scr, dc, _, _, d_ref, dx_ref) in enumerate(operands):
-                c, xs, taps = _conv(scr, p_ref, m, r0, lanes)
-                s = _sigmoid(c)
-                y = c * s
-                d = d_ref[0, at, lanes].astype(_F32)
-                if m < 2:           # through u = y r, r = rsqrt(|y|^2 + eps)
-                    r = jax.lax.rsqrt(_lane_sum(y * y) + 1e-6)
-                    u = y * r
-                    d = r * (d - u * _lane_sum(d * u))
-                d = d * (s + y * (1.0 - s))             # SiLU'
-                dc[at, lanes] = d
-                for t in range(TAPS):
-                    _add_rows(dp_ref, m * TAPS + t, lanes, d * xs[t])
-                # row u's x met tap t in row u + 3 - t's sum
-                ahead = dc[pl.ds(r0, _STEP + _EDGE), lanes]
-                dx = sum(taps[t] * _shifted(ahead, t + 1 - TAPS)[:_STEP]
-                         for t in range(TAPS))
-                dx_ref[0, at, lanes] = dx.astype(dx_ref.dtype)
+                _pull_back(scr, dc, p_ref, m, d_ref, dx_ref, dp_ref, r0,
+                           lanes, unit=m < 2)
             a, softplus, slope = _gate(f_ref, p_ref, at, lanes)
             dg = dg_ref[0, at, lanes] * a
             _add_rows(dp_ref, _A, lanes, dg * softplus)   # dg g: g' = g
@@ -292,6 +320,197 @@ def _pre_bwd(tile, interpret, res, cotangents):
 
 
 _pre.defvjp(_pre_fwd, _pre_bwd)
+
+
+# -- Gated DeltaNet before its scan ------------------------------------------
+
+def _gdn_fwd_kernel(q_ref, k_ref, v_ref, qh_ref, kh_ref, vh_ref, pq_ref,
+                    pk_ref, pv_ref, qo_ref, ko_ref, vo_ref, *scratch, heads,
+                    group, tile):
+    first = pl.program_id(2) == 0
+    operands = list(zip(scratch, (q_ref, k_ref, v_ref),
+                        (qh_ref, kh_ref, vh_ref), (pq_ref, pk_ref, pv_ref),
+                        (qo_ref, ko_ref, vo_ref)))
+    for scr, x_ref, halo_ref, _, _ in operands:
+        _fill(scr, x_ref, halo_ref, first)
+
+    def step(r0):
+        for h in range(heads):      # key head h, then its value heads
+            for n, (scr, _, _, p_ref, o_ref) in enumerate(operands):
+                for head in ([h] if n < 2
+                             else range(h * group, (h + 1) * group)):
+                    _silu_conv(scr, p_ref, 0, o_ref, r0, _lanes(head),
+                               unit=n < 2)
+
+    _walk(tile, step)
+
+
+def _gdn_bwd_kernel(x_ref, xh_ref, p_ref, dq_ref, dk_ref, dv_ref, *refs,
+                    bounds, tile):
+    # dz's ref is there where the projection has lanes past v~'s
+    dz_ref, (dx_ref, dp_ref, x_scr, dc_scr) = (
+        (refs[0], refs[1:]) if len(refs) == 5 else (None, refs))
+    j, i, n = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+
+    @pl.when(i == 0)
+    def _init():
+        dp_ref[...] = jnp.zeros_like(dp_ref)
+        dc_scr[tile:] = jnp.zeros((_EDGE, dc_scr.shape[1]), _F32)
+
+    def pull_back(d_ref, unit):
+        _fill(x_scr, x_ref, xh_ref, i == n - 1)     # tiles from the last
+
+        def step(r0):
+            for h in range(x_scr.shape[1] // HEAD_DIM):
+                _pull_back(x_scr, dc_scr, p_ref, 0, d_ref, dx_ref, dp_ref,
+                           r0, _lanes(h), unit)
+
+        _walk(tile, step, descending=True)
+        dc_scr[tile:] = dc_scr[:_EDGE]              # for the tile before
+
+    for lo, hi, d_ref, unit in zip((0,) + bounds[:2], bounds,
+                                   (dq_ref, dk_ref, dv_ref),
+                                   (True, True, False)):
+        pl.when((j >= lo) & (j < hi))(functools.partial(pull_back, d_ref,
+                                                        unit))
+    if dz_ref is not None:          # z's lanes: its cotangent as it came
+        @pl.when(j >= bounds[2])
+        def _z():
+            dx_ref[...] = dz_ref[...]
+
+
+def _gdn_layout(key_heads, width, rest):
+    """(key heads a forward grid step, lanes a backward grid step) for a
+    projection [q~ | k~ | v~ | rest] of `width` + `rest` lanes whose
+    heads are 128 wide, or None where the blocks cannot tile it: a
+    forward step reads a block of key heads' q~ and k~ and their value
+    heads' v~, the v~ block starting where a block of its width does."""
+    value_heads = width // HEAD_DIM - 2 * key_heads
+    if value_heads <= 0 or value_heads % key_heads or rest % HEAD_DIM:
+        return None
+    group = value_heads // key_heads
+    fwd = next((n for n in (4, 2, 1) if key_heads % n == 0
+                and 2 * key_heads % (group * n) == 0), None)
+    bwd = next(n for n in (8, 4, 2, 1)
+               if key_heads % n == 0 and rest // HEAD_DIM % n == 0)
+    return None if fwd is None else (fwd, bwd * HEAD_DIM)
+
+
+@functools.partial(jax.jit, static_argnames=("key_heads", "width", "tile",
+                                             "interpret"))
+def _gdn_pre_forward(y, params, key_heads, width, tile=ROW_TILE,
+                     interpret=False):
+    """y (B, S, width + rest) the projection, params (8, width + rest)
+    float32 (rows 0-3 the taps) -> q, k (B, S, Hk * 128), v (B, S, Hv *
+    128) in y's dtype."""
+    b, s, _ = y.shape
+    heads = _gdn_layout(key_heads, width, y.shape[2] - width)[0]
+    group = (width // HEAD_DIM - 2 * key_heads) // key_heads
+    lanes = heads * HEAD_DIM
+    grid = (b, key_heads // heads, s // tile)
+    # the first lane block of q~, k~, v~, in blocks of their own width
+    starts = ((lanes, 0), (lanes, key_heads // heads),
+              (group * lanes, 2 * key_heads // (group * heads)))
+
+    def specs(w, at):
+        return (pl.BlockSpec((1, tile, w), lambda b, j, i: (b, i, at + j)),
+                pl.BlockSpec((1, _HALO, w), lambda b, j, i: (
+                    b, jnp.maximum(i * (tile // _HALO) - 1, 0), at + j)),
+                pl.BlockSpec((2 * TAPS, w), lambda b, j, i: (0, at + j)))
+
+    rows, halos, taps = zip(*(specs(w, at) for w, at in starts))
+    out = [pl.BlockSpec((1, tile, w), lambda b, j, i: (b, i, j))
+           for w, _ in starts]
+    return pl.pallas_call(
+        functools.partial(_gdn_fwd_kernel, heads=heads, group=group,
+                          tile=tile),
+        grid=grid,
+        in_specs=[*rows, *halos, *taps], out_specs=out,
+        out_shape=[jax.ShapeDtypeStruct((b, s, m * key_heads * HEAD_DIM),
+                                        y.dtype) for m in (1, 1, group)],
+        scratch_shapes=[pltpu.VMEM((tile + _EDGE, w), _F32)
+                        for w, _ in starts],
+        compiler_params=_compiler_params(("parallel",) * 3),
+        interpret=interpret, name="gdn_pre_fwd",
+    )(*(y,) * 6, *(params,) * 3)
+
+
+@functools.partial(jax.jit, static_argnames=("key_heads", "width", "tile",
+                                             "interpret"))
+def _gdn_pre_backward(y, params, dq, dk, dv, dz, key_heads, width,
+                      tile=ROW_TILE, interpret=False):
+    """-> the projection's cotangent (B, S, width + rest) in y's dtype —
+    [dq~ | dk~ | dv~ | dz], dz (B, S, rest) passed on as it came — and
+    the taps' (4, width + rest) float32.  A grid step is one (batch,
+    block of `lanes` of the projection, tile); each cotangent's index
+    map holds the block it last read (or will first) where the step's
+    lanes are not its own, so that nothing is fetched twice."""
+    b, s, full = y.shape
+    lanes = _gdn_layout(key_heads, width, full - width)[1]
+    tiles = s // tile
+    bounds = (key_heads * HEAD_DIM // lanes, 2 * key_heads * HEAD_DIM // lanes,
+              width // lanes)
+    at = lambda i: tiles - 1 - i                    # tiles from the last
+    halo_at = lambda i: jnp.maximum(at(i) * (tile // _HALO) - 1, 0)
+
+    def held(rows, lo, hi, row=at):
+        def index(b, j, i):
+            r = jnp.where(j < lo, row(0), jnp.where(j < hi, row(i),
+                                                    row(tiles - 1)))
+            return b, r, jnp.clip(j - lo, 0, hi - lo - 1)
+        return pl.BlockSpec((1, rows, lanes), index)
+
+    cotangents = [held(tile, lo, hi)
+                  for lo, hi in zip((0,) + bounds, bounds + (full // lanes,))]
+    operands = (y, y, params, dq, dk, dv) + ((dz,) if full > width else ())
+    dy, dparams = pl.pallas_call(
+        functools.partial(_gdn_bwd_kernel, bounds=bounds, tile=tile),
+        grid=(b, full // lanes, tiles),
+        in_specs=[held(tile, 0, bounds[2]), held(_HALO, 0, bounds[2], halo_at),
+                  pl.BlockSpec((2 * TAPS, lanes), lambda b, j, i: (0, j)),
+                  *cotangents[:len(operands) - 3]],
+        out_specs=[pl.BlockSpec((1, tile, lanes),
+                                lambda b, j, i: (b, at(i), j)),
+                   pl.BlockSpec((1, TAPS * _SUB, lanes),
+                                lambda b, j, i: (b, 0, j))],
+        out_shape=[_like(y),
+                   jax.ShapeDtypeStruct((b, TAPS * _SUB, full), _F32)],
+        scratch_shapes=_scratch(tile, lanes, 2),
+        compiler_params=_compiler_params(), interpret=interpret,
+        name="gdn_pre_bwd",
+    )(*operands)
+    return dy, dparams.reshape(b, TAPS, _SUB, full).sum((0, 2))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _gdn(y, taps, key_heads, tile, interpret):
+    return _gdn_fwd(y, taps, key_heads, tile, interpret)[0]
+
+
+def _gdn_params(taps, full):
+    """The taps (4, width) as the kernels' (8, full) float32 block."""
+    return jnp.pad(taps.astype(_F32), ((0, TAPS), (0, full - taps.shape[1])))
+
+
+@_common.kernel_trace("kda_edge")
+def _gdn_fwd(y, taps, key_heads, tile, interpret):
+    width = taps.shape[1]
+    q, k, v = _gdn_pre_forward(y, _gdn_params(taps, y.shape[2]), key_heads,
+                               width, tile=tile, interpret=interpret)
+    return (q, k, v, y[..., width:]), (y, taps)
+
+
+@_common.kernel_trace("kda_edge")
+def _gdn_bwd(key_heads, tile, interpret, res, cotangents):
+    y, taps = res
+    width = taps.shape[1]
+    dy, dtaps = _gdn_pre_backward(y, _gdn_params(taps, y.shape[2]),
+                                  *cotangents, key_heads, width, tile=tile,
+                                  interpret=interpret)
+    return dy, dtaps[:, :width].astype(taps.dtype)
+
+
+_gdn.defvjp(_gdn_fwd, _gdn_bwd)
 
 
 # -- after the scan ----------------------------------------------------------
@@ -477,6 +696,40 @@ def kda_pre(q_raw, k_raw, v_raw, f, q_taps, k_taps, v_taps, dt_bias, a_log,
     out = _pre(*_padded(tile, q_raw, k_raw, v_raw, f), params, tile,
                bool(interpret))
     return tuple(a[:, :s] for a in out)
+
+
+@_common.kernel_trace("kda_edge")
+def gdn_pre(qkvz, ba, taps, dt_bias, a_log, key_heads, interpret=False,
+            tile=ROW_TILE):
+    """Gated DeltaNet's work between its projections and its scan
+    (nn/functional/kda.py: `gdn_pre` states it) from the projection's
+    whole output qkvz = [q~ | k~ | v~ | z] (B, S, 2 Hk d + Hv d + rest),
+    ba (B, S, 2 Hv), the taps (width, 2 Hk d + Hv d), dt_bias and a_log
+    (Hv,) -> q, k (B, S, Hk, d), v (B, S, Hv, d) in qkvz's dtype, g,
+    beta (B, S, Hv) float32, and z = the projection's lanes past the
+    taps' (B, S, rest), passed on: its cotangent and those of q~, k~,
+    v~ leave as one array.
+
+    d = 128 and width = 4 on a TPU (or under `interpret`): q, k, v from
+    `gdn_pre_fwd`, which reads q~, k~, v~ in place, and `gdn_pre_bwd`
+    behind it; a length that is no multiple of the row tile is padded
+    with zero rows.  g and beta are XLA's either way (`gdn_gate`, 2 MB
+    at the cell's shape).  Otherwise the XLA statement."""
+    b, s, full = qkvz.shape
+    width = taps.shape[1]
+    d = width // (2 * key_heads + a_log.shape[0])
+    layout = (_gdn_layout(key_heads, width, full - width)
+              if d == HEAD_DIM else None)
+    if not _fused(d, interpret, refused=taps.shape[0] != TAPS
+                  or layout is None):
+        return jax.checkpoint(_xla.gdn_pre, static_argnums=(5,))(
+            qkvz[..., :width], ba, taps, dt_bias, a_log,
+            key_heads) + (qkvz[..., width:],)
+    g, beta = jax.checkpoint(_xla.gdn_gate)(ba, dt_bias, a_log)
+    q, k, v, z = (a[:, :s] for a in _gdn(*_padded(tile, qkvz), taps,
+                                          key_heads, tile, bool(interpret)))
+    heads = lambda a: a.reshape(b, s, -1, HEAD_DIM)
+    return heads(q), heads(k), heads(v), g, beta, z
 
 
 @_common.kernel_trace("kda_edge")

@@ -654,6 +654,44 @@ class TestMosaicAcceptsForV5e:
             "_flash_backward"))
 
 
+    @pytest.mark.parametrize("tokens,key_heads", [(16384, 16), (512, 1)])
+    def test_gdn_pre_kernels(self, v5e, tokens, key_heads):
+        """The Qwen3-Next cell's pass before its scan (`kda_edge.gdn_pre`)
+        at the cell's shape, 1 x 16,384, 16 key heads under 32 value
+        heads of 128: `gdn_pre_fwd` on (256, 512 | 512 | 1,024) blocks
+        of q~, k~, v~ read in place from the projection's output,
+        `gdn_pre_bwd` on (256, 1,024) blocks of all its lanes, the
+        cotangents' index maps held where a block is not theirs —
+        compiled for a v5e; and one key head (128-lane blocks).  The
+        projection's cotangent is the backward call's own output: no
+        concatenate at its edge; neither call is named as the scan's or
+        the flash kernels' are."""
+        from paddle_tpu.ops.pallas import _common, kda_edge
+
+        put = lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=_common._COMPILE_TARGET)
+        hk, hv = key_heads, 2 * key_heads
+        width = (2 * hk + hv) * 128
+        args = (put((1, tokens, width + hv * 128), jnp.bfloat16),
+                put((1, tokens, 2 * hv), jnp.bfloat16),
+                put((4, width), jnp.float32), put((hv,), jnp.float32),
+                put((hv,), jnp.float32))
+
+        def loss(*a):
+            return sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
+                       for x in kda_edge.gdn_pre(*a, hk))
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 2))).lower(
+            *args).compile().as_text()
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        for fn in ("_gdn_pre_forward", "_gdn_pre_backward"):
+            assert sum(fn in c for c in calls) == 1, fn
+        assert not any(fn in c for c in calls for fn in (
+            "_gdn_forward", "_gdn_backward", "_kda_forward",
+            "_kda_backward", "_flash_forward", "_flash_backward"))
+        assert " concatenate(" not in text
+
     @pytest.mark.parametrize("tokens,heads,rotary_dim", [
         (16384, 64, 128), (16384, 48, 64), (512, 3, 64)])
     def test_attn_edge_kernels(self, v5e, tokens, heads, rotary_dim):

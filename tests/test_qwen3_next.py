@@ -2,7 +2,9 @@
 (interpret mode: a decay that is one scalar a head, two value heads over
 one query/key head) against the per-channel kernel fed the broadcast
 decay and repeated q and k, and against the recurrence a token at a
-time; the SiLU-gated head norm pass; `nn.GatedDeltaNet`, the
+time; the SiLU-gated head norm pass; the pass before the scan
+(`kda_edge.gdn_pre`'s kernels against its XLA statement, and the tiny
+step on them against the XLA path); `nn.GatedDeltaNet`, the
 element-wise-gated attention with zero-centred QK norms, the gated
 shared expert and the zero-centred RMSNorm against the plain reference
 (benchmark/reference/qwen3_next.py — the one the benchmark's `correct`
@@ -188,6 +190,129 @@ def test_silu_instances_are_named_and_counted_apart():
         E.kda_post(o, o, jnp.ones((128,)), 1e-6, activation="gelu")
 
 
+# -- the work before the scan ------------------------------------------------
+
+PRE_OUT = ("q", "k", "v", "g", "beta", "z")
+PRE_IN = ("qkvz", "ba", "taps", "dt_bias", "a_log")
+# (batch, tokens, key heads, value heads, row tile): two value heads a key
+# head over three tiles; as many value as key heads, batch 2, a length no
+# multiple of the tile; two key heads a forward grid step and two a
+# backward one, padded
+PRE_CASES = [(1, 96, 1, 2, 32), (2, 100, 2, 2, 32), (1, 80, 2, 4, 32)]
+PRE_IDS = ["grouped", "ungrouped-padded", "two-heads-a-step"]
+
+
+def _pre_operands(b, s, hk, hv, d=128, seed=0):
+    rng = np.random.default_rng(seed)
+    width = (2 * hk + hv) * d
+    draw = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    return (draw(b, s, width + hv * d), draw(b, s, 2 * hv), draw(4, width) / 2,
+            draw(hv), jnp.log(jnp.asarray(rng.uniform(1, 16, hv), jnp.float32)))
+
+
+def _pre_xla(qkvz, ba, taps, dt_bias, a_log, key_heads):
+    width = taps.shape[1]
+    return X.gdn_pre(qkvz[..., :width], ba, taps, dt_bias, a_log,
+                     key_heads) + (qkvz[..., width:],)
+
+
+@functools.lru_cache(maxsize=None)
+def _pre_both(b, s, hk, hv, tile):
+    """(outputs, the five cotangents) of `kda_edge.gdn_pre` through the
+    kernels and of its XLA statement, float32."""
+    args = _pre_operands(b, s, hk, hv)
+    both = []
+    for fn in (functools.partial(E.gdn_pre, key_heads=hk, interpret=True,
+                                 tile=tile),
+               functools.partial(_pre_xla, key_heads=hk)):
+        out, vjp = jax.vjp(fn, *args)
+        w = tuple(jnp.asarray(np.random.default_rng(7 + i).normal(
+            size=a.shape), a.dtype) for i, a in enumerate(out))
+        both.append((out, vjp(w)))
+    return both
+
+
+@pytest.mark.parametrize("out", range(6), ids=PRE_OUT)
+@pytest.mark.parametrize("case", PRE_CASES, ids=PRE_IDS)
+def test_gdn_pre_output_matches_its_xla_statement(case, out):
+    (got, _), (want, _) = _pre_both(*case)
+    assert got[out].shape == want[out].shape
+    assert got[out].dtype == want[out].dtype
+    assert _rel(got[out], want[out]) < 1e-6
+
+
+@pytest.mark.parametrize("operand", range(5), ids=PRE_IN)
+@pytest.mark.parametrize("case", PRE_CASES, ids=PRE_IDS)
+def test_gdn_pre_cotangent_matches_its_xla_statement(case, operand):
+    """The hand-written backward: the projection's cotangent [dq~ | dk~
+    | dv~ | dz] as one array, the taps; and XLA's for ba, dt_bias and
+    A_log beside it."""
+    (_, got), (_, want) = _pre_both(*case)
+    assert got[operand].shape == want[operand].shape
+    assert _rel(got[operand], want[operand]) < 1e-5
+
+
+@pytest.mark.parametrize("part", range(3), ids=PRE_OUT[:3])
+def test_gdn_pre_backward_reaches_into_the_tile_before(part):
+    """A cotangent of q, k or v that is non-zero only in the first rows
+    of ONE tile (the third of four): the convolution's pull-back lands
+    in the last 3 rows of the tile BEFORE (carried in VMEM by the
+    backward walk) and nowhere earlier, in that part's lanes alone."""
+    tile, first, hk = 32, 64, 1
+    args = _pre_operands(1, 128, hk, 2, seed=3)
+    fns = (functools.partial(E.gdn_pre, key_heads=hk, interpret=True,
+                             tile=tile),
+           functools.partial(_pre_xla, key_heads=hk))
+    grads = []
+    for fn in fns:
+        out, vjp = jax.vjp(fn, *args)
+        w = [jnp.zeros_like(a) for a in out]
+        w[part] = w[part].at[:, first:first + 2].set(1.0)
+        grads.append(vjp(tuple(w))[0])
+    got, want = grads
+    assert _rel(got, want) < 1e-5
+    lanes = slice(part * 128, (part + 1) * 128 if part < 2 else 512)
+    before = np.abs(np.asarray(got[0, first - 3:first, lanes]))
+    assert before.min(axis=-1).max() > 0        # rows 61..63 of tile 1
+    assert float(jnp.abs(got[0, :first - 3]).max()) == 0.0
+    assert float(jnp.abs(got[0, first + 2:]).max()) == 0.0
+    rest = np.asarray(got[0]).copy()
+    rest[:, lanes] = 0.0
+    assert float(np.abs(rest).max()) == 0.0
+
+
+def test_gdn_pre_kernels_named_counted_and_refused():
+    """`gdn_pre_fwd` / `gdn_pre_bwd` under jitted names that none of the
+    benchmark's kernel readers take for the scan's or the flash
+    kernels'; an instance counts once; heads of 64 channels take the
+    XLA statement, counted as refused; off the TPU without `interpret`,
+    the XLA path, uncounted."""
+    args = _pre_operands(1, 40, 1, 2)
+    fused = functools.partial(E.gdn_pre, key_heads=1, interpret=True)
+    loss = lambda *a: sum(jnp.sum(jnp.square(x)) for x in fused(*a))
+    before = profiler.get_int_stats()
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(
+        *args))
+    assert (_delta(before, "kda_edge_fused_total"),
+            _delta(before, "kda_edge_fallback_total")) == (1, 0)
+    assert "name=gdn_pre_fwd" in text and "name=gdn_pre_bwd" in text
+    assert "name=_gdn_pre_forward" in text
+    assert "name=_gdn_pre_backward" in text
+    assert not re.search(r"_(gdn|kda|flash)_(forward|backward)\b", text)
+    # heads of 64 channels: the XLA statement, counted as refused
+    narrow = _pre_operands(1, 40, 1, 2, d=64)
+    before = profiler.get_int_stats()
+    got = E.gdn_pre(*narrow, 1, interpret=True)
+    assert (_delta(before, "kda_edge_fused_total"),
+            _delta(before, "kda_edge_fallback_total")) == (0, 1)
+    for a, b in zip(got, _pre_xla(*narrow, 1)):
+        assert a.shape == b.shape and _rel(a, b) < 1e-6
+    before = profiler.get_int_stats()
+    E.gdn_pre(*args, 1)
+    assert (_delta(before, "kda_edge_fused_total"),
+            _delta(before, "kda_edge_fallback_total")) == (0, 0)
+
+
 # -- the layers against the reference ----------------------------------------
 
 _CFG = dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
@@ -285,6 +410,33 @@ def _reference_config(cfg):
     return {**dataclasses.asdict(cfg), "router_width": cfg.num_experts}
 
 
+def _tiny_step():
+    """A tiny model (three Gated DeltaNet layers, one full), its float32
+    parameters with the norms moved off their start and the decay's
+    rates drawn from (0.05, 1), a batch, and one jitted loss-and-gradient
+    pass over them: (config, params, batch, loss, aux, grads)."""
+    paddle_tpu.seed(3)
+    cfg = M.Qwen3NextConfig.tiny(
+        experts_held=(2, 4), num_experts_per_tok=3, recompute=True,
+        vocab_size=64)
+    model = M.Qwen3NextForCausalLM(cfg)
+    params = {k: jnp.array(v) for k, v in functional_state(model).items()}
+    rng = np.random.default_rng(0)
+    for k in params:
+        if k.endswith("layernorm.weight") or k.endswith(
+                ("q_norm.weight", "k_norm.weight", "model.norm.weight")):
+            params[k] = params[k] + jnp.asarray(
+                rng.uniform(-0.3, 0.3, params[k].shape), jnp.float32)
+        elif k.endswith("A_log"):
+            params[k] = jnp.log(jnp.asarray(
+                rng.uniform(0.05, 1.0, params[k].shape), jnp.float32))
+    batch = M.fake_batch(cfg, 2, 40, seed=5)
+    loss_fn = M.build_loss(model, bf16=False, probe=8)
+    (loss, aux), grads = jax.jit(jax.value_and_grad(
+        loss_fn, has_aux=True))(params, batch)
+    return cfg, params, batch, loss, aux, grads
+
+
 @pytest.fixture(scope="module")
 def trained():
     """One float32 loss-and-gradient pass of a tiny model (three Gated
@@ -300,25 +452,7 @@ def trained():
                                                      interpret=True))
     before = profiler.get_int_stats()
     try:
-        paddle_tpu.seed(3)
-        cfg = M.Qwen3NextConfig.tiny(
-            experts_held=(2, 4), num_experts_per_tok=3, recompute=True,
-            vocab_size=64)
-        model = M.Qwen3NextForCausalLM(cfg)
-        params = {k: jnp.array(v) for k, v in functional_state(model).items()}
-        rng = np.random.default_rng(0)
-        for k in params:
-            if k.endswith("layernorm.weight") or k.endswith(
-                    ("q_norm.weight", "k_norm.weight", "model.norm.weight")):
-                params[k] = params[k] + jnp.asarray(
-                    rng.uniform(-0.3, 0.3, params[k].shape), jnp.float32)
-            elif k.endswith("A_log"):
-                params[k] = jnp.log(jnp.asarray(
-                    rng.uniform(0.05, 1.0, params[k].shape), jnp.float32))
-        batch = M.fake_batch(cfg, 2, 40, seed=5)
-        loss_fn = M.build_loss(model, bf16=False, probe=8)
-        (loss, aux), grads = jax.jit(jax.value_and_grad(
-            loss_fn, has_aux=True))(params, batch)
+        cfg, params, batch, loss, aux, grads = _tiny_step()
         config = _reference_config(cfg)
         ref = R.forward(config, params, batch,
                         probe=M.probe_positions(40, 8))
@@ -373,6 +507,31 @@ def test_all_gradients_match_reference(trained):
     worst = max(_rel(trained["grads"][k], trained["ref_grads"][k])
                 for k in trained["grads"])
     assert worst < 2e-4
+
+
+def test_step_on_the_pre_scan_kernels_equals_the_xla_path(trained):
+    """The same tiny step with `gdn_pre`'s kernels taken (interpret
+    mode): its loss, logits and every gradient equal the XLA path's
+    (`trained`) to float32 rounding, and each of the three layers
+    counts one fused instance."""
+    mp = pytest.MonkeyPatch()
+    mp.setattr(K, "kda_attention", functools.partial(K.kda_attention,
+                                                     interpret=True))
+    mp.setattr(E, "gdn_pre", functools.partial(E.gdn_pre, interpret=True))
+    before = profiler.get_int_stats()
+    try:
+        _, _, _, loss, aux, grads = _tiny_step()
+    finally:
+        mp.undo()
+    assert (_delta(before, "kda_edge_fused_total"),
+            _delta(before, "kda_edge_fallback_total")) == (3, 0)
+    assert abs(float(loss) - float(trained["loss"])) < 1e-6
+    np.testing.assert_allclose(np.asarray(aux["probe_logits"]),
+                               np.asarray(trained["aux"]["probe_logits"]),
+                               atol=1e-5)
+    assert grads.keys() == trained["grads"].keys()
+    for k in grads:
+        assert _rel(grads[k], trained["grads"][k]) < 1e-5, k
 
 
 def test_layer_kinds_follow_the_published_interval():

@@ -688,6 +688,59 @@ def test_kda_edge_passes_against_their_xla_statement_on_tpu(tokens, heads):
             f32(b_))
 
 
+@pytest.mark.parametrize("tokens,key_heads", [(16384, 16), (1000, 1)])
+def test_gdn_pre_pass_against_its_xla_statement_on_tpu(tokens, key_heads):
+    """Gated DeltaNet's pass before its scan (`gdn_pre_fwd` /
+    `gdn_pre_bwd` through Mosaic, reading q~, k~, v~ in place from the
+    projection's whole output) at the Qwen3-Next cell's shape — 1 x
+    16,384, 16 key heads under 32 value heads of 128, bfloat16, 4 taps
+    — and at a length that is no multiple of the row tile with one key
+    head, against its XLA statement on the same chip: the six outputs
+    and the five cotangents within bfloat16 rounding; one fused
+    instance counted."""
+    from paddle_tpu.nn.functional import kda as X
+    from paddle_tpu.ops.pallas import kda_edge as E
+
+    rng = np.random.RandomState(98)
+    hk, hv, d = key_heads, 2 * key_heads, 128
+    width = (2 * hk + hv) * d
+    qkvz = jnp.asarray(rng.randn(1, tokens, width + hv * d), jnp.bfloat16)
+    ba = jnp.asarray(rng.randn(1, tokens, 2 * hv), jnp.bfloat16)
+    taps = jnp.asarray(rng.uniform(-0.5, 0.5, (4, width)), jnp.float32)
+    dt_bias = jnp.asarray(rng.randn(hv), jnp.float32)
+    a_log = jnp.asarray(np.log(rng.uniform(1, 16, hv)), jnp.float32)
+    args = (qkvz, ba, taps, dt_bias, a_log)
+
+    def xla(qkvz, *rest):
+        return X.gdn_pre(qkvz[..., :width], *rest, hk) + (qkvz[..., width:],)
+
+    shapes = [((1, tokens, h, d), jnp.bfloat16) for h in (hk, hk, hv)] + [
+        ((1, tokens, hv), jnp.float32)] * 2 + [
+        ((1, tokens, hv * d), jnp.bfloat16)]
+    cot = tuple(jnp.asarray(rng.randn(*shape), dtype)
+                for shape, dtype in shapes)
+
+    def run(fn):
+        def f(*a):
+            out, vjp = jax.vjp(fn, *a)
+            return out, vjp(cot)
+        return jax.jit(f)(*args)
+
+    before = profiler.get_int_stats()
+    out, grads = run(lambda *a: E.gdn_pre(*a, hk))
+    after = profiler.get_int_stats()
+    assert after.get("kda_edge_fused_total", 0) \
+        == before.get("kda_edge_fused_total", 0) + 1
+    assert after.get("kda_edge_fallback_total", 0) \
+        == before.get("kda_edge_fallback_total", 0)
+    ref, ref_grads = run(xla)
+    f32 = lambda a: np.asarray(a, np.float32)
+    for a, b_ in zip(out + grads, ref + ref_grads):
+        assert a.dtype == b_.dtype and a.shape == b_.shape
+        assert np.linalg.norm(f32(a) - f32(b_)) <= 2e-2 * np.linalg.norm(
+            f32(b_))
+
+
 def _attend_and_grads(attend, q, k, v, w):
     def f(q, k, v, w):      # w an operand: a closed-over one is a constant
         out, vjp = jax.vjp(lambda *a: attend(*a).astype(jnp.float32),
