@@ -54,10 +54,20 @@ The same two kernels take the Gated DeltaNet form of the recurrence
 `gdn_bwd`:
 
   * a decay that is ONE scalar a head and token, g (B, S, Hv): it
-    arrives as the chunk's (64, Hv) block beside beta's, a head's
-    column is broadcast to its 128 lanes in VMEM, and the backward
-    sums its lanes' dg (one more matmul with a ones row, as dbeta) and
-    writes (B, N, Hv, 1, 64) rows that XLA lays out as (B, S, Hv);
+    arrives as the chunk's (64, Hv) block beside beta's and is
+    cumulated once a step for all heads.  The exponent of a score is
+    then the same for every channel, so the scores are plain products
+    times one (64, 64) matrix a head (`_head_decay_shared`):
+
+        E = exp(min(G_i - G_j, 0));  [Pk; Pq] = [k; q] k^T, once a key
+        head;  Mk = tril(-1)(Pk E),  Aqk = scale tril(0)(Pq E)
+
+    with no sub-block rule, no per-lane exponentials and no key-row
+    loops; their pull-back is four products with Dk = dMk E and Dq =
+    dMq E, and G's share the row minus the column sums of dMk Mk + dMq
+    Mq (`_head_decay_rows`, `_head_decay_keys`).  dg and dbeta leave as
+    (B, N, Hv, 1, 64) rows that XLA lays out as (B, S, Hv).
+    `kda_scalar_scores_total` counts the calls built in this form;
   * twice as many value heads as query/key heads, value head h reading
     key head h // 2: a grid step's two value heads are one key head's
     pair, q and k are fetched once a step by the index map, and the
@@ -65,8 +75,10 @@ The same two kernels take the Gated DeltaNet form of the recurrence
     once.  Any other ratio has q and k repeated in HBM first
     (`kda_group_repeat_total`).
 
-A per-channel decay over as many key as value heads is the program it
-was: the forms are chosen by the operands' shapes at trace time.
+The two forms share the walk, T, U, o and the state update, and part
+at the scores.  A per-channel decay over as many key as value heads is
+the program it was: the forms are chosen by the operands' shapes at
+trace time.
 """
 
 from __future__ import annotations
@@ -241,9 +253,9 @@ def _inverse(a, row, col, rel):
     return x
 
 
-def _head_column(beta_ref, head):
-    """A head's beta as a (64, 1) column of the chunk's (64, H) block."""
-    b = beta_ref[0]
+def _head_column(b, head):
+    """A head's column of a chunk's (64, H) block (beta's, G's), as a
+    (64, 1) column."""
     lane = jax.lax.broadcasted_iota(jnp.int32, b.shape, 1)
     return jnp.sum(jnp.where(lane == head, b, 0.0), axis=1, keepdims=True)
 
@@ -253,14 +265,8 @@ def _lanes(h):
     return slice(h * HEAD_DIM, (h + 1) * HEAD_DIM)
 
 
-def _lane_row(x):
-    """A (64, 128) block's lane sums as a lane-dense (1, 64) row: the
-    exact float32 matmul with a ones row that dbeta's rows use."""
-    return _dot(jnp.ones((8, HEAD_DIM), _F32), x, _NT)[:1]
-
-
 def _chunk_matmuls(h, heads, q_ref, k_ref, v_ref, g_ref, beta_ref, st, g_scr,
-                   k_scr, scale, form=_Form(1, False)):
+                   k_scr, scale, form=_Form(1, False), shared=None):
     """The first half of a head's chunk: the cumulated gate, what is
     elementwise in it, and the matmuls that wait for nothing else —
     the scores against earlier sub-blocks and [bk; Qg] S for the state
@@ -268,56 +274,102 @@ def _chunk_matmuls(h, heads, q_ref, k_ref, v_ref, g_ref, beta_ref, st, g_scr,
     its heads before any head's second half (`_chunk_local`): matmuls
     keep their program order, so the second head's are then not behind
     the first head's lane reductions.  `form.group` value heads read
-    one key head; `form.head_decay`: g is a (64, heads) block, a head's
-    column broadcast to its lanes."""
+    one key head.  `form.head_decay`: `shared` is what the step's
+    heads share (`_head_decay_shared`); a head's G is its column of the
+    step's cumulated (64, H) block broadcast to its lanes, and its
+    scores are its key head's [k; q] k^T times E_ij = exp(G_i - G_j),
+    one (64, 64) exponential; `g_scr` and `k_scr` are not read."""
     q, k = (r[0, :, _lanes(h // form.group)].astype(_F32)
             for r in (q_ref, k_ref))
     v = v_ref[0, :, _lanes(h)].astype(_F32)
     head = pl.program_id(1) * heads + h
-    beta = _head_column(beta_ref, head)
+    beta = _head_column(beta_ref[0], head)
     row, col, rel = _positions()
-    lower = (col <= row).astype(_F32)
-    g = (jnp.broadcast_to(_head_column(g_ref, head), (CHUNK, HEAD_DIM))
-         if form.head_decay else g_ref[0, :, _lanes(h)])
-    gc = _dot(lower, g, _NN)
-    g_scr[...] = gc
-    k_scr[...] = k
+    if form.head_decay:
+        gc = jnp.broadcast_to(_head_column(shared.gc, head),
+                              (CHUNK, HEAD_DIM))
+    else:
+        lower = (col <= row).astype(_F32)
+        gc = _dot(lower, g_ref[0, :, _lanes(h)], _NN)
+        g_scr[...] = gc
+        k_scr[...] = k
     e_g = jnp.exp(gc)
     last = gc[CHUNK - 1:]
     e_out = jnp.exp(last - gc)
     x = types.SimpleNamespace(
         q=q, k=k, v=v, beta=beta, gc=gc, e_g=e_g, e_out=e_out, st=st,
-        positions=(row, col, rel), g_scr=g_scr, k_scr=k_scr,
+        positions=(row, col, rel), g_scr=g_scr, k_scr=k_scr, form=form,
         bk=beta * k * e_g, bv=beta * v, qg=scale * q * e_g, kg=k * e_out,
         d=jnp.exp(last), scale=scale)
-    x.earlier = _earlier_scores(q, k, gc, rel)
+    if form.head_decay:
+        # above the diagonal the exponent is clamped (finite) and the
+        # caller's masks drop the entry
+        x.p = shared.p[h // form.group]
+        x.e = jnp.exp(jnp.minimum(gc[:, :CHUNK] - shared.g_rows[h:h + 1],
+                                  0.0))
+    else:
+        x.earlier = _earlier_scores(q, k, gc, rel)
     x.with_state = _dot(jnp.concatenate([x.bk, x.qg], axis=0), st, _NT)
     return x
 
 
+def _head_decay_shared(q_ref, k_ref, g_ref, heads, form):
+    """What a grid step's heads share where the decay is a scalar a
+    head: the chunk's cumulated decay G of every head (64, H) and the
+    step's heads' rows of it (8, 64) — the rows picked by an exact
+    matmul with one-hot rows, so that G_i - G_i is exactly 0 —, and
+    each key head's [k; q] k^T (128, 64), the scores before the decay,
+    one product for its `form.group` value heads."""
+    row, col, _ = _positions()
+    gc = _dot((col <= row).astype(_F32), g_ref[0], _NN)
+    pick = jax.lax.broadcasted_iota(jnp.int32, (8, gc.shape[1]), 1) == (
+        pl.program_id(1) * heads
+        + jax.lax.broadcasted_iota(jnp.int32, (8, gc.shape[1]), 0))
+    products = []
+    for j in range(heads // form.group):
+        q, k = (r[0, :, _lanes(j)].astype(_F32) for r in (q_ref, k_ref))
+        products.append(_dot(jnp.concatenate([k, q], axis=0), k, _NT))
+    return types.SimpleNamespace(gc=gc, g_rows=_dot(pick.astype(_F32), gc,
+                                                    _NT), p=products)
+
+
 def _chunk_local(x):
-    """The second half: the diagonal sub-blocks, T, and U = T (bv - bk
-    S) (= U0 - W S without forming W = T bk and U0 = T bv), (C, dv)."""
+    """The second half: the diagonal sub-blocks (a decay a head: the
+    products times E), T, and U = T (bv - bk S) (= U0 - W S without
+    forming W = T bk and U0 = T bv), (C, dv)."""
     row, col, rel = x.positions
-    mk, mq = _diagonal_scores(*x.earlier, x.q, x.k, x.gc, x.g_scr, x.k_scr,
-                              rel)
-    x.mk = jnp.where(col < row, mk, 0.0)
-    x.aqk = jnp.where(col <= row, x.scale * mq, 0.0)
+    if x.form.head_decay:
+        x.mk = jnp.where(col < row, x.p[:CHUNK] * x.e, 0.0)
+        x.mq = jnp.where(col <= row, x.p[CHUNK:] * x.e, 0.0)
+        x.aqk = x.scale * x.mq
+    else:
+        mk, mq = _diagonal_scores(*x.earlier, x.q, x.k, x.gc, x.g_scr,
+                                  x.k_scr, rel)
+        x.mk = jnp.where(col < row, mk, 0.0)
+        x.aqk = jnp.where(col <= row, x.scale * mq, 0.0)
     x.t = _inverse(x.beta * x.mk, row, col, rel)
     x.u = _dot(x.t, x.bv - x.with_state[:CHUNK], _NN)
     return x
 
 
+def _scratch_at(scratch, h):
+    """(g_scr, k_scr) of head h, where the form has them."""
+    return tuple(r.at[h] for r in scratch[:2]) if scratch else (None, None)
+
+
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref,
-                    s_scr, g_scr, k_scr, *, scale, heads, form):
+                    s_scr, *scratch, scale, heads, form):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         s_scr[...] = jnp.zeros_like(s_scr)
 
     # the step's heads are independent: one's matmuls fill the waits of
     # another's lane reductions
+    shared = (_head_decay_shared(q_ref, k_ref, g_ref, heads, form)
+              if form.head_decay else None)
     xs = [_chunk_matmuls(h, heads, q_ref, k_ref, v_ref, g_ref, beta_ref,
-                         s_scr[h], g_scr.at[h], k_scr.at[h], scale, form)
+                         s_scr[h], *_scratch_at(scratch, h), scale, form,
+                         shared)
           for h in range(heads)]
     for h, x in enumerate([_chunk_local(x) for x in xs]):
         st_ref[0, 0, h] = x.st                      # S^T entering: (dv, dk)
@@ -328,16 +380,18 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref,
 
 def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
                     dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
-                    ds_scr, g_scr, k_scr, col_scr, *, scale, heads, form):
+                    ds_scr, *scratch, scale, heads, form):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         ds_scr[...] = jnp.zeros_like(ds_scr)
 
+    shared = (_head_decay_shared(q_ref, k_ref, g_ref, heads, form)
+              if form.head_decay else None)
     xs = []
     for h in range(heads):
         x = _chunk_matmuls(h, heads, q_ref, k_ref, v_ref, g_ref, beta_ref,
-                           st_ref[0, 0, h], g_scr.at[h], k_scr.at[h], scale,
-                           form)
+                           st_ref[0, 0, h], *_scratch_at(scratch, h), scale,
+                           form, shared)
         # the walk's lines that wait for neither scores nor T: from do,
         # the state entering and dS^T leaving (dv, dk)
         x.ds, x.do = ds_scr[h], do_ref[0, :, _lanes(h)].astype(_F32)
@@ -345,8 +399,13 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
         xs.append(x)
     for h, x in enumerate([_chunk_local(x) for x in xs]):
         _bwd_walk(h, x, dv_ref, dbeta_ref, ds_scr.at[h])
+    if form.head_decay:
+        for h, x in enumerate(xs):
+            _head_decay_rows(h, x, dg_ref, dbeta_ref)
+        _head_decay_keys(xs, dq_ref, dk_ref, form)
+        return
     for h, x in enumerate(xs):
-        _bwd_scores(h, x, dq_ref, dk_ref, dg_ref, col_scr.at[h], form)
+        _bwd_scores(h, x, dq_ref, dk_ref, dg_ref, scratch[2].at[h], form)
     if form.group > 1:      # a key head's value heads: dq, dk summed, once
         for j in range(heads // form.group):
             pair = xs[j * form.group:(j + 1) * form.group]
@@ -378,10 +437,14 @@ def _bwd_walk(h, x, dv_ref, dbeta_ref, ds_scr):
     ds_scr[...] = ds * x.d + _dot(do_dz, jnp.concatenate([qg, bk], axis=0),
                                   _TN)
     d_a = -jnp.where(col < row, _dot(d_bv, u, _NT), 0.0)
-    ones = jnp.ones((8, HEAD_DIM), _F32)
-    dbeta_ref[0, 0, h] = (
-        _dot(ones, d_bk * k * e_g + d_bv * x.v, _NT)
-        + _dot(ones[:, :CHUNK], d_a * x.mk, _NT))[:1]
+    if x.form.head_decay:   # a column, laid out with dg (`_head_decay_rows`)
+        x.dbeta = (jnp.sum(d_bk * k * e_g + d_bv * x.v, axis=1, keepdims=True)
+                   + jnp.sum(d_a * x.mk, axis=1, keepdims=True))
+    else:
+        ones = jnp.ones((8, HEAD_DIM), _F32)
+        dbeta_ref[0, 0, h] = (
+            _dot(ones, d_bk * k * e_g + d_bv * x.v, _NT)
+            + _dot(ones[:, :CHUNK], d_a * x.mk, _NT))[:1]
     dv_ref[0, :, _lanes(h)] = (beta * d_bv).astype(dv_ref.dtype)
     x.d_mk = beta * d_a                             # strictly lower
     x.d_mq = jnp.where(col <= row, scale * d_aqk, 0.0)
@@ -389,10 +452,10 @@ def _bwd_walk(h, x, dv_ref, dbeta_ref, ds_scr):
 
 
 def _bwd_scores(h, x, dq_ref, dk_ref, dg_ref, col_scr, form):
-    """The last third: the scores' cotangents back to q, k and G, and
-    G's to g.  Grouped: the head's dq and dk stay on `x`, float32, for
-    the caller to sum over the key head's value heads; a decay a head:
-    dg leaves as the row of its lane sums."""
+    """The last third (a per-channel decay): the scores' cotangents back
+    to q, k and G, and G's to g.  Grouped: the head's dq and dk stay on
+    `x`, float32, for the caller to sum over the key head's value
+    heads."""
     scale, g_scr, k_scr = x.scale, x.g_scr, x.k_scr
     q, k, gc, e_g, beta = x.q, x.k, x.gc, x.e_g, x.beta
     d_mk, d_mq, d_qg, d_bk, d_kg = x.d_mk, x.d_mq, x.d_qg, x.d_bk, x.d_kg
@@ -444,11 +507,50 @@ def _bwd_scores(h, x, dq_ref, dk_ref, dg_ref, col_scr, form):
     # loses where it is the key's; then the cumulation's transpose
     d_gc = (d_bk * x.bk + d_qg * x.qg - d_kg * x.kg + q * row_q
             + k * (row_k - key))
-    dg = _dot((row <= col).astype(_F32), d_gc, _NN) + x.d_last
-    if form.head_decay:
-        dg_ref[0, 0, h] = _lane_row(dg)
-    else:
-        dg_ref[0, :, _lanes(h)] = dg
+    dg_ref[0, :, _lanes(h)] = _dot((row <= col).astype(_F32), d_gc, _NN) \
+        + x.d_last
+
+
+def _head_decay_rows(h, x, dg_ref, dbeta_ref):
+    """The last third for a decay a head, a value head's part: Dk = dMk
+    * E and Dq = dMq * E, the elementwise shares of dq and dk, and dg
+    and dbeta.  G_i gains the row sums of
+    Pi = dMk * Mk + dMq * Mq and G_j loses its column sums; one exact
+    matmul of a ones row lays dG's cumulation's transpose (lanes 0..63)
+    and dbeta (lanes 64..127) out as rows."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, HEAD_DIM), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, HEAD_DIM), 1)
+    pi = x.d_mk * x.mk + x.d_mq * x.mq
+    d_gc = (jnp.sum(pi, axis=1, keepdims=True)
+            - jnp.sum(pi.T, axis=1, keepdims=True)
+            + jnp.sum(x.d_bk * x.bk + x.d_qg * x.qg - x.d_kg * x.kg,
+                      axis=1, keepdims=True))       # (64, 1)
+    rows = _dot(jnp.ones((8, CHUNK), _F32), jnp.where(
+        lane < CHUNK, jnp.where(lane <= row, d_gc, 0.0),
+        jnp.where(lane - CHUNK == row, x.dbeta, 0.0)), _NN)[:1]
+    dg_ref[0, 0, h] = rows[:, :CHUNK] + jnp.sum(x.d_last, axis=1,
+                                                keepdims=True)
+    dbeta_ref[0, 0, h] = rows[:, CHUNK:]
+    x.d_k, x.d_q = x.d_mk * x.e, x.d_mq * x.e
+    x.dq = x.scale * x.e_g * x.d_qg
+    x.dk = x.beta * x.e_g * x.d_bk + x.d_kg * x.e_out
+
+
+def _head_decay_keys(xs, dq_ref, dk_ref, form):
+    """A key head's dq and dk: its value heads' Dk and Dq summed, then
+    Dq k to q and Dk k + Dk^T k + Dq^T q to k, two matmuls."""
+    for j in range(len(xs) // form.group):
+        pair = xs[j * form.group:(j + 1) * form.group]
+        total = lambda name: functools.reduce(
+            jnp.add, [getattr(x, name) for x in pair])
+        d = jnp.concatenate([total("d_k"), total("d_q")], axis=0)
+        q, k = pair[0].q, pair[0].k
+        rows = _dot(d, k, _NN)                      # (128, dk)
+        keys = _dot(d, jnp.concatenate([k, q], axis=0), _TN)
+        dq_ref[0, :, _lanes(j)] = (total("dq") + rows[CHUNK:]).astype(
+            dq_ref.dtype)
+        dk_ref[0, :, _lanes(j)] = (total("dk") + rows[:CHUNK] + keys).astype(
+            dk_ref.dtype)
 
 
 # -- the two calls -----------------------------------------------------------
@@ -513,8 +615,8 @@ def _forward_call(q, k, v, g, beta, scale, interpret, name):
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((b, n, all_heads, HEAD_DIM, HEAD_DIM),
                                         _F32)],
-        scratch_shapes=[_scratch(heads, HEAD_DIM), _scratch(heads, CHUNK),
-                        _scratch(heads, CHUNK)],
+        scratch_shapes=[_scratch(heads, HEAD_DIM)]
+        + [_scratch(heads, CHUNK)] * (0 if form.head_decay else 2),
         compiler_params=_compiler_params(), interpret=interpret,
         name=name,
     )(q, k, v, g, beta)
@@ -540,7 +642,7 @@ def _backward_call(q, k, v, g, beta, states, do, scale, interpret, name):
                    if form.head_decay else like(g),
                    jax.ShapeDtypeStruct(row_outs, _F32)],
         scratch_shapes=[_scratch(heads, HEAD_DIM)]
-        + [_scratch(heads, CHUNK)] * 3,
+        + [_scratch(heads, CHUNK)] * (0 if form.head_decay else 3),
         compiler_params=_compiler_params(), interpret=interpret,
         name=name,
     )(q, k, v, g, beta, states, do)
@@ -592,7 +694,13 @@ def _kda_chunked(q, k, v, g, beta, scale, interpret):
 
 def _calls(q, v, g, beta):
     """The Kimi Delta Attention instances' jitted calls, or the Gated
-    DeltaNet instances' (a decay a head, or grouped heads)."""
+    DeltaNet instances' (a decay a head, or grouped heads).  A call
+    built with a decay a head takes its scores in the scalar form:
+    `kda_scalar_scores_total` += 1."""
+    from ...profiler import stat_add
+
+    if g.ndim == beta.ndim:
+        stat_add("kda_scalar_scores_total")
     if g.ndim == beta.ndim or v.shape[2] != q.shape[2]:
         return _gdn_forward, _gdn_backward
     return _kda_forward, _kda_backward

@@ -616,6 +616,40 @@ class TestMosaicAcceptsForV5e:
         assert sum("_kda_forward" in c for c in calls) == 1
         assert sum("_kda_backward" in c for c in calls) == 1
 
+    @pytest.mark.parametrize("tokens,key_heads", [(16384, 16), (1000, 1)])
+    def test_gdn_scan_kernels(self, v5e, tokens, key_heads):
+        """The Qwen3-Next cell's scan at the cell's shape — 1 x 16,384,
+        16 key heads under 32 value heads of 128, a decay a value head:
+        `gdn_fwd` / `gdn_bwd` with the scores in the scalar form (G
+        cumulated once a step for all heads on a (64, 32) block, a
+        (64, 64) exponential a head, [k; q] k^T once a key head, dg and
+        dbeta laid out as rows by one matmul) — compiled whole for a
+        v5e; and one key head over a length no multiple of 64 ((64, 2)
+        blocks of g and beta)."""
+        from paddle_tpu import profiler
+        from paddle_tpu.ops.pallas import _common
+        from paddle_tpu.ops.pallas.kda import kda_attention
+
+        put = lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=_common._COMPILE_TARGET)
+        hk, hv = key_heads, 2 * key_heads
+        args = (put((1, tokens, hk, 128), jnp.bfloat16),) * 2 + (
+            put((1, tokens, hv, 128), jnp.bfloat16),
+            put((1, tokens, hv), jnp.float32),
+            put((1, tokens, hv), jnp.float32))
+        loss = lambda *a: jnp.sum(kda_attention(*a).astype(jnp.float32))
+        before = profiler.get_int_stats()
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *args).compile().as_text()
+        assert profiler.get_int_stats().get("kda_scalar_scores_total", 0) \
+            == before.get("kda_scalar_scores_total", 0) + 2
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert sum("_gdn_forward" in c for c in calls) == 1
+        assert sum("_gdn_backward" in c for c in calls) == 1
+        assert not any("_kda_forward" in c or "_kda_backward" in c
+                       for c in calls)
+
     @pytest.mark.parametrize("tokens,heads", [(16384, 32), (512, 3)])
     def test_kda_edge_kernels(self, v5e, tokens, heads):
         """The Kimi cell's two elementwise passes around the scan

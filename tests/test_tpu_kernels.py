@@ -562,10 +562,13 @@ def test_gdn_forms_against_the_per_channel_kernels_on_tpu(tokens, key_heads):
     heads of 128, decays down to -20 a token — and at a length that is
     no multiple of 64, against the Kimi instances (`kda_fwd` /
     `kda_bwd`) fed the decay broadcast to the lanes and q and k repeated
-    to the value heads: the same float32 arithmetic a value head, so
-    the outputs and dv, dbeta agree to float32 rounding and dq, dk, dg
-    (sums over a key head's pair and a head's lanes) nearly so; and the
-    first 1,000 tokens against the recurrence."""
+    to the value heads; and the first 1,000 tokens against the
+    recurrence.  The instances take their scores in the scalar form
+    (exp(G_i - G_j) over q k^T and k k^T; `kda_scalar_scores_total`, 2
+    a differentiated instance): other float32 arithmetic than the Kimi
+    kernels', so the bfloat16 outputs agree to bfloat16 rounding, as the
+    bfloat16 gradients do, and the float32 dg, dbeta nearly to float32
+    rounding."""
     from paddle_tpu.nn.functional import kda as X
     from paddle_tpu.ops.pallas.kda import kda_attention
 
@@ -590,6 +593,8 @@ def test_gdn_forms_against_the_per_channel_kernels_on_tpu(tokens, key_heads):
     after = profiler.get_int_stats()
     for name in ("kda_head_decay_total", "kda_grouped_heads_total"):
         assert after.get(name, 0) == before.get(name, 0) + 1
+    assert after.get("kda_scalar_scores_total", 0) \
+        == before.get("kda_scalar_scores_total", 0) + 2
     assert after.get("kda_group_repeat_total", 0) \
         == before.get("kda_group_repeat_total", 0)
     alike, alike_grads = _kda_out_and_grads(
@@ -597,7 +602,7 @@ def test_gdn_forms_against_the_per_channel_kernels_on_tpu(tokens, key_heads):
     f32 = lambda a: np.asarray(a, np.float32)
     rel = lambda a, b_: np.linalg.norm(f32(a) - f32(b_)) / np.linalg.norm(
         f32(b_))
-    assert rel(out, alike) < 1e-6
+    assert rel(out, alike) < 1e-2
     for i, (a, b_) in enumerate(zip(grads, alike_grads)):
         assert a.dtype == args[i].dtype and a.shape == args[i].shape
         assert rel(a, b_) < (1e-2 if a.dtype == jnp.bfloat16 else 1e-4), i
