@@ -139,15 +139,6 @@ WATCHLIST: List[Tuple[str, str]] = [
     ("paddle_tpu/obs/telemetry.py", "Watchdog.evaluate"),
     ("paddle_tpu/obs/telemetry.py", "Watchdog.observe"),
     ("paddle_tpu/obs/telemetry.py", "_Handler.do_GET"),
-    # measured device-time profiling (ISSUE 12): note_dispatch and the
-    # autostop check run INSIDE the dispatch/step loop; window
-    # start/finish run at window boundaries but on the training
-    # thread — capture must never smuggle a sync into the
-    # hot path it is measuring
-    ("paddle_tpu/obs/devprof.py", "note_dispatch"),
-    ("paddle_tpu/obs/devprof.py", "maybe_autostop"),
-    ("paddle_tpu/obs/devprof.py", "DevprofWindow.start"),
-    ("paddle_tpu/obs/devprof.py", "DevprofWindow.finish"),
     # HBM memory observability (ISSUE 14): set/add run on the dispatch /
     # ring / ckpt hot paths; ledger_gauges runs on the telemetry
     # sampler thread and oom_report on the dispatch except-path — all

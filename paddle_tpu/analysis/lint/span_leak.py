@@ -65,7 +65,6 @@ WATCHED = [
     # work in a host timer on every decoded token
     "paddle_tpu/transforms/__init__.py",
     "paddle_tpu/analysis/verifier.py",
-    "bench.py",
 ]
 
 

@@ -35,8 +35,7 @@ Three consumers:
    with `program#<id> block<idx> op<id>` provenance;
 2. `comm_report(program, mesh_axes)`: static per-collective predicted
    wire bytes, quant-collectives-aware (`signature_token()`), which
-   bench.py stamps as `detail.sharding.predicted_collective_bytes`
-   and tests hold within ±25% of measured `collective_bytes_spmd_*`;
+   tests hold within ±25% of measured `collective_bytes_spmd_*`;
 3. `feasibility(program, old_mesh, new_mesh)`: the elastic-resharding
    precheck — re-solves the spec registry over a candidate mesh and
    reports fits/clamps/bytes-per-device delta without compiling.

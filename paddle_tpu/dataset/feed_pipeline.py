@@ -39,8 +39,7 @@ This module closes both:
   bottleneck), `ring_empty_wait_ms` (consumer starved = feed is the
   bottleneck) and the per-epoch `shard_skew_ms` gauge make a stall
   attributable from the counters alone — `attribute_stall()` is the
-  canonical classification and `bench.py` embeds it in the BENCH JSON
-  detail.
+  canonical classification.
 
 Everything here is on the executor hot path and therefore on the
 `hot-path-sync` lint watchlist: no `np.asarray`/`.numpy()`/

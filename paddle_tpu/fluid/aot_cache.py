@@ -37,7 +37,7 @@ Profiler surface: `aot_cache_hits` / `aot_cache_misses` /
 `aot_cache_store_unsupported` counters and `aot_cache_load_ms` /
 `aot_cache_store_ms` timers (a load is also the start-up phase
 `setup.cache_load`) — the cold-start win is provable from
-counters alone (bench.py --mode fleet; tools/ci.sh fleet smoke).
+counters alone (tools/ci.sh fleet smoke).
 """
 
 from __future__ import annotations

@@ -54,7 +54,7 @@ def persistent_cache_dirs() -> Tuple[str, str]:
 
 def enable_persistent_cache() -> str:
     """Turn on JAX's persistent compilation cache under the rule of
-    `persistent_cache_dirs` (chip_smoke.py, bench.py; tools/ci.sh
+    `persistent_cache_dirs` (chip_smoke.py; tools/ci.sh
     exports the same directory to the processes it starts).  Returns
     the directory in use."""
     import jax

@@ -663,8 +663,8 @@ class _AutoCheckpoint:
 
 
 def _program_label(program, fetch_names) -> str:
-    """Stable human-greppable identity for cost gauges / tracetool
-    ("MFU per program"): the program id in the verifier's provenance
+    """Stable human-greppable identity for cost gauges ("MFU per
+    program"): the program id in the verifier's provenance
     style plus the first fetch target as a hint."""
     hint = f":{fetch_names[0]}" if fetch_names else ""
     return f"program#{id(program) & 0xFFFFFF:06x}{hint}"
@@ -1025,10 +1025,6 @@ class Executor:
                         h.block_until_ready()  # sync-ok: dispatch-ahead throttle
                 if ckpt is not None:
                     ckpt.on_step()
-                # step boundary, off the dispatch call itself: finish
-                # an `obs.profile_window(steps=N)` whose budget is spent
-                # (a single attribute check when none is armed)
-                obs.devprof.maybe_autostop()
                 if step_callback is not None:
                     step_callback(self._step,
                                   step if ckpt is None
@@ -1043,9 +1039,6 @@ class Executor:
             stat_set("in_flight_steps", 0)
             if monitor is not None:
                 monitor.stop()
-            # short pass: a `steps=N` window outlived the loop; finish
-            # it so the capture is never left armed
-            obs.devprof.maybe_autostop(end_of_pass=True)
             if telemetry is not None:
                 telemetry.close()
         if ckpt is not None:
@@ -1433,9 +1426,6 @@ class Executor:
 
             entry.fn_compiled, entry.cost = compile_entry_with_cache(
                 entry, (mutable_state, const_state, feed_arrays, seed))
-        # devprof window bookkeeping: a single attribute check when
-        # no capture window is armed; never syncs, never transfers
-        obs.devprof.note_dispatch(entry.label)
         try:
             if entry.fn_compiled is not None:
                 try:

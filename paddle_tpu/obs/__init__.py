@@ -23,17 +23,16 @@ One layer, four surfaces:
   each compile-cache miss walks the AOT executable's HLO to fold
   per-instruction FLOPs/bytes/fusions/relayouts back onto source
   Program ops — through the transform pipeline's rewrites — so the
-  whole-program MFU number decomposes into named ops
-  (`tools/tracetool.py top-ops`, BENCH `detail.op_profile`).
+  whole-program MFU number decomposes into named ops; the same maps
+  name the device time `obs.devprof.device_time` reads from a chip's
+  trace (the benchmark's per-layer breakdown, `benchmark/`).
 
 * **Snapshot** (`obs.snapshot()`): one structured export — span
   summary + every profiler timer/counter + the cost gauges + the
   per-op profiles — tagged with this host's process index
   (`all_hosts=True` gathers every host's tables into one merged
-  view), embedded by bench.py in BENCH JSON `detail.obs` and by
-  `obs.export_trace` in the trace file's otherData (so
-  `tools/tracetool.py` can attribute stalls and report MFU from the
-  trace alone).
+  view), embedded by `obs.export_trace` in the trace file's
+  otherData so the one file carries its own tables.
 
 Enable/disable at runtime (`obs.enable()` / `obs.disable()`); disabled
 tracing is a single attribute check per site — the async hot path's
@@ -59,7 +58,7 @@ from .tracing import NULL_SPAN, TRACER, Tracer  # noqa: F401
 
 __all__ = ["span", "add_span", "new_flow", "attach_flow", "current_span",
            "enable", "disable", "enabled", "reset", "snapshot",
-           "export_trace", "op_profile", "profile_window", "roofline",
+           "export_trace", "op_profile",
            "mem_profile", "memory_ledger", "publish_mem_oom",
            "bisect_nonfinite", "numerics_report",
            "cost", "devprof", "memprof", "numerics", "opprof",
@@ -70,11 +69,8 @@ __all__ = ["span", "add_span", "new_flow", "attach_flow", "current_span",
 
 
 def enable(reset: bool = False) -> None:
-    """Turn span recording on (optionally clearing the buffer along
-    with any completed devprof captures — see reset())."""
+    """Turn span recording on (optionally clearing the buffer)."""
     TRACER.enable(reset=reset)
-    if reset:
-        devprof.reset()
 
 
 def disable() -> None:
@@ -86,12 +82,8 @@ def enabled() -> bool:
 
 
 def reset() -> None:
-    """Clear the span buffer and drop counter (enabled state kept).
-    Completed devprof captures are cleared too — a fresh trace must
-    not merge device tracks from a window profiled before the
-    reset."""
+    """Clear the span buffer and drop counter (enabled state kept)."""
     TRACER.reset()
-    devprof.reset()
 
 
 def span(name: str, flow=None, attrs: Optional[dict] = None):
@@ -131,31 +123,6 @@ def op_profile(program=None, label: Optional[str] = None) \
     prog_id = getattr(program, "prog_id", None) \
         if program is not None else None
     return opprof.profile_for(prog_id=prog_id, label=label)
-
-
-def profile_window(steps: Optional[int] = None,
-                   label: Optional[str] = None):
-    """Arm a bounded *measured* device-time capture window
-    (obs/devprof.py): `jax.profiler` trace around the next dispatches,
-    then `devprof.device_time` on what it wrote: device seconds by the
-    program's own names (`result["by_name"]`).  Use as a
-    context manager, or pass `steps=N` and let the Executor training
-    loop auto-stop it."""
-    return devprof.profile_window(steps=steps, label=label)
-
-
-def roofline(program=None, label: Optional[str] = None) \
-        -> Optional[Dict[str, Any]]:
-    """The measured roofline for `program` (matched by the SOURCE
-    prog_id the window's join attributed time to), for an exact window
-    `label`, or the most recent window when neither is given: per-op
-    measured time vs opprof FLOPs/bytes -> achieved-FLOPs/achieved-BW
-    and a compute-/memory-/relayout-bound verdict.  None until a
-    profile_window has finished."""
-    prog_id = getattr(program, "prog_id", None) \
-        if program is not None else None
-    res = devprof.result_for(prog_id=prog_id, label=label)
-    return res.get("roofline") if res else None
 
 
 def mem_profile(program=None, label: Optional[str] = None) \
@@ -302,7 +269,6 @@ def snapshot(all_hosts: bool = False) -> Dict[str, Any]:
         "spans": TRACER.summary(),
         "cost": cost.snapshot(),
         "op_profile": opprof.snapshot(),
-        "devprof": devprof.snapshot(),
         "memory": memprof.snapshot(),
         "numerics": numerics.snapshot(),
         **local,
@@ -498,8 +464,8 @@ def telemetry_epoch_refresh() -> None:
 
 def export_trace(path: str, include_snapshot: bool = True) -> int:
     """Write the recorded spans as Chrome-trace/Perfetto JSON.  The
-    snapshot rides in otherData so tracetool can summarize MFU and
-    stall attribution from the one file.  The device's timeline is the
+    snapshot rides in otherData, so the one file carries MFU and
+    stall attribution.  The device's timeline is the
     profiler's own trace, where the Executor's stages appear as `pt.*`
     annotations (`profiler.stage`).  Returns the span ("X") event
     count."""

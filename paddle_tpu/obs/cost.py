@@ -1,7 +1,7 @@
 """XLA cost attribution: per-executable FLOPs/bytes -> live MFU gauges.
 
 Every perf item on the ROADMAP is blocked on measurement: the MFU gap
-wants a live number instead of the hand-computed formulas in bench.py,
+wants a live number beside the benchmark's FLOPs formulas,
 and the quantized-collectives item (EQuARX, arxiv 2506.17615) needs
 per-collective bytes-on-wire counters to prove a win.  This module
 supplies both seams:
@@ -19,8 +19,7 @@ supplies both seams:
 * **Live utilization gauges** (`ProgramCost.observe_dispatch`): the
   measured inter-dispatch interval (steady-state step time — no sync,
   no transfer) combines with the cached FLOPs/bytes into `mfu_pct` and
-  `hbm_bw_pct` per program, visible in `obs.snapshot()` and embedded
-  by bench.py in BENCH JSON `detail.obs`.
+  `hbm_bw_pct` per program, visible in `obs.snapshot()`.
 
 * **Bytes-on-wire counters** (`record_collective`): the collective op
   lowerings (ops/collective_ops.py) record the logical payload bytes
@@ -47,8 +46,8 @@ import time
 import warnings
 from typing import Any, Dict, List, Optional, Tuple
 
-# per-chip peaks by `jax.devices()[0].device_kind` (bench.py and
-# chip_smoke.py read this table — one definition)
+# per-chip peaks by `jax.devices()[0].device_kind` (chip_smoke.py and
+# tools/aot_analysis.py read this table)
 DEVICE_PEAKS: Dict[str, Dict[str, Any]] = {
     "TPU v5 lite": {
         "flops": 197e12, "hbm_bps": 819e9,
@@ -80,9 +79,8 @@ def device_class() -> str:
 def require_chip():
     """`(device, peak row)` of the first device when it is a TPU the
     peak table knows; RuntimeError otherwise.  The entry points that
-    measure or prove something on the chip (bench.py, chip_smoke.py)
-    call this first: JAX itself falls back to the CPU without a chip
-    and exits 0."""
+    prove something on the chip (chip_smoke.py) call this first: JAX
+    itself falls back to the CPU without a chip and exits 0."""
     import jax
 
     d = jax.devices()[0]
@@ -189,7 +187,7 @@ class ProgramCost:
 
 
 # bounded registry of every ProgramCost this process created, for
-# obs.snapshot() / tracetool "MFU per program"; insertion-ordered so
+# obs.snapshot()'s "MFU per program"; insertion-ordered so
 # eviction drops the oldest program first
 _PROGRAMS: "collections.OrderedDict[str, ProgramCost]" = \
     collections.OrderedDict()

@@ -39,10 +39,6 @@ async checkpoints all contend over, and the one whose failure mode
   re-raising; the telemetry watchdog's `hbm_pressure` rule flips
   `/healthz` when utilization crosses the threshold or headroom drops
   below the next program's static temp requirement.
-
-stdlib-only ON PURPOSE (the tracing/opprof/devprof idiom):
-`tools/tracetool.py mem` loads this module by file path and can
-profile a raw HLO dump in environments without jax.
 """
 
 from __future__ import annotations
@@ -500,8 +496,7 @@ def device_memory_stats() -> Optional[dict]:
     the fullest device beside the sums — `fullest_frac` and
     `min_headroom_bytes`, because a sum hides one full device among
     empty ones and a program's temp must fit on EACH device.  None when
-    the backend doesn't report stats (CPU) or jax is absent (tracetool
-    path-loaded usage)."""
+    the backend doesn't report stats (CPU) or jax is absent."""
     fn = _DEVICE_STATS_FN[0]
     if fn is not None:
         try:
@@ -621,16 +616,6 @@ def memory_ledger() -> dict:
         if isinstance(peak, (int, float)):
             doc["peak_bytes"] = max(doc["peak_bytes"], int(peak))
     return doc
-
-
-def reset_ledger() -> None:
-    with _LEDGER_LOCK:
-        _ENTRIES.clear()
-        _SOURCES.clear()
-        _LEDGER_PEAK[0] = 0
-        _HBM_PEAK[0] = 0
-        _MEM_SERIES.clear()
-    _DEVICE_STATS_FN[0] = None
 
 
 def reset_peak() -> None:

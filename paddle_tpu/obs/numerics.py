@@ -47,11 +47,6 @@ Profiler stat table (asserted complete by tests/test_numerics.py):
 | `loss_scale`                    | gauge   | current AMP dynamic scale   |
 | `loss_scale_decr_total`         | counter | observed scale decrements   |
 | `numerics_drain_ms`             | timer   | stats materialization time  |
-
-stdlib-only ON PURPOSE (the tracing/opprof/devprof/memprof idiom):
-`tools/tracetool.py numerics` loads this module by file path and the
-pure helpers (`parse_mode`, `fold_stats`, `first_nonfinite`) run with
-no jax/numpy import.
 """
 
 from __future__ import annotations
@@ -172,8 +167,7 @@ def note_loss_scale(ref: Any, step: int) -> None:
 def fold_stats(keys: List[tuple], rows: List[List[float]]) \
         -> Tuple[List[dict], Dict[str, float]]:
     """Pure fold of one dispatch's (keys, materialized rows) into
-    per-op row dicts + the health-gauge dict.  Stdlib-only so the
-    tracetool selftest exercises the attribution with plain lists."""
+    per-op row dicts + the health-gauge dict, on plain lists."""
     ops: List[dict] = []
     health: Dict[str, float] = {}
     for key, row in zip(keys, rows):
@@ -189,22 +183,6 @@ def fold_stats(keys: List[tuple], rows: List[List[float]]) \
             "l2": float(row[COL_L2]),
         })
     return ops, health
-
-
-def first_nonfinite(keys: List[tuple], rows: List[List[float]]) \
-        -> Optional[dict]:
-    """First op row in program order with a non-finite count (health
-    rows skipped), or None when the dispatch was clean.  Pure."""
-    for i, (key, row) in enumerate(zip(keys, rows)):
-        if key[0] != KIND_OP:
-            continue
-        if row[COL_NAN] + row[COL_INF] > 0:
-            return {"index": i, "provenance": key[1], "var": key[2],
-                    "nan_count": int(row[COL_NAN]),
-                    "inf_count": int(row[COL_INF]),
-                    "absmax": float(row[COL_ABSMAX]),
-                    "l2": float(row[COL_L2])}
-    return None
 
 
 def _agg_row(key: Tuple[str, str]) -> dict:

@@ -31,10 +31,6 @@ graph-node-level cost attribution:
   rewriting passes append `[pass=<name>]` tags — so the table answers
   "which op still relayouts after NHWC" directly, against source-op
   identities the user can grep in their build script.
-
-stdlib-only ON PURPOSE (the tracing.py idiom): `tools/tracetool.py
-top-ops` loads this module by file path and can profile a raw HLO dump
-in environments without jax.
 """
 
 from __future__ import annotations
@@ -582,7 +578,7 @@ def profile_hlo_text(text: str, label: str = "",
 
     # instruction -> key for EVERY top-level instruction (zero-cost ops
     # included): obs/devprof.py joins the profiler's event names to
-    # these maps.  `instr_prov` keeps the source op's identity (the
+    # `instr_name`.  `instr_prov` keeps the source op's identity (the
     # cost rows' and obs/memprof.py's key); `instr_name` is
     # `scope_name`'s `(phase, path)`, which a functional step has too.
     instr_prov = _join_map(instrs, fused_comps, prov_key)
@@ -648,7 +644,8 @@ def trim_profile(profile: dict, k: int = 12) -> dict:
     keep = top_ops(profile, k)
     unattr = [r for r in profile.get("rows", [])
               if r["op"] == UNATTRIBUTED]
-    # instr_prov is join plumbing for obs/devprof.py, not snapshot data
+    # the instruction maps are join plumbing (obs/devprof.py,
+    # obs/memprof.py), not snapshot data
     out = {kk: v for kk, v in profile.items()
            if kk not in ("rows", "instr_prov", "instr_name")}
     out["rows"] = [_round_row(r) for r in keep + unattr]

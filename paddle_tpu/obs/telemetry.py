@@ -17,8 +17,7 @@ Three pieces:
   seconds.  Cumulative counters are stored as per-sample DELTAS,
   gauges as levels; memory is fixed (`capacity` points per series,
   `max_series` series) and overflow is counted, never silent.  The
-  sampler's own overhead is a timer (`telemetry_sample_ms`) so the
-  bench_diff gate can hold it down.
+  sampler's own overhead is a timer (`telemetry_sample_ms`).
 
 * **Export** — `prometheus_text()` renders the canonical scrape format
   (counters as cumulative `paddle_tpu_*` totals, gauges as levels) and
@@ -40,13 +39,11 @@ Three pieces:
   an artifacts dir — rate-limited, and GC'd with the checkpoint
   retention idiom (keep newest N, sweep half-written tmp dirs).
 
-stdlib-only and tracetool-loadable by file path (the `tracing.py` /
-`opprof.py` idiom): nothing at module level imports jax or
-paddle_tpu.  In-process wiring (profiler/cost sources, the HTTP
-attach on `train_from_dataset` / `serving.Engine`) lives in
-`paddle_tpu.obs.start_telemetry`; `tools/tracetool.py metrics` replays
-the rules over a saved JSON dump with `series_stats` / `replay_rules`
-below.
+Nothing at module level imports jax or paddle_tpu.  In-process
+wiring (profiler/cost sources, the HTTP attach on `train_from_dataset`
+/ `serving.Engine`) lives in `paddle_tpu.obs.start_telemetry`;
+`series_stats` / `replay_rules` below read and replay a saved JSON
+dump.
 """
 
 from __future__ import annotations
@@ -76,7 +73,6 @@ GAUGE_STATS = frozenset({
     "serving_kv_bytes",
     "ring_occupancy", "ring_occupancy_max",
     "in_flight_steps", "in_flight_steps_max",
-    "devprof_attributed_pct",
     "loss_scale", "nan_inf_first_step",
 })
 # timer-table entries written with time_set (per-epoch gauges), not
@@ -194,8 +190,8 @@ class MetricStore:
 
 # ---------------------------------------------------------------------------
 # Watchdog rules.  Each rule is `fn(view, cfg) -> Optional[reason]` over
-# the series view (vals/last/names) — pure, so tracetool can replay them
-# over a saved dump with no live collector.
+# the series view (vals/last/names) — pure, so `replay_rules` can replay
+# them over a saved dump with no live collector.
 # ---------------------------------------------------------------------------
 
 DEFAULT_THRESHOLDS: Dict[str, float] = {
@@ -704,7 +700,7 @@ def write_standalone_bundle(artifacts_dir: str, rule: str, reason: str,
     """Minimal flight bundle with no live collector (the executor's
     OOM catch when telemetry is not running): the given JSON payloads
     plus reason.json, published with the same atomic tmp-dir +
-    os.replace protocol so tracetool reads it like any other bundle.
+    os.replace protocol as any other bundle.
     Returns the bundle path, or None on any failure — forensics never
     raise."""
     if not artifacts_dir:
@@ -746,9 +742,7 @@ def default_sample_s() -> float:
 def default_sources() -> Callable[[], Dict[str, Any]]:
     """The in-process source bundle: profiler counter/timer tables +
     obs.cost gauges + serving latency percentiles.  Requires the
-    paddle_tpu package — NOT available when this module is loaded by
-    file path (inject scripted sources instead, as the tracetool
-    selftest does)."""
+    paddle_tpu package (tests inject scripted sources instead)."""
     from .. import profiler
     from . import cost
 
@@ -806,9 +800,6 @@ def default_sources() -> Callable[[], Dict[str, Any]]:
                 1.0 if _qc.mode() == "int8" else 0.0
         except Exception:  # noqa: BLE001 - gauge is optional
             pass
-        # devprof's capture stats need no extra source: _publish writes
-        # devprof_capture_ms / devprof_attributed_pct into the profiler
-        # tables folded above (attributed_pct is a level via GAUGE_STATS)
         return {"counters": profiler.get_int_stats(),
                 "timers_ms": profiler.get_time_stats(),
                 "gauges": gauges}
@@ -1024,7 +1015,7 @@ def prometheus_text(collector: Collector) -> str:
 
 def series_stats(doc: Dict[str, Any]) -> List[dict]:
     """Per-metric min/mean/max/last rows from a telemetry JSON dump
-    (the `tracetool metrics` table)."""
+    (one row a series)."""
     rows = []
     for name, s in sorted((doc.get("series") or {}).items()):
         vals = [p[1] for p in s.get("points", [])]

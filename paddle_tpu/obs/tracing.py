@@ -29,10 +29,6 @@ module is the substrate:
 
 * **Bounded buffer** — a long traced run cannot grow host memory
   without limit; overflow is counted (`dropped`), never silent.
-
-stdlib-only ON PURPOSE: `tools/tracetool.py` loads this module by file
-path (the tpulint idiom) so trace tooling runs in environments without
-jax or paddle_tpu installed.
 """
 
 from __future__ import annotations

@@ -235,13 +235,13 @@ def _layout_adapted(fn, op: Operator):
 # Every op lowers inside jax.named_scope(op_provenance(op)), so each
 # HLO instruction XLA emits for it carries the source op in its
 # metadata op_name — the seam obs.op_profile folds per-instruction
-# FLOPs/bytes back through, and obs.devprof joins MEASURED per-thunk
-# device time back through (profiler event name -> HLO instruction ->
-# this op_name).  Transform passes stamp `op_provenance` attrs on
+# FLOPs/bytes back through, and obs.devprof joins MEASURED device
+# time back through (profiler event name -> HLO instruction -> this
+# op_name).  Transform passes stamp `op_provenance` attrs on
 # rewritten clones (with the SOURCE program's identity plus a
 # [pass=...] tag); un-transformed ops compute it from their own ids.
-# Renaming this scope format breaks BOTH attributions at once — the
-# tracetool selftest and tests/test_devprof.py pin it.
+# Renaming this scope format breaks BOTH attributions at once —
+# tests/test_opprof.py and tests/test_devprof.py pin it.
 
 def op_provenance(op: Operator) -> str:
     """Greppable provenance string for `op`

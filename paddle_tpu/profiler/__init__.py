@@ -380,7 +380,7 @@ class stage:
     """The one instrument of a site that does a named piece of work:
     the with-block is a `jax.profiler.TraceAnnotation` called
     `pt.<name>`, so that whoever opened a profiler trace
-    (`jax.profiler.start_trace`, the benchmark, `obs.profile_window`)
+    (`jax.profiler.start_trace`, the benchmark)
     finds the stage in it on the device's clock; on exit its wall time
     goes onto the millisecond timer `timer` and, when span tracing is
     on, into the obs trace as a span called `name` (with `attrs`).

@@ -87,7 +87,7 @@ Time stats (get_time_stats, milliseconds):
 
 Latency percentiles are host-side only (they need the full per-request
 distribution, which a counter table cannot carry): a bounded reservoir
-per metric name, drained by `latency_stats()` for bench.py's p50/p99.
+per metric name, drained by `latency_stats()` as p50/p99.
 Reservoir names in use: `serving_request_ms` (submit -> response),
 `serving_prefill_chunk_ms` (host wall time per chunked-prefill chunk),
 and `serving_ttft_ms` (admission -> first token, recorded when the

@@ -5,16 +5,14 @@ program, not the Program IR: the transformed trunk must carry NHWC conv
 dimension numbers and zero interior activation transposes.  These
 helpers trace a Program's forward lowering to a jaxpr (shapes only, no
 device work) and classify what actually came out — used by
-tests/test_transforms.py for the jaxpr assertions and by bench.py for
-the `detail.layout` block.
+tests/test_transforms.py for the jaxpr assertions.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import jax
-import numpy as np
 
 
 def _specs_for(program, feed_shapes: Dict[str, tuple]):
@@ -112,23 +110,3 @@ def conv_layouts(closed_jaxpr) -> List[str]:
         out.append("NHWC" if dn.lhs_spec[1] == rank - 1 else "NCHW")
     return out
 
-
-def layout_report(program, feed_shapes: Dict[str, tuple],
-                  fetch_names: List[str],
-                  transform_stats: Optional[dict] = None) -> dict:
-    """One-stop report for bench.py `detail.layout` and the tests."""
-    jaxpr = trace_forward(program, feed_shapes, fetch_names)
-    tr = transpose_report(jaxpr)
-    convs = conv_layouts(jaxpr)
-    layout = "NHWC" if convs and all(c == "NHWC" for c in convs) else \
-        ("mixed" if any(c == "NHWC" for c in convs) else "NCHW")
-    rep = {
-        "layout": layout,
-        "convs_total": len(convs),
-        "convs_nhwc": int(np.sum([c == "NHWC" for c in convs])),
-        "interior_transposes": tr["interior"],
-        "boundary_transposes": tr["boundary"],
-    }
-    if transform_stats:
-        rep["ops_rewritten"] = dict(transform_stats)
-    return rep

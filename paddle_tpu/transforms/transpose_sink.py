@@ -1,8 +1,8 @@
 """transpose_sink: sink transpose2 ops through elementwise chains and
 cancel inverse pairs.
 
-Why: the measured roofline (obs.roofline, PR 12) verdicts relayout-
-bound ops — time spent permuting HBM instead of computing.  The
+Why: relayout-bound ops spend their time permuting HBM instead of
+computing.  The
 biggest source in user graphs is NCHW-external boundaries built with
 explicit `transpose2` ops: NCHW -> NHWC -> (elementwise work) -> NCHW
 chains where the two permutes bracket ops that do not care about

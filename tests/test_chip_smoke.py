@@ -239,7 +239,7 @@ def test_entry_point_imports_resolve():
     import importlib
 
     scripts = [os.path.join(REPO, f) for f in
-               ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+               ("chip_smoke.py", "__graft_entry__.py")]
     for sub in ("tools", "examples"):
         scripts += glob.glob(os.path.join(REPO, sub, "*.py"))
     missing = []
@@ -263,17 +263,3 @@ def test_entry_point_imports_resolve():
                                    f"{alias.name}")
     assert not missing, missing
 
-
-def test_bench_collective_mode_runs_on_the_cpu_mesh():
-    """`bench.py --mode collective` below its chip check, on the
-    8-device CPU mesh: both shard_maps trace, compile and agree on
-    the shape of the answer.  (The times it returns are CPU times and
-    go nowhere.)"""
-    import jax.numpy as jnp
-
-    import bench
-
-    det = bench.bench_collective(jax, jnp)
-    assert det["devices"] == len(jax.devices())
-    assert len(det["sizes"]) == 4
-    assert det["wire_reduction_x"] > 1.0

@@ -5,21 +5,18 @@
 * `devprof.device_time` on a recorded v5e trace (tests/data/devprof):
   the table sums to the op time, the named share, the flash kernels'
   time equal to their events'.
+* `devprof.device_time` on synthetic traces, for what the one-chip
+  recording does not hold: several chips, nested events, clipping at
+  the window's edge, several executables, a window named by a host
+  span, unmatched instructions.
 * `profiler.stage`: `pt.executor.*` annotations in a `jax.profiler`
   trace nobody but JAX opened, the timers advancing as before, and
   nothing left behind outside a session.
-* The CPU backend's thunk join: containers excluded from the measured
-  denominator, unknown thunks land in an EXPLICIT unattributed bin.
-* End-to-end (acceptance): a profiled window over the transformed toy
-  ResNet block attributes >=80% of measured device time to source
-  Program ops — asserted against the real jax.profiler capture under
-  JAX_PLATFORMS=cpu.
 """
 
 import gzip
 import os
 import re
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -35,29 +32,6 @@ from paddle_tpu import profiler
 from paddle_tpu.obs import devprof, opprof
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
-import tracetool  # noqa: E402
-
-
-@pytest.fixture(autouse=True)
-def _restore_flag():
-    yield
-    paddle_tpu.set_flags({"FLAGS_graph_transforms": "on"})
-
-
-def _resnet_block_program():
-    main, startup = framework.Program(), framework.Program()
-    with framework.program_guard(main, startup), unique_name.guard():
-        x = fluid.data("image", [2, 3, 16, 16], "float32")
-        a = fluid.layers.conv2d(x, 8, 3, padding=1, bias_attr=False)
-        a = fluid.layers.batch_norm(a, act="relu")
-        b = fluid.layers.conv2d(a, 8, 3, padding=1, bias_attr=False)
-        b = fluid.layers.batch_norm(b)
-        s = fluid.layers.conv2d(x, 8, 1, bias_attr=False)
-        s = fluid.layers.batch_norm(s)
-        y = fluid.layers.relu(fluid.layers.elementwise_add(s, b))
-        out = fluid.layers.reduce_mean(y)
-    return main, startup, out
 
 
 # ---------------------------------------------------------------------------
@@ -269,6 +243,107 @@ class TestDeviceTimeOnChipTrace:
 
 
 # ---------------------------------------------------------------------------
+# device_time on synthetic traces (`read_trace`'s documented shape)
+# ---------------------------------------------------------------------------
+
+PLANE = "/device:TPU:0"
+
+
+def _plane(ops, modules=(("jit_step", 0, 1000),)):
+    return {"ops": list(ops), "modules": list(modules)}
+
+
+def _exe(instr_name, module="jit_step"):
+    """An opprof profile as `device_time` reads it."""
+    return {"module": module, "instr_name": instr_name}
+
+
+def _ns(n):
+    return n / 1e9
+
+
+# case -> (devices, host events, executables, window_ns, expected fields)
+SYNTHETIC = {
+    # each chip's time, then the mean over chips
+    "two_chips_mean": (
+        {PLANE: _plane([("fusion.1", 0, 100)]),
+         "/device:TPU:1": _plane([("fusion.1", 0, 300)])},
+        [], {"step": _exe({"fusion.1": ("fwd", "mlp")})}, None,
+        {"chips": 2, "window_ns": (0, 300), "op_s": _ns(200),
+         "by_name": {("fwd", "mlp"): _ns(200)}, "unattributed": {}}),
+    # a while loop's own time leaves out the body ops nested in it
+    "while_excludes_its_body": (
+        {PLANE: _plane([("while.1", 0, 100), ("fusion.2", 10, 30),
+                        ("fusion.3", 50, 80)])},
+        [], {"step": _exe({"while.1": ("fwd", "loop"),
+                           "fusion.2": ("fwd", "loop/a"),
+                           "fusion.3": ("fwd", "loop/b")})}, None,
+        {"chips": 1, "op_s": _ns(100),
+         "by_name": {("fwd", "loop"): _ns(50), ("fwd", "loop/a"): _ns(20),
+                     ("fwd", "loop/b"): _ns(30)},
+         "busy": {PLANE: [(0, 100)]}}),
+    # ops across the window's edges are clipped to it; ops outside drop
+    "edge_ops_are_clipped": (
+        {PLANE: _plane([("fusion.1", 0, 100), ("fusion.2", 120, 160),
+                        ("fusion.3", 190, 260), ("fusion.4", 300, 350)])},
+        [], {"step": _exe({"fusion.1": ("fwd", "a"),
+                           "fusion.2": ("fwd", "b"),
+                           "fusion.3": ("fwd", "c"),
+                           "fusion.4": ("fwd", "d")})}, (50, 200),
+        {"window_ns": (50, 200), "op_s": _ns(100),
+         "by_name": {("fwd", "a"): _ns(50), ("fwd", "b"): _ns(40),
+                     ("fwd", "c"): _ns(10)},
+         "busy": {PLANE: [(50, 100), (120, 160), (190, 200)]}}),
+    # one instruction name in two executables: each event is joined
+    # through the map of the module that ran it
+    "modules_join_their_own_map": (
+        {PLANE: _plane([("fusion.1", 10, 50), ("fusion.1", 110, 170)],
+                       modules=[("jit_a", 0, 100), ("jit_b", 100, 200)])},
+        [], {"A": _exe({"fusion.1": ("fwd", "a")}, module="jit_a"),
+             "B": _exe({"fusion.1": ("fwd", "b")}, module="jit_b")}, None,
+        {"by_name": {("fwd", "a"): _ns(40), ("fwd", "b"): _ns(60)},
+         "programs": {"jit_a": "A", "jit_b": "B"}}),
+    # a window named by a host span is that span's LAST occurrence
+    "window_is_the_last_host_span": (
+        {PLANE: _plane([("fusion.1", 50, 60), ("fusion.2", 250, 270)])},
+        [("pt.window", 0, 100), ("pt.window", 200, 300),
+         ("python", 210, 220)],
+        {"step": _exe({"fusion.1": ("fwd", "early"),
+                       "fusion.2": ("fwd", "late")})}, "pt.window",
+        {"window_ns": (200, 300), "op_s": _ns(20),
+         "by_name": {("fwd", "late"): _ns(20)},
+         "host_spans": {"pt.window": [(0, 100), (200, 300)]}}),
+    # what no map names lands in `unattributed` by instruction family
+    "unmatched_by_family": (
+        {PLANE: _plane([("fusion.1400", 0, 40), ("custom-call.7", 40, 50),
+                        ("dot.2", 50, 80)])},
+        [], {"step": _exe({"custom-call.7": opprof.UNATTRIBUTED,
+                           "dot.2": ("bwd", "proj")})}, None,
+        {"op_s": _ns(80), "by_name": {("bwd", "proj"): _ns(30)},
+         "unattributed": {"fusion": _ns(40), "custom-call": _ns(10)}}),
+}
+
+
+class TestDeviceTimeOnSyntheticTraces:
+    @pytest.mark.parametrize("case", sorted(SYNTHETIC))
+    def test_device_time(self, case, monkeypatch):
+        devices, host, executables, window_ns, want = SYNTHETIC[case]
+        trace = {"devices": devices,
+                 "host": sorted(host, key=lambda s: s[1])}
+        monkeypatch.setattr(devprof, "read_trace", lambda path: trace)
+        table = devprof.device_time("synthetic.xplane.pb", executables,
+                                    window_ns=window_ns)
+        assert (sum(table["by_name"].values())
+                + sum(table["unattributed"].values())
+                == pytest.approx(table["op_s"], rel=1e-9))
+        for key, value in want.items():
+            if key in ("op_s", "by_name", "unattributed"):
+                assert table[key] == pytest.approx(value, rel=1e-9), key
+            else:
+                assert table[key] == value, key
+
+
+# ---------------------------------------------------------------------------
 # profiler.stage: the Executor's stages in the profiler's own trace
 # ---------------------------------------------------------------------------
 
@@ -348,133 +423,3 @@ class TestStage:
         finally:
             obs.disable()
             obs.reset()
-
-
-# ---------------------------------------------------------------------------
-# join on synthetic planes
-# ---------------------------------------------------------------------------
-
-def _selftest_profile():
-    return opprof.profile_hlo_text(
-        tracetool._SELFTEST_HLO, label="synthetic",
-        cost={"flops": 2.0 * 64 * 64 * 128, "bytes_accessed": 1e4})
-
-
-def _synthetic_space():
-    """One thunk line + one unmatched line that must be skipped."""
-    return {"planes": [{"name": "/host:CPU", "lines": [
-        {"name": "tf_XLATfrtCpuClient/3", "events": [
-             {"name": "ThunkExecutor::Execute (wait for completion)",
-              "offset_ps": 0, "duration_ps": 9_000_000, "stats": {}},
-             {"name": "dot.4", "offset_ps": 200_000,
-              "duration_ps": 4_000_000, "stats": {"program_id": 7}},
-             {"name": "relu_fusion", "offset_ps": 4_400_000,
-              "duration_ps": 3_000_000, "stats": {"program_id": 7}},
-             {"name": "all-reduce", "offset_ps": 7_600_000,
-              "duration_ps": 2_000_000, "stats": {"program_id": 7}},
-             {"name": "custom-call.9", "offset_ps": 9_800_000,
-              "duration_ps": 1_000_000, "stats": {"program_id": 7}},
-         ]},
-        {"name": "unrelated-daemon", "events": [
-            {"name": "Sleep", "offset_ps": 0, "duration_ps": 50_000_000,
-             "stats": {}}]},
-    ]}]}
-
-
-class TestJoin:
-    def test_join_tiers_and_explicit_unattributed(self):
-        profiles = {"synthetic": _selftest_profile()}
-        join = devprof.join_events(_synthetic_space(), profiles, runs=2)
-        assert join["runs"] == 2
-        # containers and the skipped daemon line never enter the
-        # measured denominator
-        assert join["measured_ns"] == 10_000.0
-        assert [s["line"] for s in join["skipped_lines"]] \
-            == ["/host:CPU/unrelated-daemon"]
-        ops = join["ops"]
-        # an event is named by its instruction: the join is exact
-        assert ops["program#7/block0/op1:mul"]["time_ns"] == 4_000.0
-        assert ops["program#7/block0/op2:relu[pass=layout_optimize]"][
-            "time_ns"] == 3_000.0
-        # the unknown thunk is binned EXPLICITLY, never silently spread
-        assert ops[devprof.UNATTRIBUTED]["time_ns"] == 1_000.0
-        assert join["attributed_pct"] == pytest.approx(90.0)
-
-    def test_roofline_bounds(self):
-        profiles = {"synthetic": _selftest_profile()}
-        join = devprof.join_events(_synthetic_space(), profiles)
-        roof = devprof.compute_roofline(join, profiles, "cpu-fallback",
-                                        pf=2e11, pb=5e10)
-        rops = {r["op"]: r for r in roof["ops"]}
-        dot = rops["program#7/block0/op1:mul"]
-        assert dot["bound"] == "compute-bound" and dot["mfu_pct"] > 0
-        assert rops[devprof.UNATTRIBUTED]["bound"] == devprof.UNATTRIBUTED
-        assert "layout_optimize" in rops[
-            "program#7/block0/op2:relu[pass=layout_optimize]"]["passes"]
-        # shares sum to ~100 over the measured denominator
-        assert sum(r["share_pct"] for r in roof["ops"]) \
-            == pytest.approx(100.0, abs=0.1)
-
-
-# ---------------------------------------------------------------------------
-# end-to-end: real capture under JAX_PLATFORMS=cpu (acceptance)
-# ---------------------------------------------------------------------------
-
-class TestDevprofEndToEnd:
-    def _capture(self, label, runs=3):
-        main, startup, out = _resnet_block_program()
-        infer = main.clone(for_test=True)
-        paddle_tpu.set_flags(
-            {"FLAGS_graph_transforms": "on,fold_bn=on"})
-        feed = {"image": np.random.RandomState(0).randn(
-            2, 3, 16, 16).astype("float32")}
-        obs.enable(reset=True)
-        scope = Scope()
-        with scope_guard(scope):
-            exe = fluid.Executor()
-            exe.run(startup)
-            # compile (cache miss) OUTSIDE the window: the capture
-            # holds steady-state dispatches only
-            exe.run(infer, feed=feed, fetch_list=[out.name])
-            with obs.profile_window(label=label):
-                for _ in range(runs):
-                    exe.run(infer, feed=feed, fetch_list=[out.name])
-        res = devprof.last_result()
-        assert res is not None and res.get("error") is None, \
-            f"capture failed: {res and res.get('error')}"
-        return infer, res
-
-    def test_window_attributes_measured_device_time(self):
-        infer, res = self._capture("e2e.attribution")
-        # ACCEPTANCE: >=80% of measured device time resolves to source
-        # Program ops of the transformed toy ResNet
-        assert res["attributed_pct"] >= 80.0, res["ops"].keys()
-        assert res["measured_ms"] > 0.0 and res["events"] > 0
-        # any remainder is binned explicitly, never silently dropped
-        if res["attributed_pct"] < 100.0:
-            assert devprof.UNATTRIBUTED in res["ops"]
-        # time landed on ops of THIS program, tagged with their passes
-        assert infer.prog_id in res["prog_ids"]
-        roof = res["roofline"]
-        assert roof["ops"] and all(
-            r["bound"] in ("compute-bound", "memory-bound",
-                           "relayout-bound", "unknown",
-                           devprof.UNATTRIBUTED)
-            for r in roof["ops"])
-        assert any(r["passes"] for r in roof["ops"])
-        # every window dispatch was logged and runs were seen
-        assert len(res["dispatches"]) == 3 and res["runs"] >= 1
-        # the capture published its gauges for telemetry/bench_diff
-        from paddle_tpu import profiler
-        assert profiler.get_int_stats().get(
-            "devprof_attributed_pct") == int(round(res["attributed_pct"]))
-        assert obs.snapshot()["devprof"]["windows"]
-
-    def test_obs_roofline_api_matches_program(self):
-        infer, res = self._capture("e2e.roofline")
-        roof = obs.roofline(infer)
-        assert roof is not None
-        assert roof["attributed_pct"] == pytest.approx(
-            res["attributed_pct"], abs=1e-6)
-        assert obs.roofline(label="e2e.roofline") is not None
-        assert obs.roofline(label="no-such-window") is None

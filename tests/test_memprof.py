@@ -23,13 +23,11 @@
   and shrinks the ledger (`compile_cache_evicted_bytes` counted), the
   ckpt snapshot doubling window is a ledger entry for exactly its
   lifetime, KV pages export `serving_kv_pages_in_use`/`serving_kv_bytes`,
-  the Chrome-trace export carries the "C" memory counter track, and
-  the bench_diff gate regresses on an hbm_peak_bytes rise > 5%.
+  and the Chrome-trace export carries the "C" memory counter track.
 """
 
 import json
 import os
-import sys
 import threading
 
 import numpy as np
@@ -42,9 +40,6 @@ from paddle_tpu.fluid import framework, unique_name
 from paddle_tpu.fluid.executor import Scope, scope_guard
 from paddle_tpu.obs import memprof, telemetry
 
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
-import bench_diff  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -637,7 +632,7 @@ class TestFeedRingLedger:
 
 
 # ---------------------------------------------------------------------------
-# surfaces: Chrome counter track + bench_diff gate
+# surfaces: Chrome counter track
 # ---------------------------------------------------------------------------
 
 class TestTraceCounterTrack:
@@ -659,20 +654,3 @@ class TestTraceCounterTrack:
         assert counters, "no memory counter track in the trace"
         assert any("scope_bytes" in e["args"] for e in counters)
 
-
-class TestBenchDiffGate:
-    def test_hbm_peak_rise_regresses_wiggle_passes(self):
-        base = bench_diff._synthetic(46.0, 100.0)
-        rise = bench_diff._synthetic(
-            46.0, 100.0, hbm_peak=int(1.10 * (1 << 30)))
-        rows = {r["metric"]: r for r in bench_diff.diff(base, rise)}
-        assert rows["hbm_peak_bytes"]["regressed"]
-        wiggle = bench_diff._synthetic(
-            46.0, 100.0, hbm_peak=int(1.03 * (1 << 30)))
-        rows = {r["metric"]: r for r in bench_diff.diff(base, wiggle)}
-        assert not rows["hbm_peak_bytes"]["regressed"]
-
-    def test_extract_reads_detail_memory(self):
-        doc = bench_diff._synthetic(46.0, 100.0, hbm_peak=123456)
-        assert bench_diff.extract_metrics(doc)["hbm_peak_bytes"] \
-            == 123456.0

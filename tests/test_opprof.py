@@ -7,13 +7,10 @@
   `obs.op_profile(program)` table whose FLOPs sum to the executable's
   own cost_analysis total (normalized exactly; raw estimate within
   tolerance), with >=95% of FLOPs attributed to named Program ops.
-* The orphaned-flow export fix, the all-hosts snapshot, and the
-  bench_diff regression gate.
+* The orphaned-flow export fix and the all-hosts snapshot.
 """
 
 import json
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -26,10 +23,27 @@ from paddle_tpu.fluid.executor import Scope, scope_guard
 from paddle_tpu.obs import opprof
 from paddle_tpu.obs.tracing import Tracer
 
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
-import bench_diff  # noqa: E402
-import tracetool  # noqa: E402
+# a dot, a relu fusion and an all-reduce of one Program, with the
+# op_name metadata the lowering stamps
+_PROGRAM_HLO = """\
+HloModule selftest, entry_computation_layout={(f32[64,128]{1,0})->f32[64,64]{1,0}}
+
+%fused_computation (param_0: f32[64,64]) -> f32[64,64] {
+  %param_0 = f32[64,64]{1,0} parameter(0)
+  %constant.1 = f32[] constant(0)
+  %broadcast.1 = f32[64,64]{1,0} broadcast(f32[] %constant.1), dimensions={}, metadata={op_name="jit(f)/program#7/block0/op2:relu[pass=layout_optimize]/max"}
+  ROOT %maximum.1 = f32[64,64]{1,0} maximum(f32[64,64]{1,0} %param_0, f32[64,64]{1,0} %broadcast.1), metadata={op_name="jit(f)/program#7/block0/op2:relu[pass=layout_optimize]/max"}
+}
+
+ENTRY %main (Arg_0.1: f32[64,128]) -> f32[64,64] {
+  %Arg_0.1 = f32[64,128]{1,0} parameter(0)
+  %constant.9 = f32[128,64]{1,0} constant({...})
+  %transpose.2 = f32[128,64]{0,1} transpose(f32[128,64]{1,0} %constant.9), dimensions={1,0}
+  %dot.4 = f32[64,64]{1,0} dot(f32[64,128]{1,0} %Arg_0.1, f32[128,64]{0,1} %transpose.2), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(f)/program#7/block0/op1:mul/dot_general"}
+  %all-reduce = f32[64,64]{1,0} all-reduce(f32[64,64]{1,0} %dot.4), replica_groups={}, to_apply=%region_0, metadata={op_name="jit(f)/program#7/block0/op3:c_allreduce_sum/psum"}
+  ROOT %relu_fusion = f32[64,64]{1,0} fusion(f32[64,64]{1,0} %all-reduce), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/program#7/block0/op2:relu[pass=layout_optimize]/max"}
+}
+"""
 
 
 @pytest.fixture(autouse=True)
@@ -233,7 +247,7 @@ ENTRY %main (a: bf16[8,8], w: bf16[2,8,8], g: s32[2]) -> f32[8,8] {
         assert "instr_name" not in trimmed and "instr_prov" not in trimmed
 
     def test_program_ops_in_both_maps(self):
-        prof = opprof.profile_hlo_text(tracetool._SELFTEST_HLO)
+        prof = opprof.profile_hlo_text(_PROGRAM_HLO)
         assert prof["instr_prov"]["dot.4"] == "program#7/block0/op1:mul"
         assert prof["instr_name"]["dot.4"] == ("fwd", "mul")
         assert prof["instr_name"]["transpose.2"] == ("fwd", "mul")
@@ -359,13 +373,14 @@ class TestOpProfileEndToEnd:
         assert "op_profile" in snap and snap["op_profile"]
         prof = list(snap["op_profile"].values())[-1]
         assert prof["rows"] and "attributed_flops_pct" in prof
-        # tracetool top-ops reads the same table back from a snapshot
-        # (or trace/BENCH JSON) artifact
-        p = tmp_path / "snap.json"
-        p.write_text(json.dumps({"otherData": {"snapshot": snap}}))
-        profs = tracetool.find_profiles(str(p))
-        assert profs
-        assert tracetool.top_ops_cmd(str(p), 5, "flops", False) == 0
+        # the exported trace carries the same table in its otherData
+        p = tmp_path / "trace.json"
+        obs.export_trace(str(p))
+        with open(p) as f:
+            embedded = json.load(f)["otherData"]["snapshot"]["op_profile"]
+        assert embedded.keys() == snap["op_profile"].keys()
+        rows = list(embedded.values())[-1]["rows"]
+        assert [r["op"] for r in rows] == [r["op"] for r in prof["rows"]]
 
     def test_opprof_env_opt_out(self, monkeypatch):
         monkeypatch.setenv("PADDLE_OBS_OPPROF", "0")
@@ -431,75 +446,3 @@ class TestAllHostsSnapshot:
         mine = snap["hosts"]["0"]
         assert mine["counters"] == snap["counters"]
         assert mine["timers_ms"] == snap["timers_ms"]
-
-
-# ---------------------------------------------------------------------------
-# bench_diff regression gate
-# ---------------------------------------------------------------------------
-
-class TestBenchDiff:
-    def test_selftest_green(self, capsys):
-        assert bench_diff.selftest(verbose=False) == 0
-        capsys.readouterr()
-
-    def test_synthetic_10pct_mfu_regression_exits_nonzero(self,
-                                                          tmp_path):
-        base = bench_diff._synthetic(mfu=42.0, step_ms=100.0)
-        cur = bench_diff._synthetic(mfu=42.0 * 0.9, step_ms=100.0)
-        bp, cp = tmp_path / "base.json", tmp_path / "cur.json"
-        bp.write_text(json.dumps(base))
-        cp.write_text(json.dumps(cur))
-        assert bench_diff.main(["--baseline", str(bp), "--current",
-                                str(cp)]) == 1
-        # the identical pair passes
-        assert bench_diff.main(["--baseline", str(bp), "--current",
-                                str(bp)]) == 0
-
-    def test_cpu_fallback_is_warn_only(self, tmp_path):
-        base = bench_diff._synthetic(mfu=42.0, step_ms=100.0)
-        cur = bench_diff._synthetic(mfu=30.0, step_ms=100.0,
-                                    device_class="cpu-fallback")
-        bp, cp = tmp_path / "base.json", tmp_path / "cur.json"
-        bp.write_text(json.dumps(base))
-        cp.write_text(json.dumps(cur))
-        assert bench_diff.main(["--baseline", str(bp), "--current",
-                                str(cp)]) == 0
-        # --strict escalates the same pair to a failure
-        assert bench_diff.main(["--baseline", str(bp), "--current",
-                                str(cp), "--strict"]) == 1
-
-    def test_committed_baseline_passes_itself(self):
-        baseline = os.path.join(REPO_ROOT, "artifacts",
-                                "bench_baseline.json")
-        assert os.path.exists(baseline), \
-            "artifacts/bench_baseline.json must be committed"
-        assert bench_diff.main(["--baseline", baseline, "--current",
-                                baseline]) == 0
-
-    def test_driver_wrapper_shape_accepted(self, tmp_path):
-        inner = bench_diff._synthetic(mfu=42.0, step_ms=100.0)
-        wrapped = tmp_path / "wrapped.json"
-        wrapped.write_text(json.dumps({"n": 5, "rc": 0,
-                                       "parsed": inner}))
-        assert bench_diff._load(str(wrapped))["metric"] == \
-            "bert_base_pretrain_mfu"
-
-
-# ---------------------------------------------------------------------------
-# tracetool selftest covers the op-profile walk (CI satellite)
-# ---------------------------------------------------------------------------
-
-class TestTracetoolTopOps:
-    def test_opprof_selftest_checks_green(self):
-        checks = tracetool._opprof_selftest_checks()
-        failed = [name for name, ok in checks if not ok]
-        assert not failed, failed
-
-    def test_top_ops_on_raw_hlo_dump(self, tmp_path):
-        p = tmp_path / "dump.hlo.txt"
-        p.write_text(tracetool._SELFTEST_HLO)
-        profs = tracetool.find_profiles(str(p))
-        assert len(profs) == 1
-        prof = next(iter(profs.values()))
-        assert prof["attributed_flops_pct"] >= 95.0
-        assert tracetool.top_ops_cmd(str(p), 5, "flops", True) == 0

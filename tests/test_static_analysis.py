@@ -400,16 +400,11 @@ def test_telemetry_on_hot_path_watchlist():
 
 
 def test_devprof_on_hot_path_watchlist():
-    """ISSUE 12: the devprof capture path is lint-watched — the
-    dispatch hook runs inside every executor.run and the window
-    start/finish sit between profiled steps, so none of
-    them may block on device sync; obs/devprof.py is also in the
-    span-leak watched set (profile_window must always close its
-    window, even when the capture fails)."""
-    watched = set(lint.hot_path_sync.WATCHLIST)
-    for qual in ("note_dispatch", "maybe_autostop",
-                 "DevprofWindow.start", "DevprofWindow.finish"):
-        assert ("paddle_tpu/obs/devprof.py", qual) in watched
+    """obs/devprof.py reads a finished trace after the fact: nothing of
+    it runs on the dispatch path, so no entry of it is on the
+    hot-path watchlist; it stays in the span-leak watched set."""
+    watched = lint.hot_path_sync.WATCHLIST
+    assert not [e for e in watched if e[0] == "paddle_tpu/obs/devprof.py"]
     assert "paddle_tpu/obs/devprof.py" in lint.span_leak.WATCHED
 
 

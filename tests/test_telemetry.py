@@ -454,7 +454,7 @@ class TestFlightRecorder:
         assert reason["errors"] == {}
         series = json.loads((bdir / "series.json").read_text())
         assert series["series"]["step_ms"]["points"][-1][1] == 500.0
-        # the dump replays through the tracetool surface
+        # the dump replays offline through replay_rules
         assert any(f["rule"] == "step_time_spike"
                    for f in replay_rules(series))
 

@@ -1,13 +1,16 @@
 #!/usr/bin/env python
-"""Compile the bench BERT step for v5e without a chip, and analyse it.
+"""Compile the BERT-base pretraining step for v5e without a chip, and
+analyse it.
 
 libtpu describes a TPU topology and compiles for it on any host
-(`jax.experimental.topologies.get_topology_desc`), so the EXACT bench
+(`jax.experimental.topologies.get_topology_desc`), so the EXACT
 computation (BERT-base, batch 32, seq 512, bf16, fused fwd+bwd+AdamW)
 can be compiled FOR v5e in a CPU sandbox and interrogated: XLA's cost
 model (flops, bytes accessed), executable memory stats, and the
-optimized-HLO structure.  Output: artifacts/aot_v5e_analysis*.json plus
-a roofline summary against the v5e peaks of paddle_tpu/obs/cost.py.
+optimized-HLO structure.  The model FLOPs are the benchmark's own count
+(`benchmark/lib/flops.py`).  Output: artifacts/aot_v5e_analysis*.json
+(gitignored) plus a roofline summary against the v5e peaks of
+paddle_tpu/obs/cost.py.
 Nothing here is a measurement: the times in the output are the
 compiler's bounds, not the chip's.
 
@@ -57,9 +60,8 @@ def main():
 
     flash = "--flash" in sys.argv
 
+    from benchmark.lib.flops import bert_fwd_flops
     from paddle_tpu.models import bert
-
-    import bench as bench_mod
 
     tiny = "--tiny" in sys.argv
     remat = "--remat" in sys.argv
@@ -101,7 +103,7 @@ def main():
 
     ca = comp.cost_analysis() or {}
     ma = comp.memory_analysis()
-    model_flops = bench_mod.bert_step_flops(cfg, batch, seq, n_masked)
+    model_flops = 3.0 * bert_fwd_flops(vars(cfg), batch, seq, n_masked)
     xla_flops = float(ca.get("flops", 0.0))
     xla_bytes = float(ca.get("bytes accessed", 0.0))
 
