@@ -31,6 +31,16 @@ def layer_kinds(config: dict) -> list:
             for i in range(config["num_hidden_layers"])]
 
 
+def attn_pattern(config: dict, kind: str, scope: str = "") -> str:
+    """A pattern for the scope paths under the attention sublayer of the
+    layers of `kind` — `linear_attn` of "gdn", `self_attn` of "full" —
+    or under its child `scope`, for the per-layer readers."""
+    at = [str(i) for i, k in enumerate(layer_kinds(config)) if k == kind]
+    sub = "linear_attn" if kind == "gdn" else "self_attn"
+    return (r"(^|/)layers/(" + "|".join(at) + r")/" + sub
+            + ("/" + scope if scope else "") + r"(/|$)")
+
+
 def layers_of(config: dict, kind: str) -> int:
     return layer_kinds(config).count(kind)
 

@@ -75,13 +75,18 @@ def test_kernel_costs_by_hand():
     assert c["bwd"]["bytes"] == rows * (4 * 6 + 4 * 3)
 
 
+def check_manifest(manifest: dict):
+    """This cell's configuration entry in `manifest`, found by name."""
+    [entry] = [c for c in manifest["configs"]
+               if c["name"] == "joyai_llm_flash"]
+    assert sorted(entry["reduced"]) == ["n_routed_experts",
+                                        "num_hidden_layers", "vocab_size"]
+
+
 def test_configuration_keeps_every_published_width():
     config = _real_config()
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
-        entry = next(c for c in json.load(f)["configs"]
-                     if c["name"] == "joyai_llm_flash")
-    assert sorted(entry["reduced"]) == ["n_routed_experts",
-                                        "num_hidden_layers", "vocab_size"]
+        check_manifest(json.load(f))
     published = {
         "hidden_size": 2048, "q_lora_rank": 1536, "kv_lora_rank": 512,
         "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,

@@ -86,17 +86,22 @@ def test_kda_core_cost_by_hand():
     assert real["fwd"]["bytes"] / 819e9 > real["fwd"]["flops"] / 197e12
 
 
+def check_manifest(manifest: dict):
+    """This cell's configuration entry in `manifest`, found by name, and
+    the manifest's limit of form: a `why` is one line of 1 to 200
+    characters."""
+    [entry] = [c for c in manifest["configs"]
+               if c["name"] == "kimi_linear_48b_a3b"]
+    assert sorted(entry["reduced"]) == ["num_experts", "num_hidden_layers",
+                                        "vocab_size"]
+    whys = [e["why"] for e in manifest["configs"] + manifest["workloads"]]
+    assert all(1 <= len(w) <= 200 and w.isprintable() for w in whys)
+
+
 def test_configuration_keeps_every_published_width():
     config = _real_config()
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    entry = next(c for c in manifest["configs"]
-                 if c["name"] == "kimi_linear_48b_a3b")
-    assert sorted(entry["reduced"]) == ["num_experts", "num_hidden_layers",
-                                        "vocab_size"]
-    # the manifest's limit of form: a `why` is one line of 1 to 200 characters
-    whys = [e["why"] for e in manifest["configs"] + manifest["workloads"]]
-    assert all(1 <= len(w) <= 200 and w.isprintable() for w in whys)
+        check_manifest(json.load(f))
     published = {
         "hidden_size": 2304, "q_lora_rank": None, "kv_lora_rank": 512,
         "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,

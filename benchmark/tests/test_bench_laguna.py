@@ -21,10 +21,15 @@ from benchmark.tests import preset_tree
 PRESET = os.path.join(preset_tree.BENCH, "tests", "preset_laguna")
 CELL = "laguna_tiny.ar"
 REAL = "laguna_xs2.ar_s16384"
-NEW_METRICS = {"attn.window_ms", "attn.full_gqa_ms", "attn.rope_gate_ms",
-               "kernel.window_flash_roofline",
-               "kernel.gqa_full_flash_roofline",
-               "kernel.window_grid_live_share", "moe.laguna_layers_ms"}
+SOURCE = "https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json"
+# the cell's own per-layer metrics (PR 38), each with its unit and source
+NEW_METRICS = {"attn.window_ms": ("ms", "device_trace"),
+               "attn.full_gqa_ms": ("ms", "device_trace"),
+               "attn.rope_gate_ms": ("ms", "device_trace"),
+               "kernel.window_flash_roofline": ("%", "device_trace"),
+               "kernel.gqa_full_flash_roofline": ("%", "device_trace"),
+               "kernel.window_grid_live_share": ("share", "program_counter"),
+               "moe.laguna_layers_ms": ("ms", "device_trace")}
 
 TINY = {"hidden_size": 8, "head_dim": 4, "num_key_value_heads": 2,
         "num_hidden_layers": 3, "sliding_window": 3,
@@ -119,25 +124,33 @@ def test_flash_costs_by_hand():
         == r"(^|/)layers/(0|4)/self_attn(/|$)"
 
 
-def test_configuration_keeps_every_published_width():
-    config = _real_config()
-    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    entry = next(c for c in manifest["configs"] if c["name"] == "laguna_xs2")
+def check_manifest(manifest: dict):
+    """This cell's entries in `manifest`, found by name wherever they
+    stand, so that entries appended after them change nothing: the
+    configuration and the cell once each, and the metrics that list
+    exactly this cell PR 38's seven, each once, with its unit and
+    source."""
+    [entry] = [c for c in manifest["configs"] if c["name"] == "laguna_xs2"]
     assert entry["reduced"] == ["num_hidden_layers", "num_experts",
                                 "vocab_size"]
-    assert entry["source"] == config["source"] == (
-        "https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json")
-    cell = next(w for w in manifest["workloads"] if w["name"] == REAL)
+    assert entry["source"] == SOURCE
+    [cell] = [w for w in manifest["workloads"] if w["name"] == REAL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "laguna_xs2", "ar_s16384", 1)
     whys = [e["why"] for e in manifest["configs"] + manifest["workloads"]]
     assert all(1 <= len(w) <= 200 and w.isprintable() for w in whys)
     ours = [m for m in manifest["per_layer"]
             if m.get("workloads") == [REAL]]
-    assert {m["name"] for m in ours} == NEW_METRICS
-    assert manifest["per_layer"][-7:] == ours
-    assert all(m["moves"] == "items_per_s_per_chip" for m in ours)
+    assert sorted(m["name"] for m in ours) == sorted(NEW_METRICS)
+    assert all((m["unit"], m["source"]) == NEW_METRICS[m["name"]]
+               and m["moves"] == "items_per_s_per_chip" for m in ours)
+
+
+def test_configuration_keeps_every_published_width():
+    config = _real_config()
+    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
+        check_manifest(json.load(f))
+    assert config["source"] == SOURCE
     published = {
         "hidden_size": 2048, "intermediate_size": 8192,
         "num_attention_heads": 48, "num_key_value_heads": 8,
@@ -258,7 +271,7 @@ def test_cell_runs_end_to_end(manifest_path, trace, monkeypatch, tmp_path,
     # one: it reads the process's own count, nothing here where no
     # kernel was traced); no device metric comes of a CPU run
     assert metrics["cache.compiles_in_window"]["value"] == 0
-    assert not (NEW_METRICS - {"kernel.window_grid_live_share"}
+    assert not (set(NEW_METRICS) - {"kernel.window_grid_live_share"}
                 | {"device.idle_share"}) & set(metrics)
 
 

@@ -1,12 +1,14 @@
 """The Qwen3-Next additions to the benchmark: `lib/flops_qwen3_next.py`
 against hand counts at a tiny size and against the figures at the
 cell's, the configuration file against the catalog's rule (every
-published width unchanged), the cell's metrics pinned BY NAME, and the
-new builder rehearsed end to end on the CPU at the tiny preset
-benchmark/tests/preset_qwen3_next (its reference comparison and gradient
-check included) — through `run.run_cell`, with files and manifest
-entries only, as the real cell is added."""
+published width unchanged), the cell's manifest entries and metrics
+pinned BY NAME, and the new builder rehearsed end to end on the CPU at
+the tiny preset benchmark/tests/preset_qwen3_next (its reference
+comparison and gradient check included, and the cell's readers over the
+compiled step's own scopes) — through `run.run_cell`, with files and
+manifest entries only, as the real cell is added."""
 
+import collections
 import json
 import math
 import os
@@ -16,11 +18,26 @@ import pytest
 
 from benchmark import run as harness
 from benchmark.lib import flops_qwen3_next as flops
+from benchmark.lib import peaks, scopes
 from benchmark.tests import preset_tree
 
 PRESET = os.path.join(preset_tree.BENCH, "tests", "preset_qwen3_next")
 CELL = "qwen3_next_tiny.ar"
 REAL = "qwen3_next_80b_a3b.ar_s16384"
+SOURCE = ("https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct/blob/main/"
+          "config.json")
+# the cell's own per-layer metrics (PR 46), each with its unit
+NEW_METRICS = {"attn.gdn_ms": "ms", "attn.gdn_pre_ms": "ms",
+               "attn.gdn_core_ms": "ms", "kernel.gdn_core_roofline": "%",
+               "attn.gated_full_ms": "ms"}
+# the accepted metrics that list no cells: every cell reports them
+UNLISTED = {
+    "input.wait_ms_per_step", "cache.compiles_in_window",
+    "cache.persistent_hits", "step.ms_p50", "step.ms_max",
+    "device.idle_share", "device.peak_hbm_gib", "setup.reach_s",
+    "setup.import_s", "setup.param_init_s", "setup.trace_lower_s",
+    "setup.kernel_trace_s", "setup.compile_s", "setup.cache_load_s",
+    "setup.reference_s", "setup.warm_up_s", "setup.named_share"}
 
 TINY = {"hidden_size": 8, "head_dim": 4, "num_attention_heads": 2,
         "num_key_value_heads": 1, "num_hidden_layers": 4,
@@ -103,20 +120,59 @@ def test_scan_cost_counts_keys_once_a_key_head():
     assert real["flops"] / 197e12 < real["bytes"] / 819e9
 
 
-def test_configuration_keeps_every_published_width():
-    config, manifest = _real_config(), _manifest()
-    entry = next(c for c in manifest["configs"]
-                 if c["name"] == "qwen3_next_80b_a3b")
+def test_reader_patterns_take_the_layers_from_the_configuration():
+    config = _real_config()
+    assert flops.attn_pattern(config, "gdn") \
+        == r"(^|/)layers/(0|1|2)/linear_attn(/|$)"
+    assert flops.attn_pattern(config, "gdn", "gdn_core") \
+        == r"(^|/)layers/(0|1|2)/linear_attn/gdn_core(/|$)"
+    assert flops.attn_pattern(config, "full") \
+        == r"(^|/)layers/(3)/self_attn(/|$)"
+    eight = dict(config, num_hidden_layers=8)
+    assert flops.attn_pattern(eight, "full") \
+        == r"(^|/)layers/(3|7)/self_attn(/|$)"
+
+
+def _check_entries(manifest: dict):
+    """The configuration and the cell, once each, found by name."""
+    [entry] = [c for c in manifest["configs"]
+               if c["name"] == "qwen3_next_80b_a3b"]
     assert entry["reduced"] == ["num_hidden_layers", "num_experts",
                                 "vocab_size"]
-    assert entry["source"] == config["source"] == (
-        "https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct/blob/main/"
-        "config.json")
-    cell = next(w for w in manifest["workloads"] if w["name"] == REAL)
+    assert entry["source"] == SOURCE
+    [cell] = [w for w in manifest["workloads"] if w["name"] == REAL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "qwen3_next_80b_a3b", "ar_s16384", 1)
     whys = [e["why"] for e in manifest["configs"] + manifest["workloads"]]
     assert all(1 <= len(w) <= 200 and w.isprintable() for w in whys)
+
+
+def _check_metrics(manifest: dict):
+    """The metrics that list the cell are its five, each once and
+    listing it alone; the metrics that list no cells are the accepted
+    ones — all by name, wherever they stand."""
+    per_layer = manifest["per_layer"]
+    ours = [m for m in per_layer if REAL in m.get("workloads", [])]
+    assert sorted(m["name"] for m in ours) == sorted(NEW_METRICS)
+    assert all(m["workloads"] == [REAL]
+               and m["unit"] == NEW_METRICS[m["name"]]
+               and m["source"] == "device_trace"
+               and m["moves"] == "items_per_s_per_chip" for m in ours)
+    assert {m["name"] for m in per_layer if "workloads" not in m} \
+        == UNLISTED
+
+
+def check_manifest(manifest: dict):
+    """This cell's entries in `manifest`, pinned by name, so that entries
+    appended after them change nothing."""
+    _check_entries(manifest)
+    _check_metrics(manifest)
+
+
+def test_configuration_keeps_every_published_width():
+    config = _real_config()
+    _check_entries(_manifest())
+    assert config["source"] == SOURCE
     published = {
         "decoder_sparse_step": 1, "full_attention_interval": 4,
         "head_dim": 256, "hidden_act": "silu", "hidden_size": 2048,
@@ -154,22 +210,9 @@ def test_configuration_keeps_every_published_width():
 
 
 def test_cell_metrics_by_name():
-    """The configuration and the cell are the last entries of their
-    lists; no per-layer metric lists the cell, so it reports the
-    accepted metrics that list no cells — pinned by name."""
-    manifest = _manifest()
-    assert manifest["configs"][-1]["name"] == "qwen3_next_80b_a3b"
-    assert manifest["workloads"][-1]["name"] == REAL
-    per_layer = manifest["per_layer"]
-    assert not [m for m in per_layer if REAL in m.get("workloads", [])]
-    reported = {m["name"] for m in per_layer if "workloads" not in m}
-    assert reported == {
-        "input.wait_ms_per_step", "cache.compiles_in_window",
-        "cache.persistent_hits", "step.ms_p50", "step.ms_max",
-        "device.idle_share", "device.peak_hbm_gib", "setup.reach_s",
-        "setup.import_s", "setup.param_init_s", "setup.trace_lower_s",
-        "setup.kernel_trace_s", "setup.compile_s", "setup.cache_load_s",
-        "setup.reference_s", "setup.warm_up_s", "setup.named_share"}
+    """The cell reports its five metrics and the accepted metrics that
+    list no cells — pinned by name, not by where they stand."""
+    _check_metrics(_manifest())
 
 
 def test_state_is_625_million_parameters():
@@ -204,6 +247,10 @@ def manifest_path(tmp_path_factory):
                                 "file": "bench/configs/qwen3_next_tiny.json"})
     manifest["workloads"].append({"name": CELL, "config": "qwen3_next_tiny",
                                   "traffic": "tiny_ar", "chips": 1})
+    real = {m["name"]: m for m in _manifest()["per_layer"]}
+    for m in manifest["per_layer"]:
+        if real.get(m["name"], {}).get("workloads") == [REAL]:
+            m["workloads"] = [CELL]
     with open(path, "w") as f:
         json.dump(manifest, f)
     return path
@@ -229,12 +276,36 @@ def tiny_tolerances(monkeypatch):
         for k in reference.GRAD_TOLERANCE})
 
 
+def _scopes_of_the_compiled_step(monkeypatch):
+    """Stands in for the device table a CPU run has not: every
+    instruction of the cell's compiled step one microsecond, under the
+    `(phase, path)` that the program's own name map gives it
+    (`obs.opprof`, which `devprof.device_time` joins the chip's trace
+    to).  Its numbers are no measurement; what it checks is that the
+    readers' patterns find the scopes the program emits, and how those
+    nest."""
+    from paddle_tpu.obs import opprof
+
+    def table(run):
+        names = opprof.profile_hlo_text(run.system._compiled.as_text(),
+                                        label="step")["instr_name"]
+        count = collections.Counter(
+            v for v in names.values() if isinstance(v, tuple))
+        return {"steps": 1, "by_name": {k: n * 1e-6
+                                        for k, n in count.items()}}
+
+    monkeypatch.setattr(scopes, "table", table)
+
+
 @pytest.mark.parametrize("trace", [False, True])
 def test_cell_runs_end_to_end(manifest_path, trace, monkeypatch, tmp_path,
                               tiny_tolerances):
     monkeypatch.setattr(harness, "OUT_DIR", str(tmp_path))
-    result = harness.run_cell(manifest_path, CELL, seed=2 ** 31 + 7,
-                              seconds=0.2, trace=trace)
+    if trace:
+        _scopes_of_the_compiled_step(monkeypatch)
+    result = harness.run_cell(
+        manifest_path, CELL, seed=2 ** 31 + 7, seconds=0.2, trace=trace,
+        peaks=peaks.peaks_for("TPU v5 lite") if trace else None)
     assert result["correct"], (result["checks"], result["reference"])
     assert result["failed"] == 0 and result["attempted"] > 0
     ref = result["reference"]
@@ -249,9 +320,16 @@ def test_cell_runs_end_to_end(manifest_path, trace, monkeypatch, tmp_path,
     if not trace:
         assert set(metrics) == {"items_per_s_per_chip", "setup_s"}
         return
-    # no device metric comes of a CPU run
+    # no device metric comes of a CPU run; the cell's five read the
+    # stand-in table over the compiled step's own scopes
     assert metrics["cache.compiles_in_window"]["value"] == 0
     assert "device.idle_share" not in metrics
+    ms = {name: metrics[name]["value"] for name in NEW_METRICS}
+    assert ms["attn.gdn_ms"] > 0 and ms["attn.gated_full_ms"] > 0
+    assert ms["attn.gdn_pre_ms"] > 0 and ms["attn.gdn_core_ms"] > 0
+    assert ms["attn.gdn_pre_ms"] + ms["attn.gdn_core_ms"] \
+        <= ms["attn.gdn_ms"]
+    assert 0 < ms["kernel.gdn_core_roofline"] <= 100
 
 
 def test_same_seed_same_inputs(manifest_path, tiny_tolerances):
@@ -260,3 +338,22 @@ def test_same_seed_same_inputs(manifest_path, tiny_tolerances):
     assert a["losses"]["warm_up"] == b["losses"]["warm_up"]
     assert a["losses"]["warm_up"] != c["losses"]["warm_up"]
 
+
+def test_new_readers_return_nothing_on_another_configuration():
+    """A reader of this cell asked about a run of another configuration
+    (no `linear_num_value_heads`), or of a run without a device table,
+    returns None and does not raise: the line leaves the metric out."""
+
+    class Run:
+        config = {"hidden_size": 8}
+        trace = None
+        peaks = None
+        system = object()
+
+    base = os.path.join(preset_tree.BENCH, "layers")
+    for name in sorted(NEW_METRICS):
+        module = harness.load_module(os.path.join(base, name + ".py"))
+        assert module.read(Run()) is None, name
+        run = Run()
+        run.config = dict(_real_config())
+        assert module.read(run) is None, name

@@ -88,3 +88,42 @@ def _fb(cost):
 def test_unknown_device_is_an_error():
     with pytest.raises(KeyError, match="no peak numbers"):
         peaks.peaks_for("TPU v9000")
+
+
+def test_gdn_core_roofline_least_time_by_hand():
+    """The least time `kernel.gdn_core_roofline` divides by, at the
+    Qwen3-Next cell's shapes: 1 x 16,384 tokens, 16 key heads and 32
+    value heads of 128 x 128, 3 Gated DeltaNet layers.  Forward reads q
+    and k (a key head) and v (a value head) in bfloat16, g and beta one
+    float32 scalar a value head, and writes o; backward reads those and
+    do and writes the five gradients.  Bound by memory both ways."""
+    from benchmark.lib import flops_qwen3_next
+
+    c, t = config("qwen3_next_80b_a3b"), traffic("ar_s16384")
+    assert (t["batch"], t["seq"]) == (1, 16384)
+    assert flops_qwen3_next.layers_of(c, "gdn") == 3
+    tokens = 16384
+    qk = tokens * 16 * 128 * 2                  # 67,108,864 bytes
+    v = tokens * 32 * 128 * 2                   # 134,217,728
+    gates = tokens * 32 * 2 * 4                 # 4,194,304
+    work = tokens * 32 * 128 * 128
+    cost = flops_qwen3_next.gdn_core_cost(
+        t["batch"], t["seq"], c["linear_num_key_heads"],
+        c["linear_num_value_heads"], c["linear_key_head_dim"],
+        c["linear_value_head_dim"])
+    assert cost["fwd"] == {"flops": 6 * work,
+                           "bytes": 2 * qk + 2 * v + gates}
+    assert cost["bwd"] == {"flops": 12 * work,
+                           "bytes": 4 * qk + 3 * v + 2 * gates}
+    assert 2 * qk + 2 * v + gates == 406_847_488
+    assert 4 * qk + 3 * v + 2 * gates == 679_477_248
+    row = peaks.peaks_for("TPU v5 lite")
+    least = 0.0
+    for phase in ("fwd", "bwd"):
+        s, bound = flops.roofline_seconds(3 * cost[phase]["flops"],
+                                          3 * cost[phase]["bytes"], row)
+        assert bound == "memory"
+        least += s
+    # 3 x 1,086,324,736 bytes at 819 GB/s
+    assert least == pytest.approx(3_258_974_208 / 819e9, rel=1e-12)
+    assert round(least * 1e3, 3) == 3.979
